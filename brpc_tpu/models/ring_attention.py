@@ -35,12 +35,17 @@ from brpc_tpu.parallel.fabric import Fabric
 __all__ = ["ring_attention", "attention_reference"]
 
 _NEG_INF = -1e30
+# The TPU's default matmul precision rounds f32 operands to bf16: on a v5e
+# the dry run's outputs were 3e-3 off a float64 oracle at the default and
+# 1e-6 off at HIGHEST (chip run, PR 21).  Kernel and oracle both ask for
+# full f32 so they can be compared at an f32 tolerance.
+_PRECISION = lax.Precision.HIGHEST
 
 
 def _block_scores(q, k, scale, causal, q_pos, k_pos):
     """Scaled scores of local queries against one KV block (+ causal mask)."""
     # q: [sq, d]  k: [sk, d]  → [sq, sk]; accumulate in f32 on the MXU.
-    s = jnp.einsum("qd,kd->qk", q, k,
+    s = jnp.einsum("qd,kd->qk", q, k, precision=_PRECISION,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         mask = q_pos[:, None] >= k_pos[None, :]
@@ -57,7 +62,7 @@ def _fold_block(acc, s, v):
     correction = jnp.exp(m - m_new)
     l_new = l * correction + jnp.sum(p, axis=-1)
     o_new = o * correction[:, None] + jnp.einsum(
-        "qk,kd->qd", p.astype(v.dtype), v,
+        "qk,kd->qd", p.astype(v.dtype), v, precision=_PRECISION,
         preferred_element_type=jnp.float32)
     return m_new, l_new, o_new
 
@@ -123,7 +128,7 @@ def attention_reference(causal: bool = False):
     @jax.jit
     def fn(q, k, v):
         d = q.shape[-1]
-        s = jnp.einsum("bqd,bkd->bqk", q, k,
+        s = jnp.einsum("bqd,bkd->bqk", q, k, precision=_PRECISION,
                        preferred_element_type=jnp.float32) / (d ** 0.5)
         if causal:
             sq, sk = s.shape[-2], s.shape[-1]
@@ -132,6 +137,7 @@ def attention_reference(causal: bool = False):
             s = jnp.where(mask, s, _NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v,
+                          precision=_PRECISION,
                           preferred_element_type=jnp.float32).astype(q.dtype)
 
     return fn

@@ -6,7 +6,9 @@ passes unless XLA fuses them.  The Pallas kernel guarantees the fusion: one
 grid over the payload, each block copied through VMEM exactly once while the
 checksum accumulates in SMEM.
 
-Falls back to the jnp composition off-TPU (tests run it in interpret mode).
+There is no off-TPU fallback: ``interpret`` is the caller's explicit choice
+(the CPU tests pass ``interpret=True``); with ``interpret=False`` Mosaic
+compiles the kernel or the call fails.
 """
 
 from __future__ import annotations

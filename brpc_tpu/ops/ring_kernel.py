@@ -7,10 +7,12 @@ controlled — e.g. overlapping each arriving chunk with consumer compute, the
 role brpc's RDMA endpoint plays for ibverbs
 (/root/reference/src/brpc/rdma/rdma_endpoint.cpp).
 
-Runs natively on a real multi-chip TPU backend, or anywhere under the
-pallas TPU interpreter via ``interpret=True`` (how the CPU-mesh tests and
-driver dryrun cover the shipping kernel). `ring_all_gather_reference` is
-the XLA-collective oracle the kernel is checked against.
+``interpret`` is the caller's explicit choice: ``False`` hands the kernel
+to Mosaic on a multi-chip TPU mesh (and fails anywhere else), ``True`` runs
+it under the pallas TPU interpreter, which is how the CPU-mesh tests and the
+CPU-mesh dry run cover it. `ring_all_gather_reference` is the
+XLA-collective oracle the kernel is checked against, never a substitute
+for it.
 """
 
 from __future__ import annotations
@@ -113,11 +115,15 @@ def ring_all_gather_pallas(fabric: Fabric, axis: str = "link",
                            interpret: bool = False):
     """Build the kernel-backed all-gather.
 
-    Runs natively on a multi-chip TPU mesh; with ``interpret=True`` it runs
-    under the pallas TPU interpreter (``pltpu.InterpretParams``), which
-    emulates the remote DMAs and semaphores on any backend — that is how the
-    CPU-mesh tests and the driver dryrun get correctness coverage of the
-    exact kernel that ships to hardware.
+    ``interpret=False`` compiles with Mosaic and needs a multi-chip TPU
+    mesh; ``interpret=True`` runs under the pallas TPU interpreter
+    (``pltpu.InterpretParams``), which emulates the remote DMAs and
+    semaphores on any backend.  Mosaic (libtpu 0.0.34, four v5e chips,
+    PR 21) accepted the kernel at one float32 ``(8, 128)`` tile per
+    device, the only shape it has been compiled at.  The whole gather
+    lives in VMEM — ``(n + 3) * chunk`` bytes — so callers keep chunks
+    small and loop over larger payloads (or use the XLA collective, as
+    chip_smoke.py's 64 MB exchange does).
     """
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
@@ -125,9 +131,10 @@ def ring_all_gather_pallas(fabric: Fabric, axis: str = "link",
     n = fabric.axis_size(axis)
     mesh_platform = fabric.mesh.devices.flat[0].platform
     if n < 2 or (not interpret and mesh_platform != "tpu"):
-        raise RuntimeError("pallas ring kernel needs a multi-chip TPU mesh; "
-                           "use interpret=True or ring_all_gather_reference "
-                           "elsewhere")
+        raise RuntimeError(
+            "pallas ring kernel compiles only for a multi-chip TPU mesh, got "
+            f"{n} x {mesh_platform!r}; interpret=True runs it under the "
+            "pallas interpreter instead")
     if len(fabric.mesh.shape) != 1:
         # The kernel addresses remote DMAs by flat LOGICAL device id, which
         # only equals the axis index on a 1-D mesh.
@@ -137,9 +144,7 @@ def ring_all_gather_pallas(fabric: Fabric, axis: str = "link",
     def spmd(x):
         chunk_rows, row_len = x.shape
         kernel = functools.partial(_ring_kernel, axis, n, chunk_rows, row_len)
-        # Chunks stay in VMEM (direct loads/stores are only legal there);
-        # total VMEM footprint = (n + 3) * chunk — callers keep chunks small
-        # and loop over larger payloads.
+        # Chunks stay in VMEM (direct loads/stores are only legal there).
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((n * chunk_rows, row_len), x.dtype),

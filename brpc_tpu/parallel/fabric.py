@@ -16,14 +16,8 @@ from typing import Sequence
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 promoted shard_map out of experimental
-    from jax import shard_map as _shard_map_fn
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-shard_map = _shard_map_fn
 
 __all__ = ["Fabric", "shard_map", "P"]
 
@@ -51,6 +45,11 @@ class Fabric:
 
         With no shape, lays every device along the last axis — the common
         "one ring" topology used by the echo benchmarks.
+
+        Devices are reshaped in list order, so logical neighbours along
+        an axis need not be physical ICI neighbours (a list-order ring
+        over a 2x2 host takes diagonal hops).  Results are the same
+        either way; hop counts are not.
         """
         devices = list(devices if devices is not None else jax.devices())
         if shape is None:
@@ -87,19 +86,10 @@ class Fabric:
     # -- SPMD wrapping ----------------------------------------------------
     def spmd(self, fn, in_specs, out_specs, check_vma: bool = False):
         """shard_map over this fabric's mesh (the SPMD entry point)."""
-        try:
-            return shard_map(
-                fn,
-                mesh=self.mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_vma=check_vma,
-            )
-        except TypeError:  # older jax spells the kwarg check_rep
-            return shard_map(
-                fn,
-                mesh=self.mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_rep=check_vma,
-            )
+        return shard_map(
+            fn,
+            mesh=self.mesh,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            check_vma=check_vma,
+        )

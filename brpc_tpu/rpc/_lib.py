@@ -2,22 +2,28 @@
 
 The C++ half is the host runtime (fibers, sockets, protocols — ARCHITECTURE.md);
 these bindings are how the Python data plane hands payloads to it.  Builds the
-library on demand with cmake if it isn't present.
+library on demand, from cpp/ as it stands, unless the one in build/ is stamped
+as built from the same bytes.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import json
 import os
 import pathlib
 import shutil
 import subprocess
+import sys
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 
 _REPO = pathlib.Path(__file__).resolve().parent.parent.parent
 _BUILD = _REPO / "build"
 _LIB_PATH = _BUILD / "libtpurpc.so"
+_STAMP_PATH = _BUILD / "libtpurpc.so.sources.json"
 _lock = threading.Lock()
 _lib = None
 
@@ -44,16 +50,41 @@ ERROR_CODES = {
 }
 
 
-def _newest_source_mtime() -> float:
-    newest = 0.0
-    for path in (_REPO / "cpp").rglob("*"):
-        if path.suffix in (".cc", ".h", ".inc", ".S", ".txt"):
-            newest = max(newest, path.stat().st_mtime)
-    return newest
+def _source_manifest() -> dict[str, str]:
+    """sha1 of every build input under cpp/, by repo-relative path."""
+    out = {}
+    for path in sorted((_REPO / "cpp").rglob("*")):
+        if path.suffix in (".cc", ".h", ".inc", ".S", ".txt") and path.is_file():
+            out[str(path.relative_to(_REPO))] = hashlib.sha1(
+                path.read_bytes()).hexdigest()
+    return out
+
+
+def _read_stamp() -> dict | None:
+    """What build/libtpurpc.so was built from, or None when there is no
+    library or nothing trustworthy says where it came from."""
+    if not _LIB_PATH.exists():
+        return None
+    try:
+        stamp = json.loads(_STAMP_PATH.read_text())
+        return stamp if isinstance(stamp["sources"], dict) else None
+    except (OSError, ValueError, KeyError, TypeError):
+        return None
+
+
+def _run_tool(cmd: list[str]) -> None:
+    # Surface the tool's diagnostics: a bare CalledProcessError with
+    # swallowed output is undiagnosable from an import failure.
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+    except subprocess.CalledProcessError as e:
+        sys.stderr.write(f"build step failed: {' '.join(cmd[:4])} ...\n"
+                         f"{e.stdout[-4000:]}\n{e.stderr[-8000:]}\n")
+        raise
 
 
 def _build_with_compiler() -> None:
-    """cmake-less fallback: compile cpp/ straight with the system C++
+    """cmake-less recipe: compile cpp/ straight with the system C++
     compiler (same flags as cpp/CMakeLists.txt) into build/obj/ and link
     libtpurpc.so.  Keeps the Python suite alive on minimal images that
     bake a toolchain but no cmake; the C++ unit BINARIES still need the
@@ -88,17 +119,6 @@ def _build_with_compiler() -> None:
         for p in cpp.rglob(pat):
             newest_h = max(newest_h, p.stat().st_mtime)
 
-    def run_tool(cmd: list[str]) -> None:
-        # Surface the compiler diagnostics: a bare CalledProcessError with
-        # swallowed stderr is undiagnosable from an import failure.
-        try:
-            subprocess.run(cmd, check=True, capture_output=True, text=True)
-        except subprocess.CalledProcessError as e:
-            raise RuntimeError(
-                f"fallback build failed: {' '.join(cmd[:2])} ...\n"
-                f"{e.stderr}"
-            ) from e
-
     def compile_one(src: pathlib.Path) -> str:
         obj = obj_dir / (
             str(src.relative_to(cpp)).replace("/", "_") + ".o"
@@ -107,43 +127,57 @@ def _build_with_compiler() -> None:
             not obj.exists()
             or obj.stat().st_mtime < max(src.stat().st_mtime, newest_h)
         ):
-            run_tool([cxx, *flags, "-c", str(src), "-o", str(obj)])
+            _run_tool([cxx, *flags, "-c", str(src), "-o", str(obj)])
         return str(obj)
 
     with ThreadPoolExecutor(max_workers=os.cpu_count() or 4) as pool:
         objs = list(pool.map(compile_one, sources))
-    run_tool(
+    _run_tool(
         [cxx, "-shared", "-o", str(_LIB_PATH), *objs,
          "-lpthread", "-lrt", "-lz", "-ldl"]
     )
 
 
-def ensure_built(all_targets: bool = False) -> None:
-    """(Re)build the native library when missing or older than any cpp/
-    source.  Shared by the bindings and the pytest fixture so there is one
-    build recipe.  Without cmake, falls back to a direct compiler build of
-    the library alone (all_targets callers must check for cmake/ctest
-    themselves and skip)."""
-    stale = (
-        not _LIB_PATH.exists()
-        or _LIB_PATH.stat().st_mtime < _newest_source_mtime()
-    )
-    if shutil.which("cmake") is None:
-        if stale:
-            _build_with_compiler()
-        return
-    if not stale and not all_targets:
-        return
-    subprocess.run(
-        ["cmake", "-S", str(_REPO / "cpp"), "-B", str(_BUILD)],
-        check=True,
-        capture_output=True,
-        text=True,
-    )
-    cmd = ["cmake", "--build", str(_BUILD), "-j", "2"]
-    if not all_targets:
-        cmd += ["--target", "tpurpc"]
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
+def ensure_built(all_targets: bool = False) -> dict:
+    """(Re)build the native library unless build/libtpurpc.so is stamped
+    as built from exactly the bytes now under cpp/.  The stamp is keyed on
+    content, not mtimes: a copy or checkout resets mtimes, and a library
+    that something else left in build/ must never be loaded.  Shared by
+    the bindings and the pytest fixture so there is one build recipe.
+    Without cmake, falls back to a direct compiler build of the library
+    alone (all_targets callers must check for cmake/ctest themselves and
+    skip).  Returns {"recipe": "reused" | "cmake" | "compiler",
+    "seconds": wall time of the build}."""
+    sources = _source_manifest()
+    stamp = _read_stamp()
+    have_cmake = shutil.which("cmake") is not None
+    if stamp is not None and stamp["sources"] == sources and (
+            stamp.get("all_targets") or not all_targets or not have_cmake):
+        return {"recipe": "reused", "seconds": 0.0}
+    t0 = time.perf_counter()
+    if stamp is not None:
+        # make and the direct recipe decide by mtime; make the mtimes say
+        # what the content says, so the build stays incremental.
+        for rel, digest in sources.items():
+            if stamp["sources"].get(rel) != digest:
+                os.utime(_REPO / rel)
+    jobs = str(os.cpu_count() or 4)
+    if have_cmake:
+        _run_tool(["cmake", "-S", str(_REPO / "cpp"), "-B", str(_BUILD)])
+        cmd = ["cmake", "--build", str(_BUILD), "-j", jobs]
+        if not all_targets:
+            cmd += ["--target", "tpurpc"]
+        if stamp is None:  # objects of unknown origin: reuse none of them
+            cmd += ["--clean-first"]
+        _run_tool(cmd)
+    else:
+        if stamp is None:
+            shutil.rmtree(_BUILD / "obj", ignore_errors=True)
+        _build_with_compiler()
+    _STAMP_PATH.write_text(json.dumps(
+        {"sources": sources, "all_targets": all_targets and have_cmake}))
+    return {"recipe": "cmake" if have_cmake else "compiler",
+            "seconds": round(time.perf_counter() - t0, 1)}
 
 
 _ensure_built = ensure_built
@@ -267,6 +301,16 @@ def load_library() -> ctypes.CDLL:
                 ctypes.c_char_p, ctypes.c_size_t,
             ]
             lib.trpc_channel_call_buf.restype = ctypes.c_int
+            # Native full-stack echo loop (capi/rpc_capi.cc): data, len,
+            # iters, concurrency, transport, resp_out, out_gbps,
+            # transport_used + len, err + len.
+            lib.trpc_bench_echo_rpc.argtypes = [
+                ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
+                ctypes.c_int, ctypes.c_char_p, ctypes.c_void_p,
+                ctypes.POINTER(ctypes.c_double), ctypes.c_char_p,
+                ctypes.c_size_t, ctypes.c_char_p, ctypes.c_size_t,
+            ]
+            lib.trpc_bench_echo_rpc.restype = ctypes.c_int
             # One-sided RMA regions + kernel probe (capi/rpc_capi.cc;
             # net/rma.h, base/proc.h).
             lib.trpc_rma_alloc.argtypes = [

@@ -13,8 +13,10 @@ buffer itself:
   hands that pointer to `IOBuf::append_user_data`, the wire writes straight
   from it, and a deleter keeps the array alive until the last IOBuf
   reference drops.  Zero copies, pointer-identity verifiable.
-- TPU-resident buffers: dlpack import fails (device memory is not host
-  addressable), so exactly ONE device→host DMA runs (`np.asarray` — the
+- TPU-resident buffers: dlpack import fails (libtpu: the device "cannot be
+  used as a DLPack device"; `unsafe_buffer_pointer()` does return a
+  pointer there, but not one that addresses the payload —
+  tools/PJRT_PROBE.md), so exactly ONE device→host DMA runs (`np.asarray` — the
   transport hop itself, the NIC-DMA analogue) and the RESULTING host buffer
   enters the IOBuf by reference.  One copy total, where the round-2 arena
   path took two (DMA into a temporary, memcpy into the slab).
