@@ -74,6 +74,21 @@ struct BatchPipelineVars {
   }};
   Maxer depth;
   LatencyRecorder latency;
+  // Phase clocks (see BatchCall's stamps): where a served call waited,
+  // summed over the calls handed out by trpc_batch_poll.  All of a
+  // call's adds happen at that one moment, on the polling thread, so a
+  // delta of these counters over any window holds whole calls only and
+  // queue + wire + land + ready is exactly polled_us - enter_us.
+  Adder calls_polled;
+  Adder calls_failed;
+  Adder queue_us;
+  Adder wire_us;
+  Adder land_us;
+  Adder ready_us;
+  Adder resp_bytes;
+  Adder land_copy_bytes;
+  Adder submits;
+  Adder submit_us;
   BatchPipelineVars() {
     inflight.expose("batch_inflight",
                     "batch-pipeline calls currently in flight, summed "
@@ -83,6 +98,33 @@ struct BatchPipelineVars {
                  "batch calls) since process start");
     latency.expose("rpc_client_batch",
                    "client-side latency of batch-pipeline calls");
+    calls_polled.expose("batch_calls_polled",
+                        "batch calls handed out by poll with status 0; "
+                        "the divisor of the four batch_*_us phase sums");
+    calls_failed.expose("batch_calls_failed",
+                        "batch calls handed out by poll with an error "
+                        "status; they count in no phase sum");
+    queue_us.expose("batch_queue_us",
+                    "us from submit entry to just before CallMethod: "
+                    "waiting for the issuing fiber and, on one "
+                    "connection, for the CallMethods issued before it");
+    wire_us.expose("batch_wire_us",
+                   "us from just before CallMethod to completion entry: "
+                   "request out, server, response in and parsed");
+    land_us.expose("batch_land_us",
+                   "us the completion fiber spent copying the response "
+                   "into the caller's buffer (0 when it landed in place)");
+    ready_us.expose("batch_ready_us",
+                    "us a finished call lay in the done-ring until the "
+                    "caller's poll handed it out");
+    resp_bytes.expose("batch_resp_bytes",
+                      "response bytes of the calls in batch_calls_polled");
+    land_copy_bytes.expose("batch_land_copy_bytes",
+                           "the part of batch_resp_bytes that the "
+                           "completion fiber's copy_to moved");
+    submits.expose("batch_submits", "accepted trpc_batch_submit crossings");
+    submit_us.expose("batch_submit_us",
+                     "us inside trpc_batch_submit, entry to return");
   }
 };
 
@@ -118,11 +160,22 @@ struct BatchCall {
   size_t resp_cap = 0;
   int64_t timeout_ms = 0;
   SubmitGroup* group = nullptr;  // non-null iff rpcz was on at submit
-  // Stamped just before CallMethod — the batch's own clock for the
+  // Phase clocks, each one monotonic_time_us() reading: CLOCK_MONOTONIC,
+  // the clock of Python's time.perf_counter (so of the benchmark's window
+  // and spans) and of rpcz and the timeline.  enter <= issue <= reply <=
+  // landed <= polled; the fifth is read in trpc_batch_poll, which folds
+  // the differences into BatchPipelineVars.  Plain fields: the issuer
+  // hand-off, the done-ring's release push and poll's acquire pop
+  // already order every write before the poll that reads it.
+  int64_t enter_us = 0;  // entry of the trpc_batch_submit that made it
+  // Stamped just before CallMethod — also the batch's own clock for the
   // rpc_client_batch recorder.  (Channel stamps cntl.call().start_us,
   // but ClusterChannel never does; relying on it dropped every cluster
   // member from the recorder.)
   int64_t issue_us = 0;
+  int64_t reply_us = 0;   // entry of on_call_done: the response is parsed
+  int64_t landed_us = 0;  // after the copy into resp_buf; else == reply_us
+  size_t land_copied = 0;  // bytes that copy moved (0: in place / no buf)
   std::atomic<bool> canceled{false};
   // Published by the issuer after CallMethod returns, so a cancel can
   // reach the in-flight fid (0 = not yet issued / cluster-internal).
@@ -164,10 +217,11 @@ struct Batch {
 // atomic push, one wake.
 void on_call_done(BatchCall* c) {
   Batch* b = c->batch;
+  c->reply_us = c->landed_us = monotonic_time_us();
   // Client-side latency into the shared recorder (issue_us 0 means the
   // call failed before issue — nothing to time).
   if (c->issue_us != 0) {
-    batch_vars().latency << monotonic_time_us() - c->issue_us;
+    batch_vars().latency << c->reply_us - c->issue_us;
   }
   g_batch_inflight.fetch_sub(1, std::memory_order_relaxed);
   SubmitGroup* g = c->group;
@@ -211,6 +265,8 @@ void on_call_done(BatchCall* c) {
               c->resp_buf;
       if (!in_place) {
         c->response.copy_to(c->resp_buf, n);
+        c->land_copied = n;
+        c->landed_us = monotonic_time_us();
       }
       c->resp_copied = true;
       c->response.clear();  // recycle pool blocks now, not at poll
@@ -376,6 +432,53 @@ void fill_completion(BatchCall* c, trpc_batch_completion* out) {
   }
 }
 
+// One drain's worth of phase sums: gathered while poll hands calls out,
+// added to the registry once per drain (ten thread-local adds a poll,
+// not ten a call).
+struct PhaseSums {
+  int64_t polled = 0;
+  int64_t failed = 0;
+  int64_t queue_us = 0;
+  int64_t wire_us = 0;
+  int64_t land_us = 0;
+  int64_t ready_us = 0;
+  int64_t resp_bytes = 0;
+  int64_t land_copy_bytes = 0;
+
+  void count(const BatchCall* c, int64_t polled_us) {
+    if (c->status != 0) {
+      ++failed;  // in no sum; it may never have been issued
+      return;
+    }
+    // Status 0: CallMethod ran and on_call_done saw a response, so
+    // every stamp is set.
+    ++polled;
+    queue_us += c->issue_us - c->enter_us;
+    wire_us += c->reply_us - c->issue_us;
+    land_us += c->landed_us - c->reply_us;
+    ready_us += polled_us - c->landed_us;
+    resp_bytes += static_cast<int64_t>(c->resp_len);
+    land_copy_bytes += static_cast<int64_t>(c->land_copied);
+  }
+
+  void publish() const {
+    BatchPipelineVars& v = batch_vars();
+    if (failed != 0) {
+      v.calls_failed << failed;
+    }
+    if (polled == 0) {
+      return;
+    }
+    v.calls_polled << polled;
+    v.queue_us << queue_us;
+    v.wire_us << wire_us;
+    v.land_us << land_us;
+    v.ready_us << ready_us;
+    v.resp_bytes << resp_bytes;
+    v.land_copy_bytes << land_copy_bytes;
+  }
+};
+
 }  // namespace
 
 extern "C" {
@@ -413,6 +516,7 @@ size_t trpc_batch_submit(void* batch, const char* method,
                          void (*req_deleter)(void*, void*),
                          void* const* req_deleter_ctxs,
                          uint64_t* tokens_out) {
+  const int64_t enter_us = monotonic_time_us();
   auto* b = static_cast<Batch*>(batch);
   if (b == nullptr || n == 0 || method == nullptr ||
       b->closing.load(std::memory_order_acquire)) {
@@ -443,6 +547,7 @@ size_t trpc_batch_submit(void* batch, const char* method,
     auto* c = new BatchCall();
     c->batch = b;
     c->group = group;
+    c->enter_us = enter_us;
     c->token = b->next_token.fetch_add(1, std::memory_order_relaxed);
     c->method = method;
     if (reqs != nullptr && reqs[i] != nullptr && req_lens[i] > 0) {
@@ -498,6 +603,8 @@ size_t trpc_batch_submit(void* batch, const char* method,
       issue_one_main(job->calls[i]);  // pool exhausted: issue inline
     }
   }
+  batch_vars().submits << 1;
+  batch_vars().submit_us << monotonic_time_us() - enter_us;
   return n;
 }
 
@@ -509,6 +616,8 @@ size_t trpc_batch_submit(void* batch, const char* method,
 // DRAIN, never the wait — a parked infinite poller must not block a
 // concurrent non-blocking poll (or destroy) behind it.  A quiesced
 // batch wakes parked pollers and they drain out with whatever is left.
+// Each drain stamps the calls it hands out (polled_us) and folds their
+// phase clocks into the batch_* counters (PhaseSums).
 // Returns the number of records written.
 size_t trpc_batch_poll(void* batch, trpc_batch_completion* out, size_t max,
                        int64_t timeout_ms) {
@@ -523,17 +632,24 @@ size_t trpc_batch_poll(void* batch, trpc_batch_completion* out, size_t max,
     const uint32_t seq = b->ev.value.load(std::memory_order_acquire);
     {
       std::lock_guard<std::mutex> consumer(b->poll_mu_);
+      PhaseSums sums;
+      int64_t polled_us = 0;  // one reading per drain that finds a call
       while (n < max) {
         BatchCall* c = pop_completion(b);
         if (c == nullptr) {
           break;
         }
+        if (polled_us == 0) {
+          polled_us = monotonic_time_us();
+        }
         fill_completion(c, &out[n]);
+        sums.count(c, polled_us);
         ++n;
         std::lock_guard<std::mutex> g(b->mu_);
         b->calls.erase(c->token);
         unref(c);
       }
+      sums.publish();
     }
     if (n > 0 || timeout_ms == 0) {
       return n;

@@ -1890,6 +1890,68 @@ TEST_CASE(batch_submit_opens_parent_span_and_depth_vars) {
   EXPECT_EQ(Flag::set("rpcz_enabled", "false"), 0);
 }
 
+TEST_CASE(batch_phase_clocks_are_ordered) {
+  // ISSUE 24: enter <= issue <= reply <= landed <= polled, seen from
+  // outside as it must be: with ONE call between two readings, each
+  // phase counter moves by that call's own difference, so no delta may
+  // be negative and their sum (polled - enter) fits this test's own
+  // interval on the same clock.
+  start_server_once();
+  Channel ch;
+  EXPECT_EQ(ch.Init(addr()), 0);
+  void* b = trpc_batch_create(&ch, 0);
+  EXPECT(b != nullptr);
+  const char* kPhases[] = {"batch_queue_us", "batch_wire_us",
+                           "batch_land_us", "batch_ready_us"};
+  auto read = [](const char* name) {
+    std::string v;
+    EXPECT(Variable::read_exposed(name, &v));
+    return atoll(v.c_str());
+  };
+  const std::string payload(32 * 1024, 'p');
+  std::string landing(payload.size(), '\0');
+  for (int i = 0; i < 24; ++i) {
+    const bool land = i % 2 == 0;  // with and without a caller buffer
+    long long before[4];
+    for (int k = 0; k < 4; ++k) {
+      before[k] = read(kPhases[k]);
+    }
+    const long long polled_before = read("batch_calls_polled");
+    const long long copied_before = read("batch_land_copy_bytes");
+    const void* req = payload.data();
+    const size_t len = payload.size();
+    void* resp_buf = land ? landing.data() : nullptr;
+    const size_t resp_cap = landing.size();
+    uint64_t token = 0;
+    const int64_t t0 = monotonic_time_us();
+    EXPECT_EQ(trpc_batch_submit(b, "Echo.Echo", &req, &len, &resp_buf,
+                                &resp_cap, 1, 10000, nullptr, nullptr,
+                                &token),
+              1u);
+    auto done = drain_batch(b, 1, 15000);
+    const int64_t t1 = monotonic_time_us();
+    EXPECT_EQ(done.size(), 1u);
+    EXPECT_EQ(done[0].status, 0);
+    if (done[0].resp_iobuf != nullptr) {
+      trpc_iobuf_destroy(done[0].resp_iobuf);
+    }
+    EXPECT_EQ(read("batch_calls_polled") - polled_before, 1);
+    long long total = 0;
+    for (int k = 0; k < 4; ++k) {
+      const long long moved = read(kPhases[k]) - before[k];
+      EXPECT(moved >= 0);
+      total += moved;
+    }
+    EXPECT(total <= t1 - t0);
+    EXPECT_EQ(read("batch_land_copy_bytes") - copied_before,
+              land ? static_cast<long long>(payload.size()) : 0);
+    if (!land) {
+      EXPECT_EQ(read("batch_land_us") - before[2], 0);  // landed == reply
+    }
+  }
+  trpc_batch_destroy(b);
+}
+
 TEST_CASE(rpcz_ring_size_reloadable) {
   start_server_once();
   const size_t original = rpcz_ring_capacity();

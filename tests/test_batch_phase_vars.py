@@ -1,0 +1,171 @@
+"""The batch pipeline's phase clocks (cpp/capi/batch_capi.cc): five stamps
+in each call's own state, folded at poll into always-on `batch_*`
+counters of the native registry.
+
+Everything here reads the counters as deltas around one pipeline's
+traffic on CPU loopback; the registry is the process's, so each test
+settles its own calls before it reads.
+"""
+
+import errno
+import time
+
+import numpy as np
+import pytest
+from test_hotpath_vars import _vars_json
+
+from brpc_tpu.rpc import Channel, Server, observe
+
+PHASES = ("batch_queue_us", "batch_wire_us", "batch_land_us",
+          "batch_ready_us")
+COUNTERS = PHASES + (
+    "batch_calls_polled", "batch_calls_failed", "batch_resp_bytes",
+    "batch_land_copy_bytes", "batch_submits", "batch_submit_us")
+
+
+@pytest.fixture
+def echo():
+    srv = Server()
+    srv.register_native_echo("Echo.Echo")
+    srv.start(0)
+    ch = Channel(f"127.0.0.1:{srv.port}", timeout_ms=10000)
+    pipe = ch.pipeline()
+    try:
+        yield srv, pipe
+    finally:
+        srv.set_faults("")
+        pipe.close()
+        ch.close()
+        srv.stop()
+
+
+def _read() -> dict:
+    dump = observe.Vars.dump()
+    return {name: dump[name] for name in COUNTERS}
+
+
+def _drain(pipe, n: int) -> list:
+    done = []
+    deadline = time.monotonic() + 15
+    while len(done) < n and time.monotonic() < deadline:
+        done.extend(pipe.poll(timeout_ms=2000))
+    assert len(done) == n
+    return done
+
+
+def _settled(pipe) -> None:
+    """Every submitted call has completed into the done-ring."""
+    deadline = time.monotonic() + 15
+    while pipe.inflight and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert pipe.inflight == 0
+
+
+def _moved(before: dict) -> dict:
+    after = _read()
+    return {name: after[name] - before[name] for name in COUNTERS}
+
+
+def test_every_polled_call_counts_once_and_the_phases_fit_its_interval(echo):
+    _, pipe = echo
+    n = 16
+    requests = [np.full(4096, i, dtype=np.uint8) for i in range(n)]
+    landing = [np.zeros(4096, dtype=np.uint8) for _ in range(n)]
+    before = _read()
+    t0 = time.perf_counter()
+    pipe.submit("Echo.Echo", requests, resp_bufs=landing)
+    done = _drain(pipe, n)
+    interval_us = (time.perf_counter() - t0) * 1e6
+    moved = _moved(before)
+    assert all(c.ok for c in done)
+    assert moved["batch_calls_polled"] == n
+    assert moved["batch_calls_failed"] == 0
+    assert all(moved[phase] >= 0 for phase in PHASES)
+    # enter ... polled of every call lies inside submit ... last poll.
+    total = sum(moved[phase] for phase in PHASES)
+    assert 0 < total <= n * (interval_us + 1)
+    assert moved["batch_wire_us"] > 0
+    assert moved["batch_submits"] == 1
+    assert 0 <= moved["batch_submit_us"] <= interval_us + 1
+    assert moved["batch_resp_bytes"] == n * 4096
+
+
+def test_a_finished_call_left_unpolled_waits_in_ready_not_on_the_wire(echo):
+    _, pipe = echo
+    n = 4
+    before = _read()
+    pipe.submit("Echo.Echo", [b"r" * 1024] * n)
+    _settled(pipe)
+    time.sleep(0.05)
+    _drain(pipe, n)
+    moved = _moved(before)
+    assert moved["batch_calls_polled"] == n
+    assert moved["batch_ready_us"] >= n * 50_000
+    assert moved["batch_wire_us"] < n * 50_000
+
+
+def test_a_slow_server_shows_on_the_wire_not_in_ready(echo):
+    srv, pipe = echo
+    n = 4
+    srv.set_faults("svr_delay=1:20")  # every dispatch parks 20 ms
+    before = _read()
+    pipe.submit("Echo.Echo", [b"w" * 1024] * n)
+    _drain(pipe, n)
+    moved = _moved(before)
+    assert moved["batch_calls_polled"] == n
+    assert moved["batch_wire_us"] >= n * 20_000
+    assert moved["batch_ready_us"] < n * 20_000
+
+
+@pytest.mark.parametrize("caller_buffer", [True, False])
+def test_land_copy_bytes_are_the_bytes_copied_into_a_caller_buffer(
+        echo, caller_buffer):
+    _, pipe = echo
+    n, size = 4, 64 << 10
+    requests = [np.full(size, i + 1, dtype=np.uint8) for i in range(n)]
+    landing = ([np.zeros(size, dtype=np.uint8) for _ in range(n)]
+               if caller_buffer else None)
+    before = _read()
+    pipe.submit("Echo.Echo", requests, resp_bufs=landing)
+    done = _drain(pipe, n)
+    moved = _moved(before)
+    assert moved["batch_resp_bytes"] == n * size
+    if caller_buffer:
+        # Plain tcp below the stripe threshold: the body arrives in pool
+        # blocks and the completion fiber copies all of it.
+        assert all(c.in_caller_buffer for c in done)
+        assert moved["batch_land_copy_bytes"] == n * size
+    else:
+        assert moved["batch_land_copy_bytes"] == 0
+        assert moved["batch_land_us"] == 0
+        for c in done:
+            c.data.release()
+
+
+def test_a_call_that_times_out_counts_as_failed_and_in_no_sum(echo):
+    srv, pipe = echo
+    n = 3
+    srv.set_faults("svr_delay=1:400")
+    before = _read()
+    pipe.submit("Echo.Echo", [b"t" * 64] * n, timeout_ms=50)
+    done = _drain(pipe, n)
+    moved = _moved(before)
+    assert {c.status for c in done} == {errno.ETIMEDOUT}
+    assert moved["batch_calls_failed"] == n
+    assert moved["batch_submits"] == 1
+    for name in PHASES + ("batch_calls_polled", "batch_resp_bytes",
+                          "batch_land_copy_bytes"):
+        assert moved[name] == 0, name
+    time.sleep(0.45)  # the parked handlers answer into a live server
+
+
+def test_the_phase_counters_are_on_the_vars_page_with_their_descriptions(
+        echo):
+    srv, _ = echo
+    page = _vars_json(srv.port)
+    assert not [name for name in COUNTERS if name not in page]
+    exposition = observe.Vars.prometheus()
+    for name in COUNTERS:
+        # Adders are Prometheus counters: `<name>_total` with a HELP line.
+        assert f"# HELP {name}_total " in exposition, name
+    assert "done-ring" in exposition
