@@ -9,6 +9,7 @@ as built from the same bytes.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import hashlib
 import json
 import os
@@ -147,7 +148,16 @@ def ensure_built(all_targets: bool = False) -> dict:
     Without cmake, falls back to a direct compiler build of the library
     alone (all_targets callers must check for cmake/ctest themselves and
     skip).  Returns {"recipe": "reused" | "cmake" | "compiler",
-    "seconds": wall time of the build}."""
+    "seconds": wall time of the build}.  One process at a time: pytest's
+    workers share build/, and in a fresh checkout each of them would
+    start a `--clean-first` build under the others."""
+    _BUILD.mkdir(parents=True, exist_ok=True)
+    with open(_BUILD / ".ensure_built.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)  # released when the file closes
+        return _ensure_built_locked(all_targets)
+
+
+def _ensure_built_locked(all_targets: bool) -> dict:
     sources = _source_manifest()
     stamp = _read_stamp()
     have_cmake = shutil.which("cmake") is not None
@@ -636,6 +646,22 @@ def load_library() -> ctypes.CDLL:
                 ctypes.POINTER(ctypes.c_uint64),
             ]
             lib.trpc_batch_submit.restype = ctypes.c_size_t
+            lib.trpc_batch_reserve.argtypes = [
+                ctypes.c_void_p, ctypes.c_size_t,
+                ctypes.POINTER(ctypes.c_uint64)]
+            lib.trpc_batch_reserve.restype = ctypes.c_size_t
+            lib.trpc_batch_submit_staged.argtypes = [
+                ctypes.c_void_p, ctypes.c_char_p,
+                ctypes.POINTER(ctypes.c_void_p),
+                ctypes.POINTER(ctypes.c_size_t),
+                ctypes.POINTER(ctypes.c_void_p),
+                ctypes.POINTER(ctypes.c_size_t),
+                ctypes.c_size_t, ctypes.c_int64,
+                ctypes.c_void_p,  # deleter fn ptr (CFUNCTYPE or None)
+                ctypes.POINTER(ctypes.c_void_p),
+                ctypes.c_void_p,  # trpc_batch_stage[n] (batch.BatchStage)
+            ]
+            lib.trpc_batch_submit_staged.restype = ctypes.c_size_t
             lib.trpc_batch_poll.argtypes = [
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
                 ctypes.c_int64,

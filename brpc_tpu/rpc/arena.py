@@ -80,9 +80,9 @@ class ArenaBlock:
         DMA then the memcpy.  Returns the byte length.  For the fully
         copy-free path, see rpc.zerocopy.append_jax — a slab only pays off
         when the block must live in registered/shm-backed memory."""
-        from brpc_tpu.rpc.zerocopy import host_view
+        from brpc_tpu.rpc.zerocopy import host_bytes
 
-        flat, _owner = host_view(array)
+        flat, _owner = host_bytes(array)
         n = flat.size
         if n > self.view.size:
             raise ValueError(f"{n} bytes > block size {self.view.size}")

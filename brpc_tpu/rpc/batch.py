@@ -23,12 +23,26 @@ Ownership rules (the zero-copy contract):
   immediately) or ride out as a `ZeroCopyResponse` view over the pool
   blocks themselves; `release()` (or GC) recycles them.  No intermediate
   `bytes` object is created at the boundary on either path.
+
+Staged requests: a request may be a `zerocopy.PendingView` — a device
+array whose transfer to the host `zerocopy.host_view` has started and
+nobody has waited for.  `submit` still returns its tokens at once; the
+calls go to the pipeline's STAGER, one thread per Batch, which waits for
+the bytes in submit order and hands the calls of a submit, once all their
+bytes have landed, to the native submit in one crossing.  Everything
+submitted while calls are with the stager queues behind them, so submit
+order stays wire order.  A submit with nothing pending and nothing queued
+ahead of it never sees the stager.
 """
 
 from __future__ import annotations
 
+import collections
 import ctypes
+import errno
+import itertools
 import threading
+import time
 
 import numpy as np
 
@@ -53,6 +67,70 @@ class BatchCompletion(ctypes.Structure):
         ("resp_iobuf", ctypes.c_void_p),
         ("err", ctypes.c_char * 120),
     ]
+
+
+class BatchStage(ctypes.Structure):
+    """ABI mirror of `struct trpc_batch_stage` (batch_capi.cc)."""
+
+    _fields_ = [
+        ("token", ctypes.c_uint64),
+        ("staged_us", ctypes.c_int64),
+        ("fetch_us", ctypes.c_int64),
+        ("fetch_bytes", ctypes.c_uint64),
+        ("status", ctypes.c_int32),
+        ("err", ctypes.c_char_p),
+    ]
+
+
+class _StagedCall:
+    """One call with the stager: everything `submit` was given for it,
+    and what the stager learns (`flat` once the bytes are here, `status`
+    and `err` if they never will be)."""
+
+    __slots__ = ("token", "submit_id", "method", "timeout_ms", "request",
+                 "resp", "staged_us", "flat", "fetch_us", "fetch_bytes",
+                 "status", "err")
+
+    def __init__(self, submit_id, method, timeout_ms, request, resp):
+        self.token = 0            # set once reserved
+        self.submit_id = submit_id
+        self.method = method
+        self.timeout_ms = timeout_ms
+        self.request = request
+        self.resp = resp          # (uint8 view, caller's buffer) or None
+        self.fetch_us = 0
+        self.fetch_bytes = 0
+        self.status = 0
+        self.err = b""
+        if isinstance(request, _zc.PendingView) and not request.landed:
+            self.staged_us = request.started_us
+            self.flat = None
+        else:
+            self.staged_us = _now_us()
+            self.flat = _as_u8(request)
+
+    @property
+    def ready(self) -> bool:
+        """Nothing left to wait for: the bytes are here, or never will be."""
+        return self.flat is not None or self.status != 0
+
+    def fetch(self) -> None:
+        """Waits for the request's bytes (GIL released in the wait)."""
+        t0 = _now_us()
+        try:
+            flat = self.request.resolve()
+        except Exception as e:  # noqa: BLE001 — completes through poll
+            self.status = errno.EIO
+            self.err = f"request fetch failed: {e!r}".encode()
+            return
+        self.fetch_us = _now_us() - t0
+        self.fetch_bytes = flat.nbytes
+        self.flat = flat
+
+
+def _now_us() -> int:
+    # CLOCK_MONOTONIC: the clock of the native runtime's phase stamps.
+    return time.monotonic_ns() // 1000
 
 
 class ZeroCopyResponse:
@@ -141,8 +219,38 @@ class Completion:
 
 
 def _as_u8(buf) -> np.ndarray:
-    """Flat uint8 view of any buffer-protocol object (no copy)."""
+    """Flat uint8 view of any buffer-protocol object (no copy); of a
+    `PendingView`, its bytes, waited for."""
+    if isinstance(buf, _zc.PendingView):
+        return buf.resolve()
     return np.frombuffer(buf, dtype=np.uint8)
+
+
+def _marshal(flats, requests, resps):
+    """The ctypes arrays of one native crossing: request pointers and
+    lengths (a None or empty `flat` sends no bytes and takes no pin),
+    landing buffers, and the pins made (ctx array, token list)."""
+    n = len(flats)
+    req_ptrs = (ctypes.c_void_p * n)()
+    req_lens = (ctypes.c_size_t * n)()
+    rb = (ctypes.c_void_p * n)()
+    rc = (ctypes.c_size_t * n)()
+    pin_ctxs = (ctypes.c_void_p * n)()
+    pins = []
+    for i, (flat, request, resp) in enumerate(zip(flats, requests, resps)):
+        if flat is not None and flat.nbytes:
+            req_ptrs[i] = flat.ctypes.data
+            req_lens[i] = flat.nbytes
+            pin_ctxs[i] = tok = _zc.pin(flat, request)
+            pins.append(tok)
+        if resp is not None:
+            rb[i] = resp[0].ctypes.data
+            rc[i] = resp[0].nbytes
+    return req_ptrs, req_lens, rb, rc, pin_ctxs, pins
+
+
+# An idle stager ends itself after this long; see _stager_main.
+_STAGER_IDLE_S = 1.0
 
 
 class Batch:
@@ -176,76 +284,61 @@ class Batch:
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         self._active_polls = 0
+        # Calls with the stager, in submit order, and by token (cancel).
+        self._staged: collections.deque[_StagedCall] = collections.deque()
+        self._staged_by_token: dict[int, _StagedCall] = {}
+        self._stager: threading.Thread | None = None
+        self._stage_work = threading.Condition(self._lock)
+        self._staged_submits = 0
+        self._settling = False  # quiesce/close: the stager drains and ends
 
     def submit(self, method: str, requests, resp_bufs=None,
                timeout_ms: int = 0) -> list[int]:
         """Submits len(requests) calls in ONE crossing; returns tokens in
         request order.  Each request is any buffer-protocol object
-        (bytes, numpy, memoryview); its bytes enter the wire path by
-        reference and stay pinned until the runtime drops them.
-        resp_bufs (optional, per-call, entries may be None) are WRITABLE
-        buffers the responses land in natively — the zero-copy receive
-        path; they must stay alive until their completion is polled."""
+        (bytes, numpy, memoryview) or a `zerocopy.PendingView`; its bytes
+        enter the wire path by reference and stay pinned until the
+        runtime drops them.  resp_bufs (optional, per-call, entries may
+        be None) are WRITABLE buffers the responses land in natively —
+        the zero-copy receive path; they must stay alive until their
+        completion is polled.  Never waits for a request's bytes: while
+        any are still on their way the calls go to the stager, and a
+        call's timeout_ms starts at its native issue."""
         if not self._ptr:
             raise ValueError("batch is closed")
         n = len(requests)
         if n == 0:
             return []
-        # Validate and stage the response buffers BEFORE any request is
-        # pinned: a raise past the pin loop would strand entries in
-        # _pinned forever (the native deleter only fires for submitted
-        # calls).
-        rb = rc = None
-        resp_views = []
+        # Validate the response buffers BEFORE anything is pinned or
+        # queued: a raise past that point would strand it.
+        resps = [None] * n
         if resp_bufs is not None:
             if len(resp_bufs) != n:
                 raise ValueError("resp_bufs length must match requests")
-            rb = (ctypes.c_void_p * n)()
-            rc = (ctypes.c_size_t * n)()
             for i, buf in enumerate(resp_bufs):
                 if buf is None:
-                    rb[i] = None
-                    rc[i] = 0
                     continue
                 v = np.frombuffer(buf, dtype=np.uint8)
                 if not v.flags.writeable:
                     raise ValueError("resp_bufs entries must be writable")
-                rb[i] = v.ctypes.data
-                rc[i] = v.nbytes
-                resp_views.append((v, buf))
-        req_ptrs = (ctypes.c_void_p * n)()
-        req_lens = (ctypes.c_size_t * n)()
-        pin_ctxs = (ctypes.c_void_p * n)()
-        tokens = (ctypes.c_uint64 * n)()
-        pins = []
-        try:
-            for i, r in enumerate(requests):
-                flat = _as_u8(r)
-                if flat.nbytes == 0:
-                    req_ptrs[i] = None
-                    req_lens[i] = 0
-                    pin_ctxs[i] = None
-                    continue
-                req_ptrs[i] = flat.ctypes.data
-                req_lens[i] = flat.nbytes
-                tok = _zc.pin(flat, r)
-                pin_ctxs[i] = tok
-                pins.append(tok)
-        except Exception:
-            for tok in pins:  # a bad request mid-loop must not leak pins
-                _zc.unpin(tok)
-            raise
-        # self._lock is held across the native submit AND the pin
-        # insertion: tokens are only known once submit returns, and a
+                resps[i] = (v, buf)
+        pending = any(isinstance(r, _zc.PendingView) and not r.landed
+                      for r in requests)
+        # self._lock is held across the native crossing AND the pin
+        # insertion: tokens are only known once it returns, and a
         # concurrent poller that drained a completion in that window
         # would pop a pin that isn't registered yet (leaking it for the
         # batch's lifetime).  poll() pops under the same lock, so it
         # blocks those few microseconds until the pins are in place.
         with self._lock:
             if not self._ptr:
-                for tok in pins:
-                    _zc.unpin(tok)
                 raise ValueError("batch is closed")
+            if pending or self._staged:
+                return self._stage(method, requests, resps, timeout_ms)
+            flats = [_as_u8(r) for r in requests]  # raises before any pin
+            tokens = (ctypes.c_uint64 * n)()
+            req_ptrs, req_lens, rb, rc, pin_ctxs, pins = _marshal(
+                flats, requests, resps)
             got = self._lib.trpc_batch_submit(
                 ctypes.c_void_p(self._ptr), method.encode(), req_ptrs,
                 req_lens, rb, rc, ctypes.c_size_t(n),
@@ -257,12 +350,120 @@ class Batch:
                     _zc.unpin(tok)
                 raise RuntimeError("batch rejected the submit (closing?)")
             out = list(tokens)
-            for t, (v, buf) in zip(
-                    (t for i, t in enumerate(out)
-                     if resp_bufs is not None and resp_bufs[i] is not None),
-                    resp_views):
-                self._resp_pins[t] = (v, buf)
+            for t, resp in zip(out, resps):
+                if resp is not None:
+                    self._resp_pins[t] = resp
         return out
+
+    # ---- the stager (self._lock held by every _stage*/_hand* caller) ----
+
+    def _stage(self, method, requests, resps, timeout_ms) -> list[int]:
+        """Queues the calls for the stager under tokens reserved now."""
+        if self._settling:
+            raise RuntimeError("batch rejected the submit (closing?)")
+        n = len(requests)
+        self._staged_submits += 1
+        calls = [_StagedCall(self._staged_submits, method, timeout_ms, r,
+                             resp)
+                 for r, resp in zip(requests, resps)]
+        tokens = (ctypes.c_uint64 * n)()
+        if self._lib.trpc_batch_reserve(
+                ctypes.c_void_p(self._ptr), ctypes.c_size_t(n), tokens) != n:
+            raise RuntimeError("batch rejected the submit (closing?)")
+        for call, token in zip(calls, tokens):
+            call.token = token
+            self._staged_by_token[token] = call
+            if call.resp is not None:
+                self._resp_pins[token] = call.resp
+        self._staged.extend(calls)
+        if self._stager is None:
+            self._stager = threading.Thread(
+                target=self._stager_main, name="trpc-batch-stager",
+                daemon=True)
+            self._stager.start()
+        self._stage_work.notify()
+        return list(tokens)
+
+    def _stager_main(self) -> None:
+        """Waits for the bytes of the head call and of the calls that were
+        submitted with it (their transfers were started together and share
+        the link, so they land together, and what the caller submitted as
+        one crossing stays one), then hands them and every ready call
+        behind them to the native submit; so on in submit order."""
+        while True:
+            with self._lock:
+                while not self._staged and not self._settling:
+                    if not self._stage_work.wait(timeout=_STAGER_IDLE_S):
+                        if not self._staged and not self._settling:
+                            # Idle: end, so that a pipeline its caller
+                            # dropped is not kept alive by its own thread;
+                            # the next pending submit starts another.
+                            self._stager = None
+                            return
+                if self._settling:
+                    for call in self._staged:
+                        if not call.ready:
+                            call.status = errno.ECANCELED
+                            call.err = b"canceled while staged"
+                    self._hand_over_ready()
+                    return
+                head = self._staged[0].submit_id
+                together = list(itertools.takewhile(
+                    lambda call: call.submit_id == head, self._staged))
+            for call in together:
+                if self._settling:
+                    break           # the rest is canceled, not waited for
+                if not call.ready:  # else: its bytes were there, or it
+                    call.fetch()    # was canceled meanwhile
+            with self._lock:
+                self._hand_over_ready()
+
+    def _hand_over_ready(self) -> None:
+        """Every ready call at the front of the queue crosses, one native
+        submit per run of calls with the same method and timeout."""
+        staged = self._staged
+        while staged and staged[0].ready:
+            key = (staged[0].method, staged[0].timeout_ms)
+            group = []
+            while (staged and staged[0].ready
+                   and (staged[0].method, staged[0].timeout_ms) == key):
+                group.append(staged.popleft())
+            self._hand_over(group)
+
+    def _hand_over(self, group) -> None:
+        n = len(group)
+        stages = (BatchStage * n)()
+        for st, call in zip(stages, group):
+            del self._staged_by_token[call.token]
+            st.token = call.token
+            st.staged_us = call.staged_us
+            st.fetch_us = call.fetch_us
+            st.fetch_bytes = call.fetch_bytes
+            st.status = call.status
+            st.err = call.err or None
+        req_ptrs, req_lens, rb, rc, pin_ctxs, pins = _marshal(
+            [c.flat if c.status == 0 else None for c in group],
+            [c.request for c in group], [c.resp for c in group])
+        got = self._lib.trpc_batch_submit_staged(
+            ctypes.c_void_p(self._ptr), group[0].method.encode(), req_ptrs,
+            req_lens, rb, rc, ctypes.c_size_t(n),
+            ctypes.c_int64(group[0].timeout_ms),
+            ctypes.cast(_zc.release_cb, ctypes.c_void_p), pin_ctxs, stages)
+        if got != n:  # the native side is closing: the calls are dropped
+            for tok in pins:
+                _zc.unpin(tok)
+            for call in group:
+                self._resp_pins.pop(call.token, None)
+
+    def _settle_stager(self) -> None:
+        """The stager hands over what has landed, completes the rest with
+        ECANCELED and ends; no submit queues behind it afterwards."""
+        with self._lock:
+            self._settling = True
+            stager = self._stager
+            self._stage_work.notify_all()
+        if stager is not None:
+            stager.join()
 
     def poll(self, max_n: int = 64, timeout_ms: int = -1) -> list[Completion]:
         """Drains up to max_n completions, blocking OUTSIDE the GIL until
@@ -311,6 +512,15 @@ class Batch:
         with self._lock:  # the native call is quick and must not race
             if not self._ptr:  # a concurrent destroy
                 return False
+            call = self._staged_by_token.get(token)
+            if call is not None:
+                # Still with the stager: it never reaches the wire, and
+                # completes now, out of turn, like any canceled call.
+                call.status = errno.ECANCELED
+                call.err = b"canceled while staged"
+                self._staged.remove(call)
+                self._hand_over([call])
+                return True
             return self._lib.trpc_batch_cancel(
                 ctypes.c_void_p(self._ptr), ctypes.c_uint64(token)) == 0
 
@@ -320,7 +530,7 @@ class Batch:
         with self._lock:
             if not self._ptr:
                 return 0
-            return self._lib.trpc_batch_outstanding(
+            return len(self._staged) + self._lib.trpc_batch_outstanding(
                 ctypes.c_void_p(self._ptr))
 
     @property
@@ -331,13 +541,15 @@ class Batch:
         with self._lock:
             if not self._ptr:
                 return 0
-            return self._lib.trpc_batch_inflight(ctypes.c_void_p(self._ptr))
+            return len(self._staged) + self._lib.trpc_batch_inflight(
+                ctypes.c_void_p(self._ptr))
 
     def quiesce(self) -> None:
         """Rejects further submits, cancels in-flight members and waits
         for them to settle; buffered completions remain pollable.  After
         this the batch no longer touches its channel (Channel.close runs
         it on every live pipeline before destroying the native channel)."""
+        self._settle_stager()
         with self._lock:  # held across the call so a concurrent close
             if self._ptr:  # cannot destroy the handle mid-quiesce
                 self._lib.trpc_batch_quiesce(ctypes.c_void_p(self._ptr))
@@ -345,6 +557,7 @@ class Batch:
     def close(self) -> None:
         """Cancels in-flight members, waits for them (and any poller on
         another thread) to settle, frees unpolled completions."""
+        self._settle_stager()  # while the handle it crosses with lives
         with self._lock:
             ptr, self._ptr = self._ptr, None
         if ptr:

@@ -16,16 +16,23 @@ buffer itself:
 - TPU-resident buffers: dlpack import fails (libtpu: the device "cannot be
   used as a DLPack device"; `unsafe_buffer_pointer()` does return a
   pointer there, but not one that addresses the payload —
-  tools/PJRT_PROBE.md), so exactly ONE device→host DMA runs (`np.asarray` — the
+  tools/PJRT_PROBE.md), so exactly ONE device→host DMA runs (the
   transport hop itself, the NIC-DMA analogue) and the RESULTING host buffer
   enters the IOBuf by reference.  One copy total, where the round-2 arena
-  path took two (DMA into a temporary, memcpy into the slab).
+  path took two (DMA into a temporary, memcpy into the slab).  That DMA is
+  only STARTED by `host_view` (`copy_to_host_async`): it returns a
+  `PendingView`, and whoever needs the bytes first waits for them — the
+  batch pipeline's stager thread (batch.py), so the caller's thread is
+  free for the responses while its requests are on their way.
 """
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import threading
+import time
+import weakref
 
 import numpy as np
 
@@ -82,15 +89,133 @@ def unpin(token: int) -> None:
         _live.pop(token, None)
 
 
+def _flat_u8(host: np.ndarray) -> np.ndarray:
+    return host.reshape(-1).view(np.uint8)
+
+
+# Bytes of device-to-host transfers that may be on their way at once (at
+# least one transfer always may).  Each lands in a fresh host buffer, and
+# on the v5e host four or more 64 MB transfers at once make the machine's
+# memory grow without bound (87 MB/s with four in flight, 1.2 GB/s with
+# eight: pages the process has freed that the kernel has not; PERF.md,
+# PR 25), while three run at 1.66 GB/s against one's 0.85 and grow nothing.
+_MAX_BYTES_IN_FLIGHT = 3 * (64 << 20)
+# Re-entrant: a PendingView collected inside _retire retires too.
+_transfers_lock = threading.RLock()
+_bytes_in_flight = 0
+_deferred: collections.deque = collections.deque()  # weakrefs, oldest first
+
+
+def _admit(nbytes: int) -> bool:
+    """_transfers_lock held: takes room for one more transfer if there is."""
+    global _bytes_in_flight
+    if _bytes_in_flight and _bytes_in_flight + nbytes > _MAX_BYTES_IN_FLIGHT:
+        return False
+    _bytes_in_flight += nbytes
+    return True
+
+
+def _retire(nbytes: int) -> None:
+    """A transfer has ended: its room goes to the oldest deferred ones."""
+    global _bytes_in_flight
+    started = []
+    with _transfers_lock:
+        _bytes_in_flight -= nbytes
+        while _deferred:
+            view = _deferred[0]()
+            # Dropped, or fetched out of turn by its own resolve(): gone.
+            if view is not None and not (view._has_room or view.landed):
+                if not _admit(view.nbytes):
+                    break
+                view._has_room = True
+                started.append(view)
+            _deferred.popleft()
+    for view in started:
+        view._array.copy_to_host_async()
+
+
+class PendingView:
+    """The bytes of an array whose transfer to the host has been asked for
+    and not yet waited for.  `nbytes` is known at once; `resolve()` blocks
+    its first caller until the bytes have landed (the wait releases the
+    GIL) and returns the flat uint8 view of the array's own cached host
+    copy, the same one to every caller.  The transfer starts at once while
+    `_MAX_BYTES_IN_FLIGHT` has room, else when an earlier one has landed
+    (or when `resolve()` asks for it).  `started_us` is the monotonic
+    clock (the native runtime's) at which the view was made."""
+
+    def __init__(self, array):
+        self.nbytes = int(array.nbytes)
+        self._array = array
+        self._flat = None
+        self._lock = threading.Lock()
+        self.started_us = time.monotonic_ns() // 1000
+        with _transfers_lock:
+            # Behind any deferred view: transfers start in the order asked.
+            self._has_room = not _deferred and _admit(self.nbytes)
+            if not self._has_room:
+                _deferred.append(weakref.ref(self))
+        if self._has_room:
+            array.copy_to_host_async()
+
+    @property
+    def landed(self) -> bool:
+        """The bytes are here: `resolve()` will not block."""
+        return self._flat is not None
+
+    def resolve(self) -> np.ndarray:
+        global _bytes_in_flight
+        with self._lock:
+            if self._flat is None:
+                with _transfers_lock:
+                    if not self._has_room:
+                        # Asked for before its turn came: over the limit
+                        # rather than wait for room behind others.
+                        self._has_room = True
+                        _bytes_in_flight += self.nbytes
+                try:
+                    self._flat = _flat_u8(np.asarray(self._array))
+                finally:
+                    self._has_room = False
+                    _retire(self.nbytes)
+            return self._flat
+
+    def __del__(self):
+        # Dropped before anyone waited for it: give its room back.
+        try:
+            if self._has_room:
+                self._has_room = False
+                _retire(self.nbytes)
+        except Exception:  # noqa: BLE001 — interpreter teardown
+            pass
+
+    def __array__(self, dtype=None, copy=None):
+        return self.resolve()
+
+
 def host_view(array):
     """(flat_uint8_view, owner): host-visible bytes of a JAX/numpy array
     with the minimum number of copies — zero for host-backed buffers
-    (dlpack import), exactly one device→host DMA otherwise."""
+    (dlpack import), exactly one device→host DMA otherwise.  Where the
+    bytes are not host-visible and the array can start its own transfer,
+    the view is a `PendingView` and this returns without waiting for it;
+    `host_bytes` is the same with the wait."""
     try:
         host = np.from_dlpack(array)
     except (RuntimeError, TypeError, BufferError, AttributeError):
+        if hasattr(array, "copy_to_host_async"):
+            return PendingView(array), array
         host = np.asarray(array)
-    return host.reshape(-1).view(np.uint8), host
+    return _flat_u8(host), host
+
+
+def host_bytes(array):
+    """`host_view` for callers that read the bytes at once: (flat uint8
+    numpy view, owner), any transfer waited for."""
+    view, owner = host_view(array)
+    if isinstance(view, PendingView):
+        view = view.resolve()
+    return view, owner
 
 
 def append_jax(iobuf_ptr: int, array, lib=None) -> int:
@@ -99,7 +224,7 @@ def append_jax(iobuf_ptr: int, array, lib=None) -> int:
     kept alive until the IOBuf drops it.  Returns the byte length."""
     global _next_token
     lib = lib or load_library()
-    flat, owner = host_view(array)
+    flat, owner = host_bytes(array)
     with _lock:
         token = _next_token
         _next_token += 1

@@ -262,7 +262,7 @@ def leg_served_path(sizes, depth: int, seed: int = SEED,
                 d2h_s, views = [], []
                 for req in reqs:
                     t0 = time.perf_counter()
-                    views.append(zerocopy.host_view(req))
+                    views.append(zerocopy.host_bytes(req))
                     d2h_s.append(time.perf_counter() - t0)
                 bufs = [np.empty(size, dtype=np.uint8) for _ in reqs]
                 pipe = ch.pipeline()

@@ -20,9 +20,18 @@
 //  - a BatchCall is freed at the LAST of {issuer done, completion polled},
 //    so cancel/poll/destroy racing an inline completion can never
 //    use-after-free (refcount of 2, registry lookups serialized on mu_).
+//
+// Staged requests: a caller whose request bytes are still on their way to
+// the host (brpc_tpu/rpc/batch.py's stager: a device array's D2H started
+// by zerocopy.host_view) takes its tokens first (trpc_batch_reserve) and
+// hands the calls over when the bytes have landed
+// (trpc_batch_submit_staged), each with the time its transfer was started;
+// the poll folds that wait in front of the four phases as batch_stage_us.
+#include <algorithm>
 #include <atomic>
 #include <cerrno>
 #include <cstring>
+#include <deque>
 #include <memory>
 #include <mutex>
 #include <string>
@@ -54,6 +63,17 @@ struct trpc_batch_completion {
   void* resp_iobuf;      // non-null: caller owns, free via trpc_iobuf_destroy
   char err[120];
 };
+
+// What the stager knows of one call it hands to trpc_batch_submit_staged
+// (mirrored by batch.py's BatchStage).
+struct trpc_batch_stage {
+  uint64_t token;        // from trpc_batch_reserve
+  int64_t staged_us;     // monotonic us at which the request was staged
+  int64_t fetch_us;      // us the stager was blocked resolving its bytes
+  uint64_t fetch_bytes;  // bytes that wait resolved (0: they were there)
+  int32_t status;        // non-zero: never issued, completes with it
+  const char* err;       // text of that status (nullable)
+};
 }  // extern "C"
 
 namespace {
@@ -78,7 +98,8 @@ struct BatchPipelineVars {
   // summed over the calls handed out by trpc_batch_poll.  All of a
   // call's adds happen at that one moment, on the polling thread, so a
   // delta of these counters over any window holds whole calls only and
-  // queue + wire + land + ready is exactly polled_us - enter_us.
+  // queue + wire + land + ready is exactly polled_us - enter_us; with
+  // stage in front of them the five are polled_us - staged_us.
   Adder calls_polled;
   Adder calls_failed;
   Adder queue_us;
@@ -89,6 +110,10 @@ struct BatchPipelineVars {
   Adder land_copy_bytes;
   Adder submits;
   Adder submit_us;
+  Adder staged_calls;
+  Adder stage_us;
+  Adder stage_fetch_us;
+  Adder stage_fetch_bytes;
   BatchPipelineVars() {
     inflight.expose("batch_inflight",
                     "batch-pipeline calls currently in flight, summed "
@@ -125,6 +150,18 @@ struct BatchPipelineVars {
     submits.expose("batch_submits", "accepted trpc_batch_submit crossings");
     submit_us.expose("batch_submit_us",
                      "us inside trpc_batch_submit, entry to return");
+    staged_calls.expose("batch_staged_calls",
+                        "the calls in batch_calls_polled whose request "
+                        "went through the pipeline's stager");
+    stage_us.expose("batch_stage_us",
+                    "us from the staging of a request (host_view started "
+                    "its device-to-host transfer) to the entry of the "
+                    "native submit that carried the call");
+    stage_fetch_us.expose("batch_stage_fetch_us",
+                          "us the stager was blocked waiting for the "
+                          "bytes of the requests of batch_staged_calls");
+    stage_fetch_bytes.expose("batch_stage_fetch_bytes",
+                             "request bytes those waits resolved");
   }
 };
 
@@ -168,6 +205,11 @@ struct BatchCall {
   // hand-off, the done-ring's release push and poll's acquire pop
   // already order every write before the poll that reads it.
   int64_t enter_us = 0;  // entry of the trpc_batch_submit that made it
+  // Staged calls only (else 0): when the request's transfer to the host
+  // was started, how long the stager was blocked on it, for what bytes.
+  int64_t staged_us = 0;
+  int64_t fetch_us = 0;
+  int64_t fetch_bytes = 0;
   // Stamped just before CallMethod — also the batch's own clock for the
   // rpc_client_batch recorder.  (Channel stamps cntl.call().start_us,
   // but ClusterChannel never does; relying on it dropped every cluster
@@ -209,6 +251,12 @@ struct Batch {
   std::unordered_map<uint64_t, BatchCall*> calls;
   std::mutex poll_mu_;       // serializes consumers
   BatchCall* drained = nullptr;  // consumer-local FIFO (reversed chain)
+  // Single-connection channels: calls waiting for the one issuing fiber,
+  // in submit order over ALL submits (the stager crosses once per landed
+  // request, and two issuing fibers could swap their first CallMethods).
+  std::mutex issue_mu_;
+  std::deque<BatchCall*> issue_q;
+  bool issuer_live = false;
 };
 
 // Completion path — runs on whatever fiber finishes the call (dispatch
@@ -292,6 +340,11 @@ void on_call_done(BatchCall* c) {
 // Issues ONE call asynchronously (the per-call body shared by both issue
 // strategies).  Consumes the issuer reference.
 void issue_call(Batch* b, BatchCall* c) {
+  if (c->cntl.Failed()) {  // handed over already failed (staging)
+    on_call_done(c);
+    unref(c);
+    return;
+  }
   if (b->closing.load(std::memory_order_acquire) ||
       c->canceled.load(std::memory_order_acquire)) {
     c->cntl.SetFailed(ECANCELED, "canceled before issue");
@@ -367,18 +420,24 @@ void issuer_exit(Batch* b) {
   b->issuers.fetch_sub(1, std::memory_order_release);
 }
 
-struct IssueJob {
-  Batch* b = nullptr;
-  std::vector<BatchCall*> calls;
-};
-
 // FIFO strategy (single-connection channels): replays the submitted
 // calls IN ORDER on one fiber, so issue order IS wire order (one writer,
 // FIFO write queue).  Completions are correlation-matched, not ordered.
+// The fiber lives while issue_q has calls; the submit that finds none
+// live starts the next.
 void issuer_main(void* p) {
-  std::unique_ptr<IssueJob> job(static_cast<IssueJob*>(p));
-  Batch* b = job->b;
-  for (BatchCall* c : job->calls) {
+  auto* b = static_cast<Batch*>(p);
+  for (;;) {
+    BatchCall* c = nullptr;
+    {
+      std::lock_guard<std::mutex> g(b->issue_mu_);
+      if (b->issue_q.empty()) {
+        b->issuer_live = false;
+        break;
+      }
+      c = b->issue_q.front();
+      b->issue_q.pop_front();
+    }
     issue_call(b, c);
   }
   issuer_exit(b);
@@ -444,6 +503,10 @@ struct PhaseSums {
   int64_t ready_us = 0;
   int64_t resp_bytes = 0;
   int64_t land_copy_bytes = 0;
+  int64_t staged = 0;
+  int64_t stage_us = 0;
+  int64_t fetch_us = 0;
+  int64_t fetch_bytes = 0;
 
   void count(const BatchCall* c, int64_t polled_us) {
     if (c->status != 0) {
@@ -456,9 +519,17 @@ struct PhaseSums {
     queue_us += c->issue_us - c->enter_us;
     wire_us += c->reply_us - c->issue_us;
     land_us += c->landed_us - c->reply_us;
-    ready_us += polled_us - c->landed_us;
+    // One reading serves a whole drain, and a call may land after it
+    // and still be popped by that drain: it waited no time, not less.
+    ready_us += std::max<int64_t>(polled_us - c->landed_us, 0);
     resp_bytes += static_cast<int64_t>(c->resp_len);
     land_copy_bytes += static_cast<int64_t>(c->land_copied);
+    if (c->staged_us != 0) {
+      ++staged;
+      stage_us += c->enter_us - c->staged_us;
+      fetch_us += c->fetch_us;
+      fetch_bytes += c->fetch_bytes;
+    }
   }
 
   void publish() const {
@@ -476,8 +547,132 @@ struct PhaseSums {
     v.ready_us << ready_us;
     v.resp_bytes << resp_bytes;
     v.land_copy_bytes << land_copy_bytes;
+    if (staged == 0) {
+      return;
+    }
+    v.staged_calls << staged;
+    v.stage_us << stage_us;
+    v.stage_fetch_us << fetch_us;
+    v.stage_fetch_bytes << fetch_bytes;
   }
 };
+
+// The body of both submits.  `stages` (nullable, n entries) carries the
+// reserved token and the staging facts of each call; without it every
+// call takes a fresh token.
+size_t submit_calls(Batch* b, const char* method, const void* const* reqs,
+                    const size_t* req_lens, void* const* resp_bufs,
+                    const size_t* resp_caps, size_t n, int64_t timeout_ms,
+                    void (*req_deleter)(void*, void*),
+                    void* const* req_deleter_ctxs,
+                    const trpc_batch_stage* stages, uint64_t* tokens_out) {
+  const int64_t enter_us = monotonic_time_us();
+  if (b == nullptr || n == 0 || method == nullptr ||
+      b->closing.load(std::memory_order_acquire)) {
+    return 0;
+  }
+  // rpcz: one parent span per submit.  start_span resolves the parent
+  // from THIS thread's ambient context — ctypes callers run submit on
+  // their own pthread, where a Python trace()/trpc_trace_set installed
+  // it — so the whole batch hangs under the user's trace.
+  SubmitGroup* group = nullptr;
+  if (rpcz_enabled()) {
+    group = new SubmitGroup();
+    group->span =
+        start_span(/*server_side=*/false, std::string("batch:") + method);
+    span_annotate(group->span, "submit n=" + std::to_string(n));
+    group->remaining.store(static_cast<int64_t>(n),
+                           std::memory_order_relaxed);
+  }
+  const int64_t now_inflight =
+      g_batch_inflight.fetch_add(static_cast<int64_t>(n),
+                                 std::memory_order_relaxed) +
+      static_cast<int64_t>(n);
+  batch_vars().depth << now_inflight;
+  std::vector<BatchCall*> calls;
+  calls.reserve(n);
+  for (size_t i = 0; i < n; ++i) {
+    auto* c = new BatchCall();
+    c->batch = b;
+    c->group = group;
+    c->enter_us = enter_us;
+    if (stages != nullptr) {
+      const trpc_batch_stage& st = stages[i];
+      c->token = st.token;
+      c->staged_us = st.staged_us;
+      c->fetch_us = st.fetch_us;
+      c->fetch_bytes = static_cast<int64_t>(st.fetch_bytes);
+      if (st.status != 0) {
+        c->cntl.SetFailed(st.status,
+                          st.err != nullptr ? st.err : "request staging failed");
+      }
+    } else {
+      c->token = b->next_token.fetch_add(1, std::memory_order_relaxed);
+    }
+    c->method = method;
+    if (reqs != nullptr && reqs[i] != nullptr && req_lens[i] > 0) {
+      if (req_deleter != nullptr) {
+        c->request.append_user_data(
+            const_cast<void*>(reqs[i]), req_lens[i], req_deleter,
+            req_deleter_ctxs != nullptr ? req_deleter_ctxs[i] : nullptr);
+      } else {
+        c->request.append(reqs[i], req_lens[i]);
+      }
+    }
+    if (resp_bufs != nullptr && resp_bufs[i] != nullptr) {
+      c->resp_buf = resp_bufs[i];
+      c->resp_cap = resp_caps != nullptr ? resp_caps[i] : 0;
+    }
+    c->timeout_ms = timeout_ms;
+    // The completion closure is bounded framework work (memcpy + atomic
+    // push + wake): safe to run inline on a dispatch fiber, no per-call
+    // completion-fiber spawn.
+    c->cntl.set_done_inline_safe(true);
+    if (tokens_out != nullptr) {
+      tokens_out[i] = c->token;
+    }
+    calls.push_back(c);
+  }
+  {
+    std::lock_guard<std::mutex> g(b->mu_);
+    for (BatchCall* c : calls) {
+      b->calls.emplace(c->token, c);
+    }
+  }
+  b->outstanding.fetch_add(static_cast<int64_t>(n),
+                           std::memory_order_release);
+  // Single-connection channels get ONE issuing fiber (issue order = wire
+  // order); everything with per-call connections fans out one fiber per
+  // call so their inline request writes run concurrently.
+  const bool fifo =
+      !b->is_cluster &&
+      static_cast<Channel*>(b->channel)->conn_type_raw() == 0;
+  if (fifo) {
+    bool start = false;
+    {
+      std::lock_guard<std::mutex> g(b->issue_mu_);
+      b->issue_q.insert(b->issue_q.end(), calls.begin(), calls.end());
+      if (!b->issuer_live) {
+        b->issuer_live = start = true;
+        b->issuers.fetch_add(1, std::memory_order_release);
+      }
+    }
+    if (start && fiber_start(nullptr, issuer_main, b, 0) != 0) {
+      issuer_main(b);  // pool exhausted: issue on the caller (GIL
+                       // already released by ctypes), never drop
+    }
+  } else {
+    b->issuers.fetch_add(static_cast<int>(n), std::memory_order_release);
+    const size_t started = fiber_start_batch(
+        issue_one_main, reinterpret_cast<void* const*>(calls.data()), n, 0);
+    for (size_t i = started; i < n; ++i) {
+      issue_one_main(calls[i]);  // pool exhausted: issue inline
+    }
+  }
+  batch_vars().submits << 1;
+  batch_vars().submit_us << monotonic_time_us() - enter_us;
+  return n;
+}
 
 }  // namespace
 
@@ -516,96 +711,47 @@ size_t trpc_batch_submit(void* batch, const char* method,
                          void (*req_deleter)(void*, void*),
                          void* const* req_deleter_ctxs,
                          uint64_t* tokens_out) {
-  const int64_t enter_us = monotonic_time_us();
+  return submit_calls(static_cast<Batch*>(batch), method, reqs, req_lens,
+                      resp_bufs, resp_caps, n, timeout_ms, req_deleter,
+                      req_deleter_ctxs, nullptr, tokens_out);
+}
+
+// Takes n tokens for calls that trpc_batch_submit_staged will carry
+// later: the caller can name its calls (return them, pin buffers under
+// them, cancel them) before their request bytes exist on the host.
+// Returns n, or 0 once the batch is closing.
+size_t trpc_batch_reserve(void* batch, size_t n, uint64_t* tokens_out) {
   auto* b = static_cast<Batch*>(batch);
-  if (b == nullptr || n == 0 || method == nullptr ||
+  if (b == nullptr || tokens_out == nullptr ||
       b->closing.load(std::memory_order_acquire)) {
     return 0;
   }
-  // rpcz: one parent span per submit.  start_span resolves the parent
-  // from THIS thread's ambient context — ctypes callers run submit on
-  // their own pthread, where a Python trace()/trpc_trace_set installed
-  // it — so the whole batch hangs under the user's trace.
-  SubmitGroup* group = nullptr;
-  if (rpcz_enabled()) {
-    group = new SubmitGroup();
-    group->span =
-        start_span(/*server_side=*/false, std::string("batch:") + method);
-    span_annotate(group->span, "submit n=" + std::to_string(n));
-    group->remaining.store(static_cast<int64_t>(n),
-                           std::memory_order_relaxed);
-  }
-  const int64_t now_inflight =
-      g_batch_inflight.fetch_add(static_cast<int64_t>(n),
-                                 std::memory_order_relaxed) +
-      static_cast<int64_t>(n);
-  batch_vars().depth << now_inflight;
-  auto job = std::make_unique<IssueJob>();
-  job->b = b;
-  job->calls.reserve(n);
+  const uint64_t first = b->next_token.fetch_add(n, std::memory_order_relaxed);
   for (size_t i = 0; i < n; ++i) {
-    auto* c = new BatchCall();
-    c->batch = b;
-    c->group = group;
-    c->enter_us = enter_us;
-    c->token = b->next_token.fetch_add(1, std::memory_order_relaxed);
-    c->method = method;
-    if (reqs != nullptr && reqs[i] != nullptr && req_lens[i] > 0) {
-      if (req_deleter != nullptr) {
-        c->request.append_user_data(
-            const_cast<void*>(reqs[i]), req_lens[i], req_deleter,
-            req_deleter_ctxs != nullptr ? req_deleter_ctxs[i] : nullptr);
-      } else {
-        c->request.append(reqs[i], req_lens[i]);
-      }
-    }
-    if (resp_bufs != nullptr && resp_bufs[i] != nullptr) {
-      c->resp_buf = resp_bufs[i];
-      c->resp_cap = resp_caps != nullptr ? resp_caps[i] : 0;
-    }
-    c->timeout_ms = timeout_ms;
-    // The completion closure is bounded framework work (memcpy + atomic
-    // push + wake): safe to run inline on a dispatch fiber, no per-call
-    // completion-fiber spawn.
-    c->cntl.set_done_inline_safe(true);
-    if (tokens_out != nullptr) {
-      tokens_out[i] = c->token;
-    }
-    job->calls.push_back(c);
+    tokens_out[i] = first + i;
   }
-  {
-    std::lock_guard<std::mutex> g(b->mu_);
-    for (BatchCall* c : job->calls) {
-      b->calls.emplace(c->token, c);
-    }
-  }
-  b->outstanding.fetch_add(static_cast<int64_t>(n),
-                           std::memory_order_release);
-  // Single-connection channels get ONE issuing fiber (issue order = wire
-  // order); everything with per-call connections fans out one fiber per
-  // call so their inline request writes run concurrently.
-  const bool fifo =
-      !b->is_cluster &&
-      static_cast<Channel*>(b->channel)->conn_type_raw() == 0;
-  if (fifo || n == 1) {
-    b->issuers.fetch_add(1, std::memory_order_release);
-    IssueJob* raw = job.release();
-    if (fiber_start(nullptr, issuer_main, raw, 0) != 0) {
-      issuer_main(raw);  // pool exhausted: issue on the caller (GIL
-                         // already released by ctypes), never drop
-    }
-  } else {
-    b->issuers.fetch_add(static_cast<int>(n), std::memory_order_release);
-    const size_t started = fiber_start_batch(
-        issue_one_main,
-        reinterpret_cast<void* const*>(job->calls.data()), n, 0);
-    for (size_t i = started; i < n; ++i) {
-      issue_one_main(job->calls[i]);  // pool exhausted: issue inline
-    }
-  }
-  batch_vars().submits << 1;
-  batch_vars().submit_us << monotonic_time_us() - enter_us;
   return n;
+}
+
+// trpc_batch_submit for calls whose tokens were reserved: stages[i] names
+// call i's token, when its request was staged and what the stager waited
+// for it; a call handed over with a non-zero status is never issued and
+// completes through the ring with that status, in its turn.
+size_t trpc_batch_submit_staged(void* batch, const char* method,
+                                const void* const* reqs,
+                                const size_t* req_lens,
+                                void* const* resp_bufs,
+                                const size_t* resp_caps, size_t n,
+                                int64_t timeout_ms,
+                                void (*req_deleter)(void*, void*),
+                                void* const* req_deleter_ctxs,
+                                const trpc_batch_stage* stages) {
+  if (stages == nullptr) {
+    return 0;
+  }
+  return submit_calls(static_cast<Batch*>(batch), method, reqs, req_lens,
+                      resp_bufs, resp_caps, n, timeout_ms, req_deleter,
+                      req_deleter_ctxs, stages, nullptr);
 }
 
 // Drains up to max completion records, blocking the calling PTHREAD (not

@@ -1,6 +1,8 @@
 """The batch pipeline's phase clocks (cpp/capi/batch_capi.cc): five stamps
 in each call's own state, folded at poll into always-on `batch_*`
-counters of the native registry.
+counters of the native registry; a staged call (batch.py's stager) also
+carries when its request's transfer to the host was started, the fifth
+phase in front of the four.
 
 Everything here reads the counters as deltas around one pipeline's
 traffic on CPU loopback; the registry is the process's, so each test
@@ -12,13 +14,16 @@ import time
 
 import numpy as np
 import pytest
+from test_batch_staging import HeldArray
 from test_hotpath_vars import _vars_json
 
-from brpc_tpu.rpc import Channel, Server, observe
+from brpc_tpu.rpc import Channel, Server, observe, zerocopy
 
 PHASES = ("batch_queue_us", "batch_wire_us", "batch_land_us",
           "batch_ready_us")
-COUNTERS = PHASES + (
+STAGING = ("batch_staged_calls", "batch_stage_us", "batch_stage_fetch_us",
+           "batch_stage_fetch_bytes")
+COUNTERS = PHASES + STAGING + (
     "batch_calls_polled", "batch_calls_failed", "batch_resp_bytes",
     "batch_land_copy_bytes", "batch_submits", "batch_submit_us")
 
@@ -88,6 +93,59 @@ def test_every_polled_call_counts_once_and_the_phases_fit_its_interval(echo):
     assert moved["batch_submits"] == 1
     assert 0 <= moved["batch_submit_us"] <= interval_us + 1
     assert moved["batch_resp_bytes"] == n * 4096
+    assert all(moved[name] == 0 for name in STAGING)   # nothing was pending
+
+
+def test_a_polled_call_counts_as_staged_once_or_not_at_all_and_the_five_fit(
+        echo):
+    _, pipe = echo
+    n, size = 6, 4096
+    direct = [np.full(size, 100 + i, dtype=np.uint8) for i in range(n)]
+    before = _read()
+    pipe.submit("Echo.Echo", direct)
+    _drain(pipe, n)
+    t0 = time.perf_counter()
+    held = [HeldArray(np.full(size, i, dtype=np.uint8), held=False)
+            for i in range(n)]
+    views = [zerocopy.host_view(a)[0] for a in held]
+    # One ready request behind the pending ones rides the stager too.
+    pipe.submit("Echo.Echo", views + [direct[0]])
+    done = _drain(pipe, n + 1)
+    interval_us = (time.perf_counter() - t0) * 1e6
+    moved = _moved(before)
+    assert all(c.ok for c in done)
+    assert moved["batch_calls_polled"] == 2 * n + 1
+    assert moved["batch_staged_calls"] == n + 1
+    assert moved["batch_stage_fetch_bytes"] == n * size    # the pending ones
+    assert 0 <= moved["batch_stage_fetch_us"] <= interval_us + 1
+    # staged ... polled of every staged call lies inside host_view ...
+    # last poll; the direct calls (stage 0) were settled before t0.
+    assert 0 < moved["batch_stage_us"] <= (n + 1) * (interval_us + 1)
+
+
+def test_a_held_fetch_shows_in_stage_and_in_no_other_phase(echo):
+    _, pipe = echo
+    n, size, hold_s = 3, 4096, 0.06
+    held = [HeldArray(np.full(size, i, dtype=np.uint8)) for i in range(n)]
+    before = _read()
+    t0 = time.perf_counter()
+    views = [zerocopy.host_view(a)[0] for a in held]
+    pipe.submit("Echo.Echo", views)
+    time.sleep(hold_s)
+    for a in held:
+        a.release()
+    _drain(pipe, n)
+    interval_us = (time.perf_counter() - t0) * 1e6
+    moved = _moved(before)
+    assert moved["batch_calls_polled"] == moved["batch_staged_calls"] == n
+    assert moved["batch_stage_us"] >= n * hold_s * 1e6
+    assert sum(moved[phase] for phase in PHASES) < n * hold_s * 1e6
+    # The five phases are polled_us - staged_us of each call.
+    five = moved["batch_stage_us"] + sum(moved[phase] for phase in PHASES)
+    assert n * hold_s * 1e6 <= five <= n * (interval_us + 1)
+    # The stager waited for the first; the others had landed by then.
+    assert hold_s * 1e6 * 0.9 <= moved["batch_stage_fetch_us"] <= interval_us
+    assert moved["batch_stage_fetch_bytes"] == n * size
 
 
 def test_a_finished_call_left_unpolled_waits_in_ready_not_on_the_wire(echo):
@@ -153,10 +211,29 @@ def test_a_call_that_times_out_counts_as_failed_and_in_no_sum(echo):
     assert {c.status for c in done} == {errno.ETIMEDOUT}
     assert moved["batch_calls_failed"] == n
     assert moved["batch_submits"] == 1
-    for name in PHASES + ("batch_calls_polled", "batch_resp_bytes",
-                          "batch_land_copy_bytes"):
+    for name in PHASES + STAGING + ("batch_calls_polled", "batch_resp_bytes",
+                                    "batch_land_copy_bytes"):
         assert moved[name] == 0, name
     time.sleep(0.45)  # the parked handlers answer into a live server
+
+
+def test_a_staged_call_that_never_issues_counts_as_failed_and_in_no_sum(
+        echo):
+    _, pipe = echo
+    lost = HeldArray(np.zeros(64, dtype=np.uint8), held=False, fails=True)
+    held = HeldArray(np.ones(64, dtype=np.uint8))
+    before = _read()
+    tokens = pipe.submit("Echo.Echo", [zerocopy.host_view(lost)[0],
+                                       zerocopy.host_view(held)[0]])
+    assert pipe.cancel(tokens[1])
+    held.release()
+    done = _drain(pipe, 2)
+    moved = _moved(before)
+    assert sorted(c.status for c in done) == sorted(
+        [errno.EIO, errno.ECANCELED])
+    assert moved["batch_calls_failed"] == 2
+    for name in PHASES + STAGING + ("batch_calls_polled",):
+        assert moved[name] == 0, name
 
 
 def test_the_phase_counters_are_on_the_vars_page_with_their_descriptions(
@@ -169,3 +246,4 @@ def test_the_phase_counters_are_on_the_vars_page_with_their_descriptions(
         # Adders are Prometheus counters: `<name>_total` with a HELP line.
         assert f"# HELP {name}_total " in exposition, name
     assert "done-ring" in exposition
+    assert "stager" in exposition
