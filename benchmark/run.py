@@ -4,10 +4,12 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The last line of stdout is one JSON object with exactly the keys
-`correct`, `attempted`, `failed`, `metrics`, `device` and, in a traced
-run, `breakdown`; with `--trace 0` the metrics are the cell's end-to-end
-metrics, with `--trace 1` its per-layer metrics.  Everything else a
-reader may want is on earlier lines, each a JSON object with a `note`.
+`correct`, `attempted`, `failed`, `metrics`, `device`, in a traced run
+`breakdown`, and last `compared`: each number that `correct` rests on
+beside its limit, which are also the last lines of stderr.  With
+`--trace 0` the metrics are the cell's end-to-end metrics, with
+`--trace 1` its per-layer metrics.  Everything else a reader may want is
+on earlier lines, each a JSON object with a `note`.
 Without the chips the cell asks for the run exits non-zero and prints no
 result; no failure is turned into a null.
 
@@ -105,6 +107,22 @@ def _tracing_cost(ev) -> dict:
             "traced_s": stop - start}
 
 
+def _compared(ev) -> dict:
+    """Each number `correct` rests on, beside its limit.  The compares
+    are exact (a byte-exact echo, an exchange against its reference), so
+    every limit is 0: the calls that failed, timed out or mismatched, and
+    1 for each fact a driver notes beside what the configuration expects
+    of it (`transport` beside `transport_expected`) where the two
+    differ."""
+    out = {"failed_calls": {"value": int(ev.failed), "limit": 0}}
+    for key, expected in ev.notes.items():
+        if key.endswith("_expected"):
+            fact = key[:-len("_expected")]
+            out[f"{fact}_differs"] = {
+                "value": int(ev.notes.get(fact) != expected), "limit": 0}
+    return out
+
+
 def run_cell(manifest: Manifest, cell_name: str, seed: int, seconds: float,
              trace: bool, platform: str = "tpu", interpret: bool = False,
              load_trace=trace_reduce.load_xplane) -> tuple[dict, list[dict]]:
@@ -118,7 +136,10 @@ def run_cell(manifest: Manifest, cell_name: str, seed: int, seconds: float,
     readers = {m["name"]: manifest.reader(m["name"])
                for m in cell.per_layer} if trace else {}
     for name, reader in readers.items():
-        if cell.driver_name not in reader.DRIVERS:
+        # DRIVERS = None: the reader needs only what every driver hands
+        # back (the trace, the call samples), so it reads any driver.
+        if (reader.DRIVERS is not None
+                and cell.driver_name not in reader.DRIVERS):
             raise ManifestError(
                 f"{name} is listed for {cell.name} but reads "
                 f"{reader.DRIVERS}, not {cell.driver_name!r}")
@@ -175,6 +196,7 @@ def run_cell(manifest: Manifest, cell_name: str, seed: int, seconds: float,
         result["breakdown"] = {
             "device_ops": trace_reduce.top_device_ops(ev.trace),
             "idle_gaps": trace_reduce.idle_by_span(ev.trace)}
+    result["compared"] = _compared(ev)
     top = stats.highest_supported(len(ev.call_s))
     notes = [{"note": "run", "workload": cell.name, "seed": seed,
               "seconds": seconds, "trace": trace,
@@ -205,6 +227,9 @@ def main(argv=None) -> int:
     for note in notes:
         print(json.dumps(note), flush=True)
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -1,7 +1,11 @@
 """Each driver end to end on the CPU at tiny sizes, through run.py's own
-functions: the traffic files of a copy of the tree are shrunk (sizes are
-data), Pallas runs interpreted, the mesh is four of the virtual CPU
-devices.  Nothing here is a measurement."""
+functions: the traffic files of a copy of the tree are shrunk to what
+each says under `rehearsal` (sizes are data, and so are a rehearsal's),
+Pallas runs interpreted, the mesh is four of the virtual CPU devices, and
+the device trace is the one recorded on the chip under the driver's name.
+Nothing here names a cell, a mix or a driver of its own accord, so a cell
+a later PR adds as files is rehearsed as it stands.  Nothing here is a
+measurement."""
 
 import gzip
 import json
@@ -17,39 +21,60 @@ from benchmark.manifest import Manifest
 from benchmark.payload import ReusedArray, SendOnce
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent.parent
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
-TINY = {
-    "tensor64M": {"payload_bytes": 1 << 20, "warm_calls": 8},
-    "small1K": {"warm_calls": 64},
-    "exchange64M": {"bytes_per_chip": 1 << 16, "warm_calls": 4},
-}
-RECORDED = {"served_echo": "trace_served_echo.json.gz",
-            "mesh_exchange": "trace_mesh_exchange.json.gz"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
+
+
+def copy_tree(root) -> None:
+    """BENCHMARK.json and the benchmark's own directory, copied to `root`."""
+    shutil.copy(ROOT / "BENCHMARK.json", root)
+    shutil.copytree(ROOT / "benchmark", root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+
+
+def shrink_traffic(root) -> None:
+    """Every mix under `root` overwritten with its own `rehearsal` sizes.
+    A `seconds` there, which no driver reads, is the rehearsal's window
+    for that mix (`_rehearse`), where the one second that is the rule
+    leaves a tail too few samples to be read."""
+    for path in sorted((root / "benchmark" / "traffic").glob("*.json")):
+        mix = json.loads(path.read_text())
+        if not isinstance(mix.get("rehearsal"), dict):
+            raise KeyError(
+                f"{path.name} has no `rehearsal` object: the keys the CPU "
+                "rehearsal overwrites, {} where the mix is small already")
+        mix.update(mix["rehearsal"], trace_seconds=0.5)
+        path.write_text(json.dumps(mix))
+
+
+def recorded_trace(manifest, driver_name: str) -> pathlib.Path:
+    """The trace recorded on the chip for this driver, by its name."""
+    path = manifest.home / "testdata" / f"trace_{driver_name}.json.gz"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"driver {driver_name!r} has no recorded trace {path}: the "
+            "CPU has no device plane, so a traced rehearsal reads one")
+    return path
 
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
     """The benchmark's tree with its traffic shrunk to a rehearsal."""
     root = tmp_path_factory.mktemp("tiny")
-    shutil.copy(ROOT / "BENCHMARK.json", root)
-    shutil.copytree(ROOT / "benchmark", root / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    for name, smaller in TINY.items():
-        path = root / "benchmark" / "traffic" / f"{name}.json"
-        mix = json.loads(path.read_text())
-        mix.update(smaller, trace_seconds=0.5)
-        path.write_text(json.dumps(mix))
+    copy_tree(root)
+    shrink_traffic(root)
     return Manifest(root)
 
 
 def _rehearse(manifest, cell, seed=7, trace=False):
     def recorded(_trace_dir):
-        name = RECORDED[manifest.cell(cell).driver_name]
-        with gzip.open(ROOT / "benchmark" / "testdata" / name, "rt") as f:
+        path = recorded_trace(manifest, manifest.cell(cell).driver_name)
+        with gzip.open(path, "rt") as f:
             return json.load(f)
 
-    return run.run_cell(manifest, cell, seed, 1.0, trace, platform="cpu",
-                        interpret=True, load_trace=recorded)
+    seconds = float(manifest.cell(cell).traffic.get("seconds", 1.0))
+    return run.run_cell(manifest, cell, seed, seconds, trace,
+                        platform="cpu", interpret=True, load_trace=recorded)
 
 
 @pytest.fixture(scope="module")
@@ -73,6 +98,11 @@ def test_untraced_run_prints_the_cells_end_to_end_metrics(
     assert set(result["device"]) == {"platform", "kind", "count",
                                      "memory_peak_bytes"}
     assert result["device"]["platform"] == "cpu"
+    # Each number compared beside its limit, as the line's last key.
+    assert list(result)[-1] == "compared"
+    assert "failed_calls" in result["compared"]
+    for c in result["compared"].values():
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
     json.dumps(result)
     driver_note = next(n for n in notes if n["note"] == "driver")
     if tiny.cell(cell).driver_name == "served_echo":
@@ -142,36 +172,62 @@ class _FaultyPipeline:
         self._real.close()
 
 
+def _cells_of(driver_name: str) -> list[str]:
+    manifest = Manifest(ROOT)
+    return [name for name in manifest.cell_names()
+            if manifest.cell(name).driver_name == driver_name]
+
+
+@pytest.mark.parametrize("cell", _cells_of("served_echo"))
 @pytest.mark.parametrize("fault", ["never_written", "tail_never_written",
                                    "tail_of_another_call"])
 def test_a_response_the_transport_did_not_deliver_fails_the_run(
-        tiny, monkeypatch, fault):
-    """The echo at this width is a copy: only because every word of a
-    request differs from the same word of every other does a stale or
-    misdelivered chunk fail the compare."""
+        tiny, monkeypatch, fault, cell):
+    """The control of every served cell: the guarantee broken is that a
+    response is byte-exact against its request.  The echo is a copy:
+    only because every word of a request differs from the same word of
+    every other does a stale or misdelivered chunk fail the compare."""
     from brpc_tpu.rpc import Channel
 
     real = Channel.pipeline
+    # 40 calls into the window: a mismatch of the warm-up would count in
+    # `failed` and in no `attempted`.
+    after = 40 + int(tiny.cell(cell).traffic["warm_calls"])
     monkeypatch.setattr(
         Channel, "pipeline",
-        lambda self: _FaultyPipeline(real(self), fault, after=40))
-    result, notes = _rehearse(tiny, "echo_tcp.tensor64M")
+        lambda self: _FaultyPipeline(real(self), fault, after=after))
+    result, notes = _rehearse(tiny, cell)
     driver_note = next(n for n in notes if n["note"] == "driver")
-    assert driver_note["device_step"] == "echo_fused"
+    fused_from = tiny.driver("served_echo").FUSED_FROM_BYTES
+    assert (driver_note["device_step"] == "echo_fused") == (
+        tiny.cell(cell).traffic["payload_bytes"] >= fused_from)
     assert result["attempted"] > 40
     assert result["correct"] is False
     assert 0 < result["failed"] <= result["attempted"]
+    compared = result["compared"]["failed_calls"]
+    assert compared == {"value": result["failed"], "limit": 0}
+
+
+def test_an_exchange_left_out_between_the_chips_fails_the_run(
+        tiny, monkeypatch):
+    """The mesh cell's control: every peer keeps the rows it had, and its
+    checksums are right for what it holds; only the compare against the
+    reference's transposition sees it."""
+    from brpc_tpu.transport.ici import IciTransport
+
+    monkeypatch.setattr(IciTransport, "all_to_all",
+                        lambda self, local, *a, **kw: local)
+    result, _ = _rehearse(tiny, "mesh_nton.exchange64M")
+    assert result["attempted"] > 0
+    assert result["correct"] is False and result["failed"] > 0
+    assert result["compared"]["failed_calls"]["value"] > 0
 
 
 def test_a_mix_with_one_call_in_flight_is_data_only(tiny, tmp_path):
-    """PERF.md's `sync1` mixes: the wire in series with the staging."""
+    """PERF.md's next row: the `sync64M` mix on `echo_shm`, the wire in
+    series with the staging."""
     root = tmp_path / "sync1"
     shutil.copytree(tiny.root, root)
-    mix = json.loads(
-        (root / "benchmark" / "traffic" / "tensor64M.json").read_text())
-    mix["calls_in_flight"] = 1
-    (root / "benchmark" / "traffic" / "sync64M.json").write_text(
-        json.dumps(mix))
     doc = json.loads((root / "BENCHMARK.json").read_text())
     doc["workloads"].append({
         "name": "echo_shm.sync64M", "config": "echo_shm",
@@ -180,7 +236,9 @@ def test_a_mix_with_one_call_in_flight_is_data_only(tiny, tmp_path):
         if "echo_shm.tensor64M" in m.get("workloads", []):
             m["workloads"].append("echo_shm.sync64M")
     (root / "BENCHMARK.json").write_text(json.dumps(doc))
-    result, _ = _rehearse(Manifest(root), "echo_shm.sync64M")
+    grown = Manifest(root)
+    assert grown.cell("echo_shm.sync64M").traffic["calls_in_flight"] == 1
+    result, _ = _rehearse(grown, "echo_shm.sync64M")
     assert result["correct"] is True and result["attempted"] > 0
     assert set(result["metrics"]) == {"goodput", "call_p50", "setup_s"}
 

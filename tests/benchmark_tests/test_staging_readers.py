@@ -44,16 +44,43 @@ def test_the_readers_divide_what_the_window_counted():
     assert [_read(name, idle) for name in NEW] == [0.0, 0.0, 0.0]
 
 
+def test_one_sided_share_is_over_the_sends_of_the_window():
+    """Both counters close with the send, so calls that were sent in the
+    window and polled after it (or the other way round) cannot lift the
+    share over 100, as they did while the divisor was the polled calls'
+    bytes (100.07 on the chip, PR 25)."""
+    body = float(64 << 20)
+    sent = {"rma_tx_bytes": 30 * body, "stripe_tx_bytes": 10 * body,
+            "batch_resp_bytes": 12 * body, "zero_copy_bytes": 0.0}
+    assert _read("one_sided_share", sent) == 75.0
+    assert _read("one_sided_share", sent | {"stripe_tx_bytes": 0.0}) == 100.0
+    assert _read("one_sided_share", sent | {"rma_tx_bytes": 0.0}) == 0.0
+    # Bodies under the stripe threshold: none moves one-sided.
+    small = {"rma_tx_bytes": 0.0, "stripe_tx_bytes": 0.0,
+             "batch_resp_bytes": 4096.0}
+    assert _read("one_sided_share", small) == 0.0
+    assert _read("one_sided_share", small | {"batch_resp_bytes": 0.0}) is None
+    assert _read("one_sided_share", {}) is None
+
+
 def test_the_manifest_lists_them_for_the_served_cells_only():
+    """Asked of the manifest, not read off a cell's name: a cell whose
+    driver the readers do not read lists none of the three; a cell they
+    read lists the two that exist at every width, and the fetch stream's
+    rate from the width at which the driver's step is `echo_fused`."""
     manifest = Manifest(ROOT)
-    for cell in manifest.cell_names():
-        listed = {m["name"] for m in manifest.cell(cell).per_layer}
-        if cell.startswith("mesh_"):
-            assert not listed & set(NEW)
-        elif cell.endswith("small1K"):
-            assert listed & set(NEW) == {"staged_share", "call_stage_us"}
+    for name in manifest.cell_names():
+        cell = manifest.cell(name)
+        listed = {m["name"] for m in cell.per_layer} & set(NEW)
+        if any(cell.driver_name not in manifest.reader(n).DRIVERS
+               for n in NEW):
+            assert not listed
+            continue
+        fused_from = manifest.driver(cell.driver_name).FUSED_FROM_BYTES
+        if cell.traffic["payload_bytes"] >= fused_from:
+            assert listed == set(NEW)
         else:
-            assert listed >= set(NEW)
+            assert listed == {"staged_share", "call_stage_us"}
 
 
 def _v5e_peaks_for_the_cpu(tmp_path, monkeypatch):
