@@ -1,0 +1,8 @@
+"""D2H/H2D staging: a block's bytes over the median `jax.device_put` of
+the landed page ended by `block_until_ready` (`h2d_rate`'s span)."""
+
+from benchmark.layer_metrics import h2d_rate
+
+UNIT = "GB/s"
+DRIVERS = ("kv_pull",)
+read = h2d_rate.read

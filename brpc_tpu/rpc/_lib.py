@@ -374,6 +374,8 @@ def load_library() -> ctypes.CDLL:
             lib.trpc_kv_codes.restype = None
             lib.trpc_kv_reset.argtypes = []
             lib.trpc_kv_reset.restype = None
+            lib.trpc_kv_note_fetch_many.argtypes = [ctypes.c_uint64]
+            lib.trpc_kv_note_fetch_many.restype = None
             # Content-addressed prefix cache (capi/kv_capi.cc; ISSUE 17).
             lib.trpc_kv_content_hash.argtypes = [
                 ctypes.c_void_p, ctypes.c_size_t,

@@ -35,6 +35,24 @@ Typical decode side::
     cli = kv.KvClient(registry_addr, use_shm=True)
     land = RmaBuffer(4 << 20)
     n = cli.fetch(1001, resp_buf=land.view)   # one-sided landing
+
+A paged latent cache (brpc_tpu/models/kv_pool.py) moves one page at a
+time, one record a layer, a block's records in one registry RPC and its
+fetches in flight together.  Prefill side, the page still in HBM::
+
+    slab = RmaBuffer(page.nbytes)
+    kv.publish_page(7, kv_pool.read_page(prefill_pool, slot), slab,
+                    node=addr, registry=reg)
+
+Decode side, into its own pool in HBM::
+
+    land = RmaBuffer(page_bytes)
+    host = np.frombuffer(land.view, np.uint16).reshape(
+        layers, page_tokens, width)
+    cli.fetch_page(7, host)                   # 61 Kv.Fetch in flight
+    decode_pool = kv_pool.write_page(decode_pool, slot,
+                                     jax.device_put(host))
+    kv.withdraw_page(7, layers, registry=reg)  # prefill side, when done
 """
 
 from __future__ import annotations
@@ -43,6 +61,9 @@ import ctypes
 import dataclasses
 import struct
 
+import numpy as np
+
+from brpc_tpu.rpc import zerocopy
 from brpc_tpu.rpc._lib import load_library
 from brpc_tpu.rpc.client import Channel, RpcError
 
@@ -57,11 +78,24 @@ assert _WIRE.size == 112
 _PREFIX_WIRE = struct.Struct("<QQQQQQQQqII64s")
 assert _PREFIX_WIRE.size == 144
 
+# Batch forms — MUST mirror cpp/net/kvstore.h KvManyGen / KvManyRecord
+# (kv-wire marker): a u64 count then that many KvWire in; the count,
+# then one entry per request entry out (status, and the generation or
+# the record).  MANY_MAX is kKvManyMax.
+_COUNT = struct.Struct("<Q")
+_MANY_GEN = struct.Struct("<qQ")
+_MANY_RECORD = struct.Struct("<q112s")
+assert _MANY_GEN.size == 16 and _MANY_RECORD.size == 120
+MANY_MAX = 4096
+
 FETCH_METHOD = "Kv.Fetch"
 REGISTER_METHOD = "KvReg.Register"
 LOOKUP_METHOD = "KvReg.Lookup"
 EVICT_METHOD = "KvReg.Evict"
 RENEW_METHOD = "KvReg.Renew"
+REGISTER_MANY_METHOD = "KvReg.RegisterMany"
+LOOKUP_MANY_METHOD = "KvReg.LookupMany"
+EVICT_MANY_METHOD = "KvReg.EvictMany"
 PREFIX_PUT_METHOD = "KvReg.PutPrefix"
 PREFIX_MATCH_METHOD = "KvReg.Match"
 PREFIX_FETCH_METHOD = "Kv.FetchPrefix"
@@ -85,6 +119,20 @@ class KvExistsError(KvError):
     the lease holds)."""
 
 
+class KvFetchManyError(KvError):
+    """A multi-record fetch in which some records did not land; the
+    call failed as a whole.  `failed` maps each such block id to its
+    error (KvMissError, KvStaleError or a transport RpcError); the code
+    is the first one's."""
+
+    def __init__(self, failed: dict):
+        first = next(iter(failed.values()))
+        super().__init__(
+            first.code, f"{len(failed)} record(s) did not land: "
+            + ", ".join(f"{bid}: {e.text}" for bid, e in failed.items()))
+        self.failed = failed
+
+
 def _codes() -> tuple[int, int, int]:
     lib = load_library()
     miss = ctypes.c_int()
@@ -100,6 +148,14 @@ def _kv_error(e: RpcError) -> RpcError:
     cls = {miss: KvMissError, stale: KvStaleError,
            exists: KvExistsError}.get(e.code)
     return cls(e.code, e.text) if cls is not None else e
+
+
+def _entry_error(status: int, what: str) -> RpcError:
+    """One entry's status of a batch answer, as the single call's error."""
+    miss, stale, exists = _codes()
+    why = {miss: "kv-miss", stale: "kv-stale",
+           exists: "kv-exists"}.get(status, "kv-error")
+    return _kv_error(RpcError(status, f"{why}: {what}"))
 
 
 @dataclasses.dataclass
@@ -200,6 +256,78 @@ def registry_count() -> int:
 def reset() -> None:
     """Test support: drops every local block and registry record."""
     load_library().trpc_kv_reset()
+
+
+# ---- pages of a paged pool: one record a layer ---------------------------
+
+_MAX_LAYERS = 1 << 16
+
+
+def page_record_id(block_id: int, layer: int) -> int:
+    """The store's id of layer `layer`'s record of page `block_id`: both
+    sides derive it, nothing but the page's id crosses between them."""
+    if not (0 <= layer < _MAX_LAYERS - 1 and 0 <= block_id < 1 << 47):
+        raise ValueError(f"no record id for page {block_id} layer {layer}")
+    return (block_id << 16) | (layer + 1)
+
+
+def publish_page(block_id: int, page, slab, offset: int = 0,
+                 lease_ms: int = 0, node: str = "",
+                 registry: "KvRegistryClient | None" = None
+                 ) -> list[KvBlockMeta]:
+    """Publishes one page of a paged pool, `page[layer]` as the record
+    `page_record_id(block_id, layer)`.  `page` is an array of leading
+    axis `layers` (a device array, or the view `zerocopy.host_view`
+    made of one when its transfer was started ahead): its bytes come to
+    the host through `zerocopy.host_view`, so under the limit on
+    transfers in flight, each layer's slice of `slab` (an RmaBuffer) from
+    `offset` on is `publish`ed, and the bytes are copied there.  With a
+    `registry` the records are registered in one `register_many`; a
+    record it refuses raises its error.  The slab's bytes belong to the
+    store until the page is withdrawn (`withdraw_page`)."""
+    layers = page.shape[0]
+    flat = (page.resolve() if isinstance(page, zerocopy.PendingView)
+            else zerocopy.host_bytes(page)[0])
+    if flat.nbytes % layers or offset < 0 or \
+            offset + flat.nbytes > slab.nbytes:
+        raise ValueError(
+            f"a page of {flat.nbytes} bytes in {layers} layers does not "
+            f"fit the slab at {offset} (slab: {slab.nbytes} bytes)")
+    record = flat.nbytes // layers
+    # Published first, copied second: a page that is live (KvExistsError)
+    # keeps its slab bytes, and nobody can look the records up before
+    # they are registered below.
+    metas: list[KvBlockMeta] = []
+    try:
+        for layer in range(layers):
+            metas.append(publish(
+                page_record_id(block_id, layer), slab,
+                offset=offset + layer * record, length=record,
+                lease_ms=lease_ms, node=node))
+    except Exception:
+        for meta in metas:
+            withdraw(meta.block_id)
+        raise
+    np.frombuffer(slab.view, dtype=np.uint8)[
+        offset:offset + flat.nbytes] = flat
+    if registry is not None:
+        for answer in registry.register_many(metas, lease_ms=lease_ms):
+            if isinstance(answer, RpcError):
+                raise answer
+    return metas
+
+
+def withdraw_page(block_id: int, layers: int,
+                  registry: "KvRegistryClient | None" = None) -> None:
+    """Takes a published page back: its records leave the registry (one
+    `evict_many`; a record already gone there is no error) and the local
+    store, after which its slab bytes may be used again.  Raises
+    KvMissError if the store did not hold a record."""
+    ids = [page_record_id(block_id, layer) for layer in range(layers)]
+    if registry is not None:
+        registry.evict_many(ids)
+    for record_id in ids:
+        withdraw(record_id)
 
 
 # ---- content-addressed prefix cache (ISSUE 17) ---------------------------
@@ -400,6 +528,54 @@ class KvRegistryClient:
             raise _kv_error(e) from None
         return struct.unpack("<Q", resp)[0]
 
+    def _call_many(self, method: str, wires: list[bytes], entry,
+                   answer) -> list:
+        """The batch form of a registry call: `wires` (packed KvWire) in
+        RPCs of at most MANY_MAX, each entry of the answers through
+        `answer(status, fields...)`, in order."""
+        out = []
+        for at in range(0, len(wires), MANY_MAX):
+            part = wires[at:at + MANY_MAX]
+            try:
+                resp = self._ch.call(
+                    method, _COUNT.pack(len(part)) + b"".join(part))
+            except RpcError as e:
+                raise _kv_error(e) from None
+            (count,) = _COUNT.unpack_from(resp)
+            if (count != len(part)
+                    or len(resp) != _COUNT.size + count * entry.size):
+                raise RpcError(-1, f"{method} answered {count} entries in "
+                               f"{len(resp)} bytes to {len(part)} records")
+            out.extend(answer(*fields) for fields in
+                       entry.iter_unpack(resp[_COUNT.size:]))
+        return out
+
+    def register_many(self, metas, lease_ms: int = 0) -> list:
+        """`register` for many records in one RPC.  Per record, in
+        order: the accepted generation, or the error `register` would
+        have raised (KvExistsError, KvStaleError) as an instance, not
+        raised — one record's refusal is not the others'."""
+        return self._call_many(
+            REGISTER_MANY_METHOD, [m.pack(lease_ms) for m in metas],
+            _MANY_GEN, lambda status, gen: gen if status == 0
+            else _entry_error(status, "register"))
+
+    def lookup_many(self, block_ids) -> list:
+        """`lookup` for many records in one RPC: per record its
+        KvBlockMeta, or a KvMissError instance."""
+        return self._call_many(
+            LOOKUP_MANY_METHOD, [_req(b) for b in block_ids],
+            _MANY_RECORD, lambda status, rec: KvBlockMeta.unpack(rec)
+            if status == 0 else _entry_error(status, "lookup"))
+
+    def evict_many(self, block_ids) -> list:
+        """`evict` for many records in one RPC: per record the evicted
+        generation, or a KvMissError instance."""
+        return self._call_many(
+            EVICT_MANY_METHOD, [_req(b) for b in block_ids],
+            _MANY_GEN, lambda status, gen: gen if status == 0
+            else _entry_error(status, "evict"))
+
     def put_prefix(self, meta: KvPrefixMeta,
                    lease_ms: int = 0) -> tuple[int, bool]:
         """Records one prefix-block replica; N publishers of the same
@@ -446,7 +622,11 @@ class KvClient:
     `fetch(block_id)` returns the bytes; `fetch(block_id, resp_buf=v)`
     lands them natively in `v` (an RmaBuffer view for the one-sided
     path) and returns the landed length.  A kv-stale answer invalidates
-    the cached record, re-resolves, and retries once."""
+    the cached record, re-resolves, and retries once.  `fetch_many`
+    lands many records with their fetches in flight together, and
+    `fetch_page` a page of a paged pool, one record a layer.  A landing
+    fetch rides the node channel's one pipeline, which lives as long as
+    the channel: one thread at a time may fetch through a client."""
 
     def __init__(self, registry_addr: str, use_shm: bool = True,
                  timeout_ms: int = 30000, qos_tenant: str = "",
@@ -460,6 +640,7 @@ class KvClient:
                                qos_priority=qos_priority)
         self.registry = KvRegistryClient(self._reg_ch)
         self._node_chs: dict[str, Channel] = {}
+        self._node_pipes: dict = {}  # node -> its channel's pipeline
         self._cache: dict[int, KvBlockMeta] = {}
         # Optional cluster-membership view (cpp/net/naming.h registry at
         # naming_addr, service naming_service): when a fetch fails at the
@@ -504,8 +685,29 @@ class KvClient:
             return
         live = {m.addr for m in members}
         for node in [n for n in self._node_chs if n not in live]:
-            self._node_chs.pop(node).close()
+            self._drop_node(node)
             self.channels_evicted += 1
+
+    def _drop_node(self, node: str) -> None:
+        """Closes the node's pipeline (cancelling what it has in flight)
+        and its channel; the next fetch from the node opens new ones."""
+        pipe = self._node_pipes.pop(node, None)
+        if pipe is not None:
+            pipe.close()
+        ch = self._node_chs.pop(node, None)
+        if ch is not None:
+            ch.close()
+
+    def _node_pipeline(self, node: str):
+        pipe = self._node_pipes.get(node)
+        if pipe is None:
+            pipe = self._node_pipes[node] = self._node_channel(
+                node).pipeline()
+        return pipe
+
+    def transports(self) -> dict[str, str]:
+        """Live transport of each node channel ("shm_ring", "tcp")."""
+        return {node: ch.transport for node, ch in self._node_chs.items()}
 
     def _node_channel(self, node: str) -> Channel:
         ch = self._node_chs.get(node)
@@ -536,6 +738,24 @@ class KvClient:
         meta = self.registry.lookup(block_id)
         self._cache[block_id] = meta
         return meta
+
+    def lookup_many(self, block_ids, refresh: bool = False) -> list:
+        """`lookup` for many records: the cache first, the rest in one
+        `KvReg.LookupMany`.  Per record its KvBlockMeta, or a
+        KvMissError instance."""
+        block_ids = list(block_ids)
+        out = [None if refresh else self._cache.get(b) for b in block_ids]
+        asked = [i for i, meta in enumerate(out) if meta is None]
+        self.cache_hits += len(block_ids) - len(asked)
+        self.cache_misses += len(asked)
+        if asked:
+            answers = self.registry.lookup_many(
+                block_ids[i] for i in asked)
+            for i, answer in zip(asked, answers):
+                out[i] = answer
+                if not isinstance(answer, RpcError):
+                    self._cache[block_ids[i]] = answer
+        return out
 
     def invalidate(self, block_id: int) -> None:
         if self._cache.pop(block_id, None) is not None:
@@ -568,13 +788,16 @@ class KvClient:
         attempts = 3 if self._naming_args[0] is not None else 2
         for attempt in range(attempts):
             meta = self.lookup(block_id, refresh=attempt > 0)
-            req = _req(block_id, generation=meta.generation)
-            ch = self._node_channel(meta.node)
             try:
                 if resp_buf is None:
-                    return ch.call(FETCH_METHOD, req,
-                                   timeout_ms=self._timeout_ms)
-                return self._fetch_into(ch, req, resp_buf)
+                    return self._node_channel(meta.node).call(
+                        FETCH_METHOD,
+                        _req(block_id, generation=meta.generation),
+                        timeout_ms=self._timeout_ms)
+                landed = self._fetch_round([(meta, resp_buf)])[0]
+                if isinstance(landed, RpcError):
+                    raise landed
+                return landed
             except RpcError as e:
                 e = _kv_error(e)
                 if isinstance(e, (KvStaleError, KvMissError)):
@@ -587,9 +810,7 @@ class KvClient:
                 # out again): drop it and re-resolve through the
                 # registry, which the new owner re-publishes into.
                 if attempt + 1 < attempts and self._node_gone(meta.node):
-                    dead = self._node_chs.pop(meta.node, None)
-                    if dead is not None:
-                        dead.close()
+                    self._drop_node(meta.node)
                     self.invalidate(block_id)
                     self.node_reresolves += 1
                     last = e
@@ -597,29 +818,115 @@ class KvClient:
                 raise
         raise last
 
-    def _fetch_into(self, ch: Channel, req: bytes, resp_buf) -> int:
-        """One fetch whose response lands natively in resp_buf (the
-        one-sided direct path when resp_buf is RmaBuffer-backed and the
-        node connection is shm/ici)."""
-        pipe = ch.pipeline()
-        try:
-            pipe.submit(FETCH_METHOD, [req], resp_bufs=[resp_buf],
-                        timeout_ms=self._timeout_ms)
-            cs = pipe.poll(max_n=1, timeout_ms=self._timeout_ms)
-            if not cs:
-                raise RpcError(-1, "kv fetch timed out in poll")
-            c = cs[0]
-            if not c.ok:
-                raise _kv_error(RpcError(c.status, c.error))
-            if not c.in_caller_buffer and c.data is not None:
-                # Copy-path degradation where the runtime returned a
-                # view instead of landing in place (tiny responses).
-                view = memoryview(resp_buf).cast("B")
-                view[:c.resp_len] = c.data.view()[:c.resp_len]
-                c.data.release()
-            return c.resp_len
-        finally:
-            pipe.close()
+    def _fetch_round(self, wanted) -> list:
+        """One `Kv.Fetch` per (meta, landing buffer) of `wanted`, all in
+        flight together: the requests to one node cross in ONE
+        `pipeline.submit`, then the pipelines are polled until every
+        record is in.  Per record, in order: the landed length, or its
+        error as an instance (the one-sided direct path where the buffer
+        is RmaBuffer-backed and stripe-eligible and the connection is
+        shm/ici; else the runtime copies the response out on
+        completion).  A node whose poll times out is dropped, so that a
+        late completion cannot be taken for a later call's."""
+        out: list = [None] * len(wanted)
+        by_node: dict[str, list[int]] = {}
+        for i, (meta, _buf) in enumerate(wanted):
+            by_node.setdefault(meta.node, []).append(i)
+        pending = []
+        for node, members in by_node.items():
+            pipe = self._node_pipeline(node)
+            tokens = pipe.submit(
+                FETCH_METHOD,
+                [_req(wanted[i][0].block_id,
+                      generation=wanted[i][0].generation) for i in members],
+                resp_bufs=[wanted[i][1] for i in members],
+                timeout_ms=self._timeout_ms)
+            pending.append((node, pipe, dict(zip(tokens, members))))
+        for node, pipe, waiting in pending:
+            while waiting:
+                done = pipe.poll(max_n=len(waiting),
+                                 timeout_ms=self._timeout_ms)
+                if not done:
+                    for i in waiting.values():
+                        out[i] = RpcError(-1, "kv fetch timed out in poll")
+                    self._drop_node(node)
+                    break
+                for c in done:
+                    i = waiting.pop(c.token, None)
+                    if i is None:
+                        continue
+                    if not c.ok:
+                        out[i] = _kv_error(RpcError(c.status, c.error))
+                        continue
+                    if not c.in_caller_buffer and c.data is not None:
+                        # The runtime handed back a view instead of
+                        # landing in place (tiny responses).
+                        view = memoryview(wanted[i][1]).cast("B")
+                        view[:c.resp_len] = c.data.view()[:c.resp_len]
+                        c.data.release()
+                    out[i] = c.resp_len
+        return out
+
+    def fetch_many(self, block_ids, resp_bufs) -> list[int]:
+        """Lands record `block_ids[i]` in `resp_bufs[i]` (writable
+        buffers), every record's fetch in flight at once, and returns
+        the landed lengths.  The lookups go through `lookup_many`; a
+        record answered kv-stale or kv-miss by its node is invalidated,
+        re-resolved and retried once, that record alone.  If any record
+        does not land, the call fails as a whole with KvFetchManyError,
+        which says which (the others' buffers hold their bytes)."""
+        block_ids = list(block_ids)
+        if len(resp_bufs) != len(block_ids):
+            raise ValueError("resp_bufs length must match block_ids")
+        load_library().trpc_kv_note_fetch_many(len(block_ids))
+        lengths = [0] * len(block_ids)
+        failed: dict[int, RpcError] = {}
+        todo = list(range(len(block_ids)))
+        for attempt in range(2):
+            metas = self.lookup_many((block_ids[i] for i in todo),
+                                     refresh=attempt > 0)
+            asked = []
+            for i, meta in zip(todo, metas):
+                if isinstance(meta, RpcError):
+                    failed[block_ids[i]] = meta  # the registry has none
+                else:
+                    asked.append((i, meta))
+            landed = self._fetch_round(
+                [(meta, resp_bufs[i]) for i, meta in asked])
+            todo = []
+            for (i, _meta), answer in zip(asked, landed):
+                if not isinstance(answer, RpcError):
+                    lengths[i] = answer
+                elif (isinstance(answer, (KvStaleError, KvMissError))
+                      and attempt == 0):
+                    self.invalidate(block_ids[i])  # generation-checked
+                    todo.append(i)
+                else:
+                    failed[block_ids[i]] = answer
+            if not todo:
+                break
+        if failed:
+            raise KvFetchManyError(failed)
+        return lengths
+
+    def fetch_page(self, block_id: int, landing):
+        """Lands the page `block_id` that `publish_page` published:
+        layer `l`'s record in `landing[l]`, the records' fetches in
+        flight together (`fetch_many`).  `landing` is a writable
+        C-contiguous numpy array of leading axis `layers` (a view of an
+        RmaBuffer for the one-sided path); it is returned, whole, ready
+        for one `jax.device_put`.  A record that is missing or of
+        another length fails the page (KvFetchManyError)."""
+        layers = landing.shape[0]
+        ids = [page_record_id(block_id, layer) for layer in range(layers)]
+        lengths = self.fetch_many(ids, list(landing))
+        layer_bytes = landing[0].nbytes
+        short = {rid: RpcError(-1, f"record of {n} bytes, layer of "
+                               f"{layer_bytes}")
+                 for rid, n in zip(ids, lengths) if n != layer_bytes}
+        if short:
+            raise KvFetchManyError(short)
+        return landing
 
     # ---- content-addressed prefix cache (ISSUE 17) ----
 
@@ -677,9 +984,8 @@ class KvClient:
         return blocks
 
     def close(self) -> None:
-        for ch in self._node_chs.values():
-            ch.close()
-        self._node_chs.clear()
+        for node in list(self._node_chs):
+            self._drop_node(node)
         if self._naming is not None:
             self._naming.close()
             self._naming = None

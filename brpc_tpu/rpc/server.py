@@ -130,7 +130,8 @@ class Server:
 
     def enable_kv_registry(self) -> None:
         """Attaches the NATIVE KV-block registry handlers
-        (KvReg.Register/Lookup/Evict/Renew, cpp/net/kvstore.h): this
+        (KvReg.Register/Lookup/Evict/Renew and the batch forms
+        KvReg.RegisterMany/LookupMany/EvictMany, cpp/net/kvstore.h): this
         server becomes a block directory mapping block_id -> {node, rkey,
         offset, len, generation} under lease-based ownership.  Call
         before start."""

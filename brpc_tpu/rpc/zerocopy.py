@@ -139,7 +139,7 @@ class PendingView:
     and not yet waited for.  `nbytes` is known at once; `resolve()` blocks
     its first caller until the bytes have landed (the wait releases the
     GIL) and returns the flat uint8 view of the array's own cached host
-    copy, the same one to every caller.  The transfer starts at once while
+    copy, the same one to every caller; `shape` is the array's.  The transfer starts at once while
     `_MAX_BYTES_IN_FLIGHT` has room, else when an earlier one has landed
     (or when `resolve()` asks for it).  `started_us` is the monotonic
     clock (the native runtime's) at which the view was made."""
@@ -162,6 +162,10 @@ class PendingView:
     def landed(self) -> bool:
         """The bytes are here: `resolve()` will not block."""
         return self._flat is not None
+
+    @property
+    def shape(self) -> tuple:
+        return tuple(self._array.shape)
 
     def resolve(self) -> np.ndarray:
         global _bytes_in_flight

@@ -99,6 +99,13 @@ void trpc_kv_codes(int* miss, int* stale, int* exists) {
   }
 }
 
+// Counts one KvClient.fetch_many of `records` records in the registry's
+// kv_fetch_many_total / kv_fetch_many_records (the fan-out runs in
+// kv.py over the batch pipeline; the counters live with the kv vars).
+void trpc_kv_note_fetch_many(uint64_t records) {
+  kv_note_fetch_many(records);
+}
+
 // ---- content-addressed prefix cache (ISSUE 17) ---------------------------
 
 // 128-bit content hash of (block bytes, token-id span) — deterministic

@@ -121,6 +121,10 @@ struct KvVars {
   Adder register_total;
   Adder lookup_total;
   Adder lookup_miss_total;
+  Adder reg_many_total;
+  Adder reg_many_records;
+  Adder fetch_many_total;
+  Adder fetch_many_records;
   std::unique_ptr<PassiveStatus<long>> store_blocks;
   std::unique_ptr<PassiveStatus<long>> store_bytes;
   std::unique_ptr<PassiveStatus<long>> registry_blocks;
@@ -151,6 +155,21 @@ struct KvVars {
         "kv_lookup_miss_total",
         "registry lookups answering kv-miss (unknown block or expired "
         "lease)");
+    reg_many_total.expose(
+        "kv_reg_many_total",
+        "batch registry RPCs (KvReg.RegisterMany/LookupMany/EvictMany) "
+        "answered by the registry on this node");
+    reg_many_records.expose(
+        "kv_reg_many_records",
+        "records those batch registry RPCs carried — divide by "
+        "kv_reg_many_total for the records per RPC");
+    fetch_many_total.expose(
+        "kv_fetch_many_total",
+        "multi-record fetches (KvClient.fetch_many) this process made: "
+        "each keeps its records' Kv.Fetch calls in flight together");
+    fetch_many_records.expose(
+        "kv_fetch_many_records",
+        "records those multi-record fetches asked for");
     store_blocks = std::make_unique<PassiveStatus<long>>(
         [] { return static_cast<long>(kv_store().count()); });
     store_blocks->expose("kv_store_blocks",
@@ -296,6 +315,11 @@ void kv_ensure_registered() {
   prefix_block_tokens_flag();
   kv_vars();
   kv_prefix_vars();
+}
+
+void kv_note_fetch_many(uint64_t records) {
+  kv_vars().fetch_many_total << 1;
+  kv_vars().fetch_many_records << static_cast<int64_t>(records);
 }
 
 KvPrefixCounters& kv_prefix_counters() {
@@ -1225,6 +1249,57 @@ void prefix_meta_to_wire(const KvPrefixMeta& m, int64_t lease_ms,
   memcpy(w->node, m.node, sizeof(w->node));
 }
 
+KvBlockMeta wire_to_meta(const KvWire& w) {
+  KvBlockMeta m;
+  m.block_id = w.block_id;
+  m.generation = w.generation;
+  m.rkey = w.rkey;
+  m.off = w.off;
+  m.len = w.len;
+  memcpy(m.node, w.node, sizeof(m.node));
+  return m;
+}
+
+void meta_to_wire(const KvBlockMeta& m, int64_t lease_ms, KvWire* w) {
+  memset(w, 0, sizeof(*w));
+  w->block_id = m.block_id;
+  w->generation = m.generation;
+  w->rkey = m.rkey;
+  w->off = m.off;
+  w->len = m.len;
+  w->lease_ms = lease_ms;
+  memcpy(w->node, m.node, sizeof(w->node));
+}
+
+// The body of the three batch handlers: a u64 count then that many
+// KvWire in, the count then one zero-initialised Entry per wire out,
+// filled by `one` in request order.  An entry's failure is its own
+// status; only a request that does not parse fails the call.
+template <typename Entry, typename One>
+void serve_many(Controller* cntl, const IOBuf& req, IOBuf* resp,
+                const char* what, One one) {
+  uint64_t n = 0;
+  if (req.size() >= sizeof(n)) {
+    req.copy_to(&n, sizeof(n));
+  }
+  if (n == 0 || n > kKvManyMax ||
+      req.size() < sizeof(n) + n * sizeof(KvWire)) {
+    cntl->SetFailed(EINVAL, std::string("bad ") + what + " record count");
+    return;
+  }
+  std::vector<KvWire> wires(n);
+  req.copy_to(wires.data(), n * sizeof(KvWire), sizeof(n));
+  std::vector<Entry> out(n);
+  for (uint64_t i = 0; i < n; ++i) {
+    wires[i].node[sizeof(wires[i].node) - 1] = '\0';
+    one(wires[i], &out[i]);
+  }
+  resp->append(&n, sizeof(n));
+  resp->append(out.data(), n * sizeof(Entry));
+  kv_vars().reg_many_total << 1;
+  kv_vars().reg_many_records << static_cast<int64_t>(n);
+}
+
 void respond_gen(IOBuf* resp, uint64_t gen) {
   resp->append(&gen, sizeof(gen));
 }
@@ -1303,7 +1378,7 @@ int kv_attach_store(Server* s) {
 
 int kv_attach_registry(Server* s) {
   kv_ensure_registered();
-  int rcs[6] = {0, 0, 0, 0, 0, 0};
+  int rcs[9] = {0, 0, 0, 0, 0, 0, 0, 0, 0};
   rcs[0] = s->RegisterMethod(
       kKvRegisterMethod, [](Controller* cntl, const IOBuf& req, IOBuf* resp,
                             Closure done) {
@@ -1313,15 +1388,9 @@ int kv_attach_registry(Server* s) {
           done();
           return;
         }
-        KvBlockMeta m;
-        m.block_id = w.block_id;
-        m.generation = w.generation;
-        m.rkey = w.rkey;
-        m.off = w.off;
-        m.len = w.len;
-        memcpy(m.node, w.node, sizeof(m.node));
         uint64_t gen = 0;
-        const int rc = kv_registry().do_register(m, w.lease_ms, &gen);
+        const int rc =
+            kv_registry().do_register(wire_to_meta(w), w.lease_ms, &gen);
         if (rc != 0) {
           fail_kv(cntl, rc, "register");
         } else {
@@ -1345,14 +1414,7 @@ int kv_attach_registry(Server* s) {
           fail_kv(cntl, rc, "lookup");
         } else {
           KvWire o;
-          memset(&o, 0, sizeof(o));
-          o.block_id = m.block_id;
-          o.generation = m.generation;
-          o.rkey = m.rkey;
-          o.off = m.off;
-          o.len = m.len;
-          o.lease_ms = left_ms;
-          memcpy(o.node, m.node, sizeof(o.node));
+          meta_to_wire(m, left_ms, &o);
           resp->append(&o, sizeof(o));
         }
         done();
@@ -1459,10 +1521,48 @@ int kv_attach_registry(Server* s) {
         }
         done();
       });
-  return rcs[0] == 0 && rcs[1] == 0 && rcs[2] == 0 && rcs[3] == 0 &&
-                 rcs[4] == 0 && rcs[5] == 0
-             ? 0
-             : -1;
+  rcs[6] = s->RegisterMethod(
+      kKvRegisterManyMethod, [](Controller* cntl, const IOBuf& req,
+                                IOBuf* resp, Closure done) {
+        serve_many<KvManyGen>(
+            cntl, req, resp, kKvRegisterManyMethod,
+            [](const KvWire& w, KvManyGen* o) {
+              o->status = kv_registry().do_register(
+                  wire_to_meta(w), w.lease_ms, &o->generation);
+            });
+        done();
+      });
+  rcs[7] = s->RegisterMethod(
+      kKvLookupManyMethod, [](Controller* cntl, const IOBuf& req,
+                              IOBuf* resp, Closure done) {
+        serve_many<KvManyRecord>(
+            cntl, req, resp, kKvLookupManyMethod,
+            [](const KvWire& w, KvManyRecord* o) {
+              KvBlockMeta m;
+              int64_t left_ms = 0;
+              o->status = kv_registry().lookup(w.block_id, &m, &left_ms);
+              if (o->status == 0) {
+                meta_to_wire(m, left_ms, &o->rec);
+              }
+            });
+        done();
+      });
+  rcs[8] = s->RegisterMethod(
+      kKvEvictManyMethod, [](Controller* cntl, const IOBuf& req,
+                             IOBuf* resp, Closure done) {
+        serve_many<KvManyGen>(
+            cntl, req, resp, kKvEvictManyMethod,
+            [](const KvWire& w, KvManyGen* o) {
+              o->status = kv_registry().evict(w.block_id, &o->generation);
+            });
+        done();
+      });
+  for (int rc : rcs) {
+    if (rc != 0) {
+      return -1;
+    }
+  }
+  return 0;
 }
 
 // ---- KvCache -------------------------------------------------------------

@@ -31,8 +31,9 @@
 //    is rejected (kEKvExists) unless the incoming generation is newer
 //    (the publisher re-published after a local evict).
 //    `kv_attach_registry(Server*)` serves "KvReg.{Register,Lookup,
-//    Evict,Renew}" — the registry can run on any node, including a
-//    third party.
+//    Evict,Renew}" and the batch forms "KvReg.{Register,Lookup,
+//    Evict}Many" (a block's records in one RPC) — the registry can run
+//    on any node, including a third party.
 //  - KvCache: the DECODE-side lookup cache.  Lookups are cached until
 //    proven stale: a fetch answered kEKvStale/kEKvMiss (generation
 //    bumped, lease expired, block evicted) invalidates the cached
@@ -105,12 +106,37 @@ struct KvWire {
 };
 static_assert(sizeof(KvWire) == 112, "KvWire is wire format — fixed");
 
+// Batch forms of the registry calls (kv-wire marker; mirrored by
+// brpc_tpu/rpc/kv.py _MANY_GEN / _MANY_RECORD): a block's records cross
+// in ONE RPC.  Request: a u64 count (1..kKvManyMax, as KvReg.Match's)
+// then that many KvWire, each filled as its single call fills it.
+// Answer: the same u64 count, then one entry per request entry, in
+// order.  A miss, a live duplicate or a stale generation is that
+// ENTRY's status (0 or a kEKv* code); only a malformed request fails
+// the call.  RegisterMany/EvictMany answer KvManyGen, LookupMany answers
+// KvManyRecord (rec as Lookup's response; zeroed where status != 0).
+constexpr uint64_t kKvManyMax = 4096;
+struct KvManyGen {
+  int64_t status;
+  uint64_t generation;
+};
+static_assert(sizeof(KvManyGen) == 16, "KvManyGen is wire format — fixed");
+struct KvManyRecord {
+  int64_t status;
+  KvWire rec;
+};
+static_assert(sizeof(KvManyRecord) == 120,
+              "KvManyRecord is wire format — fixed");
+
 // Method names (tstd, served by the attach functions below).
 inline constexpr const char* kKvFetchMethod = "Kv.Fetch";
 inline constexpr const char* kKvRegisterMethod = "KvReg.Register";
 inline constexpr const char* kKvLookupMethod = "KvReg.Lookup";
 inline constexpr const char* kKvEvictMethod = "KvReg.Evict";
 inline constexpr const char* kKvRenewMethod = "KvReg.Renew";
+inline constexpr const char* kKvRegisterManyMethod = "KvReg.RegisterMany";
+inline constexpr const char* kKvLookupManyMethod = "KvReg.LookupMany";
+inline constexpr const char* kKvEvictManyMethod = "KvReg.EvictMany";
 inline constexpr const char* kKvPrefixPutMethod = "KvReg.PutPrefix";
 inline constexpr const char* kKvPrefixMatchMethod = "KvReg.Match";
 inline constexpr const char* kKvPrefixFetchMethod = "Kv.FetchPrefix";
@@ -463,5 +489,9 @@ class KvCache {
 // Flag registration (idempotent; attach functions and the capi call it
 // so /flags sees the kv knobs before first traffic).
 void kv_ensure_registered();
+
+// Counts one client-side multi-record fetch (kv.py KvClient.fetch_many)
+// of `records` records in kv_fetch_many_total / kv_fetch_many_records.
+void kv_note_fetch_many(uint64_t records);
 
 }  // namespace trpc

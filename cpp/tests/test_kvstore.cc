@@ -9,6 +9,7 @@
 
 #include <cstring>
 #include <string>
+#include <vector>
 
 #include "base/flags.h"
 #include "base/time.h"
@@ -338,6 +339,138 @@ TEST_CASE(kv_rpc_end_to_end_with_cache_invalidation) {
   EXPECT(check_pattern(bytes2, len, 12));  // the NEW generation's bytes
   EXPECT_EQ(cache.misses(), misses_before + 1);  // stale → re-lookup
   rma_free(region);
+}
+
+// The batch forms: a block's records in one RPC, one status per entry.
+namespace {
+
+KvWire wire_for(uint64_t id, uint64_t gen, uint64_t len) {
+  KvWire w;
+  memset(&w, 0, sizeof(w));
+  w.block_id = id;
+  w.generation = gen;
+  w.rkey = 0x42;
+  w.len = len;
+  w.lease_ms = 60000;
+  snprintf(w.node, sizeof(w.node), "127.0.0.1:1");
+  return w;
+}
+
+// One batch call: count + wires out, count + one Entry per wire back.
+template <typename Entry>
+std::vector<Entry> call_many(Channel* ch, const char* method,
+                             const std::vector<KvWire>& wires,
+                             uint64_t count, int* error_code) {
+  IOBuf req, resp;
+  req.append(&count, sizeof(count));
+  req.append(wires.data(), wires.size() * sizeof(KvWire));
+  Controller cntl;
+  ch->CallMethod(method, req, &resp, &cntl);
+  *error_code = cntl.Failed() ? cntl.error_code() : 0;
+  std::vector<Entry> out;
+  if (cntl.Failed()) {
+    return out;
+  }
+  uint64_t n = 0;
+  EXPECT(resp.size() >= sizeof(n));
+  resp.copy_to(&n, sizeof(n));
+  EXPECT_EQ(resp.size(), sizeof(n) + n * sizeof(Entry));
+  out.resize(n);
+  resp.copy_to(out.data(), n * sizeof(Entry), sizeof(n));
+  return out;
+}
+
+}  // namespace
+
+TEST_CASE(kv_registry_batch_wire_forms) {
+  KvReset reset;
+  start_once();
+  Channel ch;
+  Channel::Options opts;
+  opts.timeout_ms = 20000;
+  EXPECT_EQ(ch.Init(addr(), &opts), 0);
+  int err = 0;
+
+  // Register 61 records in one RPC; a duplicate in the middle (entry 30
+  // repeats entry 3's block at the same generation) is kv-exists for
+  // that entry alone, and generation 0 is that entry's kv-stale.
+  std::vector<KvWire> wires;
+  for (uint64_t i = 0; i < 61; ++i) {
+    wires.push_back(wire_for(1000 + i, 1, 147456));
+  }
+  wires[30] = wire_for(1003, 1, 147456);
+  wires[45].generation = 0;
+  auto gens = call_many<KvManyGen>(&ch, kKvRegisterManyMethod, wires,
+                                   wires.size(), &err);
+  EXPECT_EQ(err, 0);
+  EXPECT_EQ(gens.size(), 61u);
+  for (uint64_t i = 0; i < gens.size(); ++i) {
+    const int64_t want = i == 30 ? kEKvExists : i == 45 ? kEKvStale : 0;
+    EXPECT_EQ(gens[i].status, want);
+    EXPECT_EQ(gens[i].generation, want == 0 ? 1u : 0u);
+  }
+  EXPECT_EQ(kv_registry().count(), 59u);
+
+  // Lookup: hits carry the record as KvReg.Lookup answers it, the two
+  // unregistered ids miss in place, and the order is the request's.
+  std::vector<KvWire> asks;
+  for (uint64_t i = 0; i < 61; ++i) {
+    asks.push_back(wire_for(1000 + i, 0, 0));
+  }
+  auto recs = call_many<KvManyRecord>(&ch, kKvLookupManyMethod, asks,
+                                      asks.size(), &err);
+  EXPECT_EQ(err, 0);
+  EXPECT_EQ(recs.size(), 61u);
+  for (uint64_t i = 0; i < recs.size(); ++i) {
+    if (i == 30 || i == 45) {
+      EXPECT_EQ(recs[i].status, kEKvMiss);
+      EXPECT_EQ(recs[i].rec.block_id, 0u);
+      continue;
+    }
+    EXPECT_EQ(recs[i].status, 0);
+    EXPECT_EQ(recs[i].rec.block_id, 1000 + i);
+    EXPECT_EQ(recs[i].rec.generation, 1u);
+    EXPECT_EQ(recs[i].rec.len, 147456u);
+    EXPECT(recs[i].rec.lease_ms > 0 && recs[i].rec.lease_ms <= 60000);
+    EXPECT(std::string(recs[i].rec.node) == "127.0.0.1:1");
+    KvBlockMeta single;
+    EXPECT_EQ(kv_registry().lookup(1000 + i, &single), 0);
+    EXPECT_EQ(single.generation, recs[i].rec.generation);
+  }
+
+  // Evict: the evicted generation per entry, a miss for the two holes.
+  auto gone = call_many<KvManyGen>(&ch, kKvEvictManyMethod, asks,
+                                   asks.size(), &err);
+  EXPECT_EQ(err, 0);
+  EXPECT_EQ(gone.size(), 61u);
+  for (uint64_t i = 0; i < gone.size(); ++i) {
+    const bool hole = i == 30 || i == 45;
+    EXPECT_EQ(gone[i].status, hole ? kEKvMiss : 0);
+    EXPECT_EQ(gone[i].generation, hole ? 0u : 1u);
+  }
+  EXPECT_EQ(kv_registry().count(), 0u);
+
+  // A malformed request fails the CALL: count 0, a count over the cap,
+  // and a count the body does not hold.
+  call_many<KvManyGen>(&ch, kKvRegisterManyMethod, {}, 0, &err);
+  EXPECT_EQ(err, EINVAL);
+  std::vector<KvWire> over(kKvManyMax + 1, wire_for(1, 1, 8));
+  call_many<KvManyGen>(&ch, kKvRegisterManyMethod, over, over.size(), &err);
+  EXPECT_EQ(err, EINVAL);
+  call_many<KvManyRecord>(&ch, kKvLookupManyMethod, asks, asks.size() + 1,
+                          &err);
+  EXPECT_EQ(err, EINVAL);
+  EXPECT_EQ(kv_registry().count(), 0u);
+  // The cap itself is served.
+  over.pop_back();
+  for (uint64_t i = 0; i < over.size(); ++i) {
+    over[i].block_id = 5000 + i;
+  }
+  gens = call_many<KvManyGen>(&ch, kKvRegisterManyMethod, over, over.size(),
+                              &err);
+  EXPECT_EQ(err, 0);
+  EXPECT_EQ(gens.size(), kKvManyMax);
+  EXPECT_EQ(kv_registry().count(), kKvManyMax);
 }
 
 TEST_CASE(kv_fetch_rides_one_sided_over_shm) {
