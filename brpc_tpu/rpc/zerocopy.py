@@ -24,6 +24,20 @@ buffer itself:
   `PendingView`, and whoever needs the bytes first waits for them — the
   batch pipeline's stager thread (batch.py), so the caller's thread is
   free for the responses while its requests are on their way.
+
+Where that DMA lands: jaxlib allocates the destination as a numpy array,
+so through numpy's current data-memory handler (NEP 49), and glibc would
+serve a block that large from fresh `mmap` pages every time: 16,384
+first-touch faults under every 64 MB fetch, five times the cost of the
+bytes (PERF.md, PR 25 and PR 28).  Every transfer is therefore started
+(`_start_transfer`) with the recycling handler of cpp/capi/hostpool_capi.cc
+current, for that one call and on that one thread: a block of 1 MB or
+more that a fetch landed in is kept when numpy frees it and handed to the
+next fetch of that size, from whatever thread asks.  Nothing else in the
+process is allocated there.  The price is memory the process keeps: up
+to 1 GB of such blocks may lie idle (the blocks of one pipeline at depth
+8 and 64 MB are 0.7 GB), and they go back to the kernel only past that
+bound or through `trpc_host_pool_trim`.
 """
 
 from __future__ import annotations
@@ -93,12 +107,62 @@ def _flat_u8(host: np.ndarray) -> np.ndarray:
     return host.reshape(-1).view(np.uint8)
 
 
+_MEM_HANDLER = b"mem_handler"  # the capsule's name, which numpy checks
+# (numpy's PyDataMem_SetHandler, the recycling handler's capsule): made by
+# the first transfer, so a process that stages no device array loads
+# nothing.
+_landing = None
+
+
+def _landing_handler():
+    global _landing
+    with _lock:
+        if _landing is None:
+            # numpy's C API table, as every compiled extension reaches it
+            # (`import_array`); entry 304 since numpy 1.22.
+            from numpy._core import _multiarray_umath
+
+            api = ctypes.pythonapi
+            api.PyCapsule_GetPointer.restype = ctypes.c_void_p
+            api.PyCapsule_GetPointer.argtypes = [ctypes.py_object,
+                                                 ctypes.c_char_p]
+            api.PyCapsule_New.restype = ctypes.py_object
+            api.PyCapsule_New.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
+                                          ctypes.c_void_p]
+            table = ctypes.cast(
+                api.PyCapsule_GetPointer(_multiarray_umath._ARRAY_API, None),
+                ctypes.POINTER(ctypes.c_void_p))
+            set_handler = ctypes.PYFUNCTYPE(
+                ctypes.py_object, ctypes.py_object)(table[304])
+            lib = load_library()
+            lib.trpc_host_pool_numpy_handler.restype = ctypes.c_void_p
+            capsule = api.PyCapsule_New(
+                lib.trpc_host_pool_numpy_handler(), _MEM_HANDLER, None)
+            _landing = (set_handler, capsule)
+        return _landing
+
+
+def _start_transfer(array) -> None:
+    """`array.copy_to_host_async()` with the block it lands in taken from
+    the recycling handler (the module's docstring).  numpy's current
+    handler is the thread's own (a context variable), so it is set here,
+    on the thread that makes the call, and put back."""
+    set_handler, capsule = _landing or _landing_handler()
+    previous = set_handler(capsule)
+    try:
+        array.copy_to_host_async()
+    finally:
+        set_handler(previous)
+
+
 # Bytes of device-to-host transfers that may be on their way at once (at
-# least one transfer always may).  Each lands in a fresh host buffer, and
-# on the v5e host four or more 64 MB transfers at once make the machine's
-# memory grow without bound (87 MB/s with four in flight, 1.2 GB/s with
-# eight: pages the process has freed that the kernel has not; PERF.md,
-# PR 25), while three run at 1.66 GB/s against one's 0.85 and grow nothing.
+# least one transfer always may).  It dates from when every transfer
+# landed in fresh pages: four or more 64 MB transfers at once then made
+# the machine's memory grow without bound (1.2 GB/s with eight; PERF.md,
+# PR 25).  In recycled blocks nothing grows and three transfers at once
+# read several times what any wire here takes (PERF.md, PR 28), so the
+# limit binds nothing; it goes, with `_admit` / `_retire` / `_deferred`,
+# in a PR of its own with its own memory watch (ROADMAP S2 (b), D7).
 _MAX_BYTES_IN_FLIGHT = 3 * (64 << 20)
 # Re-entrant: a PendingView collected inside _retire retires too.
 _transfers_lock = threading.RLock()
@@ -131,7 +195,7 @@ def _retire(nbytes: int) -> None:
                 started.append(view)
             _deferred.popleft()
     for view in started:
-        view._array.copy_to_host_async()
+        _start_transfer(view._array)
 
 
 class PendingView:
@@ -156,7 +220,7 @@ class PendingView:
             if not self._has_room:
                 _deferred.append(weakref.ref(self))
         if self._has_room:
-            array.copy_to_host_async()
+            _start_transfer(array)
 
     @property
     def landed(self) -> bool:
@@ -172,12 +236,15 @@ class PendingView:
         with self._lock:
             if self._flat is None:
                 with _transfers_lock:
-                    if not self._has_room:
-                        # Asked for before its turn came: over the limit
-                        # rather than wait for room behind others.
+                    # Asked for before its turn came: over the limit
+                    # rather than wait for room behind others.
+                    out_of_turn = not self._has_room
+                    if out_of_turn:
                         self._has_room = True
                         _bytes_in_flight += self.nbytes
                 try:
+                    if out_of_turn:
+                        _start_transfer(self._array)
                     self._flat = _flat_u8(np.asarray(self._array))
                 finally:
                     self._has_room = False

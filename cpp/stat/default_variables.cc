@@ -2,6 +2,7 @@
 // cpu, rss, fds, threads read from /proc and exposed in every /vars dump).
 #include <stdio.h>
 #include <string.h>
+#include <sys/resource.h>
 #include <unistd.h>
 
 #include <atomic>
@@ -52,12 +53,20 @@ double cpu_percent() {
   return pct;
 }
 
+// Pages the process touched for the first time (or again after the kernel
+// took them back) that needed no I/O: what a fresh host buffer costs.
+long faults_minor() {
+  struct rusage ru;
+  return getrusage(RUSAGE_SELF, &ru) == 0 ? ru.ru_minflt : 0;
+}
+
 struct DefaultVars {
   PassiveStatus<long> rss{[] { return proc_status_kb("VmRSS:"); }};
   PassiveStatus<long> vsz{[] { return proc_status_kb("VmSize:"); }};
   PassiveStatus<long> threads{[] { return proc_status_kb("Threads:"); }};
   PassiveStatus<long> fds{[] { return proc_fd_count(); }};
   PassiveStatus<double> cpu{[] { return cpu_percent(); }};
+  PassiveStatus<long> faults{[] { return faults_minor(); }};
   PassiveStatus<long> io_uring{
       [] { return static_cast<long>(kernel_supports("io_uring")); }};
 
@@ -68,6 +77,8 @@ struct DefaultVars {
     fds.expose("process_fd_count", "open file descriptors");
     cpu.expose("process_cpu_percent",
                "CPU use since the previous dump, percent of one core");
+    faults.expose("process_faults_minor",
+                  "minor page faults of the process since it started");
     io_uring.expose(
         "kernel_io_uring_supported",
         "1 when the running kernel answers io_uring_setup (>= 5.1); 0 "

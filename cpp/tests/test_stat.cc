@@ -311,6 +311,7 @@ TEST_CASE(default_variables_exposed) {
   trpc::expose_default_variables();
   bool rss = false;
   bool cpu = false;
+  bool faults = false;
   for (auto& [name, value] : Variable::dump_exposed()) {
     if (name == "process_memory_rss_kb" && atol(value.c_str()) > 0) {
       rss = true;
@@ -318,9 +319,13 @@ TEST_CASE(default_variables_exposed) {
     if (name == "process_cpu_percent") {
       cpu = true;
     }
+    if (name == "process_faults_minor" && atol(value.c_str()) > 0) {
+      faults = true;
+    }
   }
   EXPECT(rss);
   EXPECT(cpu);
+  EXPECT(faults);
 }
 
 TEST_CASE(contention_profiler_records_waits) {

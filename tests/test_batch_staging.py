@@ -126,9 +126,9 @@ def test_transfers_start_in_order_while_the_bytes_in_flight_have_room(
     assert [a.started for a in arrays] == [True] * 4 + [False] * 2
     views[0] = None                      # dropped unresolved: room given back
     assert [a.started for a in arrays] == [True] * 5 + [False]
-    # Asked for before its turn: fetched all the same, over the limit.
+    # Asked for before its turn: started there and then, over the limit.
     assert views[5].resolve().tobytes() == _payload(5).tobytes()
-    assert not arrays[5].started
+    assert arrays[5].started
     # One transfer may always run, whatever its size; behind others it
     # waits for all the room it needs.
     big = HeldArray(_payload(6, n=1 << 16), held=False)
