@@ -18,9 +18,7 @@ import jax.numpy as jnp
 
 from brpc_tpu.ops.checksum import sum32
 
-_ROWS = 16       # sublane-aligned block rows (uint32 min tile is 8x128);
-                 # see tools/tune_echo.py for the measured sweep backing
-                 # this default
+_ROWS = 16       # sublane-aligned block rows (uint32 min tile is 8x128)
 _COLS = 8192     # lanes per row
 _BLOCK = _ROWS * _COLS  # uint32 lanes per grid step (512KB)
 
@@ -43,8 +41,7 @@ def _kernel(x_ref, out_ref, acc_ref):
 def echo_fused(payload: jnp.ndarray, interpret: bool = False,
                rows: int = _ROWS, cols: int = _COLS):
     """payload: uint32[n] with n % (rows*cols) == 0.  Returns
-    (copy, checksum).  rows/cols pick the per-grid-step tile (tuning:
-    tools/tune_echo.py)."""
+    (copy, checksum).  rows/cols pick the per-grid-step tile."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 

@@ -193,34 +193,6 @@ def _ensure_built_locked(all_targets: bool) -> dict:
 _ensure_built = ensure_built
 
 
-def ensure_bench_echo() -> pathlib.Path:
-    """Build build/bench_echo (the C++ loopback echo benchmark) when
-    missing or stale.  Links against libtpurpc.so so it works on
-    cmake-less images too; bench.py and the perf smoke test share it."""
-    ensure_built()
-    exe = _BUILD / "bench_echo"
-    src = _REPO / "cpp" / "tools" / "bench_echo.cc"
-    if exe.exists() and exe.stat().st_mtime >= max(
-        src.stat().st_mtime, _LIB_PATH.stat().st_mtime
-    ):
-        return exe
-    cxx = shutil.which("g++") or shutil.which("c++") or shutil.which("clang++")
-    if cxx is None:
-        raise FileNotFoundError("no C++ compiler to build bench_echo")
-    subprocess.run(
-        [
-            cxx, "-std=c++20", "-O2", "-g", "-fno-omit-frame-pointer",
-            "-I", str(_REPO / "cpp"), str(src),
-            "-L", str(_BUILD), f"-Wl,-rpath,{_BUILD}",
-            "-ltpurpc", "-lpthread", "-o", str(exe),
-        ],
-        check=True,
-        capture_output=True,
-        text=True,
-    )
-    return exe
-
-
 def load_library() -> ctypes.CDLL:
     global _lib
     with _lock:
@@ -272,33 +244,10 @@ def load_library() -> ctypes.CDLL:
                 ctypes.c_size_t,
             ]
             lib.trpc_endpoint_parse.restype = ctypes.c_int
-            # Device arena + zero-copy surface (capi/base_capi.cc).
-            # Explicit marshalling for every pointer-crossing entry —
-            # tools/lint_trpc.py's capi-gil rule gates this: a missing
-            # restype silently truncates a 64-bit pointer/size_t.
-            lib.trpc_arena_create.argtypes = [
-                ctypes.c_uint32, ctypes.c_uint32, ctypes.c_int,
-            ]
-            lib.trpc_arena_create.restype = ctypes.c_void_p
-            lib.trpc_arena_destroy.argtypes = [ctypes.c_void_p]
-            lib.trpc_arena_destroy.restype = None
-            lib.trpc_arena_alloc.argtypes = [
-                ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p),
-                ctypes.POINTER(ctypes.c_uint64),
-            ]
-            lib.trpc_arena_alloc.restype = ctypes.c_void_p
-            lib.trpc_arena_release.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p,
-            ]
-            lib.trpc_arena_release.restype = None
-            lib.trpc_arena_block_size.argtypes = [ctypes.c_void_p]
-            lib.trpc_arena_block_size.restype = ctypes.c_uint32
-            lib.trpc_arena_blocks_in_use.argtypes = [ctypes.c_void_p]
-            lib.trpc_arena_blocks_in_use.restype = ctypes.c_size_t
-            lib.trpc_iobuf_append_block.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint32,
-            ]
-            lib.trpc_iobuf_append_block.restype = ctypes.c_int
+            # Zero-copy surface (capi/base_capi.cc).  Explicit marshalling
+            # for every pointer-crossing entry — tools/lint_trpc.py's
+            # capi-gil rule gates this: a missing restype silently
+            # truncates a 64-bit pointer/size_t.
             lib.trpc_iobuf_append_user_data.argtypes = [
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
                 ctypes.c_void_p,  # deleter fn ptr (CFUNCTYPE or None)

@@ -279,9 +279,9 @@ def publish_page(block_id: int, page, slab, offset: int = 0,
     `page_record_id(block_id, layer)`.  `page` is an array of leading
     axis `layers` (a device array, or the view `zerocopy.host_view`
     made of one when its transfer was started ahead): its bytes come to
-    the host through `zerocopy.host_view`, so under the limit on
-    transfers in flight, each layer's slice of `slab` (an RmaBuffer) from
-    `offset` on is `publish`ed, and the bytes are copied there.  With a
+    the host through `zerocopy.host_view`, so into a recycled landing
+    block, each layer's slice of `slab` (an RmaBuffer) from `offset` on
+    is `publish`ed, and the bytes are copied there.  With a
     `registry` the records are registered in one `register_many`; a
     record it refuses raises its error.  The slab's bytes belong to the
     store until the page is withdrawn (`withdraw_page`)."""

@@ -25,8 +25,8 @@ Three capability groups:
 
 Span collection for the AUTOMATIC per-RPC spans is gated by the
 reloadable `rpcz_enabled` flag (`enable_rpcz()`); explicit `trace()`
-spans always record.  When rpcz is off the plane costs nothing on the
-hot path (guarded by test_perf_smoke).
+spans always record.  When rpcz is off (the default) each hook is one
+relaxed load on the hot path.
 """
 
 from __future__ import annotations

@@ -18,8 +18,8 @@ buffer itself:
   pointer there, but not one that addresses the payload —
   tools/PJRT_PROBE.md), so exactly ONE device→host DMA runs (the
   transport hop itself, the NIC-DMA analogue) and the RESULTING host buffer
-  enters the IOBuf by reference.  One copy total, where the round-2 arena
-  path took two (DMA into a temporary, memcpy into the slab).  That DMA is
+  enters the IOBuf by reference.  One copy total, where landing in a
+  temporary and copying into a slab would take two.  That DMA is
   only STARTED by `host_view` (`copy_to_host_async`): it returns a
   `PendingView`, and whoever needs the bytes first waits for them — the
   batch pipeline's stager thread (batch.py), so the caller's thread is
@@ -37,16 +37,16 @@ next fetch of that size, from whatever thread asks.  Nothing else in the
 process is allocated there.  The price is memory the process keeps: up
 to 1 GB of such blocks may lie idle (the blocks of one pipeline at depth
 8 and 64 MB are 0.7 GB), and they go back to the kernel only past that
-bound or through `trpc_host_pool_trim`.
+bound or through `trpc_host_pool_trim`.  Every view's transfer starts when
+the view is made: the memory of fetches on their way is bounded by the
+depth their caller keeps open, not here.
 """
 
 from __future__ import annotations
 
-import collections
 import ctypes
 import threading
 import time
-import weakref
 
 import numpy as np
 
@@ -155,58 +155,16 @@ def _start_transfer(array) -> None:
         set_handler(previous)
 
 
-# Bytes of device-to-host transfers that may be on their way at once (at
-# least one transfer always may).  It dates from when every transfer
-# landed in fresh pages: four or more 64 MB transfers at once then made
-# the machine's memory grow without bound (1.2 GB/s with eight; PERF.md,
-# PR 25).  In recycled blocks nothing grows and three transfers at once
-# read several times what any wire here takes (PERF.md, PR 28), so the
-# limit binds nothing; it goes, with `_admit` / `_retire` / `_deferred`,
-# in a PR of its own with its own memory watch (ROADMAP S2 (b), D7).
-_MAX_BYTES_IN_FLIGHT = 3 * (64 << 20)
-# Re-entrant: a PendingView collected inside _retire retires too.
-_transfers_lock = threading.RLock()
-_bytes_in_flight = 0
-_deferred: collections.deque = collections.deque()  # weakrefs, oldest first
-
-
-def _admit(nbytes: int) -> bool:
-    """_transfers_lock held: takes room for one more transfer if there is."""
-    global _bytes_in_flight
-    if _bytes_in_flight and _bytes_in_flight + nbytes > _MAX_BYTES_IN_FLIGHT:
-        return False
-    _bytes_in_flight += nbytes
-    return True
-
-
-def _retire(nbytes: int) -> None:
-    """A transfer has ended: its room goes to the oldest deferred ones."""
-    global _bytes_in_flight
-    started = []
-    with _transfers_lock:
-        _bytes_in_flight -= nbytes
-        while _deferred:
-            view = _deferred[0]()
-            # Dropped, or fetched out of turn by its own resolve(): gone.
-            if view is not None and not (view._has_room or view.landed):
-                if not _admit(view.nbytes):
-                    break
-                view._has_room = True
-                started.append(view)
-            _deferred.popleft()
-    for view in started:
-        _start_transfer(view._array)
-
-
 class PendingView:
     """The bytes of an array whose transfer to the host has been asked for
-    and not yet waited for.  `nbytes` is known at once; `resolve()` blocks
-    its first caller until the bytes have landed (the wait releases the
-    GIL) and returns the flat uint8 view of the array's own cached host
-    copy, the same one to every caller; `shape` is the array's.  The transfer starts at once while
-    `_MAX_BYTES_IN_FLIGHT` has room, else when an earlier one has landed
-    (or when `resolve()` asks for it).  `started_us` is the monotonic
-    clock (the native runtime's) at which the view was made."""
+    and not yet waited for.  The transfer starts when the view is made;
+    `nbytes` is known at once; `resolve()` blocks its first caller until
+    the bytes have landed (the wait releases the GIL) and returns the flat
+    uint8 view of the array's own cached host copy, the same one to every
+    caller; `shape` is the array's.  `started_us` is the monotonic clock
+    (the native runtime's) at which the view was made.  How many transfers
+    are on their way at once is up to the caller: each holds one landing
+    block of its size until the view and the array are dropped."""
 
     def __init__(self, array):
         self.nbytes = int(array.nbytes)
@@ -214,13 +172,7 @@ class PendingView:
         self._flat = None
         self._lock = threading.Lock()
         self.started_us = time.monotonic_ns() // 1000
-        with _transfers_lock:
-            # Behind any deferred view: transfers start in the order asked.
-            self._has_room = not _deferred and _admit(self.nbytes)
-            if not self._has_room:
-                _deferred.append(weakref.ref(self))
-        if self._has_room:
-            _start_transfer(array)
+        _start_transfer(array)
 
     @property
     def landed(self) -> bool:
@@ -232,33 +184,10 @@ class PendingView:
         return tuple(self._array.shape)
 
     def resolve(self) -> np.ndarray:
-        global _bytes_in_flight
         with self._lock:
             if self._flat is None:
-                with _transfers_lock:
-                    # Asked for before its turn came: over the limit
-                    # rather than wait for room behind others.
-                    out_of_turn = not self._has_room
-                    if out_of_turn:
-                        self._has_room = True
-                        _bytes_in_flight += self.nbytes
-                try:
-                    if out_of_turn:
-                        _start_transfer(self._array)
-                    self._flat = _flat_u8(np.asarray(self._array))
-                finally:
-                    self._has_room = False
-                    _retire(self.nbytes)
+                self._flat = _flat_u8(np.asarray(self._array))
             return self._flat
-
-    def __del__(self):
-        # Dropped before anyone waited for it: give its room back.
-        try:
-            if self._has_room:
-                self._has_room = False
-                _retire(self.nbytes)
-        except Exception:  # noqa: BLE001 — interpreter teardown
-            pass
 
     def __array__(self, dtype=None, copy=None):
         return self.resolve()

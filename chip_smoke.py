@@ -37,9 +37,9 @@ import time
 
 SEED = 21
 PLAIN_SIZES = (1 << 10, 1 << 16)             # single_chip_echo_step
-FUSED_SIZES = (1 << 20, 1 << 24, 1 << 26)    # echo_fused, bench.py's rule
+FUSED_SIZES = (1 << 20, 1 << 24, 1 << 26)    # echo_fused: whole 512 KB blocks
 SERVED_SIZES = (1 << 10, 1 << 20, 1 << 26)
-PIPELINE_DEPTH = 8        # 512 MB in flight at 64 MB, bench.py's geometry
+PIPELINE_DEPTH = 8        # 512 MB in flight at 64 MB, the tensor64M mix's depth
 EXCHANGE_BYTES_PER_PEER = 64 << 20           # rdma_performance's width
 # The shm ring, each shm/ici connection's two 256 MB one-sided windows and
 # the 64 MB staging slab are shm_open+ftruncate files with no fallocate:
@@ -307,9 +307,9 @@ def leg_served_path(sizes, depth: int, seed: int = SEED,
 
 
 def leg_staged_path(size: int, seed: int = SEED, iters: int = 4) -> dict:
-    """The staged path as bench.py's device-origin RPC leg drives it: D2H
-    into a registered staging slab, the native echo loop over the ici
-    ring, the shm ring and tcp, echoed bytes back on the device."""
+    """The staged path of a device-origin RPC: D2H into a registered
+    staging slab, the native echo loop over the ici ring, the shm ring
+    and tcp, echoed bytes back on the device."""
     import jax
     import numpy as np
 

@@ -102,7 +102,8 @@ void IOBuf::append(const void* data, size_t n) {
     // what caps bulk goodput on paravirtualized kernels.  Genuine
     // exhaustion (slab growth failure) is a hard programming/resource
     // error at this copying entry point — the zero-copy path
-    // (append_block/trpc_arena_alloc) reports it recoverably instead.
+    // (`append_block` of a block the caller allocated) reports it recoverably
+    // instead.
     const uint32_t want =
         (arena == HostArena::instance() && n >= HostArena::kBigBlockMin)
             ? static_cast<uint32_t>(std::min<size_t>(n, 8u << 20))

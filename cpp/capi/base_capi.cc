@@ -2,94 +2,13 @@
 // parity role: the reference's C++ API surface consumed by its examples).
 #include <cstring>
 
-#include "base/device_arena.h"
 #include "base/endpoint.h"
 #include "base/iobuf.h"
 
-using trpc::Block;
-using trpc::DeviceArena;
 using trpc::EndPoint;
 using trpc::IOBuf;
 
 extern "C" {
-
-// ---- device arena (block_pool parity; see base/device_arena.h) ----------
-
-void* trpc_arena_create(uint32_t block_size, uint32_t blocks_per_slab,
-                        int shm_backed) {
-  DeviceArena::Options opts;
-  opts.block_size = block_size;
-  opts.blocks_per_slab = blocks_per_slab;
-  opts.shm_backed = shm_backed != 0;
-  return new DeviceArena(opts);
-}
-
-void trpc_arena_destroy(void* arena) {
-  delete static_cast<DeviceArena*>(arena);
-}
-
-// Allocates one block; *data_out is the caller-writable staging memory
-// (wrap it in numpy / hand it to a device DMA), *meta_out the slab/offset
-// handle a device transport would ship instead of bytes.  The block is
-// consumed by trpc_iobuf_append_block or returned via trpc_arena_release.
-void* trpc_arena_alloc(void* arena, void** data_out, uint64_t* meta_out) {
-  Block* b = static_cast<DeviceArena*>(arena)->allocate(0);
-  if (b == nullptr) {
-    return nullptr;
-  }
-  *data_out = b->data;
-  *meta_out = b->user_meta;
-  return b;
-}
-
-void trpc_arena_release(void* /*arena*/, void* block) {
-  static_cast<Block*>(block)->release();
-}
-
-uint32_t trpc_arena_block_size(void* arena) {
-  return static_cast<DeviceArena*>(arena)->block_size();
-}
-
-size_t trpc_arena_blocks_in_use(void* arena) {
-  return static_cast<DeviceArena*>(arena)->blocks_in_use();
-}
-
-// Zero-copy append: the block's [0, len) bytes enter the IOBuf without
-// copying; the caller's reference is consumed.  Returns 0, or -1 when len
-// exceeds the block capacity (a ctypes caller is a trust boundary: an
-// oversized length would put neighboring slab bytes on the wire).
-int trpc_iobuf_append_block(void* buf, void* block, uint32_t len) {
-  Block* b = static_cast<Block*>(block);
-  if (len > b->cap) {
-    b->release();  // still consumes, so the block cannot leak
-    return -1;
-  }
-  b->size = len;
-  static_cast<IOBuf*>(buf)->append_block(b, 0, len);
-  return 0;
-}
-
-// True when byte `pos` of the IOBuf physically lives inside `arena`
-// (introspection for zero-copy tests).
-int trpc_iobuf_in_arena(void* buf, void* arena, size_t pos) {
-  auto* iobuf = static_cast<IOBuf*>(buf);
-  size_t off = 0;
-  for (size_t i = 0; i < iobuf->block_count(); ++i) {
-    const IOBuf::BlockRef& ref = iobuf->ref_at(i);
-    if (pos < off + ref.length) {
-      void* base;
-      uint64_t handle;
-      uint32_t boff;
-      return static_cast<DeviceArena*>(arena)->locate(
-                 ref.block->data + ref.offset + (pos - off), &base, &handle,
-                 &boff)
-                 ? 1
-                 : 0;
-    }
-    off += ref.length;
-  }
-  return 0;
-}
 
 // Wrap caller-owned memory (e.g. a dlpack-exported JAX host buffer)
 // without copying: the bytes enter the IOBuf by reference and

@@ -601,8 +601,8 @@ int trpc_bench_echo_rpc(const void* data, size_t len, int iters,
 // Sender-owned zero-copy staging (net/ici_transport.h): registered,
 // shm-published payload memory the ICI ring ships WITHOUT its DMA copy —
 // one descriptor per payload, receiver wraps the bytes in place.  Python
-// views the slab via np.frombuffer and lands device fetches in it; see
-// bench.py's tpu_rpc leg.
+// views the slab via np.frombuffer and lands device fetches in it
+// (zerocopy.alloc_staging; chip_smoke.py's staged leg).
 void* trpc_ici_staging_alloc(size_t len, uint32_t* ordinal_out) {
   return ici_staging_alloc(len, ordinal_out);
 }

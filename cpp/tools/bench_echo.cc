@@ -64,8 +64,8 @@ int main(int argc, char** argv) {
 
   // TRPC_BENCH_FLAGS="name=value,name=value": validated runtime flag
   // flips applied before any traffic, so a harness can measure the same
-  // binary with a feature armed (e.g. trpc_timeline=true for the
-  // flag-ON overhead bound in test_perf_smoke).
+  // binary with a feature armed (e.g. trpc_timeline=true against the
+  // default for the flight recorder's flag-ON overhead).
   if (const char* spec = getenv("TRPC_BENCH_FLAGS")) {
     std::string s(spec);
     size_t pos = 0;

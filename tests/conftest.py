@@ -17,8 +17,8 @@ if "xla_force_host_platform_device_count" not in _flags:
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
-        "slow: perf smoke / long soaks, excluded from the tier-1 gate "
-        "(run with -m slow)",
+        "slow: the sanitizer matrix and long soaks, excluded from the "
+        "tier-1 gate (run with -m slow)",
     )
     config.addinivalue_line(
         "markers",

@@ -12,6 +12,7 @@ import errno
 import sys
 import threading
 import time
+import weakref
 
 import jax.numpy as jnp
 import numpy as np
@@ -116,29 +117,30 @@ def test_host_view_of_an_array_that_is_not_host_visible_returns_pending():
     assert np.asarray(view) is flat
 
 
-def test_transfers_start_in_order_while_the_bytes_in_flight_have_room(
-        monkeypatch):
-    monkeypatch.setattr(zerocopy, "_MAX_BYTES_IN_FLIGHT", 3 * 4096)
-    arrays = [HeldArray(_payload(i), held=False) for i in range(6)]
+def test_every_transfer_starts_when_its_view_is_made():
+    order = []
+
+    class Logged(HeldArray):
+        def copy_to_host_async(self) -> None:
+            super().copy_to_host_async()
+            order.append(self)
+
+    arrays = [Logged(_payload(i)) for i in range(8)]
     views = [zerocopy.host_view(a)[0] for a in arrays]
-    assert [a.started for a in arrays] == [True] * 3 + [False] * 3
-    views[1].resolve()                   # one lands: the oldest deferred goes
-    assert [a.started for a in arrays] == [True] * 4 + [False] * 2
-    views[0] = None                      # dropped unresolved: room given back
-    assert [a.started for a in arrays] == [True] * 5 + [False]
-    # Asked for before its turn: started there and then, over the limit.
-    assert views[5].resolve().tobytes() == _payload(5).tobytes()
-    assert arrays[5].started
-    # One transfer may always run, whatever its size; behind others it
-    # waits for all the room it needs.
-    big = HeldArray(_payload(6, n=1 << 16), held=False)
+    # None has landed or been waited for, and all eight are on their way.
+    assert order == arrays and not any(v.landed for v in views)
+    # Dropped unresolved: the module kept nothing of it to give back.
+    dropped = weakref.ref(views[0])
+    views[0] = None
+    assert dropped() is None
+    # Whatever its size, and whatever is still held in front of it.
+    big = Logged(_payload(8, n=1 << 16), held=False)
     big_view = zerocopy.host_view(big)[0]
-    assert not big.started
-    for view in views[2:5]:
-        view.resolve()
-    assert big.started and zerocopy._bytes_in_flight == 1 << 16
-    assert big_view.resolve().size == 1 << 16
-    assert zerocopy._bytes_in_flight == 0 and not zerocopy._deferred
+    assert order[-1] is big and big_view.resolve().size == 1 << 16
+    for a in arrays:
+        a.release()
+    for i, view in enumerate(views[1:], start=1):
+        assert view.resolve().tobytes() == _payload(i).tobytes()
 
 
 def test_host_bytes_waits_for_the_transfer():
@@ -375,14 +377,41 @@ def test_the_request_stays_pinned_until_the_runtime_drops_it(echo):
     _no_pins_left()
 
 
-def test_many_submitters_one_poller_every_call_completes_exactly_once(
-        echo, monkeypatch):
+def test_two_pipelines_stage_independently(echo):
+    """A fetch that one pipeline waits for holds back neither the start
+    nor the completion of another pipeline's: they share no queue."""
+    ch, pipe, _ = echo
+    other = ch.pipeline()
+    try:
+        stuck = HeldArray(_payload(0, n=1 << 16))
+        (stuck_token,) = pipe.submit("Echo.Echo",
+                                     [zerocopy.host_view(stuck)[0]])
+        time.sleep(0.02)                 # pipe's stager is inside the fetch
+        free = [HeldArray(_payload(i, n=1 << 16), held=False)
+                for i in (1, 2, 3)]
+        tokens = other.submit("Echo.Echo",
+                              [zerocopy.host_view(a)[0] for a in free])
+        assert all(a.started for a in free)
+        done = _drain(other, 3)
+        for i, token in enumerate(tokens, start=1):
+            assert done[token].tobytes() == _payload(i, n=1 << 16).tobytes()
+            done[token].data.release()
+        assert pipe.poll(timeout_ms=0) == [] and pipe.outstanding == 1
+        stuck.release()
+        done = _drain(pipe, 1)
+        assert done[stuck_token].ok
+        done[stuck_token].data.release()
+    finally:
+        other.close()
+    _no_pins_left()
+
+
+def test_many_submitters_one_poller_every_call_completes_exactly_once(echo):
     """Pending and ready requests from more threads than cores, a poller
     and a canceller on one pipeline, the interpreter switching threads
     every 10 us: no token is lost or handed out twice, every echo is its
     own request, and nothing stays pinned, queued or counted in flight."""
     _, pipe, _ = echo
-    monkeypatch.setattr(zerocopy, "_MAX_BYTES_IN_FLIGHT", 4 * 512)
     threads, rounds, per_submit = 12, 20, 3
     total = threads * rounds * per_submit
     sent: dict[int, bytes] = {}
@@ -440,5 +469,4 @@ def test_many_submitters_one_poller_every_call_completes_exactly_once(
             assert body == sent[token]
     assert sum(1 for status, _ in got.values() if status == 0) >= total - 36
     assert pipe.outstanding == 0 and not pipe._staged_by_token
-    assert zerocopy._bytes_in_flight == 0 and not zerocopy._deferred
     _no_pins_left()

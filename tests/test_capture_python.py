@@ -5,10 +5,9 @@ capture -> replay roundtrip through tools/traffic_replay.py, and replay
 composed with server-side chaos (svr_delay) — errors under chaos must
 stay TYPED (deadline/overload sheds), never untyped failures.
 
-The timing-bound replay-fidelity gate (rate within 10%, p99 <= 2x the
-recorded baseline, shed-don't-degrade at 2x) lives in
-tests/test_perf_smoke.py against the checked-in golden capture
-tests/data/golden_mixed.cap.
+Replay fidelity in time (offered rate and server-side p99 against the
+recorded window, shedding at twice the fitted rate) is not measured: no
+cell of the benchmark replays a capture.
 """
 
 import json

@@ -37,6 +37,13 @@ def _var(name: str) -> int:
 def echo_server():
     srv = Server()
     srv.register_native_echo("Echo.Echo")
+    srv.handled = []                     # what reached the Python handler
+
+    def counted(call, req):
+        srv.handled.append(bytes(req))
+        call.respond(req)
+
+    srv.register("Echo.Counted", counted)
     srv.start(0)
     try:
         yield srv
@@ -64,11 +71,14 @@ def test_deadline_flags_exist_and_validate():
     set_flag("trpc_cluster_retry_budget_pct", "0")
 
 
-def test_scope_surfaces_typed_error_and_sheds_server_side(echo_server):
+@pytest.mark.parametrize("method", ["Echo.Echo", "Echo.Counted"])
+def test_scope_surfaces_typed_error_and_sheds_server_side(echo_server,
+                                                          method):
     """svr_delay chaos + a tight end-to-end budget: the caller gets the
     TYPED DeadlineExpiredError at its budget (not a generic timeout at
     the much larger per-hop timeout), and the server sheds the expired
-    request before the handler — never half-executed."""
+    request before the handler — never half-executed: a Python handler
+    counts zero executions for it."""
     ch = Channel(f"127.0.0.1:{echo_server.port}", timeout_ms=10000)
     try:
         echo_server.set_faults("seed=1;svr_delay=1:150")
@@ -76,7 +86,7 @@ def test_scope_surfaces_typed_error_and_sheds_server_side(echo_server):
         t0 = time.monotonic()
         with deadline_scope(50):
             with pytest.raises(DeadlineExpiredError):
-                ch.call("Echo.Echo", b"doomed")
+                ch.call(method, b"doomed")
         dt_ms = (time.monotonic() - t0) * 1000
         assert dt_ms < 150, f"died at the budget, not the delay: {dt_ms}"
         deadline = time.monotonic() + 3
@@ -86,7 +96,9 @@ def test_scope_surfaces_typed_error_and_sheds_server_side(echo_server):
         assert _var("deadline_expired_shed_total") > shed0
         echo_server.set_faults("")
         # In-deadline traffic is unharmed.
-        assert ch.call("Echo.Echo", b"fine") == b"fine"
+        assert ch.call(method, b"fine") == b"fine"
+        assert echo_server.handled == ([b"fine"] if method == "Echo.Counted"
+                                       else [])
     finally:
         ch.close()
 

@@ -1,5 +1,8 @@
-"""Device arena: zero-copy staging path between JAX arrays and the C++ RPC
-runtime (RDMA block_pool parity — VERDICT r1 'bridge the two halves')."""
+"""Zero-copy paths between JAX arrays and the C++ RPC runtime: the array's
+own host buffer on the wire by reference (`zerocopy.append_jax`), and a
+registered ici staging slab whose bytes cross as sender-owned descriptors
+(RDMA block_pool parity; the native `DeviceArena` under it is tested in
+cpp/tests/test_rpc.cc `device_arena_zero_copy_rpc`)."""
 
 import time
 
@@ -7,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from brpc_tpu.rpc.arena import DeviceArena, call_with_block
 from brpc_tpu.rpc.client import Channel
 from brpc_tpu.rpc.server import Server
 
@@ -19,29 +21,6 @@ def echo_server():
     srv.start(0)
     yield srv
     srv.stop()
-
-
-def test_jax_array_through_arena_rpc(echo_server):
-    arena = DeviceArena(block_size=64 * 1024, blocks_per_slab=4)
-    ch = Channel(f"127.0.0.1:{echo_server.port}", timeout_ms=5000)
-
-    x = jnp.arange(4096, dtype=jnp.uint32)  # device array (cpu mesh here)
-    block = arena.alloc()
-    assert arena.blocks_in_use == 1
-    n = block.put(x)  # the single device->host landing
-    assert n == 4096 * 4
-    resp = call_with_block(ch, "Echo.Echo", block, n)
-    # The consumed block returns to the arena once the write fiber drops
-    # the last reference — a hair after the response lands; poll briefly.
-    for _ in range(200):
-        if arena.blocks_in_use == 0:
-            break
-        time.sleep(0.005)
-    assert arena.blocks_in_use == 0
-    got = np.frombuffer(resp, dtype=np.uint32)
-    np.testing.assert_array_equal(got, np.asarray(x))
-    ch.close()
-    arena.close()
 
 
 def test_zero_copy_pointer_identity():
@@ -93,24 +72,6 @@ def test_zero_copy_rpc_roundtrip(echo_server):
         time.sleep(0.005)
     assert zerocopy.live_sends() == 0
     ch.close()
-
-
-def test_arena_block_meta_and_release(echo_server):
-    arena = DeviceArena(block_size=16 * 1024, blocks_per_slab=2)
-    a = arena.alloc()
-    b = arena.alloc()
-    # lkey-analogue metas: distinct slab offsets.
-    assert a.meta != b.meta
-    assert arena.blocks_in_use == 2
-    a.release()
-    b.release()
-    assert arena.blocks_in_use == 0
-    # Slab growth beyond one slab.
-    blocks = [arena.alloc() for _ in range(5)]
-    assert arena.blocks_in_use == 5
-    for blk in blocks:
-        blk.release()
-    arena.close()
 
 
 def test_ici_staging_zero_copy_from_python():

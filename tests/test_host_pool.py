@@ -174,6 +174,31 @@ def test_a_block_is_handed_only_to_a_request_of_its_size(pool):
     assert _fetch_and_write(2 << 20)[1] == where
 
 
+def test_eight_views_made_together_hold_eight_blocks_and_give_them_back(pool):
+    class Filled(LandingArray):
+        def __init__(self, value: int):
+            super().__init__(1 << 20)
+            self.value = value
+
+        def copy_to_host_async(self) -> None:
+            self.host = np.full(self.nbytes, self.value, dtype=np.uint8)
+
+    arrays = [Filled(i + 1) for i in range(8)]
+    views = [zerocopy.host_view(a)[0] for a in arrays]
+    # Eight transfers on their way before anyone waits for one.
+    assert all(isinstance(v, zerocopy.PendingView) and not v.landed
+               for v in views)
+    assert all(get_handler_name(a.host) == POOLED for a in arrays)
+    assert len({a.host.ctypes.data for a in arrays}) == 8
+    assert pool.trpc_host_pool_idle_bytes() == 0
+    for i in reversed(range(8)):
+        flat = views[i].resolve()
+        assert flat.ctypes.data == arrays[i].host.ctypes.data
+        assert flat.size == 1 << 20 and (flat == i + 1).all()
+    del flat, views, arrays
+    assert pool.trpc_host_pool_idle_bytes() == 8 << 20
+
+
 def test_the_idle_list_is_bounded_and_the_oldest_block_goes(pool):
     # 17 blocks of 64 MB, none written: address space, not memory.
     held = [zerocopy.host_bytes(LandingArray(MB64)) for _ in range(17)]

@@ -3,9 +3,8 @@ the /tuner builtin JSON over HTTP, the flag-introspection roundtrip
 (observe.flags() == /flags?format=json == the C++ registry), and the
 tuner module's status/decisions/counters bindings.
 
-The tuner-ON perf floors (1KB QPS with the controller enabled, and the
->=90% recovery-from-wrong-flags gate) live in tests/test_perf_smoke.py
-with the other timing-bound floors.
+What the controller costs and recovers on the chip is not measured: no
+cell of the benchmark turns it on.
 """
 
 import json
@@ -48,6 +47,17 @@ def test_tuner_defaults_off_and_flags_validate():
         set_flag("trpc_tuner_eval_ticks", "0")
     with pytest.raises(ValueError):
         set_flag("trpc_tuner_hysteresis_pct", "95")
+
+
+@pytest.mark.parametrize("flag", ["rpcz_enabled", "trpc_timeline",
+                                  "trpc_tuner"])
+def test_opt_in_planes_default_off(flag):
+    """Spans, timeline events and the tuner's sampling cost the hot path
+    one relaxed load unless switched on: the compiled-in default, whatever
+    an earlier test of this process left the value at."""
+    get_flag(flag)                       # rpcz registers its flags lazily
+    record = {f["name"]: f for f in observe.flags()}[flag]
+    assert record["default"] == "false", record
 
 
 def test_flags_introspection_roundtrip():

@@ -610,8 +610,8 @@ def main(argv=None) -> int:
                     help="skip the prefix-cache phase")
     ap.add_argument("--shape", default="",
                     help="capture file whose per-tenant record shares "
-                         "set the prompt mix (e.g. "
-                         "tests/data/golden_mixed.cap)")
+                         "set the prompt mix (one saved by "
+                         "brpc_tpu.rpc.capture.save_capture)")
     ap.add_argument("--prefix-samples", type=int,
                     default=PREFIX_DEFAULTS["samples"])
     ap.add_argument("--prefix-seed", type=int,

@@ -407,11 +407,8 @@ def check_flag_references() -> None:
     dynamic_prefixes = ("max_concurrency_",)
     ref = re.compile(r'(?:set_flag|get_flag|trpc_flag_set|trpc_flag_get)'
                      r'\(\s*[bf]?"(trpc_[a-z0-9_]+)"')
-    py_roots = [REPO / "brpc_tpu", REPO / "tools", REPO / "tests",
-                REPO / "bench.py"]
-    for root in py_roots:
-        files = [root] if root.is_file() else sorted(root.rglob("*.py"))
-        for p in files:
+    for root in (REPO / "brpc_tpu", REPO / "tools", REPO / "tests"):
+        for p in sorted(root.rglob("*.py")):
             text = p.read_text()
             for m in ref.finditer(text):
                 name = m.group(1)

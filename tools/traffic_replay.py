@@ -38,8 +38,8 @@ Usage:
   python tools/traffic_replay.py --addr ... --capture cap.bin \
       --mode stat --rate-scale 2.0 --duration 5
 
-Composes with tools/load_orchestrator.py --fault-schedule (chaos while
-replaying) and bench.py's `replay` row (BENCH_REPLAY=1).
+Composes with a fault schedule on the server (`Server.set_faults`: chaos
+while replaying; tests/test_capture_python.py).
 """
 
 from __future__ import annotations
