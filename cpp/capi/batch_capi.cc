@@ -13,8 +13,10 @@
 //  - request bytes enter the wire path BY REFERENCE (caller deleter runs
 //    when the last IOBuf reference drops — which may be after a timeout
 //    completion, so the caller must free on the deleter, not on poll);
-//  - responses land in the caller's buffer (one native memcpy off-GIL on
-//    the completion fiber, pool blocks recycled immediately) or ride out
+//  - responses land in the caller's buffer (one native copy off-GIL from
+//    the completion fiber, cut over the connection's rails when the
+//    response is a one-sided window span — net/rma.h rma_land; blocks and
+//    span slots recycled immediately) or ride out
 //    as an IOBuf handle the caller owns (view in place, destroy to
 //    recycle) — no Python bytes objects at the boundary either way;
 //  - a BatchCall is freed at the LAST of {issuer done, completion polled},
@@ -45,6 +47,7 @@
 #include "net/channel.h"
 #include "net/cluster.h"
 #include "net/controller.h"
+#include "net/rma.h"
 #include "net/span.h"
 #include "stat/latency_recorder.h"
 #include "stat/reducer.h"
@@ -108,6 +111,7 @@ struct BatchPipelineVars {
   Adder ready_us;
   Adder resp_bytes;
   Adder land_copy_bytes;
+  Adder land_fanout_bytes;
   Adder submits;
   Adder submit_us;
   Adder staged_calls;
@@ -138,7 +142,8 @@ struct BatchPipelineVars {
                    "request out, server, response in and parsed");
     land_us.expose("batch_land_us",
                    "us the completion fiber spent copying the response "
-                   "into the caller's buffer (0 when it landed in place)");
+                   "into the caller's buffer, its rails' join included "
+                   "(0 when it landed in place)");
     ready_us.expose("batch_ready_us",
                     "us a finished call lay in the done-ring until the "
                     "caller's poll handed it out");
@@ -146,7 +151,11 @@ struct BatchPipelineVars {
                       "response bytes of the calls in batch_calls_polled");
     land_copy_bytes.expose("batch_land_copy_bytes",
                            "the part of batch_resp_bytes that the "
-                           "completion fiber's copy_to moved");
+                           "completion fiber's landing copy moved");
+    land_fanout_bytes.expose("batch_land_fanout_bytes",
+                             "the part of batch_land_copy_bytes whose "
+                             "copy ran on more than one rail (one-sided "
+                             "window spans of more than one chunk)");
     submits.expose("batch_submits", "accepted trpc_batch_submit crossings");
     submit_us.expose("batch_submit_us",
                      "us inside trpc_batch_submit, entry to return");
@@ -218,6 +227,7 @@ struct BatchCall {
   int64_t reply_us = 0;   // entry of on_call_done: the response is parsed
   int64_t landed_us = 0;  // after the copy into resp_buf; else == reply_us
   size_t land_copied = 0;  // bytes that copy moved (0: in place / no buf)
+  bool land_fanned = false;  // that copy ran on more than one rail
   std::atomic<bool> canceled{false};
   // Published by the issuer after CallMethod returns, so a cancel can
   // reach the in-flight fid (0 = not yet issued / cluster-internal).
@@ -261,8 +271,10 @@ struct Batch {
 
 // Completion path — runs on whatever fiber finishes the call (dispatch
 // fiber inline for responses, timeout fiber, canceller).  Bounded
-// framework work only: status capture, the native landing memcpy, one
-// atomic push, one wake.
+// framework work only: status capture, the landing copy of a response
+// that is not in place (rma_land: a one-sided window span is copied out
+// by the connection's rails and this fiber joins them, anything else is
+// one copy_to here), one atomic push, one wake.
 void on_call_done(BatchCall* c) {
   Batch* b = c->batch;
   c->reply_us = c->landed_us = monotonic_time_us();
@@ -312,12 +324,12 @@ void on_call_done(BatchCall* c) {
           c->response.ref_at(0).block->data + c->response.ref_at(0).offset ==
               c->resp_buf;
       if (!in_place) {
-        c->response.copy_to(c->resp_buf, n);
+        c->land_fanned = rma_land(c->response, c->resp_buf, n) > 1;
         c->land_copied = n;
-        c->landed_us = monotonic_time_us();
+        c->landed_us = monotonic_time_us();  // after the rails' join
       }
       c->resp_copied = true;
-      c->response.clear();  // recycle pool blocks now, not at poll
+      c->response.clear();  // recycle pool blocks / span slots now
     }
   } else {
     c->resp_len = c->response.size();
@@ -492,8 +504,8 @@ void fill_completion(BatchCall* c, trpc_batch_completion* out) {
 }
 
 // One drain's worth of phase sums: gathered while poll hands calls out,
-// added to the registry once per drain (ten thread-local adds a poll,
-// not ten a call).
+// added to the registry once per drain (a dozen thread-local adds a poll,
+// not a dozen a call).
 struct PhaseSums {
   int64_t polled = 0;
   int64_t failed = 0;
@@ -503,6 +515,7 @@ struct PhaseSums {
   int64_t ready_us = 0;
   int64_t resp_bytes = 0;
   int64_t land_copy_bytes = 0;
+  int64_t land_fanout_bytes = 0;
   int64_t staged = 0;
   int64_t stage_us = 0;
   int64_t fetch_us = 0;
@@ -524,6 +537,9 @@ struct PhaseSums {
     ready_us += std::max<int64_t>(polled_us - c->landed_us, 0);
     resp_bytes += static_cast<int64_t>(c->resp_len);
     land_copy_bytes += static_cast<int64_t>(c->land_copied);
+    if (c->land_fanned) {
+      land_fanout_bytes += static_cast<int64_t>(c->land_copied);
+    }
     if (c->staged_us != 0) {
       ++staged;
       stage_us += c->enter_us - c->staged_us;
@@ -547,6 +563,7 @@ struct PhaseSums {
     v.ready_us << ready_us;
     v.resp_bytes << resp_bytes;
     v.land_copy_bytes << land_copy_bytes;
+    v.land_fanout_bytes << land_fanout_bytes;
     if (staged == 0) {
       return;
     }

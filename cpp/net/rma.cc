@@ -8,6 +8,8 @@
 #include <unistd.h>
 
 #include <algorithm>
+#include <functional>
+#include <memory>
 #include <unordered_map>
 #include <vector>
 
@@ -560,12 +562,10 @@ struct RailJob {
   IOBuf data;                // this rail's contiguous byte range
   uint32_t first_chunk = 0;
   uint64_t chunk = 0;
-  uint64_t total = 0;
   uint64_t cid = 0;     // timeline correlation
   uint32_t rail = 0;
   bool crc = false;
   EndPoint peer;
-  std::atomic<uint32_t>* remaining = nullptr;
   // Deadline plane (net/deadline.h): polled between chunks; a triggered
   // token stops this rail (skipped bytes counted into *aborted — the
   // cancel_saved_bytes accounting and the caller's no-control-frame
@@ -652,15 +652,72 @@ void rail_run(RailJob* j) {
     ci += 1;
     off += n;
   }
-  // Release on the countdown: the joining sender must observe every
-  // chunk write this rail issued before sending the control frame.
-  j->remaining->fetch_sub(1, std::memory_order_release);
 }
 
+// ---- the fan-out (both directions: put_body writes a body into the
+// peer's region with it, rma_land copies a window span out with it) ------
+
+// How a transfer of `total` bytes in chunks of `chunk` is cut over rails:
+// `per` consecutive chunks (`rail_bytes`) a rail, `rails` of them, the
+// last holding what is left.
+struct RailCut {
+  uint32_t per = 0;
+  uint32_t rails = 0;
+  uint64_t rail_bytes = 0;
+};
+
+RailCut cut_rails(uint64_t total, uint64_t chunk, int rails) {
+  const uint32_t nchunks =
+      static_cast<uint32_t>((total + chunk - 1) / chunk);
+  const uint32_t want =
+      std::max(1u, std::min<uint32_t>(static_cast<uint32_t>(rails),
+                                      nchunks));
+  RailCut cut;
+  cut.per = (nchunks + want - 1) / want;
+  // Rails actually used: ceil(nchunks/per) — may be fewer than `want`
+  // when the rounding above packs the chunks tighter (the join counts
+  // REAL rails, or it would wait forever on lanes that never ran).
+  cut.rails = (nchunks + cut.per - 1) / cut.per;
+  cut.rail_bytes = static_cast<uint64_t>(cut.per) * chunk;
+  return cut;
+}
+
+struct RailStart {
+  const std::function<void(uint32_t)>* run = nullptr;
+  uint32_t rail = 0;
+  std::atomic<uint32_t>* remaining = nullptr;
+};
+
 void rail_fiber(void* arg) {
-  auto* j = static_cast<RailJob*>(arg);
-  rail_run(j);
-  delete j;
+  std::unique_ptr<RailStart> s(static_cast<RailStart*>(arg));
+  (*s->run)(s->rail);
+  // Release on the countdown: the joining caller must observe every
+  // byte this rail wrote before it acts on the whole (the control frame,
+  // the landed stamp).
+  s->remaining->fetch_sub(1, std::memory_order_release);
+}
+
+// Runs run(0) .. run(rails - 1) concurrently and returns when all have
+// finished: all but the last on their own fibers, the last on the
+// caller.  `run` and whatever it captures only have to outlive the call.
+void run_rails(uint32_t rails, const std::function<void(uint32_t)>& run) {
+  std::atomic<uint32_t> remaining{rails - 1};
+  for (uint32_t i = 0; i + 1 < rails; ++i) {
+    auto* s = new RailStart{&run, i, &remaining};
+    if (fiber_start(nullptr, rail_fiber, s, 0) != 0) {
+      rail_fiber(s);  // no fiber to be had: this rail runs here too
+    }
+  }
+  run(rails - 1);
+  // Bounded join: each rail is a finite range memcpy.  Acquire pairs
+  // with the rails' release countdown.
+  while (remaining.load(std::memory_order_acquire) != 0) {
+    if (in_fiber()) {
+      fiber_sleep_us(20);
+    } else {
+      usleep(20);
+    }
+  }
 }
 
 // Cuts body into rail ranges and writes them concurrently; returns when
@@ -670,62 +727,26 @@ void rail_fiber(void* arg) {
 uint64_t put_body(RmaXfer* x, char* payload_dst, IOBuf&& body,
                   uint64_t chunk, int rails, uint64_t cid, bool crc,
                   const EndPoint& peer, const DeadlineToken& tok) {
-  const uint64_t total = body.size();
-  const uint32_t nchunks =
-      static_cast<uint32_t>((total + chunk - 1) / chunk);
-  const uint32_t want =
-      std::max(1u, std::min<uint32_t>(static_cast<uint32_t>(rails),
-                                      nchunks));
-  const uint32_t per = (nchunks + want - 1) / want;  // chunks per rail
-  // Rails actually used: ceil(nchunks/per) — may be fewer than `want`
-  // when the rounding above packs the chunks tighter (the join counts
-  // REAL rails, or it would wait forever on lanes that never ran).
-  const uint32_t r = (nchunks + per - 1) / per;
-  std::atomic<uint32_t> remaining{r};
+  const RailCut cut = cut_rails(body.size(), chunk, rails);
   std::atomic<uint64_t> aborted_bytes{0};
-  RailJob* inline_job = nullptr;
-  for (uint32_t i = 0; i < r; ++i) {
-    auto* j = new RailJob();
-    j->x = x;
-    j->dst_base = payload_dst;
-    j->first_chunk = i * per;
-    j->chunk = chunk;
-    j->total = total;
-    j->cid = cid;
-    j->rail = i;
-    j->crc = crc;
-    j->peer = peer;
-    j->remaining = &remaining;
-    j->tok = tok;
-    j->aborted = &aborted_bytes;
-    const uint64_t rail_bytes =
-        std::min<uint64_t>(static_cast<uint64_t>(per) * chunk, body.size());
-    body.cutn(&j->data, rail_bytes);
-    const bool last = i + 1 == r;
-    if (!last) {
-      if (fiber_start(nullptr, rail_fiber, j, 0) != 0) {
-        rail_run(j);
-        delete j;
-      }
-    } else {
-      inline_job = j;  // the caller is rail r-1's writer
-      break;
-    }
+  std::vector<RailJob> jobs(cut.rails);
+  for (uint32_t i = 0; i < cut.rails; ++i) {
+    RailJob& j = jobs[i];
+    j.x = x;
+    j.dst_base = payload_dst;
+    j.first_chunk = i * cut.per;
+    j.chunk = chunk;
+    j.cid = cid;
+    j.rail = i;
+    j.crc = crc;
+    j.peer = peer;
+    j.tok = tok;
+    j.aborted = &aborted_bytes;
+    body.cutn(&j.data, cut.rail_bytes);  // the last takes what is left
   }
-  if (inline_job != nullptr) {
-    rail_run(inline_job);
-    delete inline_job;
-  }
-  // Bounded join: each rail is a finite chunk-range memcpy.  Acquire
-  // pairs with the rails' release countdown so every chunk write
-  // happens-before the control frame below.
-  while (remaining.load(std::memory_order_acquire) != 0) {
-    if (in_fiber()) {
-      fiber_sleep_us(20);
-    } else {
-      usleep(20);
-    }
-  }
+  // Every chunk write happens-before the control frame the caller sends
+  // after this returns (run_rails' join).
+  run_rails(cut.rails, [&jobs](uint32_t i) { rail_run(&jobs[i]); });
   // Acquire pairs with the rails' abort accounting above.
   return aborted_bytes.load(std::memory_order_acquire);
 }
@@ -781,6 +802,7 @@ struct SpanCtx {
   RmaGeom geom;
   uint64_t off = 0;
   uint64_t need = 0;
+  int mode = 0;  // SocketMode of the connection: whose rails (rma_land)
 };
 
 // Forgets the scavenger's first-seen stamps for a span's slots: called
@@ -1152,6 +1174,29 @@ int rma_rails_for(int socket_mode) {
           : flag_value(shm_rails_flag(), 4));
 }
 
+uint32_t rma_land(const IOBuf& resp, void* dst, size_t n) {
+  const IOBuf::BlockRef* ref =
+      resp.block_count() == 1 ? &resp.ref_at(0) : nullptr;
+  if (ref != nullptr && ref->block->user_deleter == &span_deleter &&
+      n <= ref->length) {
+    const auto* ctx = static_cast<const SpanCtx*>(ref->block->user_ctx);
+    const RailCut cut =
+        cut_rails(n, effective_chunk(n), rma_rails_for(ctx->mode));
+    if (cut.rails > 1) {
+      const char* src = ref->block->data + ref->offset;
+      char* out = static_cast<char*>(dst);
+      run_rails(cut.rails, [&](uint32_t i) {
+        const uint64_t off = i * cut.rail_bytes;
+        memcpy(out + off, src + off,
+               std::min<uint64_t>(cut.rail_bytes, n - off));
+      });
+      return cut.rails;
+    }
+  }
+  resp.copy_to(dst, n);
+  return 1;
+}
+
 void rma_advertise_response(SocketId sid, uint64_t cid, RpcMeta* meta) {
   uint64_t max = 0;
   uint64_t off = 0;
@@ -1408,7 +1453,8 @@ bool rma_resolve(InputMessage* msg, Socket* sock) {
                               std::memory_order_release);
     }
     auto* ctx = new SpanCtx{std::move(map), std::move(scav), geom,
-                            m.rma_off, need};
+                            m.rma_off, need,
+                            static_cast<int>(sock->mode())};
     msg->payload.append_user_data(payload, total, &span_deleter, ctx);
   }
   if (timeline::enabled()) {

@@ -9,7 +9,11 @@
 // orchestration — the shm path used to move one 64MB body through THREE
 // memcpys (producer→ring, ring→IOBuf, IOBuf→landing block); the rma path
 // moves it through ONE (sender→registered region), fanned out over
-// parallel rail fibers.
+// parallel rail fibers, when the receiver reads it where it landed (a
+// server handler's request, a response into a caller's RmaBuffer).  A
+// response for a PRIVATE caller buffer (batch plane, plain memory) is
+// TWO: sender→connection window, then window→caller buffer (rma_land),
+// each cut over the same rails.
 //
 // Model:
 //  - A REGION is pinned memory under an rkey.  Exportable regions are
@@ -199,6 +203,16 @@ bool rma_resolve(InputMessage* msg, Socket* sock);
 
 // Rails configured for a mode (trpc_shm_rails / trpc_ici_rails).
 int rma_rails_for(int socket_mode);
+
+// Copies the first n bytes of a received payload into dst (n <=
+// resp.size()): the batch plane's landing copy into a caller buffer that
+// is not in place.  A window span (rma_resolve's zero-copy wrap) of more
+// than one chunk is cut over its connection's rails the way the sender
+// cut the put — contiguous chunk ranges, all but the last on their own
+// fibers, a bounded join — so it returns only when every byte is in dst;
+// anything else is one copy_to on the caller.  Returns the rails the copy
+// ran on (1: the caller alone).  Fiber- and pthread-safe.
+uint32_t rma_land(const IOBuf& resp, void* dst, size_t n);
 
 // -- span scavenger --------------------------------------------------------
 

@@ -529,6 +529,97 @@ TEST_CASE(rma_span_scavenger_reclaims_leaked_never_live) {
   EXPECT(resp2.equals(big.data(), big.size()));
 }
 
+namespace {
+
+// One landing of `span` by rma_land, checked against copy_to's bytes.
+struct LandJob {
+  const IOBuf* span = nullptr;
+  const std::string* want = nullptr;  // copy_to's result
+  bool on_fiber = false;
+  uint32_t rails_used = 0;
+  bool exact = false;
+  bool where_expected = false;
+};
+
+void land_once(void* arg) {
+  auto* j = static_cast<LandJob*>(arg);
+  j->where_expected = in_fiber() == j->on_fiber;
+  // A canary either side: a rail that wrote past its range shows.
+  const size_t n = j->want->size();
+  std::string got(n + 128, '\x5a');
+  j->rails_used = rma_land(*j->span, &got[64], n);
+  j->exact = got.compare(64, n, *j->want) == 0 &&
+             got.find_first_not_of('\x5a') == 64 &&
+             got.find_last_not_of('\x5a') == 64 + n - 1;
+}
+
+}  // namespace
+
+TEST_CASE(rma_land_fans_a_window_span_out_like_copy_to) {
+  start_once();
+  Channel ch;
+  Channel::Options opts;
+  opts.use_shm = true;
+  opts.timeout_ms = 60000;
+  EXPECT_EQ(ch.Init(addr(), &opts), 0);
+  // Five chunks of the default 2 MB, the last one 1 MB + 3 bytes: an
+  // unaligned length and, at 2 and 4 rails, a last rail shorter than the
+  // others.  The pattern's last byte is not the canary's.
+  const std::string big = pattern((9 << 20) + 3, 11);
+  const size_t spans0 = rma_spans_in_use();
+  Controller cntl;
+  IOBuf req, resp;
+  req.append(big);
+  ch.CallMethod("Echo.Echo", req, &resp, &cntl);
+  EXPECT(!cntl.Failed());
+  // The response IS a window span: one user-data block, its slots held.
+  EXPECT_EQ(resp.block_count(), 1u);
+  EXPECT(resp.ref_at(0).block->user_deleter != nullptr);
+  EXPECT(rma_spans_in_use() > spans0);
+  std::string want(big.size(), 0);
+  EXPECT_EQ(resp.copy_to(&want[0], want.size()), want.size());
+  EXPECT(want == big);
+  const struct {
+    const char* rails;
+    uint32_t used;  // ceil(5 / ceil(5 / rails))
+  } cases[] = {{"1", 1}, {"2", 2}, {"4", 3}, {"16", 5}};
+  for (const auto& c : cases) {
+    FlagGuard rails("trpc_shm_rails", c.rails);
+    // The join's two branches: a plain pthread (usleep) and a fiber.
+    LandJob on_thread{&resp, &want, false};
+    land_once(&on_thread);
+    LandJob on_fiber{&resp, &want, true};
+    fiber_t f = 0;
+    EXPECT_EQ(fiber_start(&f, land_once, &on_fiber, 0), 0);
+    EXPECT_EQ(fiber_join(f), 0);
+    for (const LandJob* j : {&on_thread, &on_fiber}) {
+      EXPECT(j->where_expected);
+      EXPECT(j->exact);
+      EXPECT_EQ(j->rails_used, c.used);
+    }
+  }
+  // A prefix shorter than the span and under one chunk is the plain copy.
+  std::string head(4096, 0);
+  EXPECT_EQ(rma_land(resp, &head[0], head.size()), 1u);
+  EXPECT(big.compare(0, head.size(), head) == 0);
+  // Not a window span (pool blocks, or a user block of someone else's):
+  // the plain copy whatever its size.
+  IOBuf plain;
+  plain.append(big);
+  std::string copy(big.size(), 0);
+  EXPECT_EQ(rma_land(plain, &copy[0], copy.size()), 1u);
+  EXPECT(copy == big);
+  IOBuf wrapped;
+  wrapped.append_user_data(const_cast<char*>(big.data()), big.size(),
+                           [](void*, void*) {});
+  copy.assign(big.size(), 0);
+  EXPECT_EQ(rma_land(wrapped, &copy[0], copy.size()), 1u);
+  EXPECT(copy == big);
+  // Landing holds no reference of its own: the slots go with the payload.
+  resp.clear();
+  EXPECT_EQ(rma_spans_in_use(), spans0);
+}
+
 TEST_CASE(rma_kernel_capability_probe) {
   // The satellite gate: the probe answers deterministically, and on this
   // repo's dev boxes (kernel 4.4.0) io_uring is known-absent — but the
