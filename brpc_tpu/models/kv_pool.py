@@ -9,8 +9,18 @@ record that crosses the wire for one layer (brpc_tpu/rpc/kv.py
 2-byte bit pattern as uint16, because the transfer moves bits and a
 wrapping integer add of a producer is exact where a bf16 add rounds.
 
+A model whose cache is of two kinds (latent attention beside linear
+attention) holds a second pool of the same form beside it: `(slots,
+state layers, rows, 128)`, one slot a sequence, `pool[slot, layer]` the
+snapshot of one layer's recurrent state (its float32 matrix and the
+short convolution's tail, as 2-byte words in rows of 128, so that a
+slot has a page's form).  Every program here, `seeded_pool` too, takes
+either pool: none knows what the trailing axes mean.
+
 `read_page` and `write_page` are the two programs that join a pool to
-the KV plane.  `write_page` donates the pool: without the donation XLA
+the KV plane; `read_pages` and `write_pages` are the same for the pages
+of one sequence, which lie in slots of their own, in one program.  A
+write donates the pool: without the donation XLA
 copies the whole pool for one page (two pools of 5.76 GB fill a 16 GB
 chip: one such copy is out of memory), so the caller's old handle is
 dead after the call and the returned pool is the pool.
@@ -36,8 +46,22 @@ def kv_write_page(pool, slot, page):
                                            slot, axis=0)
 
 
+def kv_read_pages(pool, slots):
+    """The pages at `slots` (distinct), in their order."""
+    return jax.vmap(kv_read_page, in_axes=(None, 0))(pool, slots)
+
+
+def kv_write_pages(pool, slots, pages):
+    """The pool with `pages[i]` at `slots[i]`, the slots distinct."""
+    return lax.fori_loop(
+        0, slots.shape[0],
+        lambda i, pool: kv_write_page(pool, slots[i], pages[i]), pool)
+
+
 read_page = jax.jit(kv_read_page)
 write_page = jax.jit(kv_write_page, donate_argnums=0)
+read_pages = jax.jit(kv_read_pages)
+write_pages = jax.jit(kv_write_pages, donate_argnums=0)
 
 
 def _kv_fill(pool, key):

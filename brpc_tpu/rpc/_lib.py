@@ -325,6 +325,10 @@ def load_library() -> ctypes.CDLL:
             lib.trpc_kv_reset.restype = None
             lib.trpc_kv_note_fetch_many.argtypes = [ctypes.c_uint64]
             lib.trpc_kv_note_fetch_many.restype = None
+            lib.trpc_kv_note_sequence.argtypes = [
+                ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64,
+                ctypes.c_uint64, ctypes.c_int]
+            lib.trpc_kv_note_sequence.restype = None
             # Content-addressed prefix cache (capi/kv_capi.cc; ISSUE 17).
             lib.trpc_kv_content_hash.argtypes = [
                 ctypes.c_void_p, ctypes.c_size_t,

@@ -53,6 +53,24 @@ Decode side, into its own pool in HBM::
     decode_pool = kv_pool.write_page(decode_pool, slot,
                                      jax.device_put(host))
     kv.withdraw_page(7, layers, registry=reg)  # prefill side, when done
+
+A cache of more than one kind (latent attention beside linear attention)
+moves a sequence at a time: a `KvCacheLayout` says per layer whether it
+is paged (a record a page) or holds one snapshot a sequence (a recurrent
+state), and `publish_sequence` / `KvClient.fetch_sequence` /
+`withdraw_sequence` hand the pages and the snapshots over as one unit,
+one registry RPC each and every record's fetch in one round.  Only the
+sequence's id and its length in pages cross between the ranks; the
+snapshots asked for are those taken at that boundary::
+
+    layout = kv.KvCacheLayout(kinds, record_bytes)     # on both ranks
+    kv.publish_sequence(31, layout, pages, states, slab,
+                        node=addr, registry=reg)       # prefill side
+    pages, states = cli.fetch_sequence(31, layout, pages_landing,
+                                       states_landing)  # decode side
+    kv.withdraw_sequence(31, layout, n_pages, registry=reg)
+
+The page calls above are the layout of one paged kind with one page.
 """
 
 from __future__ import annotations
@@ -258,9 +276,12 @@ def reset() -> None:
     load_library().trpc_kv_reset()
 
 
-# ---- pages of a paged pool: one record a layer ---------------------------
+# ---- a cache of layers: paged records and per-sequence snapshots ----------
 
 _MAX_LAYERS = 1 << 16
+_MAX_SEQUENCE_PAGES = 1 << 15
+PAGED = "paged"
+SNAPSHOT = "snapshot"
 
 
 def page_record_id(block_id: int, layer: int) -> int:
@@ -271,45 +292,130 @@ def page_record_id(block_id: int, layer: int) -> int:
     return (block_id << 16) | (layer + 1)
 
 
-def publish_page(block_id: int, page, slab, offset: int = 0,
-                 lease_ms: int = 0, node: str = "",
-                 registry: "KvRegistryClient | None" = None
-                 ) -> list[KvBlockMeta]:
-    """Publishes one page of a paged pool, `page[layer]` as the record
-    `page_record_id(block_id, layer)`.  `page` is an array of leading
-    axis `layers` (a device array, or the view `zerocopy.host_view`
-    made of one when its transfer was started ahead): its bytes come to
-    the host through `zerocopy.host_view`, so into a recycled landing
-    block, each layer's slice of `slab` (an RmaBuffer) from `offset` on
-    is `publish`ed, and the bytes are copied there.  With a
-    `registry` the records are registered in one `register_many`; a
-    record it refuses raises its error.  The slab's bytes belong to the
-    store until the page is withdrawn (`withdraw_page`)."""
-    layers = page.shape[0]
-    flat = (page.resolve() if isinstance(page, zerocopy.PendingView)
-            else zerocopy.host_bytes(page)[0])
-    if flat.nbytes % layers or offset < 0 or \
-            offset + flat.nbytes > slab.nbytes:
+def sequence_record_id(seq_id: int, layer: int, number: int) -> int:
+    """The store's id of one record of sequence `seq_id`.  `number` is
+    the page's number in the sequence for a paged layer, and for a
+    snapshot layer the boundary the state was taken at, in pages: the
+    boundary is part of the id, so a snapshot of another boundary is
+    another record (kv-miss), never older bytes under the same id.  A
+    layer is of one kind, so the two never meet.  Number 0 of sequence
+    `s` is `page_record_id(s, layer)`: a lone page is a sequence of one."""
+    if not (0 <= number < _MAX_SEQUENCE_PAGES
+            and (number == 0 or 0 <= seq_id < 1 << 32)):
         raise ValueError(
-            f"a page of {flat.nbytes} bytes in {layers} layers does not "
-            f"fit the slab at {offset} (slab: {slab.nbytes} bytes)")
-    record = flat.nbytes // layers
-    # Published first, copied second: a page that is live (KvExistsError)
-    # keeps its slab bytes, and nobody can look the records up before
-    # they are registered below.
+            f"no record id for sequence {seq_id} layer {layer} at {number}")
+    return page_record_id((number << 32) | seq_id, layer)
+
+
+@dataclasses.dataclass(frozen=True)
+class KvCacheLayout:
+    """What a model's cache is made of, layer by layer.  A `paged` layer
+    holds one record of `record_bytes[layer]` a page of tokens (keys and
+    values, or MLA's latent); a `snapshot` layer holds one record a
+    sequence whatever its length (a linear-attention layer's recurrent
+    state), which is only worth anything with the pages of the boundary
+    it was taken at.  Both ranks hold the same layout; a sequence's id
+    and its length in pages are all that crosses between them."""
+
+    kinds: tuple[str, ...]
+    record_bytes: tuple[int, ...]
+
+    def __post_init__(self):
+        if (len(self.kinds) != len(self.record_bytes)
+                or not 0 < len(self.kinds) < _MAX_LAYERS
+                or any(k not in (PAGED, SNAPSHOT) for k in self.kinds)
+                or any(n <= 0 for n in self.record_bytes)):
+            raise ValueError(f"not a cache layout: {self.kinds} of "
+                             f"{self.record_bytes} bytes")
+
+    @classmethod
+    def paged(cls, layers: int, record_bytes: int) -> "KvCacheLayout":
+        return cls((PAGED,) * layers, (record_bytes,) * layers)
+
+    def layers_of(self, kind: str) -> list[int]:
+        return [l for l, k in enumerate(self.kinds) if k == kind]
+
+    def records(self, seq_id: int, pages: int) -> tuple[list, list]:
+        """(paged, snapshot): the (record id, bytes) of a sequence of
+        `pages` pages, in the order its bytes lie in a slab or a landing
+        area: page by page each paged layer's record, then each snapshot
+        layer's at the boundary `pages`."""
+        if pages < 1:
+            raise ValueError(f"a sequence of {pages} pages has no records")
+        paged_layers = self.layers_of(PAGED)
+        paged = [(sequence_record_id(seq_id, l, page), self.record_bytes[l])
+                 for page in range(pages) for l in paged_layers]
+        snapshot = [(sequence_record_id(seq_id, l, pages),
+                     self.record_bytes[l])
+                    for l in self.layers_of(SNAPSHOT)]
+        return paged, snapshot
+
+    def record_ids(self, seq_id: int, pages: int) -> list[int]:
+        paged, snapshot = self.records(seq_id, pages)
+        return [rid for rid, _ in paged + snapshot]
+
+    def sequence_bytes(self, pages: int) -> int:
+        return sum(n * (pages if k == PAGED else 1)
+                   for k, n in zip(self.kinds, self.record_bytes))
+
+
+def _host_flat(array) -> np.ndarray:
+    """The bytes of a device array, or of the view `zerocopy.host_view`
+    made of one when its transfer was started ahead, waited for."""
+    return (array.resolve() if isinstance(array, zerocopy.PendingView)
+            else zerocopy.host_bytes(array)[0])
+
+
+def _cut(area, records) -> list:
+    """A landing area (a writable C-contiguous numpy array) cut into the
+    place of each of `records` ((id, bytes)), which lie in it end to
+    end and fill it."""
+    total = sum(n for _, n in records)
+    if not area.flags.c_contiguous or area.nbytes != total:
+        raise ValueError(
+            f"a landing area of {area.nbytes} bytes for {len(records)} "
+            f"records of {total} (C-contiguous: "
+            f"{area.flags.c_contiguous})")
+    flat = area.reshape(-1).view(np.uint8)
+    bufs, at = [], 0
+    for _, nbytes in records:
+        bufs.append(flat[at:at + nbytes])
+        at += nbytes
+    return bufs
+
+
+def _publish_records(records, sources, slab, offset, lease_ms, node,
+                     registry) -> list[KvBlockMeta]:
+    """`records` ((id, bytes), in order) published out of `slab` from
+    `offset` on, end to end, and the bytes of `sources` (flat host
+    arrays, end to end the records' bytes) copied there; with a
+    `registry` all registered in one `register_many`."""
+    total = sum(n for _, n in records)
+    given = sum(flat.nbytes for flat in sources)
+    if given != total or offset < 0 or offset + total > slab.nbytes:
+        raise ValueError(
+            f"{len(records)} records of {total} bytes from {given} bytes "
+            f"at {offset}: that does not fit the slab ({slab.nbytes} "
+            f"bytes)")
+    # Published first, copied second: a record that is live
+    # (KvExistsError) keeps its slab bytes, and nobody can look the
+    # records up before they are registered below.
     metas: list[KvBlockMeta] = []
+    at = offset
     try:
-        for layer in range(layers):
-            metas.append(publish(
-                page_record_id(block_id, layer), slab,
-                offset=offset + layer * record, length=record,
-                lease_ms=lease_ms, node=node))
+        for record_id, nbytes in records:
+            metas.append(publish(record_id, slab, offset=at, length=nbytes,
+                                 lease_ms=lease_ms, node=node))
+            at += nbytes
     except Exception:
         for meta in metas:
             withdraw(meta.block_id)
         raise
-    np.frombuffer(slab.view, dtype=np.uint8)[
-        offset:offset + flat.nbytes] = flat
+    into = np.frombuffer(slab.view, dtype=np.uint8)
+    at = offset
+    for flat in sources:
+        into[at:at + flat.nbytes] = flat
+        at += flat.nbytes
     if registry is not None:
         for answer in registry.register_many(metas, lease_ms=lease_ms):
             if isinstance(answer, RpcError):
@@ -317,17 +423,71 @@ def publish_page(block_id: int, page, slab, offset: int = 0,
     return metas
 
 
-def withdraw_page(block_id: int, layers: int,
-                  registry: "KvRegistryClient | None" = None) -> None:
-    """Takes a published page back: its records leave the registry (one
-    `evict_many`; a record already gone there is no error) and the local
-    store, after which its slab bytes may be used again.  Raises
-    KvMissError if the store did not hold a record."""
-    ids = [page_record_id(block_id, layer) for layer in range(layers)]
+def publish_sequence(seq_id: int, layout: KvCacheLayout, pages, states,
+                     slab, offset: int = 0, lease_ms: int = 0,
+                     node: str = "",
+                     registry: "KvRegistryClient | None" = None
+                     ) -> list[KvBlockMeta]:
+    """Publishes a sequence's cache for a hand-over to another rank:
+    `pages[p, i]` as the record of page `p` of the layout's `i`-th paged
+    layer, `states[j]` as the snapshot of its `j`-th snapshot layer,
+    taken at the boundary `pages.shape[0]` (`states` is None where the
+    layout has no such layer; where the layers' records differ in size
+    an array's bytes are its records end to end, in that order, and
+    only `pages.shape[0]` is read of its shape).  Both are device arrays
+    or numpy arrays, or the views `zerocopy.host_view` made of them when
+    their transfers were started ahead: the bytes come to the host
+    through `zerocopy.host_view`, so into recycled landing blocks, and
+    are copied into `slab` (an RmaBuffer) from `offset` on,
+    `layout.sequence_bytes` of them, each record `publish`ed from its
+    place.  With a `registry` the records of
+    both kinds are registered in one `register_many`; a record it
+    refuses raises its error.  The slab's bytes belong to the store
+    until the sequence is withdrawn (`withdraw_sequence`)."""
+    paged, snapshot = layout.records(seq_id, pages.shape[0])
+    sources = [_host_flat(pages)]
+    if states is not None:
+        sources.append(_host_flat(states))
+    return _publish_records(paged + snapshot, sources, slab, offset,
+                            lease_ms, node, registry)
+
+
+def withdraw_sequence(seq_id: int, layout: KvCacheLayout, pages: int,
+                      registry: "KvRegistryClient | None" = None) -> None:
+    """Takes a published sequence of `pages` pages back: its records of
+    both kinds leave the registry (one `evict_many`; a record already
+    gone there is no error) and the local store, after which its slab
+    bytes may be used again.  Raises KvMissError if the store did not
+    hold a record."""
+    ids = layout.record_ids(seq_id, pages)
     if registry is not None:
         registry.evict_many(ids)
     for record_id in ids:
         withdraw(record_id)
+
+
+def publish_page(block_id: int, page, slab, offset: int = 0,
+                 lease_ms: int = 0, node: str = "",
+                 registry: "KvRegistryClient | None" = None
+                 ) -> list[KvBlockMeta]:
+    """Publishes one page of a paged pool, `page[layer]` as the record
+    `page_record_id(block_id, layer)`: `publish_sequence` for a layout
+    of `page.shape[0]` paged layers of equal records and a sequence of
+    this one page."""
+    layers = page.shape[0]
+    flat = _host_flat(page)
+    if flat.nbytes % layers:
+        raise ValueError(f"a page of {flat.nbytes} bytes is not {layers} "
+                         "layers of equal records")
+    layout = KvCacheLayout.paged(layers, flat.nbytes // layers)
+    return _publish_records(layout.records(block_id, 1)[0], [flat], slab,
+                            offset, lease_ms, node, registry)
+
+
+def withdraw_page(block_id: int, layers: int,
+                  registry: "KvRegistryClient | None" = None) -> None:
+    """`withdraw_sequence` for a page that `publish_page` published."""
+    withdraw_sequence(block_id, KvCacheLayout.paged(layers, 1), 1, registry)
 
 
 # ---- content-addressed prefix cache (ISSUE 17) ---------------------------
@@ -623,7 +783,8 @@ class KvClient:
     lands them natively in `v` (an RmaBuffer view for the one-sided
     path) and returns the landed length.  A kv-stale answer invalidates
     the cached record, re-resolves, and retries once.  `fetch_many`
-    lands many records with their fetches in flight together, and
+    lands many records with their fetches in flight together,
+    `fetch_sequence` a sequence's cache of a `KvCacheLayout`, and
     `fetch_page` a page of a paged pool, one record a layer.  A landing
     fetch rides the node channel's one pipeline, which lives as long as
     the channel: one thread at a time may fetch through a client."""
@@ -909,23 +1070,64 @@ class KvClient:
             raise KvFetchManyError(failed)
         return lengths
 
-    def fetch_page(self, block_id: int, landing):
-        """Lands the page `block_id` that `publish_page` published:
-        layer `l`'s record in `landing[l]`, the records' fetches in
-        flight together (`fetch_many`).  `landing` is a writable
-        C-contiguous numpy array of leading axis `layers` (a view of an
-        RmaBuffer for the one-sided path); it is returned, whole, ready
-        for one `jax.device_put`.  A record that is missing or of
-        another length fails the page (KvFetchManyError)."""
-        layers = landing.shape[0]
-        ids = [page_record_id(block_id, layer) for layer in range(layers)]
-        lengths = self.fetch_many(ids, list(landing))
-        layer_bytes = landing[0].nbytes
-        short = {rid: RpcError(-1, f"record of {n} bytes, layer of "
-                               f"{layer_bytes}")
-                 for rid, n in zip(ids, lengths) if n != layer_bytes}
+    def _land_records(self, records, bufs) -> None:
+        """Lands `records` ((id, bytes), in order) in `bufs`, every
+        fetch in flight together (`fetch_many`): where a buffer is
+        stripe-eligible and RmaBuffer-backed its record can land there
+        one-sided.  A record that is missing or of another length fails
+        them all (KvFetchManyError)."""
+        lengths = self.fetch_many([rid for rid, _ in records], bufs)
+        short = {rid: RpcError(-1, f"record of {n} bytes, layer of {want}")
+                 for (rid, want), n in zip(records, lengths) if n != want}
         if short:
             raise KvFetchManyError(short)
+
+    def fetch_sequence(self, seq_id: int, layout: KvCacheLayout, pages,
+                       states=None):
+        """Lands the sequence `seq_id` that `publish_sequence`
+        published, for a rank that knows its length: `pages` is a
+        writable C-contiguous numpy array of leading axes (pages of the
+        sequence, paged layers), `states` one of leading axis (snapshot
+        layers), None where the layout has none (as there, an array's
+        bytes are its records end to end); views of an RmaBuffer for
+        the one-sided path, which one record a region takes at a time
+        (cpp/net/rma.cc `rma_landing_bind`): the others of a region
+        cross the connection's window and are copied out on landing.
+        One `lookup_many`, then every record of both kinds in flight in
+        one round, each landing in its place; the snapshots asked for
+        are those of the boundary `pages.shape[0]`.  Returns `(pages,
+        states)`, each ready for one `jax.device_put`.  A record that is missing, short, or a
+        snapshot taken at another boundary refuses the hand-over whole:
+        KvFetchManyError names the records, and nothing is handed over
+        (the landing areas then hold no sequence)."""
+        paged, snapshot = layout.records(seq_id, pages.shape[0])
+        if (states is None) != (not snapshot):
+            raise ValueError("`states` goes with the layout's snapshot "
+                             "layers")
+        bufs = _cut(pages, paged)
+        if snapshot:
+            bufs += _cut(states, snapshot)
+        note = load_library().trpc_kv_note_sequence
+        try:
+            self._land_records(paged + snapshot, bufs)
+        except KvFetchManyError:
+            note(0, 0, 0, 0, 0)
+            raise
+        note(len(paged), sum(n for _, n in paged), len(snapshot),
+             sum(n for _, n in snapshot), 1)
+        return pages, states
+
+    def fetch_page(self, block_id: int, landing):
+        """Lands the page `block_id` that `publish_page` published,
+        layer `l`'s record in `landing[l]`: `fetch_sequence`'s round for
+        a layout of `landing.shape[0]` paged layers and this one page.
+        `landing` is a writable C-contiguous numpy array (a view of an
+        RmaBuffer for the one-sided path); it is returned, whole, ready
+        for one `jax.device_put`."""
+        layers = landing.shape[0]
+        layout = KvCacheLayout.paged(layers, landing.nbytes // layers)
+        records = layout.records(block_id, 1)[0]
+        self._land_records(records, _cut(landing, records))
         return landing
 
     # ---- content-addressed prefix cache (ISSUE 17) ----

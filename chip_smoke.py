@@ -6,8 +6,9 @@ and starts no child that needs it.  It drives the main path once through
 the entry points a user would call — a payload that starts in HBM is served
 by the RPC stack and ends in HBM, verified there — at the payload widths of
 the reference's suite, 1 KB to 64 MB, plus the device plane's own echo
-step, and on more than one chip the mesh plane.  It exits 0 only if every
-leg ran on the chip and verified; a missing accelerator, a mismatch or any
+step, one hand-over of a cache of two kinds through the KV plane at
+Kimi-Linear's record sizes, and on more than one chip the mesh plane.  It
+exits 0 only if every leg ran on the chip and verified; a missing accelerator, a mismatch or any
 exception is a non-zero exit and no result line.  It measures nothing that
 may be claimed: the times it prints are facts about the machine for the
 next reader, taken once.
@@ -41,6 +42,15 @@ FUSED_SIZES = (1 << 20, 1 << 24, 1 << 26)    # echo_fused: whole 512 KB blocks
 SERVED_SIZES = (1 << 10, 1 << 20, 1 << 26)
 PIPELINE_DEPTH = 8        # 512 MB in flight at 64 MB, the tensor64M mix's depth
 EXCHANGE_BYTES_PER_PEER = 64 << 20           # rdma_performance's width
+# A 1,024-token prompt's cache of Kimi-Linear-48B-A3B (the `kv_hybrid`
+# configuration's widths): 8 pages of 128 tokens x 576 of its 7 MLA
+# layers, and of its 20 KDA layers one state of 8,480 rows of 128 2-byte
+# words each (2,170,880 B: 32 x 128 x 128 float32 and the convolution's).
+HYBRID_LAYERS = 27
+HYBRID_FULL_ATTN = (4, 8, 12, 16, 20, 24, 27)
+HYBRID_PAGES = 8
+HYBRID_PAGE = (128, 576)
+HYBRID_STATE = (8480, 128)
 # The shm ring, each shm/ici connection's two 256 MB one-sided windows and
 # the 64 MB staging slab are shm_open+ftruncate files with no fallocate:
 # on a tmpfs too small to back a touched page that is a SIGBUS, not an
@@ -367,6 +377,103 @@ def leg_staged_path(size: int, seed: int = SEED, iters: int = 4) -> dict:
             "staging_land_ms": round(land_s * 1e3, 3), "legs": legs}
 
 
+def leg_kv_hybrid(pages: int, page: tuple, state: tuple,
+                  seed: int = SEED) -> dict:
+    """One hand-over of a cache of two kinds through the KV plane's
+    normal path: `pages` pages of the paged layers and one state of each
+    snapshot layer go from a prefill rank's pools in HBM to a decode
+    rank's, `publish_sequence` / `fetch_sequence` over the shm ring, and
+    both kinds are compared on the device.  The pools are a few slots:
+    the records are the published ones."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from brpc_tpu.models import kv_pool
+    from brpc_tpu.rpc import (Channel, RmaBuffer, Server, kv, observe,
+                              zerocopy)
+
+    paged = HYBRID_FULL_ATTN
+    layout = kv.KvCacheLayout(
+        tuple(kv.PAGED if layer in paged else kv.SNAPSHOT
+              for layer in range(1, HYBRID_LAYERS + 1)),
+        tuple(2 * (page[0] * page[1] if layer in paged
+                   else state[0] * state[1])
+              for layer in range(1, HYBRID_LAYERS + 1)))
+    snapshots = HYBRID_LAYERS - len(paged)
+    prefill = (kv_pool.seeded_pool(seed, 2 * pages, len(paged), *page),
+               kv_pool.seeded_pool(seed + 1, 2, snapshots, *state))
+    decode = (kv_pool.seeded_pool(seed + 2, 2 * pages, len(paged), *page),
+              kv_pool.seeded_pool(seed + 3, 2, snapshots, *state))
+    from_slots = jnp.arange(pages, dtype=jnp.int32) * 2 + 1
+    to_slots = jnp.arange(pages, dtype=jnp.int32) * 2
+    sent = (kv_pool.read_pages(prefill[0], from_slots),
+            kv_pool.read_page(prefill[1], 1))
+
+    def large_bytes():
+        dumped = observe.Vars.dump()
+        return [dumped.get(k, 0) for k in (
+            "rma_tx_bytes", "stripe_tx_bytes", "batch_resp_bytes",
+            "batch_land_copy_bytes")]
+
+    kv.reset()
+    srv = Server()
+    srv.enable_kv_store()
+    srv.enable_kv_registry()
+    srv.start(0)
+    addr = f"127.0.0.1:{srv.port}"
+    nbytes = layout.sequence_bytes(pages)
+    slab, land = RmaBuffer(nbytes), RmaBuffer(nbytes)
+    reg = kv.KvRegistryClient(Channel(addr, timeout_ms=30000),
+                              owns_channel=True)
+    cli = kv.KvClient(addr, use_shm=True, timeout_ms=30000)
+    try:
+        before = large_bytes()
+        t0 = time.perf_counter()
+        views = [zerocopy.host_view(x)[0] for x in sent]
+        staged = isinstance(views[0], zerocopy.PendingView)
+        metas = kv.publish_sequence(
+            1, layout, *(views if staged else sent), slab, lease_ms=600000,
+            node=addr, registry=reg)
+        area = np.frombuffer(land.view, dtype=np.uint16)
+        cut = sent[0].size
+        landed = cli.fetch_sequence(
+            1, layout, area[:cut].reshape(sent[0].shape),
+            area[cut:].reshape(sent[1].shape))
+        back = jax.device_put(landed)
+        decode = (kv_pool.write_pages(decode[0], to_slots, back[0]),
+                  kv_pool.write_page(decode[1], 0, back[1]))
+        jax.block_until_ready(decode)
+        handover_s = time.perf_counter() - t0
+        moved = [b - a for a, b in zip(before, large_bytes())]
+        _same_on_device(kv_pool.read_pages(decode[0], to_slots), sent[0],
+                        "hybrid hand-over, pages")
+        _same_on_device(kv_pool.read_page(decode[1], 0), sent[1],
+                        "hybrid hand-over, states")
+        kv.withdraw_sequence(1, layout, pages, registry=reg)
+        transport = cli.transports()[addr]
+        if transport != "shm_ring":
+            raise AssertionError(f"hybrid hand-over ran over {transport!r}")
+    finally:
+        cli.close()
+        reg.close()
+        srv.stop()
+        slab.free()
+        land.free()
+    return {
+        "transport": transport, "staged": staged,
+        "records": {"paged": pages * len(paged), "snapshot": snapshots,
+                    "published": len(metas)},
+        "record_bytes": {"paged": layout.record_bytes[paged[0] - 1],
+                         "snapshot": 2 * state[0] * state[1]},
+        "bytes": nbytes,
+        "kvh_one_sided_share": (100.0 * moved[0] / (moved[0] + moved[1])
+                                if moved[0] + moved[1] else None),
+        "kvh_land_copy_share": 100.0 * moved[3] / moved[2],
+        "handover_ms": round(handover_s * 1e3, 3),
+    }
+
+
 def leg_mesh_plane(interpret: bool, exchange_bytes_per_peer: int,
                    seed: int = SEED) -> dict:
     """Every sharded program over all local devices — the ring kernel
@@ -434,6 +541,8 @@ def main() -> int:
     served = report("served_path", leg_served_path(
         SERVED_SIZES, PIPELINE_DEPTH))
     staged = report("staged_path", leg_staged_path(SERVED_SIZES[-1]))
+    hybrid = report("kv_hybrid", leg_kv_hybrid(
+        HYBRID_PAGES, HYBRID_PAGE, HYBRID_STATE))
     mesh = report("mesh_plane", leg_mesh_plane(
         interpret=False, exchange_bytes_per_peer=EXCHANGE_BYTES_PER_PEER))
     report("summary", {
@@ -448,6 +557,9 @@ def main() -> int:
                               if c["channel"] == "shm"),
         "staged_ici_payload_covered":
             staged["legs"]["ici_ring"]["payload_covered"],
+        "kv_hybrid": {k: hybrid[k] for k in (
+            "transport", "bytes", "kvh_one_sided_share",
+            "kvh_land_copy_share")},
         "mesh": mesh["mesh"],
         "seconds": round(time.perf_counter() - t_start, 1),
         "claim": None,

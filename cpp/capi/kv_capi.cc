@@ -106,6 +106,15 @@ void trpc_kv_note_fetch_many(uint64_t records) {
   kv_note_fetch_many(records);
 }
 
+// Counts one KvClient.fetch_sequence in the kv_seq_* counters: what it
+// handed over of each kind, or that it refused the hand-over whole.
+void trpc_kv_note_sequence(uint64_t page_records, uint64_t page_bytes,
+                           uint64_t snapshot_records,
+                           uint64_t snapshot_bytes, int handed_over) {
+  kv_note_sequence(page_records, page_bytes, snapshot_records,
+                   snapshot_bytes, handed_over != 0);
+}
+
 // ---- content-addressed prefix cache (ISSUE 17) ---------------------------
 
 // 128-bit content hash of (block bytes, token-id span) — deterministic

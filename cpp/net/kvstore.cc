@@ -125,6 +125,12 @@ struct KvVars {
   Adder reg_many_records;
   Adder fetch_many_total;
   Adder fetch_many_records;
+  Adder seq_total;
+  Adder seq_page_records;
+  Adder seq_snapshot_records;
+  Adder seq_page_bytes;
+  Adder seq_snapshot_bytes;
+  Adder seq_refused;
   std::unique_ptr<PassiveStatus<long>> store_blocks;
   std::unique_ptr<PassiveStatus<long>> store_bytes;
   std::unique_ptr<PassiveStatus<long>> registry_blocks;
@@ -170,6 +176,25 @@ struct KvVars {
     fetch_many_records.expose(
         "kv_fetch_many_records",
         "records those multi-record fetches asked for");
+    seq_total.expose(
+        "kv_seq_total",
+        "sequence hand-overs (KvClient.fetch_sequence) this process took "
+        "in: every record of both kinds landed, the snapshots of the "
+        "pages' boundary");
+    seq_page_records.expose(
+        "kv_seq_page_records",
+        "paged-layer records those hand-overs landed");
+    seq_snapshot_records.expose(
+        "kv_seq_snapshot_records",
+        "snapshot-layer records those hand-overs landed");
+    seq_page_bytes.expose("kv_seq_page_bytes",
+                          "bytes of those paged-layer records");
+    seq_snapshot_bytes.expose("kv_seq_snapshot_bytes",
+                              "bytes of those snapshot-layer records");
+    seq_refused.expose(
+        "kv_seq_refused",
+        "sequence hand-overs refused whole: a record missing, short, or "
+        "a snapshot of another boundary");
     store_blocks = std::make_unique<PassiveStatus<long>>(
         [] { return static_cast<long>(kv_store().count()); });
     store_blocks->expose("kv_store_blocks",
@@ -320,6 +345,20 @@ void kv_ensure_registered() {
 void kv_note_fetch_many(uint64_t records) {
   kv_vars().fetch_many_total << 1;
   kv_vars().fetch_many_records << static_cast<int64_t>(records);
+}
+
+void kv_note_sequence(uint64_t page_records, uint64_t page_bytes,
+                      uint64_t snapshot_records, uint64_t snapshot_bytes,
+                      bool handed_over) {
+  if (!handed_over) {
+    kv_vars().seq_refused << 1;
+    return;
+  }
+  kv_vars().seq_total << 1;
+  kv_vars().seq_page_records << static_cast<int64_t>(page_records);
+  kv_vars().seq_page_bytes << static_cast<int64_t>(page_bytes);
+  kv_vars().seq_snapshot_records << static_cast<int64_t>(snapshot_records);
+  kv_vars().seq_snapshot_bytes << static_cast<int64_t>(snapshot_bytes);
 }
 
 KvPrefixCounters& kv_prefix_counters() {

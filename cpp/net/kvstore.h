@@ -494,4 +494,12 @@ void kv_ensure_registered();
 // of `records` records in kv_fetch_many_total / kv_fetch_many_records.
 void kv_note_fetch_many(uint64_t records);
 
+// Counts one client-side sequence hand-over (kv.py
+// KvClient.fetch_sequence): handed over, its records and bytes of each
+// kind in kv_seq_total / kv_seq_{page,snapshot}_{records,bytes};
+// refused whole, one in kv_seq_refused and nothing else.
+void kv_note_sequence(uint64_t page_records, uint64_t page_bytes,
+                      uint64_t snapshot_records, uint64_t snapshot_bytes,
+                      bool handed_over);
+
 }  // namespace trpc

@@ -257,7 +257,15 @@ void* region_create(size_t data_len, bool window, uint64_t* rkey_out) {
       g_next_ordinal.fetch_add(1, std::memory_order_relaxed);
   const std::string name = rma_shm_name(getpid(), ord);
   const size_t bytes = kRmaDataOffset + data_len;
-  const int fd = shm_open(name.c_str(), O_CREAT | O_EXCL | O_RDWR, 0600);
+  int fd = shm_open(name.c_str(), O_CREAT | O_EXCL | O_RDWR, 0600);
+  if (fd < 0 && errno == EEXIST) {
+    // The name holds this pid and an ordinal minted once in this process:
+    // an object already under it is the orphan of a dead process whose
+    // pid was recycled (killed before it could unlink).  Nobody maps it
+    // under this name any more; take the name.
+    shm_unlink(name.c_str());
+    fd = shm_open(name.c_str(), O_CREAT | O_EXCL | O_RDWR, 0600);
+  }
   if (fd < 0) {
     return nullptr;
   }

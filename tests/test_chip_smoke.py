@@ -44,6 +44,19 @@ def test_served_and_staged_legs_tiny():
     assert staged["legs"]["ici_ring"]["payload_covered"]
 
 
+def test_kv_hybrid_leg_at_the_published_snapshot_record():
+    """Two pages of 4 tokens, and the 20 snapshot records at their
+    published 2,170,880 B: over the stripe threshold, so one-sided."""
+    facts = chip_smoke.leg_kv_hybrid(2, (4, 576), chip_smoke.HYBRID_STATE)
+    assert facts["transport"] == "shm_ring"
+    assert facts["records"] == {"paged": 14, "snapshot": 20,
+                                "published": 34}
+    assert facts["record_bytes"] == {"paged": 4608, "snapshot": 2170880}
+    assert facts["bytes"] == 14 * 4608 + 20 * 2170880
+    assert facts["kvh_one_sided_share"] == 100.0
+    assert 0 < facts["kvh_land_copy_share"] <= 100.0
+
+
 def test_mesh_leg_on_the_virtual_mesh_interpreted():
     facts = chip_smoke.leg_mesh_plane(
         interpret=True, exchange_bytes_per_peer=8 * 8 * 128 * 4)
@@ -65,6 +78,9 @@ def test_result_line_has_the_contract_keys_and_no_others(monkeypatch, capsys):
         "leg_served_path": {"calls": [{"channel": "shm",
                                        "transport": "shm_ring"}]},
         "leg_staged_path": {"legs": {"ici_ring": {"payload_covered": True}}},
+        "leg_kv_hybrid": {"transport": "shm_ring", "bytes": 1,
+                          "kvh_one_sided_share": 100.0,
+                          "kvh_land_copy_share": 16.0},
         "leg_mesh_plane": {"mesh": "not_run", "devices": 1},
     }
     for name, facts in legs.items():

@@ -71,6 +71,32 @@ def test_rma_buffer_lifecycle():
         _ = buf.view
 
 
+def test_an_orphan_under_this_pids_name_does_not_refuse_the_buffer():
+    """A process killed before it could unlink leaves `trpc_rma_<pid>_<n>`
+    in /dev/shm, and pids are recycled: the name a new region asks for
+    may be there already.  It is an orphan by construction (the ordinal
+    is minted once in this process), and the region takes the name."""
+    orphans = [f"/dev/shm/trpc_rma_{os.getpid()}_{n}" for n in range(4096)]
+    made = []
+    for path in orphans:
+        try:
+            with open(path, "xb") as f:
+                f.write(b"left by a dead process")
+            made.append(path)
+        except FileExistsError:      # a live region of this process
+            pass
+    try:
+        with RmaBuffer(1 << 16) as buf:
+            view = np.frombuffer(buf.view, np.uint8)
+            assert not view.any()        # fresh pages, not the orphan's
+            view[:] = 7
+            assert len(buf) == 1 << 16
+    finally:
+        for path in made:
+            if os.path.exists(path):
+                os.unlink(path)
+
+
 def test_batch_resp_buf_remote_landing_shm(server):
     """The mirror of the C++ direct-landing case: a 16MB response is PUT
     by the server straight into the caller's registered buffer."""
