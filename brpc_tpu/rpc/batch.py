@@ -26,10 +26,12 @@ Ownership rules (the zero-copy contract):
 
 Staged requests: a request may be a `zerocopy.PendingView` — a device
 array whose transfer to the host `zerocopy.host_view` has started and
-nobody has waited for.  `submit` still returns its tokens at once; the
-calls go to the pipeline's STAGER, one thread per Batch, which waits for
-the bytes in submit order and hands the calls of a submit, once all their
-bytes have landed, to the native submit in one crossing.  Everything
+whose bytes no caller has taken.  `submit` still returns its tokens at
+once; the calls go to the pipeline's STAGER, one thread per Batch, which
+waits for the bytes in submit order (`submit` tells each view so:
+`waited_for`, and `zerocopy`'s own waiter then leaves it to the stager)
+and hands the calls of a submit, once all their bytes have landed, to the
+native submit in one crossing.  Everything
 submitted while calls are with the stager queues behind them, so submit
 order stays wire order.  A submit with nothing pending and nothing queued
 ahead of it never sees the stager.
@@ -42,7 +44,6 @@ import ctypes
 import errno
 import itertools
 import threading
-import time
 
 import numpy as np
 
@@ -103,6 +104,7 @@ class _StagedCall:
         self.status = 0
         self.err = b""
         if isinstance(request, _zc.PendingView) and not request.landed:
+            request.waited_for()    # by the stager, in fetch()
             self.staged_us = request.started_us
             self.flat = None
         else:
@@ -128,9 +130,7 @@ class _StagedCall:
         self.flat = flat
 
 
-def _now_us() -> int:
-    # CLOCK_MONOTONIC: the clock of the native runtime's phase stamps.
-    return time.monotonic_ns() // 1000
+_now_us = _zc.now_us
 
 
 class ZeroCopyResponse:

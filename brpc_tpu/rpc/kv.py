@@ -361,7 +361,8 @@ class KvCacheLayout:
 
 def _host_flat(array) -> np.ndarray:
     """The bytes of a device array, or of the view `zerocopy.host_view`
-    made of one when its transfer was started ahead, waited for."""
+    made of one when its transfer was started ahead: there already if
+    `zerocopy`'s waiter has seen it through meanwhile, else waited for."""
     return (array.resolve() if isinstance(array, zerocopy.PendingView)
             else zerocopy.host_bytes(array)[0])
 
@@ -436,11 +437,14 @@ def publish_sequence(seq_id: int, layout: KvCacheLayout, pages, states,
     an array's bytes are its records end to end, in that order, and
     only `pages.shape[0]` is read of its shape).  Both are device arrays
     or numpy arrays, or the views `zerocopy.host_view` made of them when
-    their transfers were started ahead: the bytes come to the host
-    through `zerocopy.host_view`, so into recycled landing blocks, and
-    are copied into `slab` (an RmaBuffer) from `offset` on,
-    `layout.sequence_bytes` of them, each record `publish`ed from its
-    place.  With a `registry` the records of
+    their transfers were started ahead (a view of 1 MB or more is waited
+    for by `zerocopy`'s own thread from the moment it is made, and a
+    16-bit array crosses as flat words, so a caller that comes back a
+    cycle later finds the bytes there): the
+    bytes come to the host through `zerocopy.host_view`, so into
+    recycled landing blocks, and are copied into `slab` (an RmaBuffer)
+    from `offset` on, `layout.sequence_bytes` of them, each record
+    `publish`ed from its place.  With a `registry` the records of
     both kinds are registered in one `register_many`; a record it
     refuses raises its error.  The slab's bytes belong to the store
     until the sequence is withdrawn (`withdraw_sequence`)."""

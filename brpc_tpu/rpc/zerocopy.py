@@ -21,9 +21,37 @@ buffer itself:
   enters the IOBuf by reference.  One copy total, where landing in a
   temporary and copying into a slab would take two.  That DMA is
   only STARTED by `host_view` (`copy_to_host_async`): it returns a
-  `PendingView`, and whoever needs the bytes first waits for them — the
-  batch pipeline's stager thread (batch.py), so the caller's thread is
-  free for the responses while its requests are on their way.
+  `PendingView`, and the caller's thread is free for the responses while
+  its requests are on their way.
+
+In what form it crosses: an array of 16-bit elements and two or more
+dimensions lies tiled in HBM, and the runtime undoes the tiling on the
+host, slowly: 43 MB of (20, 8480, 128) uint16 took 55 ms to land on the
+v5e host (0.78 GB/s) whoever waited for it, where the same bytes as 32-bit
+words take 5-9 ms (PERF.md, PR 32).  `_crossing_form` has the device lay
+such an array out as one flat run of 32-bit words first (0.2 ms of the
+caller's thread for the launch, under 1 ms of the device), the same bytes
+in the same order, and that is what is transferred; a view hands its
+bytes out flat anyway.
+
+Who waits for a started transfer: somebody, from the moment it starts.
+In `kv_disagg`'s loop a 9 MB page whose transfer was started three blocks
+(52 ms) ahead still cost its caller 4.9 ms when it came back for it,
+every block, although that transfer lands in 2.8 ms when it is waited for
+at once (and, in a process that does nothing else, with nobody waiting at
+all: why it stalls beside a client thread's other transfers is not
+known; PERF.md, PR 32).  So a view is handed, when it is made, to the
+module's one waiter thread (`_ViewWaiter`), which waits for the views in
+the order they were started, with the same `np.asarray` under the view's
+own lock that `resolve()` takes: a later `resolve()` finds the bytes
+there, or returns the moment the waiter's wait ends.  Two things the
+waiter can observe make it leave a view alone: a view under the landing
+pool's size line (`trpc_host_pool_min_bytes`, 1 MB: below it a transfer
+costs its latency, not its bytes, and a thread's wake costs more than it
+saves) is never queued, and a view whose `resolve()` somebody has already
+entered, or is about to (`waited_for`: the batch pipeline's stager), is
+skipped.  The waiter's queue holds a view weakly and the thread holds it
+only while it waits, and it ends itself when idle.
 
 Where that DMA lands: jaxlib allocates the destination as a numpy array,
 so through numpy's current data-memory handler (NEP 49), and glibc would
@@ -44,9 +72,12 @@ depth their caller keeps open, not here.
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import threading
 import time
+import typing
+import weakref
 
 import numpy as np
 
@@ -108,10 +139,20 @@ def _flat_u8(host: np.ndarray) -> np.ndarray:
 
 
 _MEM_HANDLER = b"mem_handler"  # the capsule's name, which numpy checks
-# (numpy's PyDataMem_SetHandler, the recycling handler's capsule): made by
-# the first transfer, so a process that stages no device array loads
-# nothing.
-_landing = None
+
+
+class _Landing(typing.NamedTuple):
+    """What a transfer needs of numpy and of cpp/capi/hostpool_capi.cc:
+    made by the first transfer, so a process that stages no device array
+    loads nothing."""
+
+    set_handler: typing.Callable    # numpy's PyDataMem_SetHandler
+    capsule: object                 # the recycling handler's
+    min_waited_bytes: int           # the pool's size line (1 MB)
+    note_view: typing.Callable      # trpc_host_view_note
+
+
+_landing: _Landing | None = None
 
 
 def _landing_handler():
@@ -138,7 +179,15 @@ def _landing_handler():
             lib.trpc_host_pool_numpy_handler.restype = ctypes.c_void_p
             capsule = api.PyCapsule_New(
                 lib.trpc_host_pool_numpy_handler(), _MEM_HANDLER, None)
-            _landing = (set_handler, capsule)
+            lib.trpc_host_pool_min_bytes.restype = ctypes.c_size_t
+            lib.trpc_host_pool_min_bytes.argtypes = []
+            lib.trpc_host_view_note.restype = None
+            lib.trpc_host_view_note.argtypes = [
+                ctypes.c_uint64, ctypes.c_int, ctypes.c_int64,
+                ctypes.c_int64]
+            _landing = _Landing(set_handler, capsule,
+                                lib.trpc_host_pool_min_bytes(),
+                                lib.trpc_host_view_note)
         return _landing
 
 
@@ -147,47 +196,178 @@ def _start_transfer(array) -> None:
     the recycling handler (the module's docstring).  numpy's current
     handler is the thread's own (a context variable), so it is set here,
     on the thread that makes the call, and put back."""
-    set_handler, capsule = _landing or _landing_handler()
-    previous = set_handler(capsule)
+    landing = _landing or _landing_handler()
+    previous = landing.set_handler(landing.capsule)
     try:
         array.copy_to_host_async()
     finally:
-        set_handler(previous)
+        landing.set_handler(previous)
+
+
+_as_words = None    # the jitted relayout, made by the first array it serves
+
+
+def _crossing_form(array):
+    """The array as it crosses to the host: itself, or where its own form
+    crosses slowly (the module's docstring: 16-bit elements, two or more
+    dimensions), its bytes as one flat run of 32-bit words, made on the
+    device.  Below the landing pool's size line the launch costs more than
+    the crossing; an odd minor dimension has no whole words."""
+    global _as_words
+    dtype = getattr(array, "dtype", None)
+    if (dtype is None or dtype.itemsize != 2 or array.ndim < 2
+            or array.shape[-1] % 2
+            or array.nbytes < _landing.min_waited_bytes
+            or not hasattr(array, "sharding")):
+        return array
+    if _as_words is None:
+        import jax
+
+        def view_words(x):
+            # Neighbours along the minor dimension share a word, the
+            # first in its low half: the array's own bytes in row-major
+            # order on a little-endian host.  (A reshape to (n, 2) and a
+            # bitcast says the same and pads the pairs to whole tiles.)
+            halves = jax.lax.bitcast_convert_type(x, "uint16")
+            low = halves[..., 0::2].astype("uint32")
+            high = halves[..., 1::2].astype("uint32")
+            return (low | (high << 16)).reshape(-1)
+
+        _as_words = jax.jit(view_words)
+    return _as_words(array)
+
+
+def now_us() -> int:
+    """CLOCK_MONOTONIC in microseconds: the clock of the native runtime's
+    phase stamps, and of `PendingView.started_us`."""
+    return time.monotonic_ns() // 1000
+
+
+# An idle waiter ends itself after this long, as the batch stager does.
+_WAITER_IDLE_S = 1.0
+
+
+class _ViewWaiter:
+    """The one thread that sees started transfers through (the module's
+    docstring): views in the order they were started.  Its queue holds
+    them weakly and the thread holds one only while it waits for it, so a
+    landing block goes back to the recycled list the moment its caller
+    lets go of the view and the array, as without a waiter."""
+
+    def __init__(self):
+        self._wake = threading.Condition()
+        self._views: collections.deque = collections.deque()
+        self._thread: threading.Thread | None = None
+
+    def watch(self, view: "PendingView") -> None:
+        with self._wake:
+            self._views.append(weakref.ref(view))
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._main, name="trpc-view-waiter", daemon=True)
+                self._thread.start()
+            self._wake.notify()
+
+    def _main(self) -> None:
+        while True:
+            with self._wake:
+                while not self._views:
+                    if (not self._wake.wait(timeout=_WAITER_IDLE_S)
+                            and not self._views):
+                        # Idle: end; the next view starts another.
+                        self._thread = None
+                        return
+                view = self._views.popleft()()
+            if view is not None:
+                view._see_through()
+                del view
+
+
+_waiter = _ViewWaiter()
 
 
 class PendingView:
-    """The bytes of an array whose transfer to the host has been asked for
-    and not yet waited for.  The transfer starts when the view is made;
-    `nbytes` is known at once; `resolve()` blocks its first caller until
-    the bytes have landed (the wait releases the GIL) and returns the flat
-    uint8 view of the array's own cached host copy, the same one to every
-    caller; `shape` is the array's.  `started_us` is the monotonic clock
-    (the native runtime's) at which the view was made.  How many transfers
-    are on their way at once is up to the caller: each holds one landing
-    block of its size until the view and the array are dropped."""
+    """The bytes of an array whose transfer to the host has been asked for.
+    The transfer starts when the view is made (of the array's crossing
+    form, `_crossing_form`), and from then on somebody waits for it: the
+    module's waiter thread, unless the view is under the landing pool's
+    size line (1 MB) or its `resolve()` was entered first (the module's
+    docstring says why: a transfer started ahead and left alone was not
+    there when its caller came back).  `nbytes` is known at once;
+    `resolve()` blocks until the bytes have landed (the wait releases the
+    GIL), which is not at all once the waiter's wait has ended, and
+    returns the flat uint8 view of the host copy, the same one to every
+    caller; `shape` is the array's.  A transfer that fails raises from
+    `resolve()` on its caller's thread.  `started_us` is the monotonic
+    clock (the native runtime's) at which the view was made.  How many
+    transfers are on their way at once is up to the caller: each holds one
+    landing block of its size until the view and the array are dropped."""
 
     def __init__(self, array):
         self.nbytes = int(array.nbytes)
         self._array = array
         self._flat = None
         self._lock = threading.Lock()
-        self.started_us = time.monotonic_ns() // 1000
-        _start_transfer(array)
+        self._entered = False   # a resolve() other than the waiter's began
+        self._noted = False     # ... and the first has been counted
+        self._landed_us = 0
+        self.started_us = now_us()
+        landing = _landing or _landing_handler()
+        self._crossing = _crossing_form(array)
+        _start_transfer(self._crossing)
+        if self.nbytes >= landing.min_waited_bytes:
+            _waiter.watch(self)
 
     @property
     def landed(self) -> bool:
-        """The bytes are here: `resolve()` will not block."""
-        return self._flat is not None
+        """A `resolve()` of the caller's has returned the bytes: the next
+        will not block.  Not what the waiter has seen through: a view is
+        handed to a pipeline the same way whether or not the waiter was
+        first (batch.py stages what has not `landed`)."""
+        return self._noted
 
     @property
     def shape(self) -> tuple:
         return tuple(self._array.shape)
 
+    def waited_for(self) -> None:
+        """The caller has a thread of its own that is about to block in
+        `resolve()` (the batch pipeline's stager): as good as entered, so
+        the module's waiter leaves the view to it."""
+        self._entered = True
+
+    def _wait(self) -> np.ndarray:
+        # self._lock held.
+        if self._flat is None:
+            self._flat = _flat_u8(np.asarray(self._crossing))
+            self._landed_us = now_us()
+        return self._flat
+
+    def _see_through(self) -> None:
+        """The waiter's part: the wait itself, unless somebody else has
+        begun it.  What the fetch raises is its caller's to see."""
+        if self._entered or not self._lock.acquire(blocking=False):
+            return
+        try:
+            self._wait()
+        except Exception:  # noqa: BLE001 - raised again by resolve()
+            pass
+        finally:
+            self._lock.release()
+
     def resolve(self) -> np.ndarray:
-        with self._lock:
-            if self._flat is None:
-                self._flat = _flat_u8(np.asarray(self._array))
+        if self._noted:
             return self._flat
+        self._entered = True
+        ahead = self._flat is not None
+        t0 = now_us()
+        with self._lock:
+            flat = self._wait()
+            first, self._noted = not self._noted, True
+        if first:
+            _landing.note_view(self.nbytes, ahead, now_us() - t0,
+                               self._landed_us - self.started_us)
+        return flat
 
     def __array__(self, dtype=None, copy=None):
         return self.resolve()
