@@ -792,16 +792,29 @@ def load_library() -> ctypes.CDLL:
             ]
             lib.trpc_call_stream_accept.restype = ctypes.c_void_p
             lib.trpc_stream_read.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_size_t,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
                 ctypes.c_int64,
             ]
             lib.trpc_stream_read.restype = ctypes.c_long
-            lib.trpc_stream_next_len.argtypes = [ctypes.c_void_p]
+            lib.trpc_stream_next_len.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64,
+            ]
             lib.trpc_stream_next_len.restype = ctypes.c_long
             lib.trpc_stream_write.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_size_t,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
             ]
             lib.trpc_stream_write.restype = ctypes.c_int
+            lib.trpc_stream_write_user.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
+                ctypes.c_void_p, ctypes.c_void_p,
+            ]
+            lib.trpc_stream_write_user.restype = ctypes.c_int
+            lib.trpc_stream_unread_high_water.argtypes = [ctypes.c_void_p]
+            lib.trpc_stream_unread_high_water.restype = ctypes.c_uint64
+            lib.trpc_server_register_stream_echo.argtypes = [
+                ctypes.c_void_p, ctypes.c_char_p,
+            ]
+            lib.trpc_server_register_stream_echo.restype = ctypes.c_int
             lib.trpc_stream_close.argtypes = [ctypes.c_void_p]
             lib.trpc_stream_close.restype = ctypes.c_int
             lib.trpc_stream_destroy.argtypes = [ctypes.c_void_p]

@@ -119,6 +119,22 @@ class Server:
             raise RuntimeError(
                 f"register_native_echo {method!r} failed (server running?)")
 
+    def register_native_stream_echo(self, method: str = "Echo.Stream") -> None:
+        """Registers a NATIVE stream echo for `method`: the handler
+        accepts the stream a request offers (`stream.open_stream`),
+        granting the window it was granted, and writes every chunk that
+        arrives back on the same stream by moving its IOBuf: no copy, no
+        Python callback, no GIL.  Its write parks on the client's window
+        inside the stream's consume fiber, so a client that stops reading
+        stops the echo and then its own writes: back-pressure runs end to
+        end.  The server-side anchor of a streamed data-plane benchmark,
+        as `register_native_echo` is of a unary one."""
+        if self._lib.trpc_server_register_stream_echo(
+                self._ptr, method.encode()) != 0:
+            raise RuntimeError(
+                f"register_native_stream_echo {method!r} failed "
+                "(server running?)")
+
     def enable_kv_store(self) -> None:
         """Attaches the NATIVE KV block-store fetch handler (Kv.Fetch,
         cpp/net/kvstore.h): blocks published from this process (kv.publish)

@@ -204,6 +204,20 @@ def _start_transfer(array) -> None:
         landing.set_handler(previous)
 
 
+def landing_block(nbytes: int) -> np.ndarray:
+    """An uninitialised uint8 array of `nbytes` allocated with the
+    recycling handler current, as a transfer's destination is: from 1 MB
+    on its pages come from the recycled list and go back to it when the
+    array dies.  For bytes that arrive from the wire on their way to the
+    device (`stream.Stream.read_block`)."""
+    landing = _landing or _landing_handler()
+    previous = landing.set_handler(landing.capsule)
+    try:
+        return np.empty(nbytes, dtype=np.uint8)
+    finally:
+        landing.set_handler(previous)
+
+
 _as_words = None    # the jitted relayout, made by the first array it serves
 
 

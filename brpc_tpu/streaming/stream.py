@@ -8,6 +8,13 @@ moves one chunk per step with ``ppermute``; ordering is the scan order and
 "completion" is dataflow — XLA double-buffers the transfer of chunk k+1
 against the consumer compute of chunk k, the overlap brpc's credit machinery
 exists to enable.
+
+This is the MESH plane's stream: chips of one XLA program, no connection,
+no window object, no C++ under it.  The SERVED path's stream, chunks over
+a `Channel`'s connection between two processes' runtimes with a credit
+window that holds up to the reading application, is
+`brpc_tpu/rpc/stream.py` (the benchmark's `stream_echo` cell and the
+inference front door ride that one).
 """
 
 from __future__ import annotations

@@ -9,6 +9,22 @@
 // heap shared_ptr holder — the stream's callbacks keep their own
 // reference, so a destroy racing a late consume batch can never free the
 // queue under the consumer.
+//
+// The window holds at this boundary: the queue keeps each chunk as the
+// IOBuf it arrived in, and a chunk's bytes go back to the writer when
+// trpc_stream_read has copied them out (StreamConsumed), not when the
+// consume fiber queued them.  Deferring the ACK to the read was chosen
+// over holding the consume fiber until the queue has room: a held fiber
+// is a parked stack per stream with unread chunks, and the inference
+// front door reads 100k token streams through this same handle; the
+// deferred ACK costs one atomic add a read and is sent from the reader's
+// thread.  So a reader that stops reading stops its writer after window
+// + one chunk, and that is also the most this queue ever holds.
+//
+// What crosses the boundary by copy is counted: one copy out of the frame
+// on a read (`stream_capi_read_copy_bytes`), and on a write one copy in
+// for trpc_stream_write (`stream_capi_write_copy_bytes`) and none for
+// trpc_stream_write_user, which wraps the caller's memory.
 #include <chrono>
 #include <condition_variable>
 #include <cstring>
@@ -22,7 +38,9 @@
 #include "fiber/fiber.h"
 #include "net/channel.h"
 #include "net/controller.h"
+#include "net/server.h"
 #include "net/stream.h"
+#include "stat/reducer.h"
 
 using namespace trpc;
 
@@ -36,9 +54,25 @@ struct CStream {
   StreamId sid = 0;
   std::mutex mu;
   std::condition_variable cv;
-  std::deque<std::string> chunks;
+  std::deque<IOBuf> chunks;
   bool closed = false;
 };
+
+struct StreamCapiVars {
+  Adder read_copy_bytes;
+  Adder write_copy_bytes;
+  StreamCapiVars() {
+    read_copy_bytes.expose("stream_capi_read_copy_bytes",
+                           "bytes trpc_stream_read copied out of a "
+                           "chunk's frame into the caller's buffer");
+    write_copy_bytes.expose("stream_capi_write_copy_bytes",
+                            "bytes trpc_stream_write copied into a chunk "
+                            "(trpc_stream_write_user copies none)");
+  }
+};
+
+// Leaked with the registry, as the other capi counters are.
+StreamCapiVars& g_vars = *new StreamCapiVars();
 
 using CStreamPtr = std::shared_ptr<CStream>;
 
@@ -50,11 +84,11 @@ StreamOptions options_for(const CStreamPtr& cs, int64_t window_bytes) {
   if (window_bytes > 0) {
     opts.window_bytes = window_bytes;
   }
+  opts.credit_on_consumed = true;  // given back by trpc_stream_read
   opts.on_message = [cs](StreamId, IOBuf&& chunk) {
-    std::string bytes = chunk.to_string();
     {
       std::lock_guard<std::mutex> g(cs->mu);
-      cs->chunks.push_back(std::move(bytes));
+      cs->chunks.push_back(std::move(chunk));
     }
     cs->cv.notify_all();
   };
@@ -66,6 +100,19 @@ StreamOptions options_for(const CStreamPtr& cs, int64_t window_bytes) {
     cs->cv.notify_all();
   };
   return opts;
+}
+
+// Waits for the next chunk: cs->mu held through `g`.  True when one is
+// queued or the stream closed, false on timeout (timeout_ms < 0 waits
+// forever, 0 does not wait).
+bool wait_next(const CStreamPtr& cs, std::unique_lock<std::mutex>& g,
+               int64_t timeout_ms) {
+  auto ready = [&cs] { return !cs->chunks.empty() || cs->closed; };
+  if (timeout_ms < 0) {
+    cs->cv.wait(g, ready);
+    return true;
+  }
+  return cs->cv.wait_for(g, std::chrono::milliseconds(timeout_ms), ready);
 }
 
 }  // namespace
@@ -138,22 +185,19 @@ void* trpc_call_stream_accept(void* call_handle, int64_t window_bytes) {
   return new CStreamPtr(std::move(cs));
 }
 
-// Blocking read of ONE chunk: returns the chunk's length (always <=
-// `cap` — the chunk is copied whole or not at all), -1 when the stream
-// is closed and drained, -2 on timeout (timeout_ms < 0 waits forever),
-// -3 when the next chunk is LARGER than `cap`.  A -3 chunk stays queued
-// and nothing is consumed: query trpc_stream_next_len and retry with a
-// buffer that fits — silent truncation would desynchronize framed
-// readers (e.g. fixed-size TokenRecord streams) without any error.
+// Blocking read of ONE chunk, copied once, out of the frame it arrived in
+// into `buf`: returns the chunk's length (always <= `cap` — the chunk is
+// copied whole or not at all), -1 when the stream is closed and drained,
+// -2 on timeout (timeout_ms < 0 waits forever), -3 when the next chunk is
+// LARGER than `cap`.  A -3 chunk stays queued and nothing is consumed:
+// query trpc_stream_next_len and retry with a buffer that fits — silent
+// truncation would desynchronize framed readers (e.g. fixed-size
+// TokenRecord streams) without any error.  The chunk's bytes go back to
+// the writer's window here, once they are out.
 long trpc_stream_read(void* h, char* buf, size_t cap, int64_t timeout_ms) {
   const CStreamPtr& cs = of(h);
   std::unique_lock<std::mutex> g(cs->mu);
-  const bool wait_forever = timeout_ms < 0;
-  auto ready = [&cs] { return !cs->chunks.empty() || cs->closed; };
-  if (wait_forever) {
-    cs->cv.wait(g, ready);
-  } else if (!cs->cv.wait_for(g, std::chrono::milliseconds(timeout_ms),
-                              ready)) {
+  if (!wait_next(cs, g, timeout_ms)) {
     return -2;
   }
   if (cs->chunks.empty()) {
@@ -162,33 +206,58 @@ long trpc_stream_read(void* h, char* buf, size_t cap, int64_t timeout_ms) {
   if (cs->chunks.front().size() > cap) {
     return -3;  // caller's buffer too small; chunk left queued
   }
-  std::string chunk = std::move(cs->chunks.front());
+  IOBuf chunk = std::move(cs->chunks.front());
   cs->chunks.pop_front();
   g.unlock();
-  if (buf != nullptr && !chunk.empty()) {
-    memcpy(buf, chunk.data(), chunk.size());
+  const size_t n = chunk.size();
+  if (buf != nullptr && n > 0) {
+    chunk.copy_to(buf, n);
+    g_vars.read_copy_bytes << static_cast<int64_t>(n);
   }
-  return static_cast<long>(chunk.size());
+  chunk.clear();
+  StreamConsumed(cs->sid, n);  // EINVAL once closed: nobody to tell
+  return static_cast<long>(n);
 }
 
-// Length of the next buffered chunk (bytes), -1 when none is buffered.
-// Pairs with a -3 read: resize and retry without losing the chunk.
-long trpc_stream_next_len(void* h) {
+// Length of the next chunk (bytes), waiting up to timeout_ms for one
+// (< 0 forever, 0 not at all): -1 when the stream is closed and drained,
+// -2 when none came in time.  Consumes nothing.  Pairs with a -3 read
+// (resize and retry without losing the chunk) and sizes a reader's
+// buffer before the chunk is copied.
+long trpc_stream_next_len(void* h, int64_t timeout_ms) {
   const CStreamPtr& cs = of(h);
-  std::lock_guard<std::mutex> g(cs->mu);
+  std::unique_lock<std::mutex> g(cs->mu);
+  if (!wait_next(cs, g, timeout_ms)) {
+    return -2;
+  }
   return cs->chunks.empty() ? -1
                             : static_cast<long>(cs->chunks.front().size());
 }
 
-// Ordered write; parks while the peer's credit window is exhausted.
-// Returns 0, EPIPE (closed / connection dead), EINVAL (gone).
+// Ordered write of a copy of `data`; parks while the peer's credit window
+// is exhausted.  Returns 0, EPIPE (closed / connection dead), EINVAL
+// (gone).
 int trpc_stream_write(void* h, const char* data, size_t len) {
   ScopedPthreadWait pin;  // StreamWrite parks on the credit window
   const CStreamPtr& cs = of(h);
   IOBuf chunk;
   if (data != nullptr && len > 0) {
     chunk.append(data, len);
+    g_vars.write_copy_bytes << static_cast<int64_t>(len);
   }
+  return StreamWrite(cs->sid, std::move(chunk));
+}
+
+// The same with the caller's memory wrapped, not copied: `deleter(data,
+// ctx)` runs once, on whatever thread drops the frame's last reference
+// (after the transport has written it, or at once when the write fails);
+// until then `data` must stay as it is.
+int trpc_stream_write_user(void* h, void* data, size_t len,
+                           void (*deleter)(void*, void*), void* ctx) {
+  ScopedPthreadWait pin;
+  const CStreamPtr& cs = of(h);
+  IOBuf chunk;
+  chunk.append_user_data(data, len, deleter, ctx);
   return StreamWrite(cs->sid, std::move(chunk));
 }
 
@@ -225,6 +294,41 @@ size_t trpc_stream_pending(void* h) {
   const CStreamPtr& cs = of(h);
   std::lock_guard<std::mutex> g(cs->mu);
   return cs->chunks.size();
+}
+
+// The most bytes this end has held received and unread (0 once the
+// stream is gone): under its window plus one chunk, by the credit gate.
+unsigned long long trpc_stream_unread_high_water(void* h) {
+  return static_cast<unsigned long long>(
+      stream_unread_high_water(of(h)->sid));
+}
+
+// Registers a NATIVE stream echo on `method`: the handler accepts the
+// stream the request offers, granting the window it was granted, and each
+// chunk that arrives is written back on the same stream by moving its
+// IOBuf: no copy, no Python callback, no GIL.  The write parks on the
+// client's window inside the consume fiber, so the chunks behind it wait
+// in the stream's queue, their bytes are not given back, and the client's
+// writes park in turn: back-pressure runs end to end.  A request that
+// offers no stream fails with EINVAL.
+int trpc_server_register_stream_echo(void* srv, const char* method) {
+  return static_cast<Server*>(srv)->RegisterMethod(
+      method, [](Controller* cntl, const IOBuf&, IOBuf*, Closure done) {
+        StreamOptions opts;
+        if (cntl->call().peer_stream_window > 0) {
+          opts.window_bytes =
+              static_cast<int64_t>(cntl->call().peer_stream_window);
+        }
+        opts.on_message = [](StreamId sid, IOBuf&& chunk) {
+          StreamWrite(sid, std::move(chunk));  // EPIPE: on_closed follows
+        };
+        opts.on_closed = [](StreamId sid) { StreamClose(sid); };
+        StreamId sid = 0;
+        if (StreamAccept(&sid, cntl, opts) != 0) {
+          cntl->SetFailed(EINVAL, "the request offered no stream");
+        }
+        done();
+      });
 }
 
 }  // extern "C"

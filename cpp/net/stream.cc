@@ -13,10 +13,50 @@
 #include "fiber/fiber.h"
 #include "net/protocol.h"
 #include "net/socket.h"
+#include "stat/reducer.h"
 
 namespace trpc {
 
 namespace {
+
+// Always-on counters of the plane, over every stream of the process (both
+// ends of a stream whose ends share it).  A chunk is "written" when
+// StreamWrite handed its frame to the socket and "consumed" when its
+// bytes were given back to the writer's window.
+struct StreamVars {
+  Adder chunks_written;
+  Adder bytes_written;
+  Adder chunks_consumed;
+  Adder bytes_consumed;
+  Adder credit_wait_us;
+  Adder acks_sent;
+  Maxer unread_high_water;
+  StreamVars() {
+    chunks_written.expose("stream_chunks_written",
+                          "stream chunks handed to the socket by "
+                          "StreamWrite");
+    bytes_written.expose("stream_bytes_written",
+                         "payload bytes of stream_chunks_written");
+    chunks_consumed.expose("stream_chunks_consumed",
+                           "stream chunks whose bytes the consumer gave "
+                           "back to the writer's window");
+    bytes_consumed.expose("stream_bytes_consumed",
+                          "payload bytes of stream_chunks_consumed");
+    credit_wait_us.expose("stream_credit_wait_us",
+                          "time StreamWrite spent parked on an exhausted "
+                          "window");
+    acks_sent.expose("stream_acks_sent",
+                     "ACK (feedback) frames sent to writers");
+    unread_high_water.expose("stream_unread_high_water_bytes",
+                             "most bytes any one stream has held received "
+                             "and not yet given back (bound: its window "
+                             "plus one chunk)");
+    unread_high_water << 0;  // a Maxer nobody fed reads INT64_MIN
+  }
+};
+
+// Leaked with the registry, as the other always-on counters are.
+StreamVars& g_vars = *new StreamVars();
 
 struct StreamMeta {
   std::atomic<uint32_t> version{0};  // even = idle slot
@@ -42,6 +82,10 @@ struct StreamMeta {
 
   // Receiver: consumed-but-unacked bytes; ACK when above half window.
   std::atomic<int64_t> unacked{0};
+  // Receiver: bytes that arrived and were not yet given back, and the
+  // most that ever was.
+  std::atomic<int64_t> unread{0};
+  std::atomic<int64_t> unread_high_water{0};
 
   std::atomic<bool> closed{false};
   Event close_ev;  // value flips 0→1 on close
@@ -123,11 +167,17 @@ void maybe_send_ack(StreamMeta* m) {
   if (peer == 0) {
     return;
   }
-  const int64_t unacked = m->unacked.load(std::memory_order_acquire);
-  if (unacked < m->opts.window_bytes / 2) {
+  if (m->unacked.load(std::memory_order_acquire) <
+      m->opts.window_bytes / 2) {
     return;
   }
-  m->unacked.fetch_sub(unacked, std::memory_order_acq_rel);
+  // One taker: the consume fiber and a reader's StreamConsumed may both
+  // be here.
+  const int64_t unacked = m->unacked.exchange(0, std::memory_order_acq_rel);
+  if (unacked <= 0) {
+    return;
+  }
+  g_vars.acks_sent << 1;
   RpcMeta ack;
   ack.type = RpcMeta::kStreamFrame;
   ack.stream_flags = RpcMeta::kStreamAck;
@@ -139,6 +189,16 @@ void maybe_send_ack(StreamMeta* m) {
   if (s) {
     s->Write(std::move(frame));
   }
+}
+
+// The consumer has used `bytes` of one chunk: they leave the unread count
+// and go back to the writer.
+void give_back(StreamMeta* m, int64_t bytes) {
+  g_vars.chunks_consumed << 1;
+  g_vars.bytes_consumed << bytes;
+  m->unread.fetch_sub(bytes, std::memory_order_acq_rel);
+  m->unacked.fetch_add(bytes, std::memory_order_acq_rel);
+  maybe_send_ack(m);  // feedback frame parity
 }
 
 int consume_handler(void* meta, IOBuf** chunks, size_t n) {
@@ -159,13 +219,17 @@ int consume_handler(void* meta, IOBuf** chunks, size_t n) {
       mark_closed(m);
       return 1;
     }
-    const size_t bytes = chunk->size();
-    if (m->opts.on_message && !m->closed.load(std::memory_order_acquire)) {
+    const int64_t bytes = static_cast<int64_t>(chunk->size());
+    const bool delivered =
+        m->opts.on_message && !m->closed.load(std::memory_order_acquire);
+    if (delivered) {
       m->opts.on_message(sid, std::move(*chunk));
     }
     delete chunk;
-    m->unacked.fetch_add(bytes, std::memory_order_acq_rel);
-    maybe_send_ack(m);  // feedback frame parity
+    // A chunk only taken delivery of is given back by StreamConsumed.
+    if (!(delivered && m->opts.credit_on_consumed)) {
+      give_back(m, bytes);
+    }
   }
   return 0;
 }
@@ -208,6 +272,8 @@ StreamId new_stream(const StreamOptions& opts) {
   m->send_window.store(opts.window_bytes, std::memory_order_relaxed);
   m->window_ev.value.store(0, std::memory_order_relaxed);
   m->unacked.store(0, std::memory_order_relaxed);
+  m->unread.store(0, std::memory_order_relaxed);
+  m->unread_high_water.store(0, std::memory_order_relaxed);
   m->closed.store(false, std::memory_order_relaxed);
   m->close_ev.value.store(0, std::memory_order_relaxed);
   m->lock();
@@ -354,10 +420,15 @@ int StreamWrite(StreamId id, IOBuf&& data) {
     }
   }
   const int64_t bytes = static_cast<int64_t>(data.size());
-  // Credit gate: park until the window admits this chunk.  Each wakeup
-  // also probes the connection so a dead peer (no CLOSE ever arriving)
-  // unparks the writer within one probe interval.
+  // Credit gate: park while the window is exhausted.  A chunk is admitted
+  // as soon as any of the window is open, whatever its size (upstream's
+  // AppendIfNotFull), and takes the credit below zero by what it overran:
+  // what is written and not given back stays under window + one chunk,
+  // and a chunk wider than the window still goes.  Each wakeup also
+  // probes the connection so a dead peer (no CLOSE ever arriving) unparks
+  // the writer within one probe interval.
   int64_t window = m->send_window.load(std::memory_order_acquire);
+  int64_t parked_at = 0;
   while (true) {
     if (m->closed.load(std::memory_order_acquire) || stream_of(id) != m) {
       return EPIPE;
@@ -369,7 +440,7 @@ int StreamWrite(StreamId id, IOBuf&& data) {
         return EPIPE;
       }
     }
-    if (window >= bytes) {
+    if (window > 0 || bytes == 0) {
       if (m->send_window.compare_exchange_weak(window, window - bytes,
                                                std::memory_order_acq_rel)) {
         break;
@@ -378,11 +449,17 @@ int StreamWrite(StreamId id, IOBuf&& data) {
     }
     const uint32_t snap = m->window_ev.value.load(std::memory_order_acquire);
     window = m->send_window.load(std::memory_order_acquire);
-    if (window >= bytes) {
+    if (window > 0) {
       continue;  // refilled between checks
+    }
+    if (parked_at == 0) {
+      parked_at = monotonic_time_us();
     }
     m->window_ev.wait(snap, monotonic_time_us() + 1000 * 1000);
     window = m->send_window.load(std::memory_order_acquire);
+  }
+  if (parked_at != 0) {
+    g_vars.credit_wait_us << (monotonic_time_us() - parked_at);
   }
   RpcMeta meta;
   meta.type = RpcMeta::kStreamFrame;
@@ -395,6 +472,17 @@ int StreamWrite(StreamId id, IOBuf&& data) {
     mark_closed(m);
     return EPIPE;
   }
+  g_vars.chunks_written << 1;
+  g_vars.bytes_written << bytes;
+  return 0;
+}
+
+int StreamConsumed(StreamId id, size_t bytes) {
+  StreamMeta* m = stream_of(id);
+  if (m == nullptr) {
+    return EINVAL;
+  }
+  give_back(m, static_cast<int64_t>(bytes));
   return 0;
 }
 
@@ -467,14 +555,28 @@ void stream_on_frame(InputMessage&& msg) {
   switch (msg.meta.stream_flags) {
     case RpcMeta::kStreamData: {
       auto* chunk = new IOBuf(std::move(msg.payload));
+      const int64_t bytes = static_cast<int64_t>(chunk->size());
       // Submit under the meta lock so a concurrent StreamClose (version
       // bump + queue stop under the same lock) can't recycle the slot
-      // between our validation and the enqueue.
+      // between our validation and the enqueue.  The bytes are counted
+      // unread before the consumer can see the chunk, so the count never
+      // reads low.
       m->lock();
-      const bool ok =
-          m->version.load(std::memory_order_relaxed) ==
-              static_cast<uint32_t>(msg.meta.stream_id >> 32) &&
-          m->consume_q != nullptr && m->consume_q->execute(chunk) == 0;
+      bool ok = m->version.load(std::memory_order_relaxed) ==
+                    static_cast<uint32_t>(msg.meta.stream_id >> 32) &&
+                m->consume_q != nullptr;
+      if (ok) {
+        const int64_t unread =
+            m->unread.fetch_add(bytes, std::memory_order_acq_rel) + bytes;
+        if (unread > m->unread_high_water.load(std::memory_order_relaxed)) {
+          m->unread_high_water.store(unread, std::memory_order_relaxed);
+          g_vars.unread_high_water << unread;
+        }
+        ok = m->consume_q->execute(chunk) == 0;
+        if (!ok) {
+          m->unread.fetch_sub(bytes, std::memory_order_acq_rel);
+        }
+      }
       m->unlock();
       if (!ok) {
         delete chunk;
@@ -525,6 +627,13 @@ void stream_on_accept_response(uint64_t local_sid, uint64_t peer_sid,
 uint64_t stream_recv_window(StreamId id) {
   StreamMeta* m = stream_of(id);
   return m != nullptr ? static_cast<uint64_t>(m->opts.window_bytes) : 0;
+}
+
+uint64_t stream_unread_high_water(StreamId id) {
+  StreamMeta* m = stream_of(id);
+  return m != nullptr ? static_cast<uint64_t>(m->unread_high_water.load(
+                            std::memory_order_acquire))
+                      : 0;
 }
 
 uint64_t stream_send_window(StreamId id) {

@@ -9,6 +9,18 @@
 // in order; ACK frames reopen the writer's window, writers park on an
 // Event when credit runs out.
 //
+// The window (upstream's max_buf_size, docs/en/streaming_rpc.md): a chunk
+// is admitted whenever the window is NOT EXHAUSTED, whatever its size
+// (AppendIfNotFull: produced < consumed + window), so a chunk of any
+// width goes on a window of any width and the bytes written and not yet
+// given back stay under window + one chunk.  The credit is signed and
+// exact: an ACK returns what was consumed.  Bytes are given back when the
+// consumer has USED them: after on_message returns, or, for a consumer
+// that only takes delivery there (the C ABI's queue), when it says so
+// with StreamConsumed.  So what lies unread at a receiver never passes
+// window + one chunk either; `stream_unread_high_water_bytes` holds the
+// most any stream of the process has held.
+//
 // Establishment piggybacks on a normal RPC (like the reference):
 //   client: StreamCreate(&sid, &cntl, opts); channel.CallMethod(...);
 //   server handler: StreamAccept(&sid, cntl, opts); ... done();
@@ -33,6 +45,10 @@ struct StreamOptions {
   // Peer closed (or connection died).
   std::function<void(StreamId)> on_closed;
   int64_t window_bytes = 2 * 1024 * 1024;  // receive window we grant
+  // on_message only takes delivery (queues the chunk for an application
+  // that reads later): its return gives no bytes back, StreamConsumed
+  // does, when the application has taken them.
+  bool credit_on_consumed = false;
 };
 
 // Client side: create a local stream and attach it to `cntl` so the next
@@ -54,8 +70,14 @@ int StreamAcceptBatch(std::vector<StreamId>* out, Controller* cntl,
                       const StreamOptions& opts);
 
 // Ordered write; parks the calling fiber while the peer's window is
-// exhausted.  Returns 0, EINVAL (gone), EPIPE (closed/conn dead).
+// exhausted (admitted as soon as any of it is open, whatever the chunk's
+// size).  Returns 0, EINVAL (gone), EPIPE (closed/conn dead).
 int StreamWrite(StreamId id, IOBuf&& data);
+
+// A stream opened with credit_on_consumed: the application has taken
+// `bytes` of what on_message delivered; they go back to the writer (an
+// ACK frame once half the window has gathered).  EINVAL when gone.
+int StreamConsumed(StreamId id, size_t bytes);
 
 // Graceful close: sends CLOSE (best effort) and destroys the local id.
 int StreamClose(StreamId id);
@@ -77,6 +99,9 @@ void stream_on_accept_response(uint64_t local_sid, uint64_t peer_sid,
                                uint64_t socket_id, uint64_t peer_window);
 // The receive window a local stream grants (advertised to the peer).
 uint64_t stream_recv_window(StreamId id);
+// The most bytes this stream has held received and not yet given back
+// (in its consume queue, inside on_message, or delivered and unread).
+uint64_t stream_unread_high_water(StreamId id);
 // Remaining send credit (the peer's advertised window minus unacked
 // writes).  0 for unknown/unestablished ids.  The inference scheduler
 // caps per-request token budgets with this so a batch write can never

@@ -168,6 +168,150 @@ TEST_CASE(flow_control_backpressure) {
   g_consume_delay_us = 0;
 }
 
+TEST_CASE(chunk_wider_than_the_window_goes_and_the_next_waits_for_it) {
+  // Upstream's admission (AppendIfNotFull): a 4 MB chunk on the 2 MB
+  // default window is admitted at once, because the window is not
+  // exhausted; it overruns the credit, so the second is held until the
+  // first has been consumed (its on_message returned) and acknowledged.
+  start_once();
+  g_srv_bytes = 0;
+  g_srv_chunks = 0;
+  g_srv_last_seq = 0;
+  g_srv_order_ok = true;
+  g_consume_delay_us = 300000;  // the first chunk is "in use" for 0.3 s
+
+  Channel ch;
+  EXPECT_EQ(ch.Init("127.0.0.1:" + std::to_string(g_port)), 0);
+  Controller cntl;
+  StreamId sid = 0;
+  EXPECT_EQ(StreamCreate(&sid, &cntl, StreamOptions{}), 0);
+  IOBuf req, resp;
+  req.append("open");
+  ch.CallMethod("Stream.Open", req, &resp, &cntl);
+  EXPECT(!cntl.Failed());
+  EXPECT_EQ(stream_send_window(sid), 2u * 1024 * 1024);
+
+  static StreamId s_sid;
+  s_sid = sid;
+  static std::atomic<int64_t> first_us{0};
+  static std::atomic<int64_t> second_us{0};
+  static std::atomic<uint64_t> credit_between{1};
+  fiber_t writer;
+  fiber_start(&writer, [](void*) {
+    const size_t kChunk = 4u * 1024 * 1024;
+    for (uint64_t seq = 1; seq <= 2; ++seq) {
+      IOBuf chunk;
+      chunk.append(&seq, 8);
+      chunk.append(std::string(kChunk - 8, 'w'));
+      const int64_t t0 = monotonic_time_us();
+      EXPECT_EQ(StreamWrite(s_sid, std::move(chunk)), 0);
+      (seq == 1 ? first_us : second_us).store(monotonic_time_us() - t0);
+      if (seq == 1) {
+        credit_between.store(stream_send_window(s_sid));
+      }
+    }
+  }, nullptr);
+  fiber_join(writer);
+  const int64_t deadline = monotonic_time_us() + 5000000;
+  while (g_srv_chunks.load() < 2 && monotonic_time_us() < deadline) {
+    usleep(10000);
+  }
+  EXPECT_EQ(g_srv_chunks.load(), 2);
+  EXPECT_EQ(g_srv_bytes.load(), 2 * 4 * 1024 * 1024);
+  EXPECT(g_srv_order_ok.load());
+  EXPECT(first_us.load() < 150000);    // admitted without a wait
+  EXPECT_EQ(credit_between.load(), 0u);  // overrun: reads as none left
+  EXPECT(second_us.load() > 200000);   // held until the first was consumed
+  g_consume_delay_us = 0;
+  StreamClose(sid);
+}
+
+namespace {
+std::atomic<uint64_t> g_held_sid{0};
+std::atomic<int64_t> g_held_bytes{0};
+}  // namespace
+
+TEST_CASE(a_consumer_that_only_takes_delivery_holds_the_writer) {
+  // credit_on_consumed: on_message queues and returns, and nothing goes
+  // back to the writer until StreamConsumed.  The writer stops after
+  // window + less than one chunk, that is what the receiver holds unread,
+  // and giving the bytes back starts it again.
+  Server srv;
+  srv.RegisterMethod(
+      "Stream.Hold", [](Controller* cntl, const IOBuf&, IOBuf* resp,
+                        Closure done) {
+        StreamOptions opts;
+        opts.window_bytes = 256 * 1024;
+        opts.credit_on_consumed = true;
+        opts.on_message = [](StreamId, IOBuf&& chunk) {
+          g_held_bytes.fetch_add(static_cast<int64_t>(chunk.size()));
+        };
+        opts.on_closed = [](StreamId sid) { StreamClose(sid); };
+        StreamId sid = 0;
+        EXPECT_EQ(StreamAccept(&sid, cntl, opts), 0);
+        g_held_sid.store(sid);
+        resp->append("accepted");
+        done();
+      });
+  EXPECT_EQ(srv.Start(0), 0);
+  Channel ch;
+  EXPECT_EQ(ch.Init("127.0.0.1:" + std::to_string(srv.port())), 0);
+  Controller cntl;
+  StreamId sid = 0;
+  EXPECT_EQ(StreamCreate(&sid, &cntl, StreamOptions{}), 0);
+  IOBuf req, resp;
+  req.append("open");
+  ch.CallMethod("Stream.Hold", req, &resp, &cntl);
+  EXPECT(!cntl.Failed());
+
+  static StreamId s_sid;
+  s_sid = sid;
+  static std::atomic<int> written{0};
+  written = 0;
+  const int64_t kChunk = 100 * 1024;
+  fiber_t writer;
+  fiber_start(&writer, [](void*) {
+    for (int i = 0; i < 8; ++i) {
+      IOBuf chunk;
+      chunk.append(std::string(100 * 1024, 'h'));
+      if (StreamWrite(s_sid, std::move(chunk)) != 0) {
+        return;
+      }
+      written.fetch_add(1);
+    }
+  }, nullptr);
+  // 256 KB of window admit three chunks of 100 KB (the third overruns).
+  int64_t deadline = monotonic_time_us() + 3000000;
+  while (g_held_bytes.load() < 3 * kChunk && monotonic_time_us() < deadline) {
+    usleep(10000);
+  }
+  usleep(300000);  // a fourth would have come by now
+  EXPECT_EQ(written.load(), 3);
+  EXPECT_EQ(g_held_bytes.load(), 3 * kChunk);
+  const StreamId held = g_held_sid.load();
+  EXPECT_EQ(stream_unread_high_water(held),
+            static_cast<uint64_t>(3 * kChunk));
+  EXPECT(stream_unread_high_water(held) <
+         static_cast<uint64_t>(256 * 1024 + kChunk));
+  // The application takes them: the writer goes on, and stops again.
+  EXPECT_EQ(StreamConsumed(held, static_cast<size_t>(3 * kChunk)), 0);
+  deadline = monotonic_time_us() + 3000000;
+  while (written.load() < 6 && monotonic_time_us() < deadline) {
+    usleep(10000);
+  }
+  usleep(300000);
+  EXPECT_EQ(written.load(), 6);
+  EXPECT_EQ(StreamConsumed(held, static_cast<size_t>(5 * kChunk)), 0);
+  fiber_join(writer);
+  EXPECT_EQ(written.load(), 8);
+  EXPECT_EQ(stream_unread_high_water(held),
+            static_cast<uint64_t>(3 * kChunk));
+  EXPECT_EQ(StreamConsumed(0, 1), EINVAL);
+  StreamClose(sid);
+  srv.Stop();
+  srv.Join();
+}
+
 TEST_CASE(write_without_stream_fails) {
   EXPECT_EQ(StreamWrite(0, IOBuf()), EINVAL);
   EXPECT_EQ(StreamWrite((0xdeadull << 33) | 1, IOBuf()), EINVAL);
