@@ -329,6 +329,14 @@ def load_library() -> ctypes.CDLL:
                 ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64,
                 ctypes.c_uint64, ctypes.c_int]
             lib.trpc_kv_note_sequence.restype = None
+            lib.trpc_kv_note_publish.argtypes = [ctypes.c_uint64,
+                                                 ctypes.c_uint64]
+            lib.trpc_kv_note_publish.restype = None
+            # Whether bytes lie in a landing block the store can publish
+            # from where they are (capi/hostpool_capi.cc).
+            lib.trpc_host_pool_holds.argtypes = [ctypes.c_void_p,
+                                                 ctypes.c_size_t]
+            lib.trpc_host_pool_holds.restype = ctypes.c_int
             # Content-addressed prefix cache (capi/kv_capi.cc; ISSUE 17).
             lib.trpc_kv_content_hash.argtypes = [
                 ctypes.c_void_p, ctypes.c_size_t,

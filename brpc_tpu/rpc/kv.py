@@ -223,15 +223,23 @@ def publish(block_id: int, buffer, offset: int = 0, length: int | None = None,
     if offset < 0 or length <= 0 or offset + length > size:
         raise ValueError(f"bad block range: off={offset} len={length} "
                          f"of {size}")
+    return _publish_at(block_id, base + offset, length, lease_ms, node,
+                       min_generation)
+
+
+def _publish_at(block_id: int, address: int, length: int, lease_ms: int,
+                node: str, min_generation: int = 0) -> KvBlockMeta:
+    """`publish` of the `length` bytes at `address`, which must lie in
+    registered memory."""
     lib = load_library()
     gen = ctypes.c_uint64()
     rkey = ctypes.c_uint64()
     off = ctypes.c_uint64()
-    rc = lib.trpc_kv_publish_ex(
-        ctypes.c_void_p(base + offset), ctypes.c_size_t(length),
-        ctypes.c_uint64(block_id), ctypes.c_int64(lease_ms),
-        ctypes.c_uint64(min_generation),
-        ctypes.byref(gen), ctypes.byref(rkey), ctypes.byref(off))
+    # Plain ints and the out-parameters themselves: the declared argtypes
+    # convert them, which is a third cheaper a record than wrapping each
+    # (61 or 76 records a hand-over, on the thread that binds the KV cells).
+    rc = lib.trpc_kv_publish_ex(address, length, block_id, lease_ms,
+                                min_generation, gen, rkey, off)
     if rc != 0:
         miss, stale, exists = _codes()
         if rc == exists:
@@ -385,38 +393,65 @@ def _cut(area, records) -> list:
     return bufs
 
 
-def _publish_records(records, sources, slab, offset, lease_ms, node,
+def _publish_records(groups, slab, offset, lease_ms, node,
                      registry) -> list[KvBlockMeta]:
-    """`records` ((id, bytes), in order) published out of `slab` from
-    `offset` on, end to end, and the bytes of `sources` (flat host
-    arrays, end to end the records' bytes) copied there; with a
-    `registry` all registered in one `register_many`."""
-    total = sum(n for _, n in records)
-    given = sum(flat.nbytes for flat in sources)
-    if given != total or offset < 0 or offset + total > slab.nbytes:
+    """Publishes the records of `groups`, each (records as (id, bytes),
+    the flat host array whose bytes they are, end to end), in order, and
+    with a `registry` registers them all in one `register_many`.
+
+    Where a group is published from is chosen by what can be observed of
+    its source.  Bytes that lie in a landing block of the host pool
+    (`trpc_host_pool_holds`: where a `PendingView`'s transfer of 1 MB or
+    more landed them, registered memory since PR 34) are published where
+    they lie, each record at its offset in the block, and nothing is
+    copied: the store co-owns the block from then on, and the pool hands
+    it to no other transfer until the last record published from it has
+    been withdrawn, evicted or replaced and no response serves its bytes
+    any more, whatever the caller does with the view and the array
+    meanwhile (cpp/capi/hostpool_capi.cc).  Any other source (a numpy
+    array of the caller's, a dlpack import on the CPU) is copied into
+    `slab` (an RmaBuffer), at the place it would have from `offset` on
+    with every group end to end, and published from there.  Either way a
+    published record's bytes are not to be written until it is gone.
+    One call counts its bytes once, in `kv_publish_in_place_bytes` and
+    `kv_publish_copy_bytes`."""
+    sizes = [sum(n for _, n in records) for records, _ in groups]
+    total = sum(sizes)
+    if (any(size != flat.nbytes for size, (_, flat) in zip(sizes, groups))
+            or offset < 0 or offset + total > slab.nbytes):
         raise ValueError(
-            f"{len(records)} records of {total} bytes from {given} bytes "
-            f"at {offset}: that does not fit the slab ({slab.nbytes} "
-            f"bytes)")
+            f"{sum(len(records) for records, _ in groups)} records of "
+            f"{total} bytes from {sum(flat.nbytes for _, flat in groups)} "
+            f"bytes at {offset}: that does not fit the slab "
+            f"({slab.nbytes} bytes)")
+    lib = load_library()
+    served_from, copies, at = [], [], offset
+    for _, flat in groups:
+        address = flat.ctypes.data
+        if not lib.trpc_host_pool_holds(address, flat.nbytes):
+            address = slab.address + at
+            copies.append((at, flat))
+        served_from.append(address)
+        at += flat.nbytes
     # Published first, copied second: a record that is live
-    # (KvExistsError) keeps its slab bytes, and nobody can look the
-    # records up before they are registered below.
+    # (KvExistsError) keeps its bytes and its block, and nobody can look
+    # the records up before they are registered below.
     metas: list[KvBlockMeta] = []
-    at = offset
     try:
-        for record_id, nbytes in records:
-            metas.append(publish(record_id, slab, offset=at, length=nbytes,
-                                 lease_ms=lease_ms, node=node))
-            at += nbytes
+        for (records, _), address in zip(groups, served_from):
+            for record_id, nbytes in records:
+                metas.append(_publish_at(record_id, address, nbytes,
+                                         lease_ms, node))
+                address += nbytes
     except Exception:
         for meta in metas:
             withdraw(meta.block_id)
         raise
-    into = np.frombuffer(slab.view, dtype=np.uint8)
-    at = offset
-    for flat in sources:
-        into[at:at + flat.nbytes] = flat
-        at += flat.nbytes
+    copied = 0
+    for at, flat in copies:
+        np.frombuffer(slab.view, dtype=np.uint8)[at:at + flat.nbytes] = flat
+        copied += flat.nbytes
+    lib.trpc_kv_note_publish(total - copied, copied)
     if registry is not None:
         for answer in registry.register_many(metas, lease_ms=lease_ms):
             if isinstance(answer, RpcError):
@@ -440,20 +475,28 @@ def publish_sequence(seq_id: int, layout: KvCacheLayout, pages, states,
     their transfers were started ahead (a view of 1 MB or more is waited
     for by `zerocopy`'s own thread from the moment it is made, and a
     16-bit array crosses as flat words, so a caller that comes back a
-    cycle later finds the bytes there): the
-    bytes come to the host through `zerocopy.host_view`, so into
-    recycled landing blocks, and are copied into `slab` (an RmaBuffer)
-    from `offset` on, `layout.sequence_bytes` of them, each record
-    `publish`ed from its place.  With a `registry` the records of
+    cycle later finds the bytes there): the bytes come to the host
+    through `zerocopy.host_view`, so a transfer of 1 MB or more lands
+    them in a block of the host pool, and from there each record is
+    `publish`ed where it lies, with no copy on the host; the pool keeps
+    that block from every other transfer until the sequence is withdrawn
+    (or its records are evicted or replaced), whatever becomes of the
+    view and the array.  A source that is no such block (a numpy array,
+    a host-visible device array, a transfer under 1 MB) is copied into
+    `slab` (an RmaBuffer) from `offset` on, at its place among the
+    `layout.sequence_bytes` bytes, and its records are published from
+    there: those slab bytes belong to the store until the sequence is
+    withdrawn (`withdraw_sequence`).  With a `registry` the records of
     both kinds are registered in one `register_many`; a record it
-    refuses raises its error.  The slab's bytes belong to the store
-    until the sequence is withdrawn (`withdraw_sequence`)."""
+    refuses raises its error.  A sequence with a live record is refused
+    whole (KvExistsError) and the live records keep their bytes."""
     paged, snapshot = layout.records(seq_id, pages.shape[0])
-    sources = [_host_flat(pages)]
-    if states is not None:
-        sources.append(_host_flat(states))
-    return _publish_records(paged + snapshot, sources, slab, offset,
-                            lease_ms, node, registry)
+    if states is None:
+        groups = [(paged + snapshot, _host_flat(pages))]
+    else:
+        groups = [(paged, _host_flat(pages)),
+                  (snapshot, _host_flat(states))]
+    return _publish_records(groups, slab, offset, lease_ms, node, registry)
 
 
 def withdraw_sequence(seq_id: int, layout: KvCacheLayout, pages: int,
@@ -461,8 +504,9 @@ def withdraw_sequence(seq_id: int, layout: KvCacheLayout, pages: int,
     """Takes a published sequence of `pages` pages back: its records of
     both kinds leave the registry (one `evict_many`; a record already
     gone there is no error) and the local store, after which its slab
-    bytes may be used again.  Raises KvMissError if the store did not
-    hold a record."""
+    bytes may be used again and the landing blocks it was published
+    from go back to the host pool once their arrays are dropped.  Raises
+    KvMissError if the store did not hold a record."""
     ids = layout.record_ids(seq_id, pages)
     if registry is not None:
         registry.evict_many(ids)
@@ -477,14 +521,15 @@ def publish_page(block_id: int, page, slab, offset: int = 0,
     """Publishes one page of a paged pool, `page[layer]` as the record
     `page_record_id(block_id, layer)`: `publish_sequence` for a layout
     of `page.shape[0]` paged layers of equal records and a sequence of
-    this one page."""
+    this one page, so out of the block the page's transfer landed in
+    where there is one, and through `slab` otherwise."""
     layers = page.shape[0]
     flat = _host_flat(page)
     if flat.nbytes % layers:
         raise ValueError(f"a page of {flat.nbytes} bytes is not {layers} "
                          "layers of equal records")
     layout = KvCacheLayout.paged(layers, flat.nbytes // layers)
-    return _publish_records(layout.records(block_id, 1)[0], [flat], slab,
+    return _publish_records([(layout.records(block_id, 1)[0], flat)], slab,
                             offset, lease_ms, node, registry)
 
 

@@ -68,6 +68,15 @@ to 1 GB of such blocks may lie idle (the blocks of one pipeline at depth
 bound or through `trpc_host_pool_trim`.  Every view's transfer starts when
 the view is made: the memory of fetches on their way is bounded by the
 depth their caller keeps open, not here.
+
+What such a block is: a registered shm region (`rma_alloc`), so that the
+KV store, which serves registered memory only, publishes a page or a
+sequence out of the very block its transfer landed in (`kv.py`
+`_publish_records`; no copy into a slab).  A block a record was published
+from stays out of the recycled list, whatever becomes of the view and the
+array, until the record is withdrawn, evicted or replaced and no response
+serves its bytes any more: the pool reads that off the region's own
+reference count (PERF.md, PR 34).
 """
 
 from __future__ import annotations

@@ -115,6 +115,12 @@ void trpc_kv_note_sequence(uint64_t page_records, uint64_t page_bytes,
                    snapshot_bytes, handed_over != 0);
 }
 
+// Counts one kv.publish_page / publish_sequence: the bytes published
+// from the block they landed in, and the bytes copied into the slab.
+void trpc_kv_note_publish(uint64_t in_place_bytes, uint64_t copy_bytes) {
+  kv_note_publish(in_place_bytes, copy_bytes);
+}
+
 // ---- content-addressed prefix cache (ISSUE 17) ---------------------------
 
 // 128-bit content hash of (block bytes, token-id span) — deterministic

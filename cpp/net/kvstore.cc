@@ -131,6 +131,8 @@ struct KvVars {
   Adder seq_page_bytes;
   Adder seq_snapshot_bytes;
   Adder seq_refused;
+  Adder publish_in_place_bytes;
+  Adder publish_copy_bytes;
   std::unique_ptr<PassiveStatus<long>> store_blocks;
   std::unique_ptr<PassiveStatus<long>> store_bytes;
   std::unique_ptr<PassiveStatus<long>> registry_blocks;
@@ -195,6 +197,15 @@ struct KvVars {
         "kv_seq_refused",
         "sequence hand-overs refused whole: a record missing, short, or "
         "a snapshot of another boundary");
+    publish_in_place_bytes.expose(
+        "kv_publish_in_place_bytes",
+        "bytes of pages and sequences published from the block their "
+        "device-to-host transfer landed in (kv.publish_page / "
+        "publish_sequence): nothing copied on the host");
+    publish_copy_bytes.expose(
+        "kv_publish_copy_bytes",
+        "bytes of pages and sequences copied into the caller's slab to be "
+        "published: their source was not memory the store can serve");
     store_blocks = std::make_unique<PassiveStatus<long>>(
         [] { return static_cast<long>(kv_store().count()); });
     store_blocks->expose("kv_store_blocks",
@@ -359,6 +370,11 @@ void kv_note_sequence(uint64_t page_records, uint64_t page_bytes,
   kv_vars().seq_page_bytes << static_cast<int64_t>(page_bytes);
   kv_vars().seq_snapshot_records << static_cast<int64_t>(snapshot_records);
   kv_vars().seq_snapshot_bytes << static_cast<int64_t>(snapshot_bytes);
+}
+
+void kv_note_publish(uint64_t in_place_bytes, uint64_t copy_bytes) {
+  kv_vars().publish_in_place_bytes << static_cast<int64_t>(in_place_bytes);
+  kv_vars().publish_copy_bytes << static_cast<int64_t>(copy_bytes);
 }
 
 KvPrefixCounters& kv_prefix_counters() {

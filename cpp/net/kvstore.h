@@ -502,4 +502,10 @@ void kv_note_sequence(uint64_t page_records, uint64_t page_bytes,
                       uint64_t snapshot_records, uint64_t snapshot_bytes,
                       bool handed_over);
 
+// Counts one publish of a page or a sequence (kv.py _publish_records):
+// the bytes published where their transfer landed them in
+// kv_publish_in_place_bytes, the bytes copied into the caller's slab
+// first in kv_publish_copy_bytes.
+void kv_note_publish(uint64_t in_place_bytes, uint64_t copy_bytes);
+
 }  // namespace trpc

@@ -4,8 +4,10 @@ cpp/capi/hostpool_capi.cc): a block the process has touched before.
 `LandingArray` stands in for a TPU-resident array as jaxlib 0.9 fetches
 one: `copy_to_host_async` allocates the destination as a numpy array,
 through numpy's current data-memory handler, on the calling thread.
-glibc behaves here as on the chip's host; the counts are page faults,
-not times.
+A block of 1 MB to 1 GB is a registered shm region (PR 34: the KV store
+publishes from it where the bytes landed), anything else libc's; a
+region's pages fault in on first touch as libc's fresh pages do.  The
+counts are page faults, not times.
 """
 
 import ctypes
@@ -23,6 +25,7 @@ from numpy._core.multiarray import get_handler_name
 from brpc_tpu.rpc import Server, observe, zerocopy
 from brpc_tpu.rpc._lib import load_library
 
+MB = 1 << 20
 MB64 = 64 << 20
 POOLED = "trpc_host_pool"
 
@@ -45,6 +48,7 @@ def pool():
     lib = load_library()
     lib.trpc_host_pool_idle_bytes.restype = ctypes.c_size_t
     lib.trpc_host_pool_trim.restype = ctypes.c_size_t
+    gc.collect()    # what an earlier test left to the collector
     lib.trpc_host_pool_trim()
     yield lib
     lib.trpc_host_pool_trim()
@@ -212,6 +216,86 @@ def test_the_idle_list_is_bounded_and_the_oldest_block_goes(pool):
     del got
     assert pool.trpc_host_pool_trim() == 1 << 30
     assert pool.trpc_host_pool_idle_bytes() == 0
+
+
+def test_a_pooled_block_is_a_registered_region_and_no_other_is(pool):
+    """From the size line to the idle bound a landing block is memory
+    the KV store can publish from; a smaller one, and one numpy asked
+    zeroed (calloc), are libc's."""
+    regions = int(pool.trpc_rma_region_count())
+    block = zerocopy.landing_block(2 * MB)
+    where = block.ctypes.data
+    assert int(pool.trpc_rma_region_count()) == regions + 1
+    assert pool.trpc_host_pool_holds(where, 2 * MB)
+    assert pool.trpc_host_pool_holds(where + MB, MB)
+    assert not pool.trpc_host_pool_holds(where + MB, MB + 1)
+    assert not pool.trpc_host_pool_holds(where - 1, 2)
+    small = zerocopy.landing_block(MB - 4096)
+    assert get_handler_name(small) == POOLED
+    assert not pool.trpc_host_pool_holds(small.ctypes.data, 1)
+    zeroed = zerocopy._landing.set_handler(zerocopy._landing.capsule)
+    try:
+        cleared = np.zeros(2 * MB, dtype=np.uint8)
+    finally:
+        zerocopy._landing.set_handler(zeroed)
+    assert get_handler_name(cleared) == POOLED
+    assert not pool.trpc_host_pool_holds(cleared.ctypes.data, 1)
+    del small, cleared
+    assert pool.trpc_host_pool_idle_bytes() == 0
+    # Idle, the region stays; trimmed, it goes.
+    del block
+    assert pool.trpc_host_pool_idle_bytes() == 2 * MB
+    assert pool.trpc_host_pool_holds(where, 2 * MB)
+    assert int(pool.trpc_rma_region_count()) == regions + 1
+    assert pool.trpc_host_pool_trim() == 2 * MB
+    assert not pool.trpc_host_pool_holds(where, 1)
+    assert int(pool.trpc_rma_region_count()) == regions
+
+
+@pytest.mark.parametrize("before, after", [
+    (2 * MB, 3 * MB), (3 * MB, 2 * MB), (2 * MB, MB // 2), (MB // 2, 2 * MB),
+    (2 * MB, 2 * MB)], ids=["grown", "shrunk", "under_the_line",
+                            "over_the_line", "the_same"])
+def test_realloc_keeps_the_bytes_and_each_kind_its_own(pool, before, after):
+    """numpy's in-place resize goes through the handler's realloc: a
+    region moves to a block of the new size (a region again where that is
+    pooled) and goes back to the list; libc's block stays libc's."""
+    block = zerocopy.landing_block(before)
+    block[:] = np.arange(before, dtype=np.uint32).view(np.uint8)[:before]
+    where, was_held = block.ctypes.data, before >= MB
+    assert bool(pool.trpc_host_pool_holds(where, before)) == was_held
+    block.resize(after, refcheck=False)
+    kept = min(before, after)
+    assert np.array_equal(
+        block[:kept], np.arange(before, dtype=np.uint32).view(np.uint8)[:kept])
+    assert get_handler_name(block) == POOLED
+    # A region's successor is pooled by its size; libc's is realloc's.
+    assert bool(pool.trpc_host_pool_holds(block.ctypes.data, after)) == (
+        was_held and after >= MB)
+    if was_held and after != before:
+        assert pool.trpc_host_pool_idle_bytes() == before
+        assert _fetch_and_write(before)[1] == where
+    elif after == before:
+        assert block.ctypes.data == where
+    now_held = was_held and after >= MB
+    del block
+    assert pool.trpc_host_pool_idle_bytes() == (
+        (before if was_held and after != before else 0)
+        + (after if now_held else 0))
+
+
+def test_the_idle_bound_frees_the_oldest_region(pool):
+    regions = int(pool.trpc_rma_region_count())
+    held = [zerocopy.landing_block(MB64) for _ in range(17)]
+    assert int(pool.trpc_rma_region_count()) == regions + 17
+    oldest = held[0].ctypes.data
+    while held:
+        held.pop(0)
+    assert pool.trpc_host_pool_idle_bytes() == 1 << 30
+    assert int(pool.trpc_rma_region_count()) == regions + 16
+    assert not pool.trpc_host_pool_holds(oldest, 1)
+    assert pool.trpc_host_pool_trim() == 1 << 30
+    assert int(pool.trpc_rma_region_count()) == regions
 
 
 def test_process_faults_minor_counts_fresh_pages():
