@@ -49,6 +49,7 @@
 #include "net/controller.h"
 #include "net/rma.h"
 #include "net/span.h"
+#include "net/wire_split.h"
 #include "stat/latency_recorder.h"
 #include "stat/reducer.h"
 #include "stat/variable.h"
@@ -118,6 +119,14 @@ struct BatchPipelineVars {
   Adder stage_us;
   Adder stage_fetch_us;
   Adder stage_fetch_bytes;
+  // The wire phase cut at the server's stamps (net/wire_split.h), for
+  // the polled calls whose response carried them.
+  Adder split_calls;
+  Adder srv_queue_us;
+  Adder srv_handler_us;
+  Adder net_us;
+  Adder leg_calls;
+  Adder req_leg_us;
   BatchPipelineVars() {
     inflight.expose("batch_inflight",
                     "batch-pipeline calls currently in flight, summed "
@@ -171,6 +180,32 @@ struct BatchPipelineVars {
                           "bytes of the requests of batch_staged_calls");
     stage_fetch_bytes.expose("batch_stage_fetch_bytes",
                              "request bytes those waits resolved");
+    split_calls.expose("batch_split_calls",
+                       "the calls in batch_calls_polled whose response "
+                       "carried the server's phase stamps; the divisor of "
+                       "batch_srv_queue_us, batch_srv_handler_us and "
+                       "batch_net_us");
+    srv_queue_us.expose("batch_srv_queue_us",
+                        "the part of batch_wire_us between the request "
+                        "being whole at the server and its handler being "
+                        "entered, by the server's clock");
+    srv_handler_us.expose("batch_srv_handler_us",
+                          "the part of batch_wire_us between the handler "
+                          "being entered and its done() running, by the "
+                          "server's clock");
+    net_us.expose("batch_net_us",
+                  "batch_wire_us of those calls less the server's share "
+                  "(arrival to done): the request's and the response's "
+                  "leg together, valid whatever the peer's clock");
+    leg_calls.expose("batch_leg_calls",
+                     "the calls in batch_split_calls whose connection's "
+                     "two ends read one clock (the shm ring, or a peer "
+                     "address of this host); the divisor of "
+                     "batch_req_leg_us");
+    req_leg_us.expose("batch_req_leg_us",
+                      "us from just before CallMethod to the request "
+                      "being whole at the server; the response's leg is "
+                      "batch_net_us less this, over the same calls");
   }
 };
 
@@ -212,7 +247,10 @@ struct BatchCall {
   // landed <= polled; the fifth is read in trpc_batch_poll, which folds
   // the differences into BatchPipelineVars.  Plain fields: the issuer
   // hand-off, the done-ring's release push and poll's acquire pop
-  // already order every write before the poll that reads it.
+  // already order every write before the poll that reads it.  The
+  // server's own three readings between issue and reply (arrival,
+  // handler, done) come back in the response and lie in
+  // cntl.call().srv; the poll cuts wire at them (net/wire_split.h).
   int64_t enter_us = 0;  // entry of the trpc_batch_submit that made it
   // Staged calls only (else 0): when the request's transfer to the host
   // was started, how long the stager was blocked on it, for what bytes.
@@ -520,6 +558,12 @@ struct PhaseSums {
   int64_t stage_us = 0;
   int64_t fetch_us = 0;
   int64_t fetch_bytes = 0;
+  int64_t split = 0;
+  int64_t srv_queue_us = 0;
+  int64_t srv_handler_us = 0;
+  int64_t net_us = 0;
+  int64_t legs = 0;
+  int64_t req_leg_us = 0;
 
   void count(const BatchCall* c, int64_t polled_us) {
     if (c->status != 0) {
@@ -539,6 +583,21 @@ struct PhaseSums {
     land_copy_bytes += static_cast<int64_t>(c->land_copied);
     if (c->land_fanned) {
       land_fanout_bytes += static_cast<int64_t>(c->land_copied);
+    }
+    // A response without the stamps (an older peer, a cluster member's
+    // internal controller) splits nothing.
+    const Controller::CallState& call = c->cntl.call();
+    const WireSplit w =
+        split_wire(c->issue_us, c->reply_us, call.srv, call.srv_same_clock);
+    if (w.split) {
+      ++split;
+      srv_queue_us += w.srv_queue_us;
+      srv_handler_us += w.srv_handler_us;
+      net_us += w.net_us;
+      if (w.legs) {
+        ++legs;
+        req_leg_us += w.req_leg_us;
+      }
     }
     if (c->staged_us != 0) {
       ++staged;
@@ -564,6 +623,16 @@ struct PhaseSums {
     v.resp_bytes << resp_bytes;
     v.land_copy_bytes << land_copy_bytes;
     v.land_fanout_bytes << land_fanout_bytes;
+    if (split != 0) {
+      v.split_calls << split;
+      v.srv_queue_us << srv_queue_us;
+      v.srv_handler_us << srv_handler_us;
+      v.net_us << net_us;
+      if (legs != 0) {
+        v.leg_calls << legs;
+        v.req_leg_us << req_leg_us;
+      }
+    }
     if (staged == 0) {
       return;
     }

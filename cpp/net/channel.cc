@@ -218,6 +218,15 @@ void tstd_process_response(InputMessage&& msg) {
     cntl->call().offered_stream = 0;
     cntl->call().extra_offered.clear();
   }
+  if (msg.meta.srv.arrival_us != 0) {
+    // The server's phase stamps (net/wire_split.h), kept for whoever
+    // folds the call (the batch pipeline's poll).  Whether they can be
+    // set against OUR clock is the connection's to say.
+    Controller::CallState& call = cntl->call();
+    call.srv = msg.meta.srv;
+    SocketRef conn(Socket::Address(msg.socket));
+    call.srv_same_clock = conn && conn->peer_shares_clock();
+  }
   if (msg.meta.error_code != 0) {
     cntl->SetFailed(msg.meta.error_code, msg.meta.error_text);
   } else {
@@ -460,7 +469,10 @@ void Channel::CallMethod(const std::string& method, const IOBuf& request,
   cntl->call().done = std::move(done);
   cntl->call().start_us = monotonic_time_us();
   // Controller reuse: a previous call's connection ownership must not
-  // leak into this call's early-failure paths.
+  // leak into this call's early-failure paths, nor its server stamps
+  // into a call that fails before any response.
+  cntl->call().srv = {};
+  cntl->call().srv_same_clock = false;
   cntl->call().socket_id = 0;
   cntl->call().conn_type = 0;
   cntl->call().conn_auth = nullptr;

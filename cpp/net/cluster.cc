@@ -1412,6 +1412,10 @@ void ClusterChannel::call_hedged(std::shared_ptr<Cluster> cluster,
     cntl->response_attachment() =
         std::move(ctx->cntls[w].response_attachment());
     cntl->set_latency_us(ctx->cntls[w].latency_us());
+    // The winner's server stamps are the call's (net/wire_split.h).
+    const Controller::CallState& won = ctx->cntls[w].call();
+    cntl->call().srv = won.srv;
+    cntl->call().srv_same_clock = won.srv_same_clock;
   }
 }
 

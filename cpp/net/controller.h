@@ -16,6 +16,7 @@
 #include "base/iobuf.h"
 #include "fiber/fid.h"
 #include "net/data_pool.h"
+#include "net/wire_split.h"
 
 namespace trpc {
 
@@ -193,8 +194,17 @@ class Controller {
     // poll it between chunks.  Null on the client side and on requests
     // shed before dispatch.
     std::shared_ptr<CancelScope> cancel_scope;
+    // The server's phase stamps (net/wire_split.h), its own monotonic
+    // clock.  Server: srv.handler_us is read just before the handler is
+    // called (0 = answered before any handler: shed, rejected, failed).
+    // Client: the three a tstd response carried back (0 = it carried
+    // none: an older peer, a protocol adaptor, a call that failed
+    // locally) and whether that connection's two ends read one clock.
+    SrvStamps srv;
+    bool srv_same_clock = false;
   };
   CallState& call() { return call_; }
+  const CallState& call() const { return call_; }
 
   // Pooled per-request scratch object, created by the server's
   // session_local_data_factory (simple_data_pool parity).  Null when no

@@ -6,6 +6,7 @@
 
 #include "base/flags.h"
 #include "base/logging.h"
+#include "base/time.h"
 #include "base/tls_cache.h"
 #include "fiber/analysis.h"
 #include "fiber/fiber.h"
@@ -282,6 +283,11 @@ size_t cut_and_dispatch(Socket* s, SocketId id) {
           if (!rma_resolve(msg, s)) {
             free_input_message(msg);
             continue;
+          }
+          if (msg->meta.type == RpcMeta::kRequest) {
+            // A one-sided request is whole now, not when its control
+            // frame was cut.
+            msg->arrival_us = monotonic_time_us();
           }
         }
         const Protocol* p = protocol_at(s->pinned_protocol);

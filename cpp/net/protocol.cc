@@ -9,7 +9,6 @@
 
 #include "base/logging.h"
 #include "net/socket.h"
-#include "stat/capture.h"
 
 namespace trpc {
 
@@ -55,6 +54,23 @@ uint64_t get_u64(const char* p) {
 constexpr char kMagic[4] = {'T', 'R', 'P', '1'};
 constexpr size_t kHeaderLen = 4 + 4 + 8;  // magic | meta_len | payload_len
 
+// Server phase stamps (RpcMeta::srv): arrival u64, then the two
+// differences as u32 us.  Shorter than the trace group on purpose: a
+// tail of exactly this length can be no older group, and a decoder that
+// predates the stamps skips a tail under 24 bytes whole.
+constexpr size_t kSrvStampBytes = 8 + 4 + 4;
+
+uint32_t saturate_u32(int64_t v) {
+  return v <= 0 ? 0
+                : v >= 0xffffffffll ? 0xffffffffu : static_cast<uint32_t>(v);
+}
+
+void put_srv_stamps(std::string* s, const SrvStamps& srv) {
+  put_u64(s, static_cast<uint64_t>(srv.arrival_us));
+  put_u32(s, saturate_u32(srv.handler_us - srv.arrival_us));
+  put_u32(s, saturate_u32(srv.done_us - srv.handler_us));
+}
+
 std::string encode_meta(const RpcMeta& m) {
   std::string s;
   s.push_back(static_cast<char>(m.type));
@@ -73,17 +89,26 @@ std::string encode_meta(const RpcMeta& m) {
   // remain), so presence/absence are both wire-compatible — and the
   // streaming hot path never pays for it.  Layout: trace(24B), then
   // compress+checksum(6B), then batch streams(4B+), then stripe(24B),
-  // then qos(3B+), then rma(52B), then deadline(8B); each later group
-  // implies every earlier one.
-  const bool has_deadline = m.deadline_us != 0;
-  const bool has_rma =
-      m.rma_rkey != 0 || m.rma_resp_rkey != 0 || has_deadline;
-  const bool has_qos =
-      m.qos_priority != 0 || !m.qos_tenant.empty() || has_rma;
-  const bool has_stripe = m.stripe_id != 0 || has_qos;
-  const bool has_streams = !m.extra_streams.empty() || has_stripe;
-  const bool has_comp =
-      m.compress_type != 0 || m.has_checksum || has_streams;
+  // then qos(3B+), then rma(52B), then deadline(8B), then the server's
+  // phase stamps(16B); each later group implies every earlier one.
+  // The stamps ride every response, so where they are the ONLY active
+  // group they go alone, as a 16-byte tail (kSrvStampBytes): a plain
+  // response pays 16 bytes for them, not the 145 of the whole tail.
+  const bool has_srv = m.srv.arrival_us != 0;
+  bool has_deadline = m.deadline_us != 0;
+  bool has_rma = m.rma_rkey != 0 || m.rma_resp_rkey != 0 || has_deadline;
+  bool has_qos = m.qos_priority != 0 || !m.qos_tenant.empty() || has_rma;
+  bool has_stripe = m.stripe_id != 0 || has_qos;
+  bool has_streams = !m.extra_streams.empty() || has_stripe;
+  bool has_comp = m.compress_type != 0 || m.has_checksum || has_streams;
+  if (has_srv) {
+    if (m.trace_id == 0 && !has_comp) {
+      put_srv_stamps(&s, m.srv);
+      return s;
+    }
+    has_deadline = has_rma = has_qos = has_stripe = has_streams = has_comp =
+        true;
+  }
   if (m.trace_id != 0 || has_comp) {
     // tail-group 1 (trace): trace/span/parent ids, 24B.
     put_u64(&s, m.trace_id);
@@ -132,6 +157,11 @@ std::string encode_meta(const RpcMeta& m) {
                 // tail-group 7 (deadline): remaining budget µs, 8B
                 // (net/deadline.h).
                 put_u64(&s, m.deadline_us);
+                if (has_srv) {
+                  // tail-group 8 (srv_stamps): the server's phase
+                  // stamps, 16B.
+                  put_srv_stamps(&s, m.srv);
+                }
               }
             }
           }
@@ -140,6 +170,18 @@ std::string encode_meta(const RpcMeta& m) {
     }
   }
   return s;
+}
+
+// The stamps are untrusted like every wire value: an arrival the two
+// differences could overflow from reads as absent.
+void get_srv_stamps(const char* p, SrvStamps* srv) {
+  const uint64_t arrival = get_u64(p);
+  if (arrival == 0 || arrival > (1ull << 62)) {
+    return;
+  }
+  srv->arrival_us = static_cast<int64_t>(arrival);
+  srv->handler_us = srv->arrival_us + get_u32(p + 8);
+  srv->done_us = srv->handler_us + get_u32(p + 12);
 }
 
 bool decode_meta(const std::string& s, RpcMeta* m) {
@@ -175,6 +217,12 @@ bool decode_meta(const std::string& s, RpcMeta* m) {
   }
   m->error_text.assign(p, elen);
   p += elen;
+  if (end - p == static_cast<ptrdiff_t>(kSrvStampBytes)) {
+    // The server's phase stamps alone (no older encoder emits a tail
+    // under 24 bytes).
+    get_srv_stamps(p, &m->srv);
+    return true;
+  }
   if (end - p >= 24) {  // tail-group 1 (trace)
     m->trace_id = get_u64(p);
     m->span_id = get_u64(p + 8);
@@ -227,6 +275,12 @@ bool decode_meta(const std::string& s, RpcMeta* m) {
                 if (end - p >= 8) {  // tail-group 7 (deadline)
                   m->deadline_us = get_u64(p);
                   p += 8;
+                  if (end - p >=
+                      static_cast<ptrdiff_t>(kSrvStampBytes)) {
+                    // tail-group 8 (srv_stamps)
+                    get_srv_stamps(p, &m->srv);
+                    p += kSrvStampBytes;
+                  }
                 }
               } else {
                 // Previous-version frame (44B group, pre-rma_resp_off):
@@ -285,20 +339,19 @@ ParseError tstd_parse(IOBuf* source, InputMessage* out, Socket* sock) {
   if (!decode_meta(meta_bytes, &out->meta)) {
     return ParseError::kCorrupted;
   }
-  if (out->meta.deadline_us != 0 || capture::enabled()) {
-    // Anchor the relative budget to OUR clock at cut time: queueing
-    // (QoS lanes, dispatch backlog) then counts against it.  Unstamped
-    // traffic skips the clock read — unless traffic capture is on,
-    // which needs a parse-time arrival for every request so recorded
-    // queue time and inter-arrival gaps are honest.
-    out->arrival_us = monotonic_time_us();
-  }
   source->cutn(&out->payload, payload_len);
   if (out->meta.has_checksum &&
       crc32c(out->payload) != out->meta.checksum) {
     // The transport delivered different bytes than were sent: the
     // connection's framing can no longer be trusted.
     return ParseError::kCorrupted;
+  }
+  if (out->meta.type == RpcMeta::kRequest) {
+    // The request is cut and whole: every request's arrival, on OUR
+    // clock (InputMessage::arrival_us).  Queueing behind this point
+    // (QoS lanes, dispatch backlog) counts against a deadline's budget
+    // and in the method's queue time.
+    out->arrival_us = monotonic_time_us();
   }
   return ParseError::kOk;
 }

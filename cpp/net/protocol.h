@@ -15,6 +15,7 @@
 #include <vector>
 
 #include "base/iobuf.h"
+#include "net/wire_split.h"
 
 namespace trpc {
 
@@ -123,6 +124,14 @@ struct RpcMeta {
   // Seventh optional wire-tail group — zero (absent) when the caller
   // has no deadline, so unset traffic stays byte-identical.
   uint64_t deadline_us = 0;
+  // Server phase stamps (net/wire_split.h), on a kResponse only: the
+  // SERVER's monotonic clock when the request was whole, when its
+  // handler was entered and when the handler's done() ran.  All zero
+  // (absent) from a peer that predates them.  On the wire 16 bytes
+  // (arrival u64, then handler - arrival and done - handler as u32 us,
+  // saturating at 71 minutes): alone after error_text when no other
+  // tail group rides the frame, else as the eighth group.
+  SrvStamps srv;
   std::string method;
   std::string error_text;
 
@@ -156,6 +165,7 @@ struct RpcMeta {
     rma_resp_max = 0;
     rma_resp_off = 0;
     deadline_us = 0;
+    srv = {};
     method.clear();
     error_text.clear();
   }
@@ -165,10 +175,15 @@ struct InputMessage {
   RpcMeta meta;
   IOBuf payload;  // body (+ attachment tail per meta.attachment_size)
   SocketId socket = 0;
-  // Arrival clock of a deadline-stamped request, read at parse (cut)
-  // time: the server's absolute deadline is arrival_us + deadline_us,
-  // so time spent queued in a QoS lane counts against the budget.  0 on
-  // unstamped traffic — the hot path never reads the clock for it.
+  // Arrival clock of a kRequest: monotonic_time_us() when the request
+  // was cut from the connection and whole (tstd_parse, after the
+  // payload's checksum; a striped or one-sided request when its
+  // reassembly is complete).  The one anchor of everything that counts
+  // from arrival: the server's absolute deadline is arrival_us +
+  // deadline_us (so time queued in a QoS lane counts against the
+  // budget), the per-method rpc_server_<method>_queue_us, the stamps a
+  // response carries back, and capture's queue time.  0 on every other
+  // frame type, and on a message no parser cut.
   int64_t arrival_us = 0;
   // Protocol-private context (the reference subclasses InputMessageBase per
   // protocol; an opaque pointer is the condensed seam).  HTTP stores its

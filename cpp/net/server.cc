@@ -141,6 +141,48 @@ const Server::MethodProperty* Server::find_restful(
   return nullptr;
 }
 
+Server::MethodPhases::MethodPhases(const std::string& method) {
+  const std::string base = "rpc_server_" + method;
+  calls.expose(base + "_calls",
+               "tstd requests of " + method + " answered, whatever the "
+               "status; the divisor of its three _us phase sums");
+  queue_us.expose(base + "_queue_us",
+                  "us from a request of " + method + " being cut from its "
+                  "connection and whole to its handler being entered: "
+                  "QoS lane, dispatch backlog, admission, injected delay");
+  handler_us.expose(base + "_handler_us",
+                    "us from the handler of " + method + " being entered "
+                    "to its done() running (0 for a call answered before "
+                    "any handler: shed, rejected, failed)");
+  send_us.expose(base + "_send_us",
+                 "us from the done() of " + method + " being entered to "
+                 "the response being handed to the connection: the put "
+                 "into a one-sided window, the stripes, or the frame");
+}
+
+namespace {
+
+// One MethodPhases a method name in the process: a name in the variable
+// registry has one owner, and two Servers that register the same method
+// (a store on each of two ranks) are one series to whoever reads /vars.
+// Weak: the series goes when the last Server with the method does.
+std::shared_ptr<Server::MethodPhases> method_phases(
+    const std::string& method) {
+  static std::mutex* mu = new std::mutex();
+  static auto* by_name =
+      new std::map<std::string, std::weak_ptr<Server::MethodPhases>>();
+  std::lock_guard<std::mutex> g(*mu);
+  std::weak_ptr<Server::MethodPhases>& slot = (*by_name)[method];
+  std::shared_ptr<Server::MethodPhases> phases = slot.lock();
+  if (phases == nullptr) {
+    phases = std::make_shared<Server::MethodPhases>(method);
+    slot = phases;
+  }
+  return phases;
+}
+
+}  // namespace
+
 int Server::RegisterMethod(const std::string& full_name, Handler handler) {
   if (running()) {
     return -1;
@@ -150,6 +192,7 @@ int Server::RegisterMethod(const std::string& full_name, Handler handler) {
   prop.latency = std::make_shared<LatencyRecorder>();
   prop.latency->expose("rpc_server_" + full_name,
                        "server-side latency of " + full_name);
+  prop.phases = method_phases(full_name);
   methods_[full_name] = std::move(prop);
   return 0;
 }
@@ -1060,15 +1103,23 @@ void tstd_process_request(InputMessage&& msg) {
   cntl->call().sl_pool =
       srv != nullptr ? srv->session_data_pool() : nullptr;
   auto* response = new IOBuf();
+  // The first two of a request's four stamps (net/wire_split.h; the
+  // other two are read in done()).  start_us has ONE meaning: this
+  // function's entry, i.e. the dispatch fiber picked the request up
+  // (behind the QoS lane and the fiber spawn, in front of admission and
+  // any injected delay).  It anchors the method's LatencyRecorder and
+  // everything fed the same number (limiter, tenant governor, SLO):
+  // dispatch start to response handed off, as it always was.
+  // arrival_us is the parser's (InputMessage::arrival_us): the request
+  // whole; a message no parser stamped arrived when it was picked up.
   const int64_t start_us = monotonic_time_us();
+  const int64_t arrival_us = msg.arrival_us != 0 ? msg.arrival_us : start_us;
   // Deadline plane (net/deadline.h): anchor the wire's relative budget
-  // to the request's parse-time arrival clock, so QoS-lane queueing and
-  // dispatch backlog count against it.  A budget that already expired
-  // is shed below, BEFORE it can consume an admission slot or a
-  // handler.
+  // to the request's arrival clock, so QoS-lane queueing and dispatch
+  // backlog count against it.  A budget that already expired is shed
+  // below, BEFORE it can consume an admission slot or a handler.
   int64_t deadline_abs = 0;
-  if (msg.meta.deadline_us != 0 && msg.arrival_us != 0 &&
-      deadline_wire_enabled()) {
+  if (msg.meta.deadline_us != 0 && deadline_wire_enabled()) {
     // Gated on the SAME flag that controls stamping: trpc_deadline_wire
     // off is the operator kill-switch for the whole plane on this node
     // — incoming stamps from flag-on peers are then ignored too, as the
@@ -1081,7 +1132,7 @@ void tstd_process_request(InputMessage&& msg) {
     const uint64_t budget = msg.meta.deadline_us < kMaxBudgetUs
                                 ? msg.meta.deadline_us
                                 : kMaxBudgetUs;
-    deadline_abs = msg.arrival_us + static_cast<int64_t>(budget);
+    deadline_abs = arrival_us + static_cast<int64_t>(budget);
     cntl->set_deadline_abs_us(deadline_abs);
   }
   const bool deadline_dead = deadline_abs != 0 && start_us >= deadline_abs;
@@ -1113,6 +1164,8 @@ void tstd_process_request(InputMessage&& msg) {
       (srv != nullptr && srv->running()) ? srv->find_method(method) : nullptr;
   std::shared_ptr<LatencyRecorder> lat =
       prop != nullptr ? prop->latency : nullptr;
+  std::shared_ptr<Server::MethodPhases> phases =
+      prop != nullptr ? prop->phases : nullptr;
   std::shared_ptr<ConcurrencyLimiter> limiter =
       prop != nullptr ? prop->limiter : nullptr;
   // Per-tenant QoS admission (net/qos.h): runs FIRST so a shed request
@@ -1152,20 +1205,25 @@ void tstd_process_request(InputMessage&& msg) {
   // run done() too, so the recorded error mix covers kEOverloaded /
   // kEDeadlineExpired sheds, not just handler outcomes.
   const bool cap_on = capture::enabled();
-  const int64_t cap_arrival =
-      msg.arrival_us != 0 ? msg.arrival_us : start_us;
   const uint64_t cap_req_bytes = msg.payload.size();
   const uint32_t cap_budget = static_cast<uint32_t>(
       std::min<uint64_t>(msg.meta.deadline_us, 0xffffffffull));
   const uint64_t cap_trace = msg.meta.trace_id;
   const uint64_t cap_pspan = msg.meta.span_id;
-  Closure done = [socket_id, cid, cntl, response, start_us, srv, lat,
-                  limiter, gov, slo, tenant_entry, span, cap_on,
-                  cap_arrival, cap_req_bytes, cap_budget, cap_trace,
-                  cap_pspan] {
+  Closure done = [socket_id, cid, cntl, response, start_us, arrival_us,
+                  srv, lat, phases, limiter, gov, slo, tenant_entry, span,
+                  cap_on, cap_req_bytes, cap_budget, cap_trace, cap_pspan] {
+    // Third stamp.  A call answered before any handler (shed, rejected,
+    // failed on the way) entered none: its handler time is 0 and all of
+    // arrival -> here is queue.
+    const int64_t done_us = monotonic_time_us();
+    const int64_t handler_us = cntl->call().srv.handler_us != 0
+                                   ? cntl->call().srv.handler_us
+                                   : done_us;
     RpcMeta meta;
     meta.type = RpcMeta::kResponse;
     meta.correlation_id = cid;
+    meta.srv = {arrival_us, handler_us, done_us};
     meta.error_code = cntl->error_code();
     meta.error_text = cntl->error_text();
     meta.stream_id = cntl->call().accepted_stream;  // acceptance piggyback
@@ -1226,7 +1284,16 @@ void tstd_process_request(InputMessage&& msg) {
       stripe_frame_send(socket_id, std::move(meta),
                         std::move(*response));
     }
-    const int64_t latency_us = monotonic_time_us() - start_us;
+    // Fourth stamp: the send call returned (the one-sided put is
+    // written, the stripes or the frame are the connection's).
+    const int64_t sent_us = monotonic_time_us();
+    const int64_t latency_us = sent_us - start_us;
+    if (phases != nullptr) {
+      phases->calls << 1;
+      phases->queue_us << handler_us - arrival_us;
+      phases->handler_us << done_us - handler_us;
+      phases->send_us << sent_us - done_us;
+    }
     if (limiter != nullptr) {
       limiter->on_response(latency_us, cntl->Failed());
     }
@@ -1245,16 +1312,16 @@ void tstd_process_request(InputMessage&& msg) {
     }
     if (cap_on && capture::enabled()) {
       capture::Sample cs;
-      cs.arrival_mono_us = cap_arrival;
+      cs.arrival_mono_us = arrival_us;
       cs.trace_id = cap_trace;
       cs.parent_span_id = cap_pspan;
       cs.request_bytes = cap_req_bytes;
       cs.response_bytes = response_bytes;
       cs.status = cntl->error_code();
       cs.queue_us = static_cast<uint32_t>(
-          std::max<int64_t>(0, start_us - cap_arrival));
-      cs.handler_us =
-          static_cast<uint32_t>(std::max<int64_t>(0, latency_us));
+          std::max<int64_t>(0, handler_us - arrival_us));
+      cs.handler_us = static_cast<uint32_t>(
+          std::max<int64_t>(0, sent_us - handler_us));
       cs.deadline_budget_us = cap_budget;
       cs.priority = cntl->qos_priority();
       cs.method = cntl->method();
@@ -1455,10 +1522,16 @@ void tstd_process_request(InputMessage&& msg) {
     UsercodePool::instance()->run(
         [handler, cntl, request = std::move(request), response,
          done = std::move(done)]() mutable {
+          // Second stamp, on the backup pthread: the pool's own queue
+          // is queue too.
+          cntl->call().srv.handler_us = monotonic_time_us();
           (*handler)(cntl, request, response, std::move(done));
         });
     return;
   }
+  // Second stamp: everything up to here (QoS lane, dispatch backlog,
+  // admission, an injected svr_delay) was queue.
+  cntl->call().srv.handler_us = monotonic_time_us();
   (*handler)(cntl, request, response, std::move(done));
 }
 

@@ -26,6 +26,7 @@
 #include "net/qos.h"
 #include "net/socket.h"
 #include "stat/latency_recorder.h"
+#include "stat/reducer.h"
 
 namespace trpc {
 
@@ -47,10 +48,27 @@ class Server {
 
   // Per-method properties (parity: MethodProperty + MethodStatus,
   // server.h:399 / details/method_status.h — auto-created qps/latency vars).
+  // Where the calls of one method spent their time inside this process,
+  // from the four stamps of a tstd request (server.cc,
+  // tstd_process_request): always-on, folded once a call at the end of
+  // its done(), so a delta over any window holds whole calls only.
+  // Exposed as rpc_server_<method>_{calls,queue_us,handler_us,send_us};
+  // one set a method name in the process, shared by every Server that
+  // registers it.
+  struct MethodPhases {
+    explicit MethodPhases(const std::string& method);
+    Adder calls;       // requests answered, whatever the status
+    Adder queue_us;    // request whole -> handler entered
+    Adder handler_us;  // handler entered -> its done() ran (0: no handler)
+    Adder send_us;     // done() entered -> response handed to the connection
+  };
   struct MethodProperty {
     Handler handler;
+    // Dispatch start (entry of tstd_process_request, or the adaptor's)
+    // to response handed off: the limiter's input, unchanged by phases.
     std::shared_ptr<LatencyRecorder> latency;
     std::shared_ptr<ConcurrencyLimiter> limiter;  // null = unlimited
+    std::shared_ptr<MethodPhases> phases;
   };
 
   // Admission control for one method: "" unlimited, "<N>" constant, "auto"

@@ -1,5 +1,6 @@
 #include "net/socket.h"
 
+#include <arpa/inet.h>
 #include <errno.h>
 #include <fcntl.h>
 #include <netinet/tcp.h>
@@ -78,6 +79,7 @@ void Socket::reset_for_reuse(const Options& opts) {
                        (opts.transport != nullptr && !opts.transport->fd_based()),
                    std::memory_order_relaxed);
   nevent_.store(0, std::memory_order_relaxed);
+  peer_clock_.store(-1, std::memory_order_relaxed);    // pre-publication
   on_readable_ = opts.on_readable;
   ctx_ = opts.ctx;
   read_buf_.clear();
@@ -94,6 +96,28 @@ void Socket::reset_for_reuse(const Options& opts) {
   parse_state_owner = nullptr;
   auth_ok.store(false, std::memory_order_relaxed);    // pre-publication
   wq_head_.store(nullptr, std::memory_order_relaxed);  // pre-publication
+}
+
+bool Socket::peer_shares_clock() {
+  int8_t known = peer_clock_.load(std::memory_order_relaxed);
+  if (known >= 0) {
+    return known == 1;
+  }
+  bool same = false;
+  if (mode_ == SocketMode::kShm || remote_.is_unix() ||
+      (ntohl(remote_.ip) >> 24) == 127) {  // the ring, a path, loopback
+    same = true;
+  } else if (fd_ >= 0 && connected()) {
+    sockaddr_in local{};
+    socklen_t len = sizeof(local);
+    same = getsockname(fd_, reinterpret_cast<sockaddr*>(&local), &len) == 0 &&
+           local.sin_family == AF_INET &&
+           local.sin_addr.s_addr == remote_.ip;
+  } else {
+    return false;  // not connected yet: decide when it is
+  }
+  peer_clock_.store(same ? 1 : 0, std::memory_order_relaxed);
+  return same;
 }
 
 Socket* Socket::Address(SocketId id) {

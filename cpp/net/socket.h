@@ -98,6 +98,13 @@ class Socket {
   SocketId id() const;
   const EndPoint& remote() const { return remote_; }
   Transport* transport() const { return transport_; }
+  // True where the two ends of this connection read one CLOCK_MONOTONIC,
+  // from what the connection itself shows: it is the shm ring (one host
+  // by construction), a unix socket, or a tcp connection whose peer
+  // address is this host's own (loopback, or the address this end is
+  // bound to).  Decided once per connected generation.  What lets the
+  // caller cut a call's wire time into its two legs (net/wire_split.h).
+  bool peer_shares_clock();
   IOBuf& read_buf() { return read_buf_; }
   // Protocol index pinned after first successful parse (-1 = unknown).
   int pinned_protocol = -1;
@@ -182,6 +189,9 @@ class Socket {
   Transport* transport_ = nullptr;
   std::atomic<bool> failed_{false};
   std::atomic<bool> connected_{false};
+  // peer_shares_clock's memo: -1 undecided, else the answer.  Relaxed:
+  // every reader that finds -1 computes the same value.
+  std::atomic<int8_t> peer_clock_{-1};
   std::atomic<int> nevent_{0};
   void (*on_readable_)(SocketId, void*) = nullptr;
   void* ctx_ = nullptr;

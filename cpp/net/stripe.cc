@@ -259,6 +259,9 @@ void dispatch_entry(const std::shared_ptr<StripeEntry>& e) {
       auto arrival = std::make_shared<StripeArrival>();
       arrival->rails = e->rails;
       m.ctx = std::move(arrival);
+      // A striped request is whole when its last chunk has landed
+      // (InputMessage::arrival_us).
+      m.arrival_us = monotonic_time_us();
     }
   }
   // Per-chunk CRCs were verified frame-by-frame at parse; the head's CRC

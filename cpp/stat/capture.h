@@ -51,8 +51,8 @@ struct Sample {
   uint64_t request_bytes = 0;
   uint64_t response_bytes = 0;
   int32_t status = 0;            // 0 ok, else kE* error code
-  uint32_t queue_us = 0;         // parse -> dispatch
-  uint32_t handler_us = 0;       // dispatch -> response handed off
+  uint32_t queue_us = 0;         // request whole -> handler entered
+  uint32_t handler_us = 0;       // handler entered -> response handed off
   uint32_t deadline_budget_us = 0;  // wire tail-group 7 budget (0 = none)
   uint8_t priority = 0;          // tail-group 5
   std::string method;

@@ -161,10 +161,13 @@ TEST_CASE(wire_roundtrip_and_unset_byte_identity) {
     uint32_t meta_len = 0;
     memcpy(&meta_len, hdr + 4, 4);
     EXPECT_EQ(meta_len, 43u);  // no tail groups emitted
+    const int64_t before = monotonic_time_us();
     InputMessage msg;
     EXPECT(p.parse(&frame, &msg, nullptr) == ParseError::kOk);
     EXPECT_EQ(msg.meta.deadline_us, 0u);
-    EXPECT_EQ(msg.arrival_us, 0);  // unstamped: no clock read either
+    // Every request's arrival is read at the cut, budget or none: the
+    // server's phase stamps count from it (net/wire_split.h).
+    EXPECT(msg.arrival_us >= before);
   }
   // Deadline-only meta: groups 1..7 ride (121B tail), the budget
   // roundtrips exactly, and arrival is stamped at cut.

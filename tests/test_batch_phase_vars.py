@@ -23,7 +23,11 @@ PHASES = ("batch_queue_us", "batch_wire_us", "batch_land_us",
           "batch_ready_us")
 STAGING = ("batch_staged_calls", "batch_stage_us", "batch_stage_fetch_us",
            "batch_stage_fetch_bytes")
-COUNTERS = PHASES + STAGING + (
+# The wire phase cut at the server's stamps (tests/test_server_phase_vars.py
+# holds them to their meaning; here they ride along as whole-call sums).
+SPLIT = ("batch_split_calls", "batch_srv_queue_us", "batch_srv_handler_us",
+         "batch_net_us", "batch_leg_calls", "batch_req_leg_us")
+COUNTERS = PHASES + STAGING + SPLIT + (
     "batch_calls_polled", "batch_calls_failed", "batch_resp_bytes",
     "batch_land_copy_bytes", "batch_submits", "batch_submit_us")
 
@@ -90,6 +94,12 @@ def test_every_polled_call_counts_once_and_the_phases_fit_its_interval(echo):
     total = sum(moved[phase] for phase in PHASES)
     assert 0 < total <= n * (interval_us + 1)
     assert moved["batch_wire_us"] > 0
+    # Every one of them came back with the server's stamps, and the
+    # server's share and the two legs are inside the wire phase.
+    assert moved["batch_split_calls"] == moved["batch_leg_calls"] == n
+    assert (moved["batch_srv_queue_us"] + moved["batch_srv_handler_us"]
+            + moved["batch_net_us"] == moved["batch_wire_us"])
+    assert 0 <= moved["batch_req_leg_us"] <= moved["batch_net_us"]
     assert moved["batch_submits"] == 1
     assert 0 <= moved["batch_submit_us"] <= interval_us + 1
     assert moved["batch_resp_bytes"] == n * 4096
@@ -211,8 +221,9 @@ def test_a_call_that_times_out_counts_as_failed_and_in_no_sum(echo):
     assert {c.status for c in done} == {errno.ETIMEDOUT}
     assert moved["batch_calls_failed"] == n
     assert moved["batch_submits"] == 1
-    for name in PHASES + STAGING + ("batch_calls_polled", "batch_resp_bytes",
-                                    "batch_land_copy_bytes"):
+    for name in PHASES + STAGING + SPLIT + (
+            "batch_calls_polled", "batch_resp_bytes",
+            "batch_land_copy_bytes"):
         assert moved[name] == 0, name
     time.sleep(0.45)  # the parked handlers answer into a live server
 
