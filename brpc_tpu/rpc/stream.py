@@ -37,6 +37,16 @@ counters `stream_capi_write_copy_bytes` / `stream_capi_read_copy_bytes`
 say so.  `write_array` / `read_array` are the same two calls with the
 device on either side.
 
+Where a wide chunk travels: over the runtime's large-message threshold
+(`trpc_stripe_threshold`, 2 MB) on a connection with a one-sided session
+(the shm ring), the chunk's bytes are put straight into the peer's
+receive window and only a descriptor is framed, in the chunk's place in
+the stream's order; the reader's one copy is then out of that window,
+over the connection's rails.  Under the threshold, over tcp, or when the
+window is full, the chunk is one in-band frame as before.  Nothing at
+this surface changes; `stream_one_sided_bytes` counts the bytes that went
+that way.
+
 Thousands of logical streams multiplex over ONE connection: a StreamId
 is a runtime handle, not a socket, which is how the inference front door
 (brpc_tpu/rpc/infer.py) holds 100k+ token streams under a 20k fd cap.
@@ -163,7 +173,9 @@ class Stream:
         """One ordered chunk in a uint8 block of its own length taken
         from the recycled landing blocks (`zerocopy.landing_block`: pages
         already faulted in, given back when the array dies): where a wide
-        chunk lands on its way to the device."""
+        chunk lands on its way to the device.  Still one copy: a chunk that
+        came through the one-sided window is copied out of its span over
+        the connection's rails, and the span goes back to the window."""
         block = zerocopy.landing_block(self.next_len(timeout_ms))
         self.read_into(block, timeout_ms=0)
         return block
@@ -187,8 +199,11 @@ class Stream:
         `zerocopy.PendingView`, whose transfer is waited for here.  From
         WRITE_BY_REFERENCE_FROM bytes on, the chunk is `data`'s own
         memory, kept alive until the frame has been written and not to be
-        changed until then; below it the bytes are copied.  Raises on a
-        closed stream or dead connection (EPIPE/EINVAL as RpcError)."""
+        changed until then; below it the bytes are copied.  A chunk over
+        the large-message threshold on the shm ring is put into the peer's
+        one-sided receive window from that memory before `write` returns,
+        and its frame carries the descriptor alone.  Raises on a closed
+        stream or dead connection (EPIPE/EINVAL as RpcError)."""
         if self._handle is None:
             raise StreamClosedError(0)
         if isinstance(data, zerocopy.PendingView):

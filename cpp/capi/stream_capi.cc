@@ -22,8 +22,10 @@
 // + one chunk, and that is also the most this queue ever holds.
 //
 // What crosses the boundary by copy is counted: one copy out of the frame
-// on a read (`stream_capi_read_copy_bytes`), and on a write one copy in
-// for trpc_stream_write (`stream_capi_write_copy_bytes`) and none for
+// (or, for a chunk that came one-sided, out of the connection's receive
+// window: rma_land, over the rails) on a read
+// (`stream_capi_read_copy_bytes`), and on a write one copy in for
+// trpc_stream_write (`stream_capi_write_copy_bytes`) and none for
 // trpc_stream_write_user, which wraps the caller's memory.
 #include <chrono>
 #include <condition_variable>
@@ -38,6 +40,7 @@
 #include "fiber/fiber.h"
 #include "net/channel.h"
 #include "net/controller.h"
+#include "net/rma.h"
 #include "net/server.h"
 #include "net/stream.h"
 #include "stat/reducer.h"
@@ -56,6 +59,7 @@ struct CStream {
   std::condition_variable cv;
   std::deque<IOBuf> chunks;
   bool closed = false;
+  bool destroyed = false;  // the handle is gone: nobody will read
 };
 
 struct StreamCapiVars {
@@ -88,6 +92,9 @@ StreamOptions options_for(const CStreamPtr& cs, int64_t window_bytes) {
   opts.on_message = [cs](StreamId, IOBuf&& chunk) {
     {
       std::lock_guard<std::mutex> g(cs->mu);
+      if (cs->destroyed) {
+        return;  // left with the consume fiber, which drops it
+      }
       cs->chunks.push_back(std::move(chunk));
     }
     cs->cv.notify_all();
@@ -185,11 +192,12 @@ void* trpc_call_stream_accept(void* call_handle, int64_t window_bytes) {
   return new CStreamPtr(std::move(cs));
 }
 
-// Blocking read of ONE chunk, copied once, out of the frame it arrived in
-// into `buf`: returns the chunk's length (always <= `cap` — the chunk is
-// copied whole or not at all), -1 when the stream is closed and drained,
-// -2 on timeout (timeout_ms < 0 waits forever), -3 when the next chunk is
-// LARGER than `cap`.  A -3 chunk stays queued and nothing is consumed:
+// Blocking read of ONE chunk, copied once, out of the frame (or the window
+// span) it arrived in into `buf`: returns the chunk's length (always <=
+// `cap` — the chunk is copied whole or not at all), -1 when the stream is
+// closed and drained, -2 on timeout (timeout_ms < 0 waits forever), -3
+// when the next chunk is LARGER than `cap`.  A -3 chunk stays queued and
+// nothing is consumed:
 // query trpc_stream_next_len and retry with a buffer that fits — silent
 // truncation would desynchronize framed readers (e.g. fixed-size
 // TokenRecord streams) without any error.  The chunk's bytes go back to
@@ -211,7 +219,10 @@ long trpc_stream_read(void* h, char* buf, size_t cap, int64_t timeout_ms) {
   g.unlock();
   const size_t n = chunk.size();
   if (buf != nullptr && n > 0) {
-    chunk.copy_to(buf, n);
+    // As a unary response lands (batch_capi.cc): a chunk that came as a
+    // one-sided window span is copied out over the connection's rails,
+    // anything else by the one copy_to.
+    rma_land(chunk, buf, n);
     g_vars.read_copy_bytes << static_cast<int64_t>(n);
   }
   chunk.clear();
@@ -276,12 +287,22 @@ int trpc_stream_close(void* h) {
 
 // Close (if still open) and free the handle.  The stream's callbacks
 // hold their own reference, so a consume batch mid-delivery finishes
-// against live memory.
+// against live memory: the queue outlives the handle until the stream's
+// slot is taken again, so what lies in it unread is dropped here, not
+// then (a chunk that came one-sided holds slots of the connection's
+// receive window until it is dropped).
 void trpc_stream_destroy(void* h) {
   if (h == nullptr) {
     return;
   }
   trpc_stream_close(h);
+  std::deque<IOBuf> unread;
+  {
+    const CStreamPtr& cs = of(h);
+    std::lock_guard<std::mutex> g(cs->mu);
+    cs->destroyed = true;
+    unread.swap(cs->chunks);
+  }
   delete static_cast<CStreamPtr*>(h);
 }
 

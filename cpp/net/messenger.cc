@@ -250,8 +250,17 @@ size_t cut_and_dispatch(Socket* s, SocketId id) {
         if (msg->meta.type == RpcMeta::kStreamFrame) {
           // Stream frames keep per-connection arrival order: handled inline
           // (the per-stream ExecutionQueue serializes the user callback).
+          // A frame whose body went one-sided (net/rma.h) is resolved
+          // here too, so it takes its place in that order with its body
+          // in hand.  A failed resolve drops the chunk, and the stream
+          // with it: a stream cannot time ONE chunk out and keep its
+          // order.
           batch.flush();
-          stream_on_frame(std::move(*msg));
+          if (msg->meta.rma_rkey != 0 && !rma_resolve(msg, s)) {
+            stream_on_chunk_lost(msg->meta.stream_id);
+          } else {
+            stream_on_frame(std::move(*msg));
+          }
           free_input_message(msg);
           continue;
         }

@@ -99,8 +99,9 @@ struct RpcMeta {
   // never pays for it.
   uint8_t qos_priority = 0;
   std::string qos_tenant;
-  // One-sided RMA (net/rma.h).  On a control frame (kRequest/kResponse
-  // with rma_rkey != 0 and an EMPTY payload): the body landed
+  // One-sided RMA (net/rma.h).  On a control frame (kRequest/kResponse,
+  // or a kStreamFrame of kStreamData whose correlation_id names the
+  // transfer, with rma_rkey != 0 and an EMPTY payload): the body landed
   // out-of-band — rma_len bytes written by the sender into the named
   // registered region at rma_off of its data area (kRmaDirectOff = the
   // region's own data start, completion bitmap in the region header),

@@ -1226,7 +1226,14 @@ int rma_try_send(SocketId primary, RpcMeta* meta, IOBuf* body,
                  uint64_t target_rkey, uint64_t target_max,
                  uint64_t target_off, const DeadlineToken& tok) {
   const uint64_t total = body->size();
-  if (meta->stream_id != 0 || !stripe_eligible(total)) {
+  // A stream's DATA frame is a large body like any other: StreamWrite
+  // writes the control frame where the in-band frame would have gone, so
+  // the stream's order holds, and names the transfer in correlation_id
+  // (net/stream.cc).  A request or response that offers or accepts a
+  // stream stays in band.
+  const bool stream_data = meta->type == RpcMeta::kStreamFrame &&
+                           meta->stream_flags == RpcMeta::kStreamData;
+  if ((meta->stream_id != 0 && !stream_data) || !stripe_eligible(total)) {
     return 1;
   }
   SocketRef s(Socket::Address(primary));

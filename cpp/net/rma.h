@@ -168,7 +168,9 @@ uint64_t rma_landing_rkey(uint64_t cid, uint64_t* max_out,
 // the server may then put the response straight into the caller's buffer.
 void rma_advertise_response(SocketId sid, uint64_t cid, RpcMeta* meta);
 
-// Attempts the one-sided path for meta+body on `primary`.
+// Attempts the one-sided path for meta+body on `primary`: a request, a
+// response, or a stream's DATA frame (net/stream.cc; its correlation_id
+// is the transfer's token, as a call's is).
 //   0  sent: body consumed, chunks written into the peer region, control
 //      frame queued on the primary socket.
 //   1  not applicable (below threshold, no session, descriptor path
@@ -197,8 +199,9 @@ int rma_try_send(SocketId primary, RpcMeta* meta, IOBuf* body,
 // release-fenced completion bitmap and per-chunk CRCs, and swaps the
 // out-of-band payload into msg->payload (window spans wrap zero-copy
 // with a slot-freeing deleter; direct transfers wrap the caller's own
-// buffer).  False: drop the message whole — the call times out, no
-// partial bytes ever dispatch.
+// buffer).  False: drop the message whole — the call times out (the
+// messenger closes a stream whose chunk this was), no partial bytes ever
+// dispatch.
 bool rma_resolve(InputMessage* msg, Socket* sock);
 
 // Rails configured for a mode (trpc_shm_rails / trpc_ici_rails).

@@ -12,7 +12,9 @@
 #include "fiber/execution_queue.h"
 #include "fiber/fiber.h"
 #include "net/protocol.h"
+#include "net/rma.h"
 #include "net/socket.h"
+#include "net/stripe.h"
 #include "stat/reducer.h"
 
 namespace trpc {
@@ -26,6 +28,7 @@ namespace {
 struct StreamVars {
   Adder chunks_written;
   Adder bytes_written;
+  Adder one_sided_bytes;
   Adder chunks_consumed;
   Adder bytes_consumed;
   Adder credit_wait_us;
@@ -37,6 +40,11 @@ struct StreamVars {
                           "StreamWrite");
     bytes_written.expose("stream_bytes_written",
                          "payload bytes of stream_chunks_written");
+    one_sided_bytes.expose("stream_one_sided_bytes",
+                           "the part of stream_bytes_written whose chunk "
+                           "was put into the peer's receive window "
+                           "(net/rma.h), its frame carrying the descriptor "
+                           "alone");
     chunks_consumed.expose("stream_chunks_consumed",
                            "stream chunks whose bytes the consumer gave "
                            "back to the writer's window");
@@ -75,9 +83,16 @@ struct StreamMeta {
   Event established_ev;               // value flips 0→1 when peer_sid set
 
   StreamOptions opts;
+  // The call that opened the stream asked for checksums: every data frame
+  // carries one (over the frame's payload, or per chunk of a one-sided
+  // transfer).
+  bool checksum = false;
 
   // Sender credit (bytes we may still send before more ACKs).
   std::atomic<int64_t> send_window{0};
+  // Data frames written so far: makes each one's transfer token.
+  // Relaxed: uniqueness only.
+  std::atomic<uint64_t> tx_seq{0};
   Event window_ev;  // bumped on every ACK / close
 
   // Receiver: consumed-but-unacked bytes; ACK when above half window.
@@ -183,12 +198,7 @@ void maybe_send_ack(StreamMeta* m) {
   ack.stream_flags = RpcMeta::kStreamAck;
   ack.stream_id = peer;
   ack.ack_bytes = static_cast<uint64_t>(unacked);
-  IOBuf frame;
-  tstd_pack(&frame, ack, IOBuf());
-  SocketRef s(Socket::Address(m->sock));
-  if (s) {
-    s->Write(std::move(frame));
-  }
+  stripe_frame_send(m->sock, std::move(ack), IOBuf());  // best effort
 }
 
 // The consumer has used `bytes` of one chunk: they leave the unread count
@@ -234,7 +244,7 @@ int consume_handler(void* meta, IOBuf** chunks, size_t n) {
   return 0;
 }
 
-StreamId new_stream(const StreamOptions& opts) {
+StreamId new_stream(const StreamOptions& opts, const Controller* cntl) {
   // First stream in the process arms the socket-failure observer so
   // connection death reaches every bound stream (closes the wedge where a
   // reader with no pending write never learns the peer died).
@@ -266,11 +276,13 @@ StreamId new_stream(const StreamOptions& opts) {
   }
   m->slot = slot;
   m->opts = opts;
+  m->checksum = cntl->checksum_enabled();
   m->sock = 0;
   m->peer_sid.store(0, std::memory_order_relaxed);
   m->established_ev.value.store(0, std::memory_order_relaxed);
   m->send_window.store(opts.window_bytes, std::memory_order_relaxed);
   m->window_ev.value.store(0, std::memory_order_relaxed);
+  m->tx_seq.store(0, std::memory_order_relaxed);
   m->unacked.store(0, std::memory_order_relaxed);
   m->unread.store(0, std::memory_order_relaxed);
   m->unread_high_water.store(0, std::memory_order_relaxed);
@@ -289,6 +301,19 @@ StreamId new_stream(const StreamOptions& opts) {
   return m->id();
 }
 
+// Best-effort CLOSE frame to the peer's end.
+void send_close(StreamMeta* m) {
+  const uint64_t peer = m->peer_sid.load(std::memory_order_acquire);
+  if (peer == 0) {
+    return;
+  }
+  RpcMeta meta;
+  meta.type = RpcMeta::kStreamFrame;
+  meta.stream_flags = RpcMeta::kStreamClose;
+  meta.stream_id = peer;
+  stripe_frame_send(m->sock, std::move(meta), IOBuf());
+}
+
 void mark_closed(StreamMeta* m) {
   if (m->closed.exchange(true, std::memory_order_acq_rel)) {
     return;
@@ -305,10 +330,39 @@ void mark_closed(StreamMeta* m) {
   }
 }
 
+// Closes incarnation `sid` of `m` behind the chunks already queued: the
+// close rides the consume queue as a sentinel, under the meta lock.  The
+// version bump and the queue's stop happen under this same lock, so a
+// stale sid (a concurrent StreamClose and the slot's reuse since the
+// caller's stream_of) cannot close the NEXT incarnation at birth; a
+// sentinel that lands anyway drains against the old incarnation before
+// new_stream resets state.
+void close_in_order(StreamMeta* m, StreamId sid) {
+  m->lock();
+  const bool ver_ok = m->version.load(std::memory_order_relaxed) ==
+                      static_cast<uint32_t>(sid >> 32);
+  const bool queued = ver_ok && m->consume_q != nullptr &&
+                      m->consume_q->execute(nullptr) == 0;
+  m->unlock();
+  if (ver_ok && !queued) {
+    mark_closed(m);
+  }
+}
+
+// A data frame's transfer token (it rides the frame's correlation_id,
+// which a stream frame does not otherwise use): the receiver's stream id
+// mixed with the frame's sequence number on its stream, so that no two
+// transfers on a connection share one, and a put that comes late into a
+// window span since recycled fails rma_resolve's compare of the span's
+// token with its control frame's.
+uint64_t transfer_token(uint64_t peer_sid, uint64_t seq) {
+  return (peer_sid * 0x9E3779B97F4A7C15ull) ^ seq;
+}
+
 }  // namespace
 
 int StreamCreate(StreamId* out, Controller* cntl, const StreamOptions& opts) {
-  const StreamId sid = new_stream(opts);
+  const StreamId sid = new_stream(opts, cntl);
   if (sid == 0) {
     return ENOMEM;
   }
@@ -322,7 +376,7 @@ namespace {
 // Accepts ONE offered (peer_sid, peer_window); returns the local id.
 StreamId accept_one(Controller* cntl, const StreamOptions& opts,
                     uint64_t peer_sid, uint64_t peer_window) {
-  const StreamId sid = new_stream(opts);
+  const StreamId sid = new_stream(opts, cntl);
   if (sid == 0) {
     return 0;
   }
@@ -361,7 +415,7 @@ int StreamCreateBatch(std::vector<StreamId>* out, int count,
   }
   out->clear();
   for (int i = 0; i < count; ++i) {
-    const StreamId sid = new_stream(opts);
+    const StreamId sid = new_stream(opts, cntl);
     if (sid == 0) {
       for (StreamId created : *out) {
         StreamClose(created);
@@ -465,15 +519,32 @@ int StreamWrite(StreamId id, IOBuf&& data) {
   meta.type = RpcMeta::kStreamFrame;
   meta.stream_flags = RpcMeta::kStreamData;
   meta.stream_id = m->peer_sid.load(std::memory_order_acquire);
-  IOBuf frame;
-  tstd_pack(&frame, meta, data);
-  SocketRef s(Socket::Address(m->sock));
-  if (!s || s->Write(std::move(frame)) != 0) {
+  meta.correlation_id = transfer_token(
+      meta.stream_id, m->tx_seq.fetch_add(1, std::memory_order_relaxed));
+  meta.has_checksum = m->checksum;
+  // A chunk over the large-message threshold, on a connection with a
+  // one-sided session, is put into the peer's receive window and only its
+  // descriptor is framed (net/rma.h: the path a unary body takes, chosen
+  // by the same rule).  The control frame is written when the put is
+  // done, where the in-band frame would have been written, so the
+  // stream's DATA, ACK and CLOSE frames keep the socket's order whichever
+  // way each body went.  1: not this time (no session, under the
+  // threshold, window full): the chunk is framed whole, as a unary body
+  // is.
+  int one_sided = rma_try_send(m->sock, &meta, &data, 0, 0);
+  if (one_sided > 0 &&
+      stripe_frame_send(m->sock, std::move(meta), std::move(data)) != 0) {
+    one_sided = -1;
+  }
+  if (one_sided < 0) {
     mark_closed(m);
     return EPIPE;
   }
   g_vars.chunks_written << 1;
   g_vars.bytes_written << bytes;
+  if (one_sided == 0) {
+    g_vars.one_sided_bytes << bytes;
+  }
   return 0;
 }
 
@@ -491,19 +562,8 @@ int StreamClose(StreamId id) {
   if (m == nullptr) {
     return EINVAL;
   }
-  // Best-effort CLOSE to the peer.
-  const uint64_t peer = m->peer_sid.load(std::memory_order_acquire);
-  if (peer != 0 && !m->closed.load(std::memory_order_acquire)) {
-    RpcMeta meta;
-    meta.type = RpcMeta::kStreamFrame;
-    meta.stream_flags = RpcMeta::kStreamClose;
-    meta.stream_id = peer;
-    IOBuf frame;
-    tstd_pack(&frame, meta, IOBuf());
-    SocketRef s(Socket::Address(m->sock));
-    if (s) {
-      s->Write(std::move(frame));
-    }
+  if (!m->closed.load(std::memory_order_acquire)) {
+    send_close(m);
   }
   mark_closed(m);
   // Destroy the local id under the meta lock: frame submission validates
@@ -589,24 +649,25 @@ void stream_on_frame(InputMessage&& msg) {
       m->window_ev.value.fetch_add(1, std::memory_order_release);
       m->window_ev.wake_all();
       break;
-    case RpcMeta::kStreamClose: {
+    case RpcMeta::kStreamClose:
       // Ordered close: deliver queued data first via the sentinel.
-      m->lock();
-      const bool ver_ok =
-          m->version.load(std::memory_order_relaxed) ==
-          static_cast<uint32_t>(msg.meta.stream_id >> 32);
-      const bool queued =
-          ver_ok && m->consume_q != nullptr &&
-          m->consume_q->execute(nullptr) == 0;
-      m->unlock();
-      if (ver_ok && !queued) {
-        mark_closed(m);
-      }
+      close_in_order(m, msg.meta.stream_id);
       break;
-    }
     default:
       break;
   }
+}
+
+void stream_on_chunk_lost(uint64_t stream_id) {
+  StreamMeta* m = stream_of(stream_id);
+  if (m == nullptr) {
+    return;
+  }
+  // The writer is told (its next write fails with EPIPE), and this end
+  // closes behind the chunks that did arrive: its reader drains them and
+  // then reads the close, never the chunk after the lost one.
+  send_close(m);
+  close_in_order(m, stream_id);
 }
 
 void stream_on_accept_response(uint64_t local_sid, uint64_t peer_sid,
@@ -663,22 +724,7 @@ void stream_on_connection_failed(uint64_t socket_id) {
     if (m == nullptr) {
       continue;
     }
-    // Route the close through the consume queue under the meta lock
-    // (the kStreamFrame close path): a concurrent StreamClose + slot
-    // reuse between the stream_of snapshot and an unguarded mark_closed
-    // would close the NEXT incarnation at birth.  The version bump and
-    // queue stop happen under this same lock, so a stale sid can no
-    // longer reach the new stream; a sentinel that lands anyway drains
-    // against the old incarnation before new_stream resets state.
-    m->lock();
-    const bool ver_ok = m->version.load(std::memory_order_relaxed) ==
-                        static_cast<uint32_t>(sid >> 32);
-    const bool queued = ver_ok && m->consume_q != nullptr &&
-                        m->consume_q->execute(nullptr) == 0;
-    m->unlock();
-    if (ver_ok && !queued) {
-      mark_closed(m);
-    }
+    close_in_order(m, sid);
   }
 }
 

@@ -7,7 +7,11 @@
 // connection; frames ride the tstd protocol (meta.type = kStreamFrame) and
 // are consumed through a per-stream ExecutionQueue so handlers see chunks
 // in order; ACK frames reopen the writer's window, writers park on an
-// Event when credit runs out.
+// Event when credit runs out.  A chunk over the large-message threshold,
+// on a connection with a one-sided session (net/rma.h), is put into the
+// peer's receive window and its frame carries the descriptor alone; the
+// frame keeps its place in the connection's order and the credit gate
+// counts the chunk as before (`stream_one_sided_bytes`).
 //
 // The window (upstream's max_buf_size, docs/en/streaming_rpc.md): a chunk
 // is admitted whenever the window is NOT EXHAUSTED, whatever its size
@@ -91,6 +95,12 @@ bool StreamExists(StreamId id);
 // -- internal (messenger hook) -------------------------------------------
 struct InputMessage;
 void stream_on_frame(InputMessage&& msg);
+// A data frame of `stream_id` (this end's id) arrived without its body: the
+// one-sided transfer its descriptor names did not verify (net/rma.h).  A
+// unary call times such a message out; a stream cannot time ONE chunk out
+// and keep its order, so the stream closes at both ends, behind the chunks
+// that arrived before.
+void stream_on_chunk_lost(uint64_t stream_id);
 // Bind the client stream to the server's accepted id (response path).
 // `peer_window` is the receive window the peer advertised — it becomes our
 // send credit (windows are exchanged at establishment, like the stream

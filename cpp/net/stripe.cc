@@ -381,6 +381,10 @@ uint64_t stripe_make_id() {
 
 bool stripe_should(SocketId primary, uint64_t stream_id,
                    uint64_t body_bytes) {
+  // Stripes ride several rails and reassemble in whatever order they
+  // land: nothing of a stream, whose frames keep the connection's order,
+  // is ever striped (a wide stream chunk goes one-sided, net/rma.h, or in
+  // band).
   if (stream_id != 0 || !stripe_eligible(body_bytes)) {
     return false;
   }

@@ -1,8 +1,12 @@
 // Streaming RPC tests (parity: test/brpc_streaming_rpc_unittest.cpp model —
-// establish over a normal RPC, ordered chunks, flow control, close).
+// establish over a normal RPC, ordered chunks, flow control, close), and a
+// wide chunk's way through the connection's one-sided window (net/rma.h):
+// order across the two ways, what a transfer that does not verify does to
+// the stream, and what a close leaves allocated.
 #include <unistd.h>
 
 #include <atomic>
+#include <mutex>
 #include <string>
 #include <vector>
 
@@ -10,8 +14,11 @@
 #include "fiber/fiber.h"
 #include "fiber/sync.h"
 #include "net/channel.h"
+#include "net/fault.h"
+#include "net/rma.h"
 #include "net/server.h"
 #include "net/stream.h"
+#include "stat/reducer.h"
 #include "tests/test_util.h"
 
 using namespace trpc;
@@ -480,6 +487,225 @@ TEST_CASE(unaccepted_batch_offers_close_promptly) {
   EXPECT_EQ(StreamWrite(sids[2], std::move(c2)), EINVAL);
   EXPECT(monotonic_time_us() - t0 < 2000000);
   StreamClose(sids[0]);
+}
+
+// ---- a wide chunk rides the connection's one-sided window -----------------
+
+namespace {
+
+int64_t exposed(const char* name) {
+  std::string out;
+  EXPECT(Variable::read_exposed(name, &out));
+  return strtoll(out.c_str(), nullptr, 10);
+}
+
+// A chunk of `n` bytes that says which it is: its sequence number, then a
+// pattern salted with it, so a chunk out of place or put at a wrong offset
+// differs.
+IOBuf numbered_chunk(uint64_t seq, size_t n) {
+  std::string s(n, 0);
+  for (size_t i = 0; i < n; ++i) {
+    s[i] = static_cast<char>(((i + seq) * 2654435761u) >> 13);
+  }
+  memcpy(&s[0], &seq, std::min<size_t>(8, n));
+  IOBuf chunk;
+  chunk.append(s);
+  return chunk;
+}
+
+// What the keeping server saw.
+std::mutex g_kept_mu;
+std::vector<IOBuf> g_kept;            // guarded by g_kept_mu
+std::atomic<int> g_kept_chunks{0};
+std::atomic<bool> g_kept_exact{true};
+std::atomic<int> g_kept_closed{0};
+std::atomic<bool> g_keep_parked{false};  // on_message parks while set
+std::atomic<uint64_t> g_keep_sid{0};
+
+// Stream.Keep: accepts with a 32 MB window and only takes delivery: every
+// chunk is checked against numbered_chunk(arrival order) and kept, so a
+// chunk that came as a window span holds its span until the test lets go.
+Server* keeping_server() {
+  static Server* srv = [] {
+    auto* s = new Server();
+    s->RegisterMethod(
+        "Stream.Keep", [](Controller* cntl, const IOBuf&, IOBuf* resp,
+                          Closure done) {
+          StreamOptions opts;
+          opts.window_bytes = 32 << 20;
+          opts.credit_on_consumed = true;
+          opts.on_message = [](StreamId, IOBuf&& chunk) {
+            while (g_keep_parked.load()) {
+              fiber_sleep_us(1000);
+            }
+            // Calls are serialized per stream: the count is the order.
+            const uint64_t seq =
+                static_cast<uint64_t>(g_kept_chunks.load()) + 1;
+            const std::string want =
+                numbered_chunk(seq, chunk.size()).to_string();
+            if (!chunk.equals(want.data(), want.size())) {
+              g_kept_exact.store(false);
+            }
+            {
+              std::lock_guard<std::mutex> g(g_kept_mu);
+              g_kept.push_back(std::move(chunk));
+            }
+            g_kept_chunks.fetch_add(1);
+          };
+          opts.on_closed = [](StreamId sid) {
+            g_kept_closed.fetch_add(1);
+            StreamClose(sid);
+          };
+          StreamId sid = 0;
+          EXPECT_EQ(StreamAccept(&sid, cntl, opts), 0);
+          g_keep_sid.store(sid);
+          resp->append("accepted");
+          done();
+        });
+    EXPECT_EQ(s->Start(0), 0);
+    return s;
+  }();
+  return srv;
+}
+
+void forget_kept() {
+  std::lock_guard<std::mutex> g(g_kept_mu);
+  g_kept.clear();
+}
+
+// Opens a stream to Stream.Keep over the shm ring; the connection is new,
+// so its windows are sized by the flags as they are now.
+StreamId open_kept(Channel* ch, bool checksum) {
+  g_kept_chunks = 0;
+  g_kept_exact = true;
+  g_kept_closed = 0;
+  Channel::Options opts;
+  opts.use_shm = true;
+  opts.timeout_ms = 20000;
+  EXPECT_EQ(ch->Init("127.0.0.1:" + std::to_string(keeping_server()->port()),
+                     &opts),
+            0);
+  Controller cntl;
+  cntl.set_enable_checksum(checksum);
+  StreamId sid = 0;
+  EXPECT_EQ(StreamCreate(&sid, &cntl, StreamOptions{}), 0);
+  IOBuf req, resp;
+  req.append("open");
+  ch->CallMethod("Stream.Keep", req, &resp, &cntl);
+  EXPECT(!cntl.Failed());
+  EXPECT(ch->transport_name() == "shm_ring");
+  return sid;
+}
+
+bool wait_until(const std::function<bool()>& cond, int64_t timeout_us) {
+  const int64_t deadline = monotonic_time_us() + timeout_us;
+  while (!cond() && monotonic_time_us() < deadline) {
+    usleep(5000);
+  }
+  return cond();
+}
+
+struct FaultGuard {
+  ~FaultGuard() { FaultActor::global().set(""); }
+};
+
+}  // namespace
+
+TEST_CASE(wide_chunks_ride_the_window_and_keep_their_place_among_narrow_ones) {
+  Channel ch;
+  const StreamId sid = open_kept(&ch, /*checksum=*/false);
+  const int64_t one_sided0 = exposed("stream_one_sided_bytes");
+  const int64_t written0 = exposed("stream_bytes_written");
+  const int64_t rma0 = exposed("rma_tx_bytes");
+  const int64_t full0 = exposed("rma_window_full");
+  const size_t widths[] = {4u << 20, 1024,       1u << 20, 4u << 20,
+                           3u << 20, 1024,       2u << 20, (2u << 20) + 8,
+                           8,        4u << 20};
+  int64_t all = 0, wide = 0;
+  uint64_t seq = 0;
+  for (size_t n : widths) {
+    EXPECT_EQ(StreamWrite(sid, numbered_chunk(++seq, n)), 0);
+    all += static_cast<int64_t>(n);
+    wide += n > (2u << 20) ? static_cast<int64_t>(n) : 0;  // over, not at
+  }
+  EXPECT(wait_until([&] { return g_kept_chunks.load() == 10; }, 5000000));
+  EXPECT(g_kept_exact.load());
+  EXPECT_EQ(exposed("stream_bytes_written") - written0, all);
+  EXPECT_EQ(exposed("stream_one_sided_bytes") - one_sided0, wide);
+  EXPECT_EQ(exposed("rma_tx_bytes") - rma0, wide);
+  EXPECT_EQ(exposed("rma_window_full") - full0, 0);
+  // Five spans lie kept, in 4 MB slots: a span header and 4 MB are two,
+  // the 3 MB and the 2 MB + 8 chunks one each.
+  EXPECT_EQ(rma_spans_in_use(), 8u);
+  EXPECT_EQ(stream_unread_high_water(g_keep_sid.load()),
+            static_cast<uint64_t>(all));
+  forget_kept();  // each span's deleter runs with its chunk
+  EXPECT_EQ(rma_spans_in_use(), 0u);
+  StreamClose(sid);
+  EXPECT(wait_until([] { return g_kept_closed.load() == 1; }, 3000000));
+}
+
+TEST_CASE(a_checksummed_stream_refuses_a_corrupted_span_and_closes) {
+  // The call that opens the stream asks for checksums: an in-band frame
+  // carries its payload's, a one-sided transfer one a chunk in the span's
+  // header.  One flipped byte in the window fails rma_resolve; a unary
+  // call would time out alone, a stream closes: the chunk behind the
+  // refused one is never delivered in its place.
+  Channel ch;
+  const StreamId sid = open_kept(&ch, /*checksum=*/true);
+  EXPECT_EQ(StreamWrite(sid, numbered_chunk(1, 1024)), 0);
+  EXPECT_EQ(StreamWrite(sid, numbered_chunk(2, 4u << 20)), 0);
+  EXPECT(wait_until([] { return g_kept_chunks.load() == 2; }, 5000000));
+  EXPECT(g_kept_exact.load());
+  const int64_t rejected0 = exposed("rma_rejected");
+  {
+    FaultGuard guard;
+    // The first decision after this is a rail's first chunk: the frames
+    // of a quiet connection are all written.
+    EXPECT_EQ(FaultActor::global().set("seed=3;corrupt=1.0;max=1"), 0);
+    EXPECT_EQ(StreamWrite(sid, numbered_chunk(3, 4u << 20)), 0);
+    EXPECT(wait_until(
+        [&] { return exposed("rma_rejected") == rejected0 + 1; }, 5000000));
+  }
+  EXPECT(wait_until([] { return g_kept_closed.load() == 1; }, 3000000));
+  // The writer learns: the refusing end sent a CLOSE.
+  EXPECT(wait_until(
+      [&] { return StreamWrite(sid, numbered_chunk(4, 1024)) == EPIPE; },
+      3000000));
+  EXPECT_EQ(g_kept_chunks.load(), 2);
+  EXPECT(g_kept_exact.load());
+  forget_kept();
+  EXPECT_EQ(rma_spans_in_use(), 0u);  // the refused span was given back
+  StreamClose(sid);
+}
+
+TEST_CASE(a_close_drops_the_spans_still_queued_behind_the_consumer) {
+  // The consumer is inside on_message with the first chunk while two more
+  // wait in the stream's queue, each a span of the receive window.  The
+  // receiving end closes: the queue stops, the queued chunks are dropped
+  // and every span's deleter runs; nothing of the window stays allocated.
+  Channel ch;
+  const StreamId sid = open_kept(&ch, /*checksum=*/false);
+  g_keep_parked = true;
+  for (uint64_t seq = 1; seq <= 3; ++seq) {
+    EXPECT_EQ(StreamWrite(sid, numbered_chunk(seq, 4u << 20)), 0);
+  }
+  const StreamId kept = g_keep_sid.load();
+  EXPECT(wait_until(
+      [&] { return stream_unread_high_water(kept) == 3u * (4u << 20); },
+      5000000));
+  EXPECT_EQ(rma_spans_in_use(), 6u);
+  EXPECT_EQ(StreamClose(kept), 0);
+  g_keep_parked = false;
+  // The one the consumer had in hand is the application's to let go of.
+  EXPECT(wait_until([] { return rma_spans_in_use() == 2; }, 5000000));
+  EXPECT_EQ(g_kept_chunks.load(), 1);
+  forget_kept();
+  EXPECT_EQ(rma_spans_in_use(), 0u);
+  // The writer's end reads the CLOSE.
+  EXPECT(wait_until(
+      [&] { return StreamWrite(sid, numbered_chunk(9, 8)) != 0; }, 3000000));
+  StreamClose(sid);
 }
 
 TEST_MAIN

@@ -6,11 +6,16 @@ writer when the application has read it), what `write` and `read_into`
 copy is what the counters say, a device array goes in and comes out, and
 the native stream echo (`Server.register_native_stream_echo`) keeps
 order.  The system is compared with the plain reference
-(benchmark/reference_stream.py) at small sizes.  Nothing here is a
-measurement.
+(benchmark/reference_stream.py) at small sizes.  A chunk over the
+large-message threshold on the shm ring rides the connection's one-sided
+window (PR 36): what goes that way and what falls back in band, in what
+order it arrives, what a lost transfer does to the stream, and what is
+left allocated afterwards.  Nothing here is a measurement.
 """
 
+import contextlib
 import gc
+import os
 import threading
 import time
 
@@ -19,8 +24,9 @@ import pytest
 
 from benchmark import reference_stream
 from brpc_tpu.rpc import (Channel, RpcError, Server, StreamChunkTooLargeError,
-                          StreamTimeoutError, observe, open_stream, stream,
-                          zerocopy)
+                          StreamClosedError, StreamTimeoutError, fault, flags,
+                          observe, open_stream, stream, zerocopy)
+from brpc_tpu.rpc._lib import load_library
 
 KB, MB = 1 << 10, 1 << 20
 METHOD = "Echo.Stream"
@@ -57,10 +63,8 @@ def native_echo(request):
         srv.close()
 
 
-@pytest.fixture
-def python_peer():
-    """(a Channel, the list the server's Python handler puts its accepted
-    end of each stream into): a reader the test controls."""
+@contextlib.contextmanager
+def _python_peer(use_shm: bool):
     srv = Server()
     accepted = []
     windows = {"next": 0}
@@ -71,7 +75,7 @@ def python_peer():
 
     srv.register(METHOD, handler)
     port = srv.start()
-    ch = Channel(f"127.0.0.1:{port}", timeout_ms=10000)
+    ch = Channel(f"127.0.0.1:{port}", timeout_ms=10000, use_shm=use_shm)
     try:
         yield ch, accepted, windows
     finally:
@@ -79,6 +83,23 @@ def python_peer():
             peer.destroy()
         ch.close()
         srv.close()
+
+
+@pytest.fixture
+def python_peer():
+    """(a Channel, the list the server's Python handler puts its accepted
+    end of each stream into, the window the next accept grants): a reader
+    the test controls."""
+    with _python_peer(use_shm=False) as peer:
+        yield peer
+
+
+@pytest.fixture
+def shm_peer():
+    """`python_peer` on the shm ring, whose connection has a one-sided
+    session."""
+    with _python_peer(use_shm=True) as peer:
+        yield peer
 
 
 def test_a_chunk_wider_than_the_window_goes_and_the_next_waits_for_its_read(
@@ -368,3 +389,249 @@ def test_the_system_delivers_what_the_reference_does(native_echo, seed):
     assert running == running_expected
     assert st.unread_high_water <= bound
     st.destroy()
+
+
+# ---- a wide chunk rides the connection's one-sided window (PR 36) ---------
+
+ONE_SIDED = ("stream_one_sided_bytes", "stream_bytes_written", "rma_tx_bytes",
+             "rma_window_full", "rma_rejected")
+
+
+def _threshold() -> int:
+    return int(flags.get_flag("trpc_stripe_threshold"))
+
+
+def _own_shm_names() -> set:
+    return {name for name in os.listdir("/dev/shm")
+            if name.startswith((f"trpc_rma_{os.getpid()}_",
+                                f"trpc_{os.getpid()}_"))}
+
+
+@contextlib.contextmanager
+def _flag(name: str, value):
+    old = flags.get_flag(name)
+    flags.set_flag(name, str(value))
+    try:
+        yield
+    finally:
+        flags.set_flag(name, old)
+
+
+def test_chunks_on_both_sides_of_the_threshold_arrive_in_the_order_written(
+        native_echo):
+    """4 MB chunks between 1 KB and 1 MB ones, six open against 16 MB
+    windows.  Over shm every chunk over the threshold goes through the
+    window, there and back, and the unary plane's counter rises by the
+    same bytes; over tcp there is no session and none does.  Either way
+    what comes back is what was written, in that order."""
+    rng = np.random.default_rng(36)
+    widths = [4 * MB, KB, MB, 4 * MB, 4 * MB, KB, 3 * MB, MB, 4 * MB, 1,
+              _threshold(), _threshold() + 4, KB, 4 * MB]
+    chunks = [rng.integers(0, 256, w, dtype=np.uint8) for w in widths]
+    wide = sum(w for w in widths if w > _threshold())
+    st, _ = open_stream(native_echo, METHOD, window_bytes=16 * MB)
+    over_shm = native_echo.transport == "shm_ring"
+    before = _counters(*ONE_SIDED)
+    landed = np.empty(4 * MB, dtype=np.uint8)
+    written = 0
+    for i, chunk in enumerate(chunks):
+        while written < len(chunks) and written - i < 6:
+            st.write(chunks[written])
+            written += 1
+        assert st.read_into(landed, timeout_ms=10000) == chunk.size, i
+        assert np.array_equal(landed[:chunk.size], chunk), i
+    after = _counters(*ONE_SIDED)
+    delta = {name: after[name] - before[name] for name in ONE_SIDED}
+    assert delta["stream_bytes_written"] == 2 * sum(widths)
+    assert delta["stream_one_sided_bytes"] == (2 * wide if over_shm else 0)
+    assert delta["rma_tx_bytes"] == delta["stream_one_sided_bytes"]
+    assert delta["rma_window_full"] == delta["rma_rejected"] == 0
+    assert st.unread_high_water <= reference_stream.unread_bound(
+        16 * MB, 4 * MB)
+    st.destroy()
+
+
+def test_with_every_chunk_one_sided_the_unread_stay_under_window_plus_a_chunk(
+        shm_peer):
+    """The credit is taken before the put and the unread bytes are counted
+    when the descriptor's frame arrives, so the bound is the in-band
+    one: what changes is where the unread bytes lie."""
+    ch, accepted, windows = shm_peer
+    windows["next"] = 8 * MB
+    chunk = np.arange(3 * MB // 4, dtype=np.uint32)            # 3 MB
+    st, _ = open_stream(ch, METHOD)
+    assert _wait(lambda: len(accepted) == 1)
+    peer = accepted[0]
+    before = _counters(*ONE_SIDED)
+    count = {"written": 0}
+
+    def write_eight():
+        for _ in range(8):
+            st.write(chunk)
+            count["written"] += 1
+
+    writer = threading.Thread(target=write_eight)
+    writer.start()
+    # 8 MB admit three chunks of 3 MB: the third overruns the window.
+    assert _wait(lambda: peer.pending() == 3)
+    time.sleep(0.3)
+    assert count["written"] == 3 and peer.pending() == 3
+    bound = reference_stream.unread_bound(8 * MB, chunk.nbytes)
+    assert peer.unread_high_water == 3 * chunk.nbytes <= bound
+    landed = np.empty(chunk.nbytes, dtype=np.uint8)
+    for _ in range(8):
+        assert peer.read_into(landed, timeout_ms=5000) == chunk.nbytes
+        assert np.array_equal(landed.view(np.uint32), chunk)
+        assert peer.pending() <= 3
+    writer.join(5.0)
+    assert not writer.is_alive() and count["written"] == 8
+    assert peer.unread_high_water <= bound
+    after = _counters(*ONE_SIDED)
+    assert after["stream_one_sided_bytes"] - before[
+        "stream_one_sided_bytes"] == 8 * chunk.nbytes
+    assert after["rma_window_full"] == before["rma_window_full"]
+    st.destroy()
+
+
+def test_a_receive_window_too_small_for_the_chunks_unread_sends_the_rest_in_band():
+    """`trpc_rma_window_bytes` at its 16 MB minimum is 64 slots of 256 KB:
+    three unread 4 MB chunks (17 slots each with their span header) fill
+    it, and the chunks behind them go in band, as a unary body does on a
+    full window; the order holds across the two ways, and once the spans
+    have been read the window takes chunks again."""
+    rng = np.random.default_rng(37)
+    chunks = [rng.integers(0, 256, 4 * MB, dtype=np.uint8) for _ in range(7)]
+    with _flag("trpc_rma_window_bytes", 16 * MB), _python_peer(
+            use_shm=True) as (ch, accepted, windows):
+        windows["next"] = 32 * MB
+        st, _ = open_stream(ch, METHOD)
+        assert _wait(lambda: len(accepted) == 1)
+        peer = accepted[0]
+        before = _counters(*ONE_SIDED)
+        for chunk in chunks[:5]:
+            st.write(chunk)
+        assert _wait(lambda: peer.pending() == 5)
+        held = _counters(*ONE_SIDED)
+        assert held["stream_one_sided_bytes"] - before[
+            "stream_one_sided_bytes"] == 3 * 4 * MB
+        assert held["rma_window_full"] - before["rma_window_full"] == 2
+        landed = np.empty(4 * MB, dtype=np.uint8)
+        for chunk in chunks[:5]:
+            assert peer.read_into(landed, timeout_ms=5000) == 4 * MB
+            assert np.array_equal(landed, chunk)
+        for chunk in chunks[5:]:
+            st.write(chunk)
+            assert peer.read_into(landed, timeout_ms=5000) == 4 * MB
+            assert np.array_equal(landed, chunk)
+        after = _counters(*ONE_SIDED)
+        assert after["stream_one_sided_bytes"] - before[
+            "stream_one_sided_bytes"] == 5 * 4 * MB
+        assert after["stream_bytes_written"] - before[
+            "stream_bytes_written"] == 7 * 4 * MB
+        assert after["rma_window_full"] - before["rma_window_full"] == 2
+        assert after["rma_rejected"] == before["rma_rejected"]
+        st.destroy()
+
+
+def test_a_chunk_whose_transfer_does_not_verify_closes_the_stream(shm_peer):
+    """One chunk of the put is dropped, its completion bit stays clear and
+    `rma_resolve` refuses the descriptor's frame.  A unary call would time
+    out alone; a stream cannot lose ONE chunk and keep its order, so the
+    reader gets what arrived before, then the close, never the chunk
+    behind the lost one, and the writer's next write fails."""
+    ch, accepted, windows = shm_peer
+    windows["next"] = 16 * MB
+    st, _ = open_stream(ch, METHOD)
+    assert _wait(lambda: len(accepted) == 1)
+    peer = accepted[0]
+    st.write(b"first")
+    assert _wait(lambda: peer.pending() == 1)
+    before = _counters(*ONE_SIDED)
+    fault.set_schedule("seed=36;drop=1.0;max=1")
+    try:
+        st.write(np.full(4 * MB, 7, dtype=np.uint8))    # lost on its way
+    finally:
+        fault.set_schedule("")
+    assert _wait(lambda: _counters("rma_rejected")["rma_rejected"]
+                 == before["rma_rejected"] + 1)
+
+    def write_fails():
+        try:
+            st.write(b"behind the lost one")
+        except RpcError:
+            return True
+        return False
+
+    assert _wait(write_fails)
+    assert peer.read(timeout_ms=5000) == b"first"
+    with pytest.raises(StreamClosedError):
+        peer.read(timeout_ms=5000)
+    assert peer.pending() == 0
+    # The faulted span went back to the window with the refusal.
+    assert _wait(lambda: load_library().trpc_rma_spans_in_use() == 0)
+    st.destroy()
+
+
+@pytest.mark.parametrize("ending", ["the_reader_closes", "the_writer_closes"])
+def test_chunks_left_unread_in_the_window_give_their_slots_back(ending):
+    """Three 4 MB chunks lie unread in the reader's receive window (two
+    slots each).  However the stream ends, each span's deleter runs when
+    its chunk is dropped, and nothing of the connection is left in
+    /dev/shm."""
+    lib = load_library()
+    names = _own_shm_names()
+    assert lib.trpc_rma_spans_in_use() == 0
+    chunk = np.full(4 * MB, 9, dtype=np.uint8)
+    with _python_peer(use_shm=True) as (ch, accepted, windows):
+        windows["next"] = 16 * MB
+        st, _ = open_stream(ch, METHOD)
+        assert _wait(lambda: len(accepted) == 1)
+        peer = accepted[0]
+        for _ in range(3):
+            st.write(chunk)
+        assert _wait(lambda: peer.pending() == 3)
+        assert lib.trpc_rma_spans_in_use() == 6
+        assert _own_shm_names() > names
+        if ending == "the_reader_closes":
+            peer.destroy()
+        else:
+            st.destroy()
+            # The chunks stay readable behind the close; the application
+            # lets go of them with its end.
+            landed = np.empty(4 * MB, dtype=np.uint8)
+            assert peer.read_into(landed, timeout_ms=5000) == 4 * MB
+            assert lib.trpc_rma_spans_in_use() == 4
+            peer.destroy()
+        assert _wait(lambda: lib.trpc_rma_spans_in_use() == 0)
+        st.destroy()
+    assert _wait(lambda: _own_shm_names() == names)
+
+
+def test_a_dead_connection_with_echoes_unread_leaves_nothing_behind():
+    """The native echo has put three 4 MB echoes into the client's receive
+    window and the client has read none when the server goes away: what
+    arrived stays readable, and once the client lets go of its end neither
+    a window slot nor a name in /dev/shm is left of the connection."""
+    lib = load_library()
+    names = _own_shm_names()
+    chunk = np.full(4 * MB, 5, dtype=np.uint8)
+    srv = Server()
+    srv.register_native_stream_echo(METHOD)
+    ch = Channel(f"127.0.0.1:{srv.start()}", timeout_ms=10000, use_shm=True)
+    try:
+        st, _ = open_stream(ch, METHOD, window_bytes=16 * MB)
+        for _ in range(3):
+            st.write(chunk)
+        assert _wait(lambda: st.pending() == 3)
+        assert _wait(lambda: lib.trpc_rma_spans_in_use() == 6)
+        srv.close()
+        landed = np.empty(4 * MB, dtype=np.uint8)
+        assert st.read_into(landed, timeout_ms=5000) == 4 * MB
+        assert np.array_equal(landed, chunk)
+        assert st.pending() == 2
+        st.destroy()                        # two echoes never read
+        assert _wait(lambda: lib.trpc_rma_spans_in_use() == 0)
+    finally:
+        ch.close()
+        srv.close()
+    assert _wait(lambda: _own_shm_names() == names)
