@@ -351,18 +351,18 @@ def load_library() -> ctypes.CDLL:
                 ctypes.c_size_t,
             ]
             lib.trpc_kv_prefix_chain.restype = ctypes.c_size_t
-            lib.trpc_kv_prefix_publish.argtypes = [
+            lib.trpc_kv_prefix_publish_at.argtypes = [
                 ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint32,
                 ctypes.c_void_p, ctypes.c_size_t,
                 ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t,
-                ctypes.c_int64, ctypes.c_uint64,
+                ctypes.c_int64, ctypes.c_uint64, ctypes.c_int,
                 ctypes.POINTER(ctypes.c_uint64),
                 ctypes.POINTER(ctypes.c_uint64),
                 ctypes.POINTER(ctypes.c_uint64),
                 ctypes.POINTER(ctypes.c_uint64),
                 ctypes.POINTER(ctypes.c_uint64),
             ]
-            lib.trpc_kv_prefix_publish.restype = ctypes.c_int
+            lib.trpc_kv_prefix_publish_at.restype = ctypes.c_int
             lib.trpc_kv_prefix_withdraw.argtypes = [
                 ctypes.c_uint64, ctypes.c_uint64,
             ]

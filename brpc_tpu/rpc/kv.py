@@ -71,6 +71,37 @@ snapshots asked for are those taken at that boundary::
     kv.withdraw_sequence(31, layout, n_pages, registry=reg)
 
 The page calls above are the layout of one paged kind with one page.
+
+The other half of the plane is a content-addressed PREFIX CACHE: a block
+is one page of a prompt's cache, named by a chain key (the token ids of
+the whole prefix through that page, `prefix_chain`) and by the content
+hash of its bytes and token span.  Nothing is positional, a block is
+asked for as often as prompts share it, and the node's two-tier store
+keeps it under two budgets: hot blocks in registered pages
+(`trpc_kv_prefix_hot_bytes`; past it the least recently touched is
+demoted to the heap, never dropped) and everything under
+`trpc_kv_store_bytes` (past it expired, then heap, then hot blocks are
+dropped, and a dropped block answers kv-stale).  A touch, a fetch's or
+the publish of content the store already holds, leaves its block hot, so
+the two tiers are one order by last touch.  A rank that admits the
+next turn of a conversation::
+
+    groups = cli.match_prefix(tokens)            # one KvReg.Match
+    landed = cli.fetch_prefix_blocks(groups, landing=rows, window=16)
+    pool = kv_pool.write_pages(pool, slots[:len(landed)],
+                               jax.device_put(rows[:len(landed)]))
+    # ...prefill the pages from len(landed) on, then offer them:
+    kv.publish_prefix_run(keys[len(landed):], len(landed),
+                          zerocopy.host_view(new_pages)[0], token_spans,
+                          node=addr, registry=reg)   # one PutPrefixMany
+
+`fetch_prefix_blocks` rides the node channel's pipeline as `Kv.Fetch`
+does (a window of blocks in flight, each over `trpc_stripe_threshold`
+through the connection's one-sided window) and ends the run at the first
+block no replica serves; `publish_prefix_run` hashes each page where its
+bytes lie and the store takes it there, with no copy, when they lie in
+the landing block its device-to-host transfer wrote (`prefix_publish`),
+else copies it once.
 """
 
 from __future__ import annotations
@@ -115,6 +146,7 @@ REGISTER_MANY_METHOD = "KvReg.RegisterMany"
 LOOKUP_MANY_METHOD = "KvReg.LookupMany"
 EVICT_MANY_METHOD = "KvReg.EvictMany"
 PREFIX_PUT_METHOD = "KvReg.PutPrefix"
+PREFIX_PUT_MANY_METHOD = "KvReg.PutPrefixMany"
 PREFIX_MATCH_METHOD = "KvReg.Match"
 PREFIX_FETCH_METHOD = "Kv.FetchPrefix"
 
@@ -589,18 +621,26 @@ def _token_array(tokens):
     return (ctypes.c_uint64 * max(len(toks), 1))(*toks), len(toks)
 
 
+def _byte_view(data) -> np.ndarray:
+    """The bytes of `data` where they lie, as a flat uint8 array: a
+    `zerocopy.PendingView` is waited for, anything else is read through
+    the buffer protocol (C-contiguous), nothing copied."""
+    if isinstance(data, zerocopy.PendingView):
+        data = data.resolve()
+    return np.frombuffer(data, dtype=np.uint8)
+
+
 def content_hash(data, tokens=()) -> tuple[int, int]:
     """128-bit content hash of (block bytes, token-id span) — identical
     inputs hash identically in every process (the fleet dedup key)."""
     lib = load_library()
-    buf = bytes(data)
+    flat = _byte_view(data)
     tok_arr, ntok = _token_array(tokens)
     hi = ctypes.c_uint64()
     lo = ctypes.c_uint64()
     lib.trpc_kv_content_hash(
-        ctypes.cast(ctypes.c_char_p(buf), ctypes.c_void_p),
-        ctypes.c_size_t(len(buf)), tok_arr, ctypes.c_size_t(ntok),
-        ctypes.byref(hi), ctypes.byref(lo))
+        ctypes.c_void_p(flat.ctypes.data), ctypes.c_size_t(flat.nbytes),
+        tok_arr, ctypes.c_size_t(ntok), ctypes.byref(hi), ctypes.byref(lo))
     return hi.value, lo.value
 
 
@@ -625,14 +665,26 @@ def prefix_publish(key: tuple[int, int], depth: int, data, tokens,
                    lease_ms: int = 0, node: str = "",
                    min_generation: int = 0) -> tuple[KvPrefixMeta, bool]:
     """Publishes one prefix block into the local two-tier store under its
-    content hash (bytes are COPIED into store-owned registered pages —
-    any buffer works, no RmaBuffer needed).  Returns (meta, fresh):
-    fresh=False is the cache-hit path — identical content was already
-    live, the lease renewed, and NO bytes were admitted (the caller's
-    bytes-not-recomputed accounting)."""
+    content hash.  `data` is any C-contiguous buffer (bytes, a numpy
+    array) or a `zerocopy.PendingView`; the hash reads the bytes where
+    they lie.  Where the block's bytes then live is chosen by what can be
+    observed of their source, as `_publish_records` chooses: bytes in a
+    landing block of the host pool (`trpc_host_pool_holds`: where a
+    view's transfer of 1 MB or more landed them) are TAKEN where they
+    lie, nothing copied, and the store co-owns the block until the prefix
+    block is demoted to the heap tier or dropped, whatever becomes of the
+    view and the array (the pool hands the block to no other transfer
+    meanwhile); any other source (no RmaBuffer needed) is copied once
+    into store-owned pages.  Counted in `kv_prefix_publish_in_place_bytes`
+    / `kv_prefix_publish_copy_bytes`.  Returns (meta, fresh): fresh=False
+    is the cache-hit path — identical content was already live, the lease
+    renewed, and NO bytes were admitted (the caller's
+    bytes-not-recomputed accounting); a block that was in the heap tier
+    is hot again, on these bytes where they lie or on one copy of them
+    (`kv_prefix_renew_promote`)."""
     lib = load_library()
-    buf = bytes(data)
-    if not buf:
+    flat = _byte_view(data)
+    if not flat.nbytes:
         raise ValueError("empty prefix block")
     tok_arr, ntok = _token_array(tokens)
     hash_hi = ctypes.c_uint64()
@@ -640,23 +692,52 @@ def prefix_publish(key: tuple[int, int], depth: int, data, tokens,
     gen = ctypes.c_uint64()
     rkey = ctypes.c_uint64()
     off = ctypes.c_uint64()
-    rc = lib.trpc_kv_prefix_publish(
-        ctypes.c_uint64(key[0]), ctypes.c_uint64(key[1]),
-        ctypes.c_uint32(depth),
-        ctypes.cast(ctypes.c_char_p(buf), ctypes.c_void_p),
-        ctypes.c_size_t(len(buf)), tok_arr, ctypes.c_size_t(ntok),
-        ctypes.c_int64(lease_ms), ctypes.c_uint64(min_generation),
-        ctypes.byref(hash_hi), ctypes.byref(hash_lo), ctypes.byref(gen),
-        ctypes.byref(rkey), ctypes.byref(off))
+    address = flat.ctypes.data
+    rc = lib.trpc_kv_prefix_publish_at(
+        key[0], key[1], depth, address, flat.nbytes, tok_arr, ntok,
+        lease_ms, min_generation,
+        lib.trpc_host_pool_holds(address, flat.nbytes),
+        hash_hi, hash_lo, gen, rkey, off)
     _miss, _stale, exists = _codes()
     if rc != 0 and rc != exists:
         raise MemoryError(
             f"kv prefix publish failed (rc={rc}): the block must fit "
             "trpc_kv_store_bytes")
     meta = KvPrefixMeta(key[0], key[1], hash_hi.value, hash_lo.value,
-                        gen.value, rkey.value, off.value, len(buf), depth,
-                        node)
+                        gen.value, rkey.value, off.value, flat.nbytes,
+                        depth, node)
     return meta, rc == 0
+
+
+def publish_prefix_run(keys, first_depth: int, pages, token_spans,
+                       lease_ms: int = 0, node: str = "",
+                       registry: "KvRegistryClient | None" = None
+                       ) -> list[tuple[KvPrefixMeta, bool]]:
+    """Publishes a run of consecutive prefix blocks, the new pages of one
+    prompt: block `j` of `pages` under chain key `keys[j]` at depth
+    `first_depth + j` with the token span `token_spans[j]`
+    (`prefix_publish`, so out of the block the pages' transfer landed in
+    where there is one).  `pages` is a device array, a numpy array, or
+    the view `zerocopy.host_view` made of one when its transfer was
+    started ahead; its bytes are the blocks end to end, equal in size.
+    With a `registry` the replicas are recorded in ONE
+    `put_prefix_many`; a record it refuses raises its error.  Returns
+    `prefix_publish`'s (meta, fresh) per block."""
+    flat = _host_flat(pages)
+    if not keys or flat.nbytes % len(keys) or len(token_spans) != len(keys):
+        raise ValueError(f"{flat.nbytes} bytes are not {len(keys)} equal "
+                         f"blocks with {len(token_spans)} token spans")
+    nbytes = flat.nbytes // len(keys)
+    out = [prefix_publish(key, first_depth + j,
+                          flat[j * nbytes:(j + 1) * nbytes], token_spans[j],
+                          lease_ms=lease_ms, node=node)
+           for j, key in enumerate(keys)]
+    if registry is not None:
+        for answer in registry.put_prefix_many([meta for meta, _ in out],
+                                               lease_ms=lease_ms):
+            if isinstance(answer, RpcError):
+                raise answer
+    return out
 
 
 def prefix_withdraw(hash_key: tuple[int, int]) -> None:
@@ -801,6 +882,23 @@ class KvRegistryClient:
             raise e from None
         return struct.unpack("<Q", resp)[0], True
 
+    def put_prefix_many(self, metas, lease_ms: int = 0) -> list:
+        """`put_prefix` for many replica records in one round trip
+        (`KvReg.PutPrefixMany`, the shape of `register_many`).  Per
+        record, in order: (generation, fresh) as `put_prefix` returns
+        it, or the error it would have raised (KvStaleError: a zombie
+        generation, or the chain key held under another content hash) as
+        an instance, not raised."""
+        def answer(status, gen):
+            if status == 0:
+                return gen, True
+            e = _entry_error(status, "put-prefix")
+            return (gen, False) if isinstance(e, KvExistsError) else e
+
+        return self._call_many(
+            PREFIX_PUT_MANY_METHOD, [m.pack(lease_ms) for m in metas],
+            _MANY_GEN, answer)
+
     def match(self, keys) -> list[KvPrefixMeta]:
         """Longest cached prefix: one replica record per live replica of
         every matched chain key, grouped in chain order (the walk stops
@@ -822,6 +920,12 @@ class KvRegistryClient:
     def close(self) -> None:
         if self._owns:
             self._ch.close()
+
+
+def _block_fetch(meta: KvBlockMeta, buf) -> tuple:
+    """`_fetch_round`'s entry for one block record: its node, its
+    `Kv.Fetch` request at the generation looked up, its landing buffer."""
+    return (meta.node, _req(meta.block_id, generation=meta.generation), buf)
 
 
 class KvClient:
@@ -1004,7 +1108,7 @@ class KvClient:
                         FETCH_METHOD,
                         _req(block_id, generation=meta.generation),
                         timeout_ms=self._timeout_ms)
-                landed = self._fetch_round([(meta, resp_buf)])[0]
+                landed = self._fetch_round([_block_fetch(meta, resp_buf)])[0]
                 if isinstance(landed, RpcError):
                     raise landed
                 return landed
@@ -1028,28 +1132,27 @@ class KvClient:
                 raise
         raise last
 
-    def _fetch_round(self, wanted) -> list:
-        """One `Kv.Fetch` per (meta, landing buffer) of `wanted`, all in
-        flight together: the requests to one node cross in ONE
-        `pipeline.submit`, then the pipelines are polled until every
-        record is in.  Per record, in order: the landed length, or its
-        error as an instance (the one-sided direct path where the buffer
-        is RmaBuffer-backed and stripe-eligible and the connection is
-        shm/ici; else the runtime copies the response out on
-        completion).  A node whose poll times out is dropped, so that a
-        late completion cannot be taken for a later call's."""
+    def _fetch_round(self, wanted, method: str = FETCH_METHOD) -> list:
+        """One call of `method` (`Kv.Fetch`, `Kv.FetchPrefix`) per (node,
+        request, landing buffer) of `wanted`, all in flight together: the
+        requests to one node cross in ONE `pipeline.submit`, then the
+        pipelines are polled until every record is in.  Per record, in
+        order: the landed length, or its error as an instance (the
+        one-sided direct path where the buffer is RmaBuffer-backed and
+        stripe-eligible and the connection is shm/ici; else the runtime
+        copies the response out on completion).  A node whose poll times
+        out is dropped, so that a late completion cannot be taken for a
+        later call's."""
         out: list = [None] * len(wanted)
         by_node: dict[str, list[int]] = {}
-        for i, (meta, _buf) in enumerate(wanted):
-            by_node.setdefault(meta.node, []).append(i)
+        for i, (node, _request, _buf) in enumerate(wanted):
+            by_node.setdefault(node, []).append(i)
         pending = []
         for node, members in by_node.items():
             pipe = self._node_pipeline(node)
             tokens = pipe.submit(
-                FETCH_METHOD,
-                [_req(wanted[i][0].block_id,
-                      generation=wanted[i][0].generation) for i in members],
-                resp_bufs=[wanted[i][1] for i in members],
+                method, [wanted[i][1] for i in members],
+                resp_bufs=[wanted[i][2] for i in members],
                 timeout_ms=self._timeout_ms)
             pending.append((node, pipe, dict(zip(tokens, members))))
         for node, pipe, waiting in pending:
@@ -1071,7 +1174,7 @@ class KvClient:
                     if not c.in_caller_buffer and c.data is not None:
                         # The runtime handed back a view instead of
                         # landing in place (tiny responses).
-                        view = memoryview(wanted[i][1]).cast("B")
+                        view = memoryview(wanted[i][2]).cast("B")
                         view[:c.resp_len] = c.data.view()[:c.resp_len]
                         c.data.release()
                     out[i] = c.resp_len
@@ -1102,7 +1205,7 @@ class KvClient:
                 else:
                     asked.append((i, meta))
             landed = self._fetch_round(
-                [(meta, resp_bufs[i]) for i, meta in asked])
+                [_block_fetch(meta, resp_bufs[i]) for i, meta in asked])
             todo = []
             for (i, _meta), answer in zip(asked, landed):
                 if not isinstance(answer, RpcError):
@@ -1208,31 +1311,65 @@ class KvClient:
         vetoes."""
         return groups[-1][0].node if groups else ""
 
-    def fetch_prefix(self, tokens, block_tokens: int = 0) -> list[bytes]:
-        """Fetches every cached prefix block for `tokens` in chain
-        order, failing over across replicas: a replica that answers
-        stale/faulted serves nothing (whole-or-nothing per block) and
-        the next replica is tried.  The returned list may be shorter
-        than the match when every replica of a block fails — the
-        cacheable prefix simply ends there (callers recompute the
-        rest)."""
-        blocks: list[bytes] = []
-        for group in self.match_prefix(tokens, block_tokens):
-            data = None
-            for rep in group:
-                ch = self._node_channel(rep.node)
-                try:
-                    data = ch.call(PREFIX_FETCH_METHOD, rep.pack(),
-                                   timeout_ms=self._timeout_ms)
+    def fetch_prefix_blocks(self, groups, landing=None,
+                            window: int = 16) -> list:
+        """Lands the matched run `groups` (`match_prefix`'s answer, or a
+        slice of it) block by block in chain order and returns the landed
+        blocks, each a flat uint8 array of its block's length.  The
+        `Kv.FetchPrefix` calls ride the node channel's pipeline as
+        `Kv.Fetch` does, at most `window` blocks in flight (a block over
+        `trpc_stripe_threshold` on the shm ring crosses the connection's
+        one-sided window, so `window` blocks must fit
+        `trpc_rma_window_bytes`).  Block `i` lands in `landing[i]`
+        (writable C-contiguous numpy arrays of the blocks' sizes, or one
+        array whose leading axis is the blocks: rows of a registered
+        buffer for the one-sided landing); without `landing`, in a
+        recycled block of the host pool (`zerocopy.landing_block`).
+        Fail-over across replicas and whole-or-nothing per block, as
+        ever: a replica that answers stale, faulted, short or not at all
+        serves nothing and the block's next replica is asked; where every
+        replica of a block fails the cacheable prefix ends there and the
+        list is that much shorter than `groups` (callers recompute the
+        rest; blocks behind the hole are never handed out)."""
+        landed: list = []
+        for at in range(0, len(groups), max(1, window)):
+            part = groups[at:at + max(1, window)]
+            bufs = [(zerocopy.landing_block(group[0].length)
+                     if landing is None
+                     else landing[at + i].reshape(-1).view(np.uint8))
+                    for i, group in enumerate(part)]
+            ok = [False] * len(part)
+            replica = 0
+            while True:
+                asked = [i for i, group in enumerate(part)
+                         if not ok[i] and replica < len(group)]
+                if not asked:
                     break
-                except RpcError:
-                    # Stale, chunk-faulted, or dead replica: the block
-                    # is never admitted partially — try the next one.
-                    continue
-            if data is None:
+                answers = self._fetch_round(
+                    [(part[i][replica].node, part[i][replica].pack(),
+                      bufs[i]) for i in asked], PREFIX_FETCH_METHOD)
+                for i, answer in zip(asked, answers):
+                    # Stale, chunk-faulted, short or dead replica: the
+                    # block is never admitted partially.
+                    ok[i] = answer == part[i][replica].length
+                replica += 1
+            run = ok.index(False) if False in ok else len(ok)
+            landed.extend(bufs[:run])
+            if run < len(part):
                 break
-            blocks.append(data)
-        return blocks
+        return landed
+
+    def fetch_prefix(self, tokens, block_tokens: int = 0) -> list[bytes]:
+        """Fetches every cached prefix block for `tokens` in chain order
+        as `bytes`: `match_prefix`, then `fetch_prefix_blocks` (its
+        fail-over and whole-or-nothing rules), each landed block copied
+        out once.  The returned list may be shorter than the match when
+        every replica of a block fails — the cacheable prefix simply
+        ends there (callers recompute the rest).  A caller that goes on
+        to the device keeps the landed arrays instead
+        (`fetch_prefix_blocks`)."""
+        return [block.tobytes() for block in self.fetch_prefix_blocks(
+            self.match_prefix(tokens, block_tokens))]
 
     def close(self) -> None:
         for node in list(self._node_chs):

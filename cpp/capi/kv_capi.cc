@@ -5,6 +5,8 @@
 // and attach the native handlers to a server.
 #include <string.h>
 
+#include <utility>
+
 #include "base/iobuf.h"
 #include "net/kvstore.h"
 #include "net/server.h"
@@ -149,26 +151,29 @@ size_t trpc_kv_prefix_chain(const uint64_t* tokens, size_t ntokens,
                          reinterpret_cast<Key128*>(keys_out), max_keys);
 }
 
-// Publishes one prefix block into the two-tier store (bytes are COPIED
-// into store-owned registered pages — any caller memory works).  Fills
-// the content hash, minted generation and hot-tier coordinates.
-// Returns 0 (fresh bytes admitted), kEKvExists (2103: identical content
-// already live — the cache-hit path, lease renewed, outputs filled), or
-// -1 (over budget / bad args).
-int trpc_kv_prefix_publish(uint64_t key_hi, uint64_t key_lo, uint32_t depth,
-                           const void* data, size_t len,
-                           const uint64_t* tokens, size_t ntokens,
-                           int64_t lease_ms, uint64_t min_generation,
-                           uint64_t* hash_hi, uint64_t* hash_lo,
-                           uint64_t* gen_out, uint64_t* rkey_out,
-                           uint64_t* off_out) {
+// Publishes one prefix block into the two-tier store.  `in_place` != 0
+// and a source in registered memory: the store takes the bytes where
+// they lie and co-owns their region (the caller's promise that nobody
+// writes them meanwhile: kv.py passes it for the host pool's landing
+// blocks); else they are copied once into store-owned pages — any caller
+// memory works.  Fills the content hash, minted generation and hot-tier
+// coordinates.  Returns 0 (fresh bytes admitted), kEKvExists (2103:
+// identical content already live — the cache-hit path, lease renewed,
+// outputs filled), or -1 (over budget / bad args).
+int trpc_kv_prefix_publish_at(uint64_t key_hi, uint64_t key_lo,
+                              uint32_t depth, const void* data, size_t len,
+                              const uint64_t* tokens, size_t ntokens,
+                              int64_t lease_ms, uint64_t min_generation,
+                              int in_place, uint64_t* hash_hi,
+                              uint64_t* hash_lo, uint64_t* gen_out,
+                              uint64_t* rkey_out, uint64_t* off_out) {
   Key128 key;
   key.hi = key_hi;
   key.lo = key_lo;
   KvPrefixMeta m;
   const int rc = kv_store().publish_prefix(key, depth, data, len, tokens,
                                            ntokens, lease_ms, &m,
-                                           min_generation);
+                                           min_generation, in_place != 0);
   if (rc != 0 && rc != kEKvExists) {
     return rc;
   }
@@ -214,40 +219,33 @@ size_t trpc_kv_prefix_registry_replicas() {
   return kv_registry().prefix_replicas();
 }
 
-// Prefix-tier outcome counters since process start.
+// Prefix-tier outcome counters (the registry's kv_prefix_* Adders) since
+// process start or the last trpc_kv_reset.
 void trpc_kv_prefix_counters(uint64_t* promote, uint64_t* demote,
                              uint64_t* hot_hits, uint64_t* cold_hits,
                              uint64_t* dedup) {
   KvPrefixCounters& c = kv_prefix_counters();
-  if (promote != nullptr) {
-    *promote = KvPrefixCounters::read(c.promote);
-  }
-  if (demote != nullptr) {
-    *demote = KvPrefixCounters::read(c.demote);
-  }
-  if (hot_hits != nullptr) {
-    *hot_hits = KvPrefixCounters::read(c.hot_hits);
-  }
-  if (cold_hits != nullptr) {
-    *cold_hits = KvPrefixCounters::read(c.cold_hits);
-  }
-  if (dedup != nullptr) {
-    *dedup = KvPrefixCounters::read(c.dedup);
+  const std::pair<uint64_t*, const Adder*> outs[] = {
+      {promote, &c.promote}, {demote, &c.demote}, {hot_hits, &c.hot_hits},
+      {cold_hits, &c.cold_hits}, {dedup, &c.dedup}};
+  for (const auto& [out, counter] : outs) {
+    if (out != nullptr) {
+      *out = KvPrefixCounters::read(*counter);
+    }
   }
 }
 
 // Test support: drops every local block, tombstone, and registry record
 // (both the id-addressed and content-addressed tiers) and zeroes the
-// prefix outcome counters.
+// five prefix outcome counters trpc_kv_prefix_counters reads.
 void trpc_kv_reset() {
   kv_store().clear();
   kv_registry().clear();
   KvPrefixCounters& c = kv_prefix_counters();
-  c.promote.store(0, std::memory_order_relaxed);
-  c.demote.store(0, std::memory_order_relaxed);
-  c.hot_hits.store(0, std::memory_order_relaxed);
-  c.cold_hits.store(0, std::memory_order_relaxed);
-  c.dedup.store(0, std::memory_order_relaxed);
+  for (Adder* counter :
+       {&c.promote, &c.demote, &c.hot_hits, &c.cold_hits, &c.dedup}) {
+    counter->reset();
+  }
 }
 
 }  // extern "C"

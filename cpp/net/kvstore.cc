@@ -1,7 +1,9 @@
 #include "net/kvstore.h"
 
 #include <errno.h>
+#include <stdlib.h>
 #include <string.h>
+#include <unistd.h>
 
 #include <algorithm>
 #include <limits>
@@ -229,81 +231,13 @@ KvVars& kv_vars() {
   return *v;
 }
 
+// The prefix tier's gauges; its counters are KvPrefixCounters' Adders.
 struct KvPrefixVars {
-  Adder publish_total;
-  Adder fetch_total;
-  Adder put_total;
-  Adder match_total;
-  Adder match_blocks;
-  std::unique_ptr<PassiveStatus<long>> dedup_total;
-  std::unique_ptr<PassiveStatus<long>> promote_total;
-  std::unique_ptr<PassiveStatus<long>> demote_total;
-  std::unique_ptr<PassiveStatus<long>> hot_hit_total;
-  std::unique_ptr<PassiveStatus<long>> cold_hit_total;
   std::unique_ptr<PassiveStatus<long>> store_blocks;
   std::unique_ptr<PassiveStatus<long>> store_hot_bytes;
   std::unique_ptr<PassiveStatus<long>> store_cold_bytes;
   std::unique_ptr<PassiveStatus<long>> registry_records;
   KvPrefixVars() {
-    publish_total.expose(
-        "kv_prefix_publish_total",
-        "content-addressed prefix blocks published (fresh bytes copied "
-        "into this node's two-tier prefix store)");
-    fetch_total.expose("kv_prefix_fetch_total",
-                       "prefix-block fetches served by this node (hot "
-                       "zero-copy + cold/promoted)");
-    put_total.expose(
-        "kv_prefix_put_total",
-        "prefix-replica registrations accepted by the registry on this "
-        "node (one chain key folds N publishers into a replica set)");
-    match_total.expose(
-        "kv_prefix_match_total",
-        "longest-cached-prefix queries answered by the registry on this "
-        "node (KvReg.Match walks chain keys until first miss)");
-    match_blocks.expose(
-        "kv_prefix_match_blocks",
-        "prefix blocks matched across all KvReg.Match answers (sum of "
-        "matched depths — divide by kv_prefix_match_total for the mean "
-        "cached-prefix length)");
-    dedup_total = std::make_unique<PassiveStatus<long>>([] {
-      return static_cast<long>(
-          KvPrefixCounters::read(kv_prefix_counters().dedup));
-    });
-    dedup_total->expose(
-        "kv_prefix_dedup_total",
-        "publishes that folded into an existing replica set instead of "
-        "minting a new record (fleet-wide content dedup events)");
-    promote_total = std::make_unique<PassiveStatus<long>>([] {
-      return static_cast<long>(
-          KvPrefixCounters::read(kv_prefix_counters().promote));
-    });
-    promote_total->expose(
-        "kv_prefix_promote_total",
-        "cold prefix blocks promoted back into registered-RMA pages on "
-        "fetch (promotion-on-hit)");
-    demote_total = std::make_unique<PassiveStatus<long>>([] {
-      return static_cast<long>(
-          KvPrefixCounters::read(kv_prefix_counters().demote));
-    });
-    demote_total->expose(
-        "kv_prefix_demote_total",
-        "hot prefix blocks spilled to the unregistered cold tier under "
-        "trpc_kv_prefix_hot_bytes pressure (demoted, not dropped)");
-    hot_hit_total = std::make_unique<PassiveStatus<long>>([] {
-      return static_cast<long>(
-          KvPrefixCounters::read(kv_prefix_counters().hot_hits));
-    });
-    hot_hit_total->expose(
-        "kv_prefix_hot_hit_total",
-        "prefix fetches served zero-copy from hot registered pages");
-    cold_hit_total = std::make_unique<PassiveStatus<long>>([] {
-      return static_cast<long>(
-          KvPrefixCounters::read(kv_prefix_counters().cold_hits));
-    });
-    cold_hit_total->expose(
-        "kv_prefix_cold_hit_total",
-        "prefix fetches that found the block demoted in the cold tier "
-        "(each one attempts promotion back to hot)");
     store_blocks = std::make_unique<PassiveStatus<long>>(
         [] { return static_cast<long>(kv_store().prefix_count()); });
     store_blocks->expose(
@@ -351,6 +285,7 @@ void kv_ensure_registered() {
   prefix_block_tokens_flag();
   kv_vars();
   kv_prefix_vars();
+  kv_prefix_counters();
 }
 
 void kv_note_fetch_many(uint64_t records) {
@@ -375,6 +310,91 @@ void kv_note_sequence(uint64_t page_records, uint64_t page_bytes,
 void kv_note_publish(uint64_t in_place_bytes, uint64_t copy_bytes) {
   kv_vars().publish_in_place_bytes << static_cast<int64_t>(in_place_bytes);
   kv_vars().publish_copy_bytes << static_cast<int64_t>(copy_bytes);
+}
+
+KvPrefixCounters::KvPrefixCounters() {
+  publish_total.expose(
+      "kv_prefix_publish_total",
+      "content-addressed prefix blocks admitted into this node's two-tier "
+      "prefix store (fresh bytes; a re-offer of live content is "
+      "kv_prefix_publish_renewed)");
+  publish_bytes.expose("kv_prefix_publish_bytes",
+                       "bytes of those admitted prefix blocks");
+  publish_copy_bytes.expose(
+      "kv_prefix_publish_copy_bytes",
+      "bytes of admitted prefix blocks copied once into store-owned "
+      "pages: their source was not a landing block the store may keep");
+  publish_in_place_bytes.expose(
+      "kv_prefix_publish_in_place_bytes",
+      "bytes of admitted prefix blocks taken where their device-to-host "
+      "transfer landed them: nothing copied on the host");
+  publish_renewed.expose(
+      "kv_prefix_publish_renewed",
+      "prefix publishes that found identical content live: the lease "
+      "renewed, no bytes admitted (the cache-hit path of a re-offer)");
+  renew_promote.expose(
+      "kv_prefix_renew_promote",
+      "renewing prefix publishes that found the block in the heap tier "
+      "and brought it back hot on the publisher's bytes (in place, else "
+      "one copy): a touch is a touch, a publisher's as a fetch's");
+  hash_us.expose("kv_prefix_hash_us",
+                 "time prefix publishes spent hashing block bytes and "
+                 "token spans (us)");
+  fetch_total.expose("kv_prefix_fetch_total",
+                     "prefix-block fetches served by this node: "
+                     "kv_prefix_hot_hits + kv_prefix_cold_hits");
+  hot_hits.expose(
+      "kv_prefix_hot_hits",
+      "prefix fetches served zero-copy from hot registered pages");
+  cold_hits.expose(
+      "kv_prefix_cold_hits",
+      "prefix fetches that found the block demoted in the cold tier "
+      "(each one attempts promotion back to hot)");
+  promote.expose(
+      "kv_prefix_promote",
+      "cold prefix blocks promoted back into registered-RMA pages on "
+      "fetch (promotion-on-hit)");
+  demote.expose(
+      "kv_prefix_demote",
+      "hot prefix blocks spilled to the unregistered cold tier under "
+      "trpc_kv_prefix_hot_bytes pressure (demoted, not dropped)");
+  dropped.expose(
+      "kv_prefix_dropped",
+      "prefix blocks dropped from the store with a generation tombstone: "
+      "trpc_kv_store_bytes pressure, a lapsed lease, or a withdraw");
+  fetch_stale.expose(
+      "kv_prefix_fetch_stale",
+      "prefix fetches answered kv-stale (dropped or withdrawn block, "
+      "lapsed lease, generation mismatch): the cached prefix ends there");
+  lock_wait_us.expose(
+      "kv_prefix_lock_wait_us",
+      "time prefix fetches and publishes waited for the store's lock "
+      "(us; a lock found free counts nothing)");
+  put_total.expose(
+      "kv_prefix_put_total",
+      "prefix-replica registrations accepted by the registry on this "
+      "node (one chain key folds N publishers into a replica set)");
+  put_many_total.expose(
+      "kv_prefix_put_many_total",
+      "KvReg.PutPrefixMany calls answered by the registry on this node");
+  put_many_records.expose(
+      "kv_prefix_put_many_records",
+      "records those calls carried: divide by kv_prefix_put_many_total "
+      "for the records a round trip");
+  dedup.expose(
+      "kv_prefix_dedup",
+      "publishes that folded into an existing replica set instead of "
+      "minting a new record (fleet-wide content dedup events)");
+  match_total.expose(
+      "kv_prefix_match_total",
+      "longest-cached-prefix queries answered by the registry on this "
+      "node (KvReg.Match walks chain keys until first miss)");
+  match_keys.expose("kv_prefix_match_keys",
+                    "chain keys those queries asked for");
+  match_blocks.expose(
+      "kv_prefix_match_blocks",
+      "prefix blocks matched across all KvReg.Match answers (sum of "
+      "matched depths: over kv_prefix_match_keys, the share cached)");
 }
 
 KvPrefixCounters& kv_prefix_counters() {
@@ -467,6 +487,192 @@ KvStore& kv_store() {
   static KvStore* s = new KvStore();
   return *s;
 }
+
+namespace {
+
+// A store-owned hot region with nobody's bytes in it.
+struct SpareRegion {
+  char* data = nullptr;
+  size_t len = 0;
+  std::shared_ptr<RmaMapping> map;  // the holder's own reference
+};
+
+// What the two tiers let go of and take up again: store-owned registered
+// regions (a demote or a drop frees one, a promote or a copied publish
+// needs one) and heap blocks (the reverse), kept by size up to
+// kSpareBytes each, so that a move in a store at its budgets writes
+// pages that are faulted in already.  A process-wide list under its own
+// lock, never taken with the store's; emptied by KvStore::clear() and
+// when the process ends normally (a region is a name in /dev/shm).
+class Spares;
+Spares& spares();
+
+class Spares {
+ public:
+  static constexpr size_t kSpareBytes = 256u << 20;
+  // Who owns the mapping of a region nobody reads: the region registry
+  // and this list (cpp/capi/hostpool_capi.cc reads the same count).
+  static constexpr long kOwnersAtRest = 2;
+
+  void give(SpareRegion r) {
+    {
+      std::lock_guard<std::mutex> g(mu_);
+      if (region_bytes_ + r.len <= kSpareBytes) {
+        if (made_by_ != getpid()) {
+          made_by_ = getpid();
+          // A forked child leaves its parent's names alone.
+          atexit([] {
+            if (getpid() == spares().made_by_) {
+              spares().clear();
+            }
+          });
+        }
+        region_bytes_ += r.len;
+        regions_.push_back(std::move(r));
+        return;
+      }
+    }
+    r.map.reset();
+    rma_free(r.data);
+  }
+  // A region of `len` bytes that no response is still serving, or none.
+  bool take(size_t len, SpareRegion* out) {
+    std::lock_guard<std::mutex> g(mu_);
+    for (size_t i = regions_.size(); i-- > 0;) {
+      if (regions_[i].len == len &&
+          regions_[i].map.use_count() <= kOwnersAtRest) {
+        // Acquire: the last reader's reads happened before it let go of
+        // the mapping, and so before whatever is written here next.
+        std::atomic_thread_fence(std::memory_order_acquire);
+        *out = std::move(regions_[i]);
+        regions_.erase(regions_.begin() + static_cast<ptrdiff_t>(i));
+        region_bytes_ -= len;
+        return true;
+      }
+    }
+    return false;
+  }
+  void give_heap(char* data, size_t len) {
+    {
+      std::lock_guard<std::mutex> g(mu_);
+      if (heap_bytes_ + len <= kSpareBytes) {
+        heap_bytes_ += len;
+        heap_.emplace_back(len, data);
+        return;
+      }
+    }
+    free(data);
+  }
+  char* take_heap(size_t len) {
+    {
+      std::lock_guard<std::mutex> g(mu_);
+      for (size_t i = heap_.size(); i-- > 0;) {
+        if (heap_[i].first == len) {
+          char* data = heap_[i].second;
+          heap_.erase(heap_.begin() + static_cast<ptrdiff_t>(i));
+          heap_bytes_ -= len;
+          return data;
+        }
+      }
+    }
+    return static_cast<char*>(malloc(len));
+  }
+  void clear() {
+    std::vector<SpareRegion> regions;
+    std::vector<std::pair<size_t, char*>> heap;
+    {
+      std::lock_guard<std::mutex> g(mu_);
+      regions.swap(regions_);
+      heap.swap(heap_);
+      region_bytes_ = heap_bytes_ = 0;
+    }
+    for (SpareRegion& r : regions) {
+      r.map.reset();
+      rma_free(r.data);
+    }
+    for (auto& [len, data] : heap) {
+      free(data);
+    }
+  }
+
+ private:
+  std::mutex mu_;
+  std::vector<SpareRegion> regions_;
+  std::vector<std::pair<size_t, char*>> heap_;
+  size_t region_bytes_ = 0;
+  size_t heap_bytes_ = 0;
+  pid_t made_by_ = 0;
+};
+
+Spares& spares() {
+  static Spares* s = new Spares();
+  return *s;
+}
+
+}  // namespace
+
+// One prefix block's bytes in the heap tier: immutable once made, shared
+// by the block and by every response that serves them.  The memory goes
+// back to the spares when the last owner lets go, and the next demote of
+// that size takes it from there: pages already faulted in (a fresh 9 MB
+// block costs this host's first touch of 2,196 pages).
+class KvHeapBlock {
+ public:
+  static std::shared_ptr<const KvHeapBlock> copy_of(const void* data,
+                                                    size_t len) {
+    char* mine = spares().take_heap(len);
+    if (mine == nullptr) {
+      return nullptr;
+    }
+    memcpy(mine, data, len);
+    return std::shared_ptr<const KvHeapBlock>(new KvHeapBlock(mine, len));
+  }
+  ~KvHeapBlock() { spares().give_heap(data_, len_); }
+  KvHeapBlock(const KvHeapBlock&) = delete;
+  KvHeapBlock& operator=(const KvHeapBlock&) = delete;
+  const char* data() const { return data_; }
+
+ private:
+  KvHeapBlock(char* data, size_t len) : data_(data), len_(len) {}
+  char* data_;
+  size_t len_;
+};
+
+// What a drop or a move of a prefix block let go of (kvstore.h): freed
+// by its destructor, which every user declares BEFORE its lock so that
+// it runs after the unlock.
+struct KvStore::PrefixTrash {
+  std::vector<std::shared_ptr<RmaMapping>> maps;  // of blocks taken in place
+  std::vector<SpareRegion> regions;               // store-owned
+  std::vector<std::shared_ptr<const KvHeapBlock>> cold;
+  // Takes the memory of either tier, whoever held it.
+  void take(const char* hot_data, size_t len, bool owned,
+            std::shared_ptr<RmaMapping> map,
+            std::shared_ptr<const KvHeapBlock> heap) {
+    if (owned && hot_data != nullptr) {
+      regions.push_back({const_cast<char*>(hot_data), len, std::move(map)});
+    } else if (map != nullptr) {
+      maps.push_back(std::move(map));
+    }
+    if (heap != nullptr) {
+      cold.push_back(std::move(heap));
+    }
+  }
+  // Takes what `b` holds of either tier; `b` holds nothing afterwards.
+  void take(PrefixBlock* b) {
+    take(b->hot_data, b->meta.len, b->owned, std::move(b->map),
+         std::move(b->cold));
+    b->map = nullptr;
+    b->cold = nullptr;
+    b->hot_data = nullptr;
+    b->owned = false;
+  }
+  ~PrefixTrash() {
+    for (SpareRegion& r : regions) {
+      spares().give(std::move(r));
+    }
+  }
+};
 
 void KvStore::evict_locked(uint64_t block_id, bool count_var) {
   auto it = blocks_.find(block_id);
@@ -567,6 +773,7 @@ int KvStore::withdraw(uint64_t block_id) {
 }
 
 size_t KvStore::withdraw_all() {
+  PrefixTrash trash;
   std::lock_guard<std::mutex> g(mu_);
   size_t n = 0;
   while (!blocks_.empty()) {
@@ -578,7 +785,7 @@ size_t KvStore::withdraw_all() {
   // gets kv-stale and fails over to another replica (or re-publishes) —
   // never bytes from a dying pid.
   while (!prefix_blocks_.empty()) {
-    evict_prefix_locked(prefix_blocks_.begin()->first);
+    evict_prefix_locked(prefix_blocks_.begin()->first, &trash);
     ++n;
   }
   return n;
@@ -685,45 +892,115 @@ uint64_t KvStore::bytes_used() {
 }
 
 void KvStore::clear() {
-  std::lock_guard<std::mutex> g(mu_);
-  blocks_.clear();
-  tombstones_.clear();
-  bytes_ = 0;
-  for (auto& [hash, b] : prefix_blocks_) {
-    if (b.hot && b.hot_data != nullptr) {
-      b.map.reset();
-      rma_free(b.hot_data);
+  {
+    PrefixTrash trash;
+    std::lock_guard<std::mutex> g(mu_);
+    blocks_.clear();
+    tombstones_.clear();
+    bytes_ = 0;
+    for (auto& [hash, b] : prefix_blocks_) {
+      trash.take(&b);
     }
+    prefix_blocks_.clear();
+    prefix_tombstones_.clear();
+    lru_hot_.clear();
+    lru_cold_.clear();
+    prefix_leases_.clear();
+    prefix_hot_bytes_ = 0;
+    prefix_cold_bytes_ = 0;
   }
-  prefix_blocks_.clear();
-  prefix_tombstones_.clear();
-  prefix_hot_bytes_ = 0;
-  prefix_cold_bytes_ = 0;
+  spares().clear();
 }
 
 // ---- KvStore prefix tier (two-tier content-addressed store) --------------
+//
+// Everything a block's bytes are copied for (the one copy of a publish
+// whose source the store may not keep, a demote, a promote) and every
+// allocation and release of megabytes runs with mu_ RELEASED: the mover
+// marks the block `moving` under the lock, copies outside it from memory
+// it co-owns (the mapping, the heap block), and under the lock again
+// installs the result only if the block it finds is still the one it
+// left (same generation, still moving); a block dropped or withdrawn
+// meanwhile just loses the copy, and so does a demote whose block was
+// touched meanwhile (it stays hot, as the most recently used).  A block
+// that is moving is served from the tier it is still in.  Room in the
+// hot tier is RESERVED under the lock before the bytes are written
+// (prefix_hot_reserved_), so the hot bytes never pass their budget while
+// copies are under way.
 
-void KvStore::demote_locked(PrefixBlock* b) {
-  if (!b->hot) {
+namespace {
+
+// The store's lock for a prefix fetch or publish: what it waited is
+// kv_prefix_lock_wait_us (a lock found free reads no clock).
+void lock_counted(std::unique_lock<std::mutex>* lk) {
+  if (lk->try_lock()) {
     return;
   }
-  // Copy out FIRST, then release the pages: any in-flight serve holds
-  // its own mapping reference (KvServeCtx), so rma_free's munmap defers
-  // past it — the demote is invisible to readers mid-response.
-  b->cold.assign(b->hot_data, b->meta.len);
-  b->map.reset();
-  rma_free(b->hot_data);
-  b->hot_data = nullptr;
-  b->meta.rkey = 0;
-  b->meta.off = 0;
-  b->hot = false;
-  prefix_hot_bytes_ -= b->meta.len;
-  prefix_cold_bytes_ += b->meta.len;
-  kv_prefix_counters().bump(kv_prefix_counters().demote);
-  record_kv(b->meta.hash.lo, kKvOpDemote, b->meta.len);
+  const int64_t t0 = monotonic_time_us();
+  lk->lock();
+  kv_prefix_counters().lock_wait_us << (monotonic_time_us() - t0);
 }
 
-void KvStore::evict_prefix_locked(const Key128& hash) {
+uint64_t flag_bytes(Flag* f, int64_t otherwise) {
+  return static_cast<uint64_t>(
+      std::max<int64_t>(f != nullptr ? f->int64_value() : otherwise, 1));
+}
+
+// A store-owned registered region holding a copy of [data, data+len),
+// pinned: a spare one of that size, else a new one (fresh pages); nullptr
+// when registered memory is exhausted.  A copy of megabytes: never under
+// mu_.
+char* copy_to_region(const void* data, size_t len,
+                     std::shared_ptr<RmaMapping>* map, uint64_t* rkey,
+                     uint64_t* off) {
+  SpareRegion spare;
+  void* pages = nullptr;
+  if (spares().take(len, &spare)) {
+    pages = spare.data;
+    spare.map.reset();
+  } else {
+    uint64_t alloc_rkey = 0;
+    pages = rma_alloc(len, &alloc_rkey);
+    if (pages == nullptr) {
+      return nullptr;
+    }
+  }
+  memcpy(pages, data, len);
+  *map = rma_pin_exportable(pages, len, rkey, off);
+  if (*map == nullptr) {
+    rma_free(pages);
+    return nullptr;
+  }
+  return static_cast<char*>(pages);
+}
+
+// Deleter context for a block served from the heap tier: co-owns the
+// bytes, which are never written once they are the block's.
+struct KvColdServeCtx {
+  std::shared_ptr<const KvHeapBlock> bytes;
+};
+void kv_cold_serve_deleter(void*, void* vctx) {
+  delete static_cast<KvColdServeCtx*>(vctx);
+}
+
+}  // namespace
+
+void KvStore::touch_prefix_locked(PrefixBlock* b) {
+  PrefixLru& lru = b->hot ? lru_hot_ : lru_cold_;
+  lru.splice(lru.end(), lru, b->lru_at);
+  ++b->touches;
+}
+
+void KvStore::set_prefix_lease_locked(PrefixBlock* b, const Key128& hash,
+                                      int64_t deadline_us) {
+  if (b->deadline_us != 0) {
+    prefix_leases_.erase(b->lease_at);
+  }
+  b->deadline_us = deadline_us;
+  b->lease_at = prefix_leases_.emplace(deadline_us, hash);
+}
+
+void KvStore::evict_prefix_locked(const Key128& hash, PrefixTrash* trash) {
   auto it = prefix_blocks_.find(hash);
   if (it == prefix_blocks_.end()) {
     return;
@@ -732,119 +1009,292 @@ void KvStore::evict_prefix_locked(const Key128& hash) {
   prefix_tombstones_[hash] = b.meta.generation;
   if (b.hot) {
     prefix_hot_bytes_ -= b.meta.len;
-    b.map.reset();
-    rma_free(b.hot_data);
+    lru_hot_.erase(b.lru_at);
   } else {
     prefix_cold_bytes_ -= b.meta.len;
+    lru_cold_.erase(b.lru_at);
   }
+  prefix_leases_.erase(b.lease_at);
+  trash->take(&b);
   record_kv(hash.lo, kKvOpEvict, b.meta.len);
   kv_vars().evict_total << 1;
+  kv_prefix_counters().dropped << 1;
   prefix_blocks_.erase(it);
 }
 
-bool KvStore::fit_hot_locked(uint64_t incoming, uint64_t hot_budget) {
+bool KvStore::fit_total_locked(uint64_t incoming, uint64_t total_budget,
+                               int64_t now, PrefixTrash* trash) {
+  // Total-store pressure (blocks + hot + cold vs trpc_kv_store_bytes):
+  // expired blocks drop first, then LRU cold, then LRU hot — dropping
+  // always tombstones so evicted fetches answer kv-stale.
+  auto used = [this] {
+    return bytes_ + prefix_hot_bytes_ + prefix_hot_reserved_ +
+           prefix_cold_bytes_;
+  };
+  while (used() + incoming > total_budget && !prefix_blocks_.empty()) {
+    Key128 victim;
+    if (prefix_leases_.begin()->first <= now) {
+      victim = prefix_leases_.begin()->second;
+    } else if (!lru_cold_.empty()) {
+      victim = lru_cold_.front();
+    } else {
+      victim = lru_hot_.front();
+    }
+    evict_prefix_locked(victim, trash);
+  }
+  return used() + incoming <= total_budget;
+}
+
+bool KvStore::demote_one(std::unique_lock<std::mutex>* lk,
+                         PrefixTrash* trash) {
+  // The victim: the least recently touched hot block that no move is
+  // copying already.
+  PrefixBlock* victim = nullptr;
+  Key128 hash;
+  for (const Key128& h : lru_hot_) {
+    PrefixBlock& b = prefix_blocks_.find(h)->second;
+    if (!b.moving) {
+      victim = &b;
+      hash = h;
+      break;
+    }
+  }
+  if (victim == nullptr) {
+    return false;
+  }
+  victim->moving = true;
+  const uint64_t gen = victim->meta.generation;
+  const uint64_t touches = victim->touches;
+  const char* data = victim->hot_data;
+  const size_t len = victim->meta.len;
+  // Co-owned for the copy: a drop meanwhile cannot unmap the pages.
+  std::shared_ptr<RmaMapping> pinned = victim->map;
+  lk->unlock();
+  auto cold = KvHeapBlock::copy_of(data, len);
+  pinned.reset();
+  lk->lock();
+  auto it = prefix_blocks_.find(hash);
+  if (it == prefix_blocks_.end() || it->second.meta.generation != gen ||
+      !it->second.moving) {
+    trash->cold.push_back(std::move(cold));  // dropped meanwhile
+    return true;
+  }
+  if (it->second.touches != touches) {
+    // Fetched or renewed while the copy ran (a window's fetches reach a
+    // chain's hot blocks while its cold ones, promoted, push them out):
+    // it is the most recently used block now and stays hot; the caller
+    // finds the next victim.  Installed, it would lie in the heap tier
+    // in front of every hot block, and be dropped before them.
+    it->second.moving = false;
+    trash->cold.push_back(std::move(cold));
+    return true;
+  }
+  // Any in-flight serve holds its own mapping reference (KvServeCtx), so
+  // letting the pages go is invisible to readers mid-response.
+  PrefixBlock& b = it->second;
+  trash->take(&b);
+  b.cold = std::move(cold);
+  b.meta.rkey = 0;
+  b.meta.off = 0;
+  b.hot = false;
+  b.moving = false;
+  lru_cold_.splice(lru_cold_.end(), lru_hot_, b.lru_at);
+  prefix_hot_bytes_ -= len;
+  prefix_cold_bytes_ += len;
+  kv_prefix_counters().demote << 1;
+  record_kv(hash.lo, kKvOpDemote, len);
+  return true;
+}
+
+bool KvStore::reserve_hot(std::unique_lock<std::mutex>* lk,
+                          uint64_t incoming, PrefixTrash* trash) {
+  const uint64_t hot_budget =
+      flag_bytes(prefix_hot_bytes_flag(), 256ll << 20);
   if (incoming > hot_budget) {
     return false;  // publishes straight to cold
   }
   // Hot pressure DEMOTES (never drops): the bytes stay serveable, they
-  // just lose the zero-copy fast path until a hit promotes them back.
-  while (prefix_hot_bytes_ + incoming > hot_budget) {
-    PrefixBlock* victim = nullptr;
-    uint64_t oldest_touch = std::numeric_limits<uint64_t>::max();
-    for (auto& [hash, b] : prefix_blocks_) {
-      if (b.hot && b.touch_seq < oldest_touch) {
-        oldest_touch = b.touch_seq;
-        victim = &b;
-      }
-    }
-    if (victim == nullptr) {
+  // just lose the registered pages until a hit promotes them back.
+  while (prefix_hot_bytes_ + prefix_hot_reserved_ + incoming > hot_budget) {
+    if (!demote_one(lk, trash)) {
       return false;  // nothing left to demote yet still over: can't fit
     }
-    demote_locked(victim);
   }
+  prefix_hot_reserved_ += incoming;
   return true;
+}
+
+KvStore::PrefixBlock* KvStore::promote_marked(
+    std::unique_lock<std::mutex>* lk, const Key128& hash, const void* src,
+    std::shared_ptr<RmaMapping> map, uint64_t rkey, uint64_t off,
+    PrefixTrash* trash) {
+  PrefixBlock* b = &prefix_blocks_.find(hash)->second;
+  const uint64_t gen = b->meta.generation;
+  const uint64_t len = b->meta.len;
+  const char* pages = nullptr;
+  bool owned = false;
+  if (reserve_hot(lk, len, trash)) {
+    if (map != nullptr) {
+      pages = static_cast<const char*>(src);
+    } else {
+      lk->unlock();
+      pages = copy_to_region(src, len, &map, &rkey, &off);
+      lk->lock();
+      owned = pages != nullptr;
+    }
+    prefix_hot_reserved_ -= len;
+  }
+  // The lock was released (a demote's copy, this copy): the block may
+  // have been dropped, or dropped and published again.
+  auto it = prefix_blocks_.find(hash);
+  b = it == prefix_blocks_.end() ? nullptr : &it->second;
+  if (b == nullptr || b->meta.generation != gen || !b->moving) {
+    trash->take(pages, len, owned, std::move(map), nullptr);
+    return nullptr;
+  }
+  b->moving = false;
+  if (pages == nullptr) {
+    trash->take(nullptr, len, false, std::move(map), nullptr);
+    return b;
+  }
+  trash->take(b);  // the heap block
+  b->hot_data = pages;
+  b->map = std::move(map);
+  b->owned = owned;
+  b->meta.rkey = rkey;
+  b->meta.off = off;
+  b->hot = true;
+  lru_hot_.splice(lru_hot_.end(), lru_cold_, b->lru_at);
+  prefix_hot_bytes_ += len;
+  prefix_cold_bytes_ -= len;
+  return b;
 }
 
 int KvStore::publish_prefix(const Key128& key, uint32_t depth,
                             const void* data, size_t len,
                             const uint64_t* tokens, size_t ntokens,
                             int64_t lease_ms, KvPrefixMeta* out,
-                            uint64_t min_generation) {
+                            uint64_t min_generation, bool in_place) {
   kv_ensure_registered();
   if (key.zero() || data == nullptr || len == 0) {
     return -1;
   }
+  KvPrefixCounters& counted = kv_prefix_counters();
   Key128 hash;
+  const int64_t hash_t0 = monotonic_time_us();
   kv_content_hash(data, len, tokens, ntokens, &hash);
-  const uint64_t total_budget = static_cast<uint64_t>(std::max<int64_t>(
-      store_bytes_flag() != nullptr ? store_bytes_flag()->int64_value()
-                                    : (1ll << 30),
-      1));
-  const uint64_t hot_budget = static_cast<uint64_t>(std::max<int64_t>(
-      prefix_hot_bytes_flag() != nullptr
-          ? prefix_hot_bytes_flag()->int64_value()
-          : (256ll << 20),
-      1));
+  counted.hash_us << (monotonic_time_us() - hash_t0);
+  const uint64_t total_budget =
+      flag_bytes(store_bytes_flag(), 1ll << 30);
   if (len > total_budget) {
     return -1;
   }
+  // A source the store may keep: registered memory the caller lets it
+  // co-own (pinned before the lock; a refusal just means a copy).
+  std::shared_ptr<RmaMapping> map;
+  uint64_t rkey = 0;
+  uint64_t off = 0;
+  if (in_place) {
+    map = rma_pin_exportable(data, len, &rkey, &off);
+  }
   const int64_t now = monotonic_time_us();
-  std::lock_guard<std::mutex> g(mu_);
+  PrefixTrash trash;
+  std::unique_lock<std::mutex> lk(mu_, std::defer_lock);
+  lock_counted(&lk);
   auto it = prefix_blocks_.find(hash);
   if (it != prefix_blocks_.end()) {
     if (it->second.deadline_us > now) {
       // Live block with identical content: THE cache-hit path.  The
       // lease renews and the record echoes, but kEKvExists tells the
       // caller these bytes did NOT need recomputing/copying.
-      PrefixBlock& b = it->second;
-      b.deadline_us = effective_lease_us(lease_ms);
-      b.touch_seq = ++touch_counter_;
-      if (out != nullptr) {
-        *out = b.meta;
+      PrefixBlock* b = &it->second;
+      set_prefix_lease_locked(b, hash, effective_lease_us(lease_ms));
+      touch_prefix_locked(b);
+      KvPrefixMeta meta = b->meta;
+      if (!b->hot && !b->moving) {
+        // A touch brings a block to the hot tier, a publisher's as a
+        // fetch's: the heap block takes the publisher's bytes as its hot
+        // pages (in place, else copied once) and lets its own go.  Left
+        // in the heap tier it would be dropped before every hot block,
+        // however recently it was renewed.
+        b->moving = true;
+        b = promote_marked(&lk, hash, data, std::move(map), rkey, off,
+                           &trash);
+        if (b != nullptr) {
+          meta = b->meta;
+          if (b->hot) {
+            counted.renew_promote << 1;
+          }
+        }
       }
+      if (out != nullptr) {
+        *out = meta;
+      }
+      counted.publish_renewed << 1;
       return kEKvExists;
     }
-    evict_prefix_locked(hash);  // lapsed: fold to tombstone, re-admit
+    evict_prefix_locked(hash, &trash);  // lapsed: tombstone, re-admit
   }
-  // Total-store pressure (blocks + hot + cold vs trpc_kv_store_bytes):
-  // expired blocks drop first, then LRU cold, then LRU hot — dropping
-  // always tombstones so evicted fetches answer kv-stale.
-  while (bytes_ + prefix_hot_bytes_ + prefix_cold_bytes_ + len >
-             total_budget &&
-         !prefix_blocks_.empty()) {
-    Key128 victim;
-    uint64_t oldest_cold = std::numeric_limits<uint64_t>::max();
-    uint64_t oldest_hot = std::numeric_limits<uint64_t>::max();
-    Key128 victim_cold;
-    Key128 victim_hot;
-    bool found = false;
-    for (const auto& [h, b] : prefix_blocks_) {
-      if (b.deadline_us <= now) {
-        victim = h;
-        found = true;
-        break;
-      }
-      if (!b.hot && b.touch_seq < oldest_cold) {
-        oldest_cold = b.touch_seq;
-        victim_cold = h;
-      }
-      if (b.hot && b.touch_seq < oldest_hot) {
-        oldest_hot = b.touch_seq;
-        victim_hot = h;
-      }
-    }
-    if (!found) {
-      victim = oldest_cold != std::numeric_limits<uint64_t>::max()
-                   ? victim_cold
-                   : victim_hot;
-    }
-    evict_prefix_locked(victim);
-  }
-  if (bytes_ + prefix_hot_bytes_ + prefix_cold_bytes_ + len >
-      total_budget) {
+  if (!fit_total_locked(len, total_budget, now, &trash)) {
     return -1;  // regular blocks own the budget: don't evict them here
   }
-  PrefixBlock b;
+  // Hot placement: registered pages so fetches serve from them.  The
+  // room is reserved first; the bytes then move with the lock released.
+  // Falls to the cold tier when the block outsizes the hot budget or
+  // registered memory is exhausted — cold still serves.
+  const char* hot_data = nullptr;
+  bool owned = false;
+  std::shared_ptr<const KvHeapBlock> cold;
+  if (reserve_hot(&lk, len, &trash)) {
+    if (map != nullptr) {
+      hot_data = static_cast<const char*>(data);
+    } else {
+      lk.unlock();
+      hot_data = copy_to_region(data, len, &map, &rkey, &off);
+      lk.lock();
+      owned = hot_data != nullptr;
+    }
+    if (hot_data == nullptr) {
+      prefix_hot_reserved_ -= len;
+    }
+  }
+  if (hot_data == nullptr) {
+    map.reset();
+    // The heap tier's bytes count against the total from here on; the
+    // copy itself runs outside the lock.
+    prefix_cold_bytes_ += len;
+    lk.unlock();
+    cold = KvHeapBlock::copy_of(data, len);
+    lk.lock();
+    prefix_cold_bytes_ -= len;
+  }
+  if (hot_data != nullptr) {
+    prefix_hot_reserved_ -= len;  // the room is the block's own from here
+  }
+  auto discard = [&] {
+    trash.take(hot_data, len, owned, std::move(map), std::move(cold));
+  };
+  // The lock was released: another publisher of the same content may
+  // have admitted it meanwhile, and then this copy is not needed.
+  it = prefix_blocks_.find(hash);
+  if (it != prefix_blocks_.end()) {
+    discard();
+    PrefixBlock& b = it->second;
+    set_prefix_lease_locked(&b, hash, effective_lease_us(lease_ms));
+    touch_prefix_locked(&b);
+    if (out != nullptr) {
+      *out = b.meta;
+    }
+    counted.publish_renewed << 1;
+    return kEKvExists;
+  }
+  // And other publishers may have filled the store meanwhile: the total
+  // is made to fit again now.
+  if (!fit_total_locked(len, total_budget, now, &trash)) {
+    discard();
+    return -1;
+  }
+  PrefixBlock& b = prefix_blocks_[hash];
   b.meta.key = key;
   b.meta.hash = hash;
   b.meta.generation =
@@ -852,133 +1302,114 @@ int KvStore::publish_prefix(const Key128& key, uint32_t depth,
   prefix_tombstones_[hash] = b.meta.generation;
   b.meta.len = len;
   b.meta.depth = depth;
-  b.deadline_us = effective_lease_us(lease_ms);
-  b.touch_seq = ++touch_counter_;
-  // Hot placement: store-owned registered pages so fetches serve
-  // zero-copy.  Falls to the cold tier when the block outsizes the hot
-  // budget or registered memory is exhausted — cold still serves.
-  bool placed_hot = false;
-  if (fit_hot_locked(len, hot_budget)) {
-    uint64_t rkey = 0;
-    void* pages = rma_alloc(len, &rkey);
-    if (pages != nullptr) {
-      memcpy(pages, data, len);
-      uint64_t pin_rkey = 0;
-      uint64_t pin_off = 0;
-      b.map = rma_pin_exportable(pages, len, &pin_rkey, &pin_off);
-      if (b.map != nullptr) {
-        b.hot_data = static_cast<char*>(pages);
-        b.meta.rkey = pin_rkey;
-        b.meta.off = pin_off;
-        b.hot = true;
-        prefix_hot_bytes_ += len;
-        placed_hot = true;
-      } else {
-        rma_free(pages);
-      }
-    }
-  }
-  if (!placed_hot) {
-    b.cold.assign(static_cast<const char*>(data), len);
+  set_prefix_lease_locked(&b, hash, effective_lease_us(lease_ms));
+  if (hot_data != nullptr) {
+    b.hot_data = hot_data;
+    b.map = std::move(map);
+    b.owned = owned;
+    b.meta.rkey = rkey;
+    b.meta.off = off;
+    b.hot = true;
+    prefix_hot_bytes_ += len;
+    b.lru_at = lru_hot_.insert(lru_hot_.end(), hash);
+  } else {
+    b.cold = std::move(cold);
     prefix_cold_bytes_ += len;
+    b.lru_at = lru_cold_.insert(lru_cold_.end(), hash);
   }
   if (out != nullptr) {
     *out = b.meta;
   }
   record_kv(hash.lo, kKvOpPublish, len);
-  prefix_blocks_[hash] = std::move(b);
-  kv_prefix_vars().publish_total << 1;
+  counted.publish_total << 1;
+  counted.publish_bytes << static_cast<int64_t>(len);
+  (b.hot && !b.owned ? counted.publish_in_place_bytes
+                     : counted.publish_copy_bytes)
+      << static_cast<int64_t>(len);
   return 0;
 }
 
 int KvStore::fetch_prefix(const Key128& hash, uint64_t expected_gen,
                           IOBuf* out) {
   kv_ensure_registered();
+  KvPrefixCounters& counted = kv_prefix_counters();
   const int64_t now = monotonic_time_us();
-  std::lock_guard<std::mutex> g(mu_);
+  PrefixTrash trash;
+  std::unique_lock<std::mutex> lk(mu_, std::defer_lock);
+  lock_counted(&lk);
   auto it = prefix_blocks_.find(hash);
   if (it == prefix_blocks_.end() || it->second.deadline_us <= now) {
     if (it != prefix_blocks_.end()) {
-      evict_prefix_locked(hash);  // serve-time validity, as fetch()
+      evict_prefix_locked(hash, &trash);  // serve-time validity, as fetch()
     }
     if (prefix_tombstones_.find(hash) != prefix_tombstones_.end()) {
       kv_vars().stale_total << 1;
+      counted.fetch_stale << 1;
       record_kv(hash.lo, kKvOpStale, 0);
       return kEKvStale;
     }
     return kEKvMiss;
   }
-  PrefixBlock& b = it->second;
+  PrefixBlock* b = &it->second;
   // expected_gen 0 accepts any live generation (content addressing
   // already names the exact bytes; the generation only fences zombies).
-  if (expected_gen != 0 && b.meta.generation != expected_gen) {
+  if (expected_gen != 0 && b->meta.generation != expected_gen) {
     kv_vars().stale_total << 1;
-    record_kv(hash.lo, kKvOpStale, b.meta.len);
+    counted.fetch_stale << 1;
+    record_kv(hash.lo, kKvOpStale, b->meta.len);
     return kEKvStale;
   }
-  b.touch_seq = ++touch_counter_;
-  if (!b.hot) {
-    kv_prefix_counters().bump(kv_prefix_counters().cold_hits);
+  const uint64_t len = b->meta.len;
+  auto served = [&] {
+    counted.fetch_total << 1;
+    kv_vars().fetch_bytes << static_cast<int64_t>(len);
+    record_kv(hash.lo, kKvOpServe, len);
+    return 0;
+  };
+  auto serve_heap = [&](const std::shared_ptr<const KvHeapBlock>& bytes) {
+    out->append_user_data(const_cast<char*>(bytes->data()), len,
+                          &kv_cold_serve_deleter, new KvColdServeCtx{bytes});
+    return served();
+  };
+  touch_prefix_locked(b);
+  if (b->hot) {
+    counted.hot_hits << 1;
+  } else {
+    counted.cold_hits << 1;
     // Promotion-on-hit: copy back into registered pages so the NEXT
-    // fetch is zero-copy again.  Failure to promote (registered memory
-    // exhausted) still serves — a plain copy of the cold bytes.
-    const uint64_t hot_budget = static_cast<uint64_t>(std::max<int64_t>(
-        prefix_hot_bytes_flag() != nullptr
-            ? prefix_hot_bytes_flag()->int64_value()
-            : (256ll << 20),
-        1));
-    bool promoted = false;
-    if (fit_hot_locked(b.meta.len, hot_budget)) {
-      uint64_t rkey = 0;
-      void* pages = rma_alloc(b.meta.len, &rkey);
-      if (pages != nullptr) {
-        memcpy(pages, b.cold.data(), b.meta.len);
-        uint64_t pin_rkey = 0;
-        uint64_t pin_off = 0;
-        std::shared_ptr<RmaMapping> map =
-            rma_pin_exportable(pages, b.meta.len, &pin_rkey, &pin_off);
-        if (map != nullptr) {
-          b.hot_data = static_cast<char*>(pages);
-          b.map = std::move(map);
-          b.meta.rkey = pin_rkey;
-          b.meta.off = pin_off;
-          b.hot = true;
-          prefix_hot_bytes_ += b.meta.len;
-          prefix_cold_bytes_ -= b.meta.len;
-          b.cold.clear();
-          b.cold.shrink_to_fit();
-          kv_prefix_counters().bump(kv_prefix_counters().promote);
-          record_kv(hash.lo, kKvOpPromote, b.meta.len);
-          promoted = true;
-        } else {
-          rma_free(pages);
-        }
+    // fetch is a hot hit again.  One mover a block: a second fetch of
+    // it meanwhile is served from the heap, as is one whose promotion
+    // finds no room or no registered memory.
+    if (!b->moving) {
+      b->moving = true;
+      std::shared_ptr<const KvHeapBlock> bytes = b->cold;
+      b = promote_marked(&lk, hash, bytes->data(), nullptr, 0, 0, &trash);
+      if (b == nullptr) {
+        // Gone meanwhile: the bytes read are still this generation's,
+        // validated when the fetch began; serve them and keep nothing.
+        return serve_heap(bytes);
+      }
+      if (b->hot) {
+        counted.promote << 1;
+        record_kv(hash.lo, kKvOpPromote, len);
       }
     }
-    if (!promoted) {
-      out->append(b.cold.data(), b.meta.len);
-      kv_prefix_vars().fetch_total << 1;
-      kv_vars().fetch_bytes << static_cast<int64_t>(b.meta.len);
-      record_kv(hash.lo, kKvOpServe, b.meta.len);
-      return 0;
-    }
-  } else {
-    kv_prefix_counters().bump(kv_prefix_counters().hot_hits);
   }
-  auto* ctx = new KvServeCtx{b.map};
-  out->append_user_data(b.hot_data, b.meta.len, &kv_serve_deleter, ctx);
-  kv_prefix_vars().fetch_total << 1;
-  kv_vars().fetch_bytes << static_cast<int64_t>(b.meta.len);
-  record_kv(hash.lo, kKvOpServe, b.meta.len);
-  return 0;
+  if (!b->hot) {
+    return serve_heap(b->cold);
+  }
+  out->append_user_data(const_cast<char*>(b->hot_data), len,
+                        &kv_serve_deleter, new KvServeCtx{b->map});
+  return served();
 }
 
 int KvStore::withdraw_prefix(const Key128& hash) {
+  PrefixTrash trash;
   std::lock_guard<std::mutex> g(mu_);
   if (prefix_blocks_.find(hash) == prefix_blocks_.end()) {
     return kEKvMiss;
   }
-  evict_prefix_locked(hash);
+  evict_prefix_locked(hash, &trash);
   return 0;
 }
 
@@ -1150,7 +1581,7 @@ int KvRegistry::put_prefix(const KvPrefixMeta& meta, int64_t lease_ms,
       if (gen_out != nullptr) {
         *gen_out = meta.generation;
       }
-      kv_prefix_vars().put_total << 1;
+      kv_prefix_counters().put_total << 1;
       return 0;
     }
   }
@@ -1162,9 +1593,9 @@ int KvRegistry::put_prefix(const KvPrefixMeta& meta, int64_t lease_ms,
   fence = std::max(fence, meta.generation);
   if (folded) {
     // N publishers, one record: the fleet-wide dedup event.
-    kv_prefix_counters().bump(kv_prefix_counters().dedup);
+    kv_prefix_counters().dedup << 1;
   }
-  kv_prefix_vars().put_total << 1;
+  kv_prefix_counters().put_total << 1;
   if (gen_out != nullptr) {
     *gen_out = meta.generation;
   }
@@ -1177,7 +1608,8 @@ size_t KvRegistry::match(const Key128* keys, size_t n,
   kv_ensure_registered();
   const int64_t now = monotonic_time_us();
   std::lock_guard<std::mutex> g(mu_);
-  kv_prefix_vars().match_total << 1;
+  kv_prefix_counters().match_total << 1;
+  kv_prefix_counters().match_keys << static_cast<int64_t>(n);
   size_t matched = 0;
   for (size_t i = 0; i < n; ++i) {
     auto it = prefix_.find(keys[i]);
@@ -1204,7 +1636,7 @@ size_t KvRegistry::match(const Key128* keys, size_t n,
     }
     ++matched;
   }
-  kv_prefix_vars().match_blocks << static_cast<int64_t>(matched);
+  kv_prefix_counters().match_blocks << static_cast<int64_t>(matched);
   return matched;
 }
 
@@ -1304,6 +1736,21 @@ void prefix_meta_to_wire(const KvPrefixMeta& m, int64_t lease_ms,
   memcpy(w->node, m.node, sizeof(w->node));
 }
 
+KvPrefixMeta wire_to_prefix_meta(const KvPrefixWire& w) {
+  KvPrefixMeta m;
+  m.key.hi = w.key_hi;
+  m.key.lo = w.key_lo;
+  m.hash.hi = w.hash_hi;
+  m.hash.lo = w.hash_lo;
+  m.generation = w.generation;
+  m.rkey = w.rkey;
+  m.off = w.off;
+  m.len = w.len;
+  m.depth = w.depth;
+  memcpy(m.node, w.node, sizeof(m.node));
+  return m;
+}
+
 KvBlockMeta wire_to_meta(const KvWire& w) {
   KvBlockMeta m;
   m.block_id = w.block_id;
@@ -1330,20 +1777,20 @@ void meta_to_wire(const KvBlockMeta& m, int64_t lease_ms, KvWire* w) {
 // KvWire in, the count then one zero-initialised Entry per wire out,
 // filled by `one` in request order.  An entry's failure is its own
 // status; only a request that does not parse fails the call.
-template <typename Entry, typename One>
-void serve_many(Controller* cntl, const IOBuf& req, IOBuf* resp,
-                const char* what, One one) {
+template <typename Entry, typename Wire = KvWire, typename One>
+uint64_t serve_many(Controller* cntl, const IOBuf& req, IOBuf* resp,
+                    const char* what, One one) {
   uint64_t n = 0;
   if (req.size() >= sizeof(n)) {
     req.copy_to(&n, sizeof(n));
   }
   if (n == 0 || n > kKvManyMax ||
-      req.size() < sizeof(n) + n * sizeof(KvWire)) {
+      req.size() < sizeof(n) + n * sizeof(Wire)) {
     cntl->SetFailed(EINVAL, std::string("bad ") + what + " record count");
-    return;
+    return 0;
   }
-  std::vector<KvWire> wires(n);
-  req.copy_to(wires.data(), n * sizeof(KvWire), sizeof(n));
+  std::vector<Wire> wires(n);
+  req.copy_to(wires.data(), n * sizeof(Wire), sizeof(n));
   std::vector<Entry> out(n);
   for (uint64_t i = 0; i < n; ++i) {
     wires[i].node[sizeof(wires[i].node) - 1] = '\0';
@@ -1351,8 +1798,18 @@ void serve_many(Controller* cntl, const IOBuf& req, IOBuf* resp,
   }
   resp->append(&n, sizeof(n));
   resp->append(out.data(), n * sizeof(Entry));
-  kv_vars().reg_many_total << 1;
-  kv_vars().reg_many_records << static_cast<int64_t>(n);
+  return n;
+}
+
+// The three batch calls over block records count themselves here.
+template <typename Entry, typename One>
+void serve_many_blocks(Controller* cntl, const IOBuf& req, IOBuf* resp,
+                       const char* what, One one) {
+  const uint64_t n = serve_many<Entry>(cntl, req, resp, what, one);
+  if (n != 0) {
+    kv_vars().reg_many_total << 1;
+    kv_vars().reg_many_records << static_cast<int64_t>(n);
+  }
 }
 
 void respond_gen(IOBuf* resp, uint64_t gen) {
@@ -1433,7 +1890,7 @@ int kv_attach_store(Server* s) {
 
 int kv_attach_registry(Server* s) {
   kv_ensure_registered();
-  int rcs[9] = {0, 0, 0, 0, 0, 0, 0, 0, 0};
+  int rcs[10] = {0, 0, 0, 0, 0, 0, 0, 0, 0, 0};
   rcs[0] = s->RegisterMethod(
       kKvRegisterMethod, [](Controller* cntl, const IOBuf& req, IOBuf* resp,
                             Closure done) {
@@ -1519,19 +1976,9 @@ int kv_attach_registry(Server* s) {
           done();
           return;
         }
-        KvPrefixMeta m;
-        m.key.hi = w.key_hi;
-        m.key.lo = w.key_lo;
-        m.hash.hi = w.hash_hi;
-        m.hash.lo = w.hash_lo;
-        m.generation = w.generation;
-        m.rkey = w.rkey;
-        m.off = w.off;
-        m.len = w.len;
-        m.depth = w.depth;
-        memcpy(m.node, w.node, sizeof(m.node));
         uint64_t gen = 0;
-        const int rc = kv_registry().put_prefix(m, w.lease_ms, &gen);
+        const int rc = kv_registry().put_prefix(wire_to_prefix_meta(w),
+                                                w.lease_ms, &gen);
         if (rc != 0) {
           // kEKvExists included: the caller already holds this exact
           // record (idempotent renew) — the Python client maps it to
@@ -1579,7 +2026,7 @@ int kv_attach_registry(Server* s) {
   rcs[6] = s->RegisterMethod(
       kKvRegisterManyMethod, [](Controller* cntl, const IOBuf& req,
                                 IOBuf* resp, Closure done) {
-        serve_many<KvManyGen>(
+        serve_many_blocks<KvManyGen>(
             cntl, req, resp, kKvRegisterManyMethod,
             [](const KvWire& w, KvManyGen* o) {
               o->status = kv_registry().do_register(
@@ -1590,7 +2037,7 @@ int kv_attach_registry(Server* s) {
   rcs[7] = s->RegisterMethod(
       kKvLookupManyMethod, [](Controller* cntl, const IOBuf& req,
                               IOBuf* resp, Closure done) {
-        serve_many<KvManyRecord>(
+        serve_many_blocks<KvManyRecord>(
             cntl, req, resp, kKvLookupManyMethod,
             [](const KvWire& w, KvManyRecord* o) {
               KvBlockMeta m;
@@ -1605,11 +2052,27 @@ int kv_attach_registry(Server* s) {
   rcs[8] = s->RegisterMethod(
       kKvEvictManyMethod, [](Controller* cntl, const IOBuf& req,
                              IOBuf* resp, Closure done) {
-        serve_many<KvManyGen>(
+        serve_many_blocks<KvManyGen>(
             cntl, req, resp, kKvEvictManyMethod,
             [](const KvWire& w, KvManyGen* o) {
               o->status = kv_registry().evict(w.block_id, &o->generation);
             });
+        done();
+      });
+  rcs[9] = s->RegisterMethod(
+      kKvPrefixPutManyMethod, [](Controller* cntl, const IOBuf& req,
+                                 IOBuf* resp, Closure done) {
+        const uint64_t n = serve_many<KvManyGen, KvPrefixWire>(
+            cntl, req, resp, kKvPrefixPutManyMethod,
+            [](const KvPrefixWire& w, KvManyGen* o) {
+              o->status = kv_registry().put_prefix(
+                  wire_to_prefix_meta(w), w.lease_ms, &o->generation);
+            });
+        if (n != 0) {
+          kv_prefix_counters().put_many_total << 1;
+          kv_prefix_counters().put_many_records
+              << static_cast<int64_t>(n);
+        }
         done();
       });
   for (int rc : rcs) {

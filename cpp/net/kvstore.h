@@ -25,6 +25,20 @@
 //    GENERATION (monotonic per block id, tombstones survive eviction);
 //    a byte budget (trpc_kv_store_bytes) evicts expired-then-LRU blocks
 //    under pressure.  `kv_attach_store(Server*)` serves "Kv.Fetch".
+//    Its second tier is the content-addressed PREFIX store (ISSUE 17;
+//    at a deployment's block width since PR 37): blocks named by chain
+//    key and content hash, hot in registered pages or cold on the heap.
+//    A publish takes the bytes where they lie (a landing block of the
+//    host pool, co-owned through its mapping until the block is demoted
+//    or dropped) or copies them once; a demote MOVES the bytes to the
+//    heap and lets the region go; a promote copies them back.  The hash,
+//    every copy and every release of a block's memory run OUTSIDE the
+//    store's one lock (a `moving` mark under it, the result installed
+//    only if the block is still the one that was left, and a demote's
+//    only if nothing touched the block meanwhile), room in the hot
+//    tier is reserved before it is written, and a victim is the front
+//    of a touch-ordered list, never a walk.  Served by
+//    "Kv.FetchPrefix"; counted by KvPrefixCounters' registry Adders.
 //  - KvRegistry (`kv_registry()`): the directory.  Lease-based
 //    ownership: every record carries a deadline; expired records answer
 //    kEKvMiss and are pruned lazily.  Double-register of a live block
@@ -33,7 +47,9 @@
 //    `kv_attach_registry(Server*)` serves "KvReg.{Register,Lookup,
 //    Evict,Renew}" and the batch forms "KvReg.{Register,Lookup,
 //    Evict}Many" (a block's records in one RPC) — the registry can run
-//    on any node, including a third party.
+//    on any node, including a third party.  Prefix replicas are recorded
+//    by "KvReg.PutPrefix" / "KvReg.PutPrefixMany" (a turn's new blocks
+//    in one RPC) and found by "KvReg.Match" (longest cached prefix).
 //  - KvCache: the DECODE-side lookup cache.  Lookups are cached until
 //    proven stale: a fetch answered kEKvStale/kEKvMiss (generation
 //    bumped, lease expired, block evicted) invalidates the cached
@@ -53,6 +69,8 @@
 
 #include <atomic>
 #include <cstdint>
+#include <list>
+#include <map>
 #include <memory>
 #include <mutex>
 #include <string>
@@ -60,6 +78,7 @@
 #include <vector>
 
 #include "base/iobuf.h"
+#include "stat/reducer.h"
 
 namespace trpc {
 
@@ -138,6 +157,7 @@ inline constexpr const char* kKvRegisterManyMethod = "KvReg.RegisterMany";
 inline constexpr const char* kKvLookupManyMethod = "KvReg.LookupMany";
 inline constexpr const char* kKvEvictManyMethod = "KvReg.EvictMany";
 inline constexpr const char* kKvPrefixPutMethod = "KvReg.PutPrefix";
+inline constexpr const char* kKvPrefixPutManyMethod = "KvReg.PutPrefixMany";
 inline constexpr const char* kKvPrefixMatchMethod = "KvReg.Match";
 inline constexpr const char* kKvPrefixFetchMethod = "Kv.FetchPrefix";
 
@@ -206,6 +226,11 @@ struct KvPrefixMeta {
 // Match sends a u64 count + count x 16-byte chain keys and answers a
 // u64 record count + that many KvPrefixWire records (one per live
 // replica, grouped in chain order — lease_ms = remaining ms).
+// PutPrefixMany is RegisterMany's shape over this record: a u64 count
+// (1..kKvManyMax) then that many KvPrefixWire in, the count then one
+// KvManyGen per record out, in order (status 0, kEKvExists for the
+// idempotent re-offer, or kEKvStale; the accepted generation): a turn's
+// new blocks are recorded in ONE round trip.
 struct KvPrefixWire {
   uint64_t key_hi;
   uint64_t key_lo;
@@ -223,25 +248,44 @@ struct KvPrefixWire {
 static_assert(sizeof(KvPrefixWire) == 144,
               "KvPrefixWire is wire format — fixed");
 
-// Process-wide prefix-tier outcome counters (read by the capi and the
-// perf harness; mirrored as vars by kvstore.cc).
+// Process-wide prefix-plane counters: ONE set, always-on Adders of the
+// native registry (exposed as `kv_prefix_<member>` when the kv vars
+// register), read as window deltas by benchmark/counters.py, by
+// observe.Vars.dump() and /vars, and by trpc_kv_prefix_counters.
 struct KvPrefixCounters {
-  std::atomic<uint64_t> promote{0};    // cold block re-pinned hot on fetch
-  std::atomic<uint64_t> demote{0};     // hot block spilled to the heap tier
-  std::atomic<uint64_t> hot_hits{0};   // prefix fetches served zero-copy
-  std::atomic<uint64_t> cold_hits{0};  // prefix fetches served from cold
-  std::atomic<uint64_t> dedup{0};      // registry replica folds (same hash)
-  // Relaxed: monotonic stat counters — nothing is published through
-  // them; a stale read only blurs a dashboard or test assertion.
-  void bump(std::atomic<uint64_t>& c) {
-    c.fetch_add(1, std::memory_order_relaxed);
-  }
-  // Relaxed: same monotonic-stat rationale as bump().
-  static uint64_t read(const std::atomic<uint64_t>& c) {
-    return c.load(std::memory_order_relaxed);
+  Adder publish_total;   // fresh blocks admitted by publish_prefix
+  Adder publish_bytes;   // their bytes
+  Adder publish_copy_bytes;      // ... copied once into store-owned pages
+  Adder publish_in_place_bytes;  // ... taken where they lay (no copy)
+  Adder publish_renewed;  // publishes of live identical content (lease
+                          // renewed, nothing admitted: the cache hit)
+  Adder renew_promote;    // ... that found the block in the heap tier
+                          // and made the publisher's bytes its hot pages
+  Adder hash_us;         // time publish_prefix spent in kv_content_hash
+  Adder fetch_total;     // prefix fetches served (hot + cold)
+  Adder hot_hits;        // ... from registered pages
+  Adder cold_hits;       // ... that found the block in the heap tier
+  Adder promote;         // cold blocks copied back into registered pages
+  Adder demote;          // hot blocks moved to the heap tier
+  Adder dropped;         // blocks dropped (budget, lapsed lease, withdraw)
+  Adder fetch_stale;     // prefix fetches answered kv-stale
+  Adder lock_wait_us;    // time prefix fetches and publishes waited for
+                         // the store's lock
+  Adder put_total;       // replica registrations accepted by the registry
+  Adder put_many_total;  // KvReg.PutPrefixMany calls answered
+  Adder put_many_records;  // records they carried
+  Adder dedup;           // registry replica folds (same chain key + hash)
+  Adder match_total;     // KvReg.Match queries answered
+  Adder match_keys;      // chain keys they asked
+  Adder match_blocks;    // blocks they matched (sum of matched depths)
+  KvPrefixCounters();    // exposes each under its name
+  static uint64_t read(const Adder& c) {
+    return static_cast<uint64_t>(c.get_value());
   }
 };
 KvPrefixCounters& kv_prefix_counters();
+
+class KvHeapBlock;  // one block's bytes in the heap tier (kvstore.cc)
 
 // ---- node-local block store (prefill side) -------------------------------
 
@@ -291,28 +335,46 @@ class KvStore {
 
   // ---- content-addressed prefix tier (two-tier store, ISSUE 17) ----
   //
-  // Publishes one prefix block under its CONTENT hash.  Unlike
-  // publish(), the store COPIES the bytes into a store-owned
-  // registered-RMA region (hot tier) — callers need no RmaBuffer, and
-  // demote/promote can move the bytes without caller coordination.
-  // The content hash is computed here (bytes + token span) and echoed
-  // in *out with the minted generation.  Re-publishing a LIVE block
-  // with the same content hash is the cache-hit path: the lease renews
-  // and *out fills, but the return is kEKvExists so callers can count
-  // bytes-NOT-recomputed.  Budget: hot bytes under
-  // trpc_kv_prefix_hot_bytes (LRU hot blocks DEMOTE to the cold heap
-  // tier, never drop); total store bytes (blocks + hot + cold) under
-  // trpc_kv_store_bytes (expired-then-LRU cold blocks drop with
-  // generation tombstones).  Returns 0, kEKvExists, or -1.
+  // Publishes one prefix block under its CONTENT hash.  The content
+  // hash is computed here, over the bytes where they lie (bytes + token
+  // span), outside the store's lock, and echoed in *out with the minted
+  // generation.  Where the bytes go: with `in_place` and a source inside
+  // an exportable (rma_alloc'd) region the block is TAKEN where it lies
+  // and co-owns the region's mapping until it is demoted or dropped,
+  // nothing copied (the caller's promise: nobody writes those bytes
+  // while anybody owns the mapping — the host pool's landing blocks keep
+  // it, cpp/capi/hostpool_capi.cc; kv.py decides by
+  // trpc_host_pool_holds); any other source is copied ONCE into a
+  // store-owned registered region (callers need no RmaBuffer), or into
+  // the heap tier when the hot tier cannot take it.  Re-publishing a
+  // LIVE block with the same content hash is the cache-hit path: the
+  // lease renews and *out fills, but the return is kEKvExists so
+  // callers can count bytes-NOT-recomputed; a block found in the heap
+  // tier comes back hot on the publisher's bytes (taken in place or
+  // copied once, as above; its generation stays).  Budget: hot bytes
+  // under trpc_kv_prefix_hot_bytes (LRU hot blocks DEMOTE to the cold
+  // heap tier, never drop); total store bytes (blocks + hot + cold)
+  // under trpc_kv_store_bytes (expired, then LRU cold, then LRU hot
+  // blocks drop with generation tombstones).  Every touch, a publish's
+  // as a fetch's, leaves its block hot, so the hot tier is the blocks
+  // touched last and the two tiers are ONE order by last touch: a
+  // block is never dropped before one touched earlier (but for the
+  // order in which fetches in flight together are served, and a heap
+  // block that found no registered memory).  Every copy of a block's
+  // bytes (a demote, the one copy of a publish) runs OUTSIDE the lock.
+  // Returns 0, kEKvExists, or -1.
   int publish_prefix(const Key128& key, uint32_t depth, const void* data,
                      size_t len, const uint64_t* tokens, size_t ntokens,
                      int64_t lease_ms, KvPrefixMeta* out,
-                     uint64_t min_generation = 0);
+                     uint64_t min_generation = 0, bool in_place = false);
   // Serves one prefix block by content hash: generation AND lease
   // validated at serve time (same stale rules as fetch()).  A hot hit
   // serves zero-copy from the registered pages; a cold hit PROMOTES the
-  // block back into a registered region first (falling back to a plain
-  // copy if registered memory is exhausted).  0, kEKvStale, kEKvMiss.
+  // block back into a registered region first, the copy (and the demote
+  // of whatever it displaces) outside the lock, so fetches of other
+  // blocks are served meanwhile; a cold block that cannot be promoted
+  // (registered memory exhausted, another fetch already promoting it) is
+  // served zero-copy from the heap.  0, kEKvStale, kEKvMiss.
   int fetch_prefix(const Key128& hash, uint64_t expected_gen, IOBuf* out);
   // Explicit eviction by content hash (generation tombstones).
   int withdraw_prefix(const Key128& hash);
@@ -333,22 +395,55 @@ class KvStore {
     int64_t deadline_us = 0;
     uint64_t touch_seq = 0;  // LRU clock (publish/fetch bumps)
   };
+  using PrefixLru = std::list<Key128>;
+  using PrefixLeases = std::multimap<int64_t, Key128>;
   struct PrefixBlock {
     KvPrefixMeta meta;        // rkey/off valid only while hot
-    char* hot_data = nullptr;  // store-owned rma_alloc region (hot tier)
+    const char* hot_data = nullptr;   // registered pages (hot tier)
     std::shared_ptr<RmaMapping> map;  // pins hot pages across serves
-    std::string cold;                 // the bytes while demoted
+    bool owned = false;  // hot pages are a store-owned rma_alloc region;
+                         // else a region taken in place, co-owned by map
+    std::shared_ptr<const KvHeapBlock> cold;  // the bytes while demoted
     bool hot = false;
+    bool moving = false;  // a demote or promote is copying the bytes
+                          // outside mu_ (no second move starts meanwhile)
+    uint64_t touches = 0;  // fetches and renewals so far: a demote that
+                           // finds one more after its copy leaves it hot
     int64_t deadline_us = 0;
-    uint64_t touch_seq = 0;
+    PrefixLru::iterator lru_at;       // in lru_hot_ or lru_cold_
+    PrefixLeases::iterator lease_at;  // in prefix_leases_
   };
+  // What a drop or a move let go of: released after mu_ is (an rma_free
+  // or the free of a heap block is a munmap of megabytes).
+  struct PrefixTrash;
   // Evicts one block under mu_ (iterator-safe helper).
   void evict_locked(uint64_t block_id, bool count_var);
-  // Prefix-tier helpers, all under mu_: spill one hot block's bytes to
-  // the heap tier / drop one block entirely (tombstoning) / make room.
-  void demote_locked(PrefixBlock* b);
-  void evict_prefix_locked(const Key128& hash);
-  bool fit_hot_locked(uint64_t incoming, uint64_t hot_budget);
+  // Prefix-tier helpers, all entered and left with mu_ held.
+  void touch_prefix_locked(PrefixBlock* b);
+  void set_prefix_lease_locked(PrefixBlock* b, const Key128& hash,
+                               int64_t deadline_us);
+  void evict_prefix_locked(const Key128& hash, PrefixTrash* trash);
+  // Drops expired, then LRU cold, then LRU hot prefix blocks until
+  // `incoming` more bytes fit trpc_kv_store_bytes; false if they cannot.
+  bool fit_total_locked(uint64_t incoming, uint64_t total_budget,
+                        int64_t now, PrefixTrash* trash);
+  // Moves the LRU hot block's bytes to the heap tier; mu_ is RELEASED
+  // for the copy and held again on return.  False: no hot block to move.
+  bool demote_one(std::unique_lock<std::mutex>* lk, PrefixTrash* trash);
+  // Reserves `incoming` bytes of the hot budget (prefix_hot_reserved_),
+  // demoting LRU hot blocks while they do not fit; may release mu_.
+  bool reserve_hot(std::unique_lock<std::mutex>* lk, uint64_t incoming,
+                   PrefixTrash* trash);
+  // Brings heap-tier block `hash`, which the caller has just marked
+  // `moving`, to the hot tier: room reserved, then `map` taken (registered
+  // memory at `src` the store may co-own: no copy) or, without one, `src`
+  // copied into a region of the store's with mu_ released.  Returns the
+  // block as it is afterwards, still in the heap tier where there was no
+  // room or no registered memory; nullptr if it was dropped meanwhile.
+  PrefixBlock* promote_marked(std::unique_lock<std::mutex>* lk,
+                              const Key128& hash, const void* src,
+                              std::shared_ptr<RmaMapping> map, uint64_t rkey,
+                              uint64_t off, PrefixTrash* trash);
   std::mutex mu_;
   std::unordered_map<uint64_t, Block> blocks_;
   std::unordered_map<Key128, PrefixBlock, Key128Hash> prefix_blocks_;
@@ -359,7 +454,15 @@ class KvStore {
   std::unordered_map<uint64_t, uint64_t> tombstones_;
   std::unordered_map<Key128, uint64_t, Key128Hash> prefix_tombstones_;
   uint64_t bytes_ = 0;
+  // Tier order, least recently touched first; a demote moves the front
+  // of lru_hot_ to the back of lru_cold_, a promote or a publish to the
+  // back of lru_hot_: cold then hot is the whole tier in touch order,
+  // and a victim is a list's front, never a walk over the blocks.
+  PrefixLru lru_hot_;
+  PrefixLru lru_cold_;
+  PrefixLeases prefix_leases_;  // by deadline: the lapsed lie in front
   uint64_t prefix_hot_bytes_ = 0;
+  uint64_t prefix_hot_reserved_ = 0;  // room held for copies under way
   uint64_t prefix_cold_bytes_ = 0;
   uint64_t touch_counter_ = 0;
 };

@@ -7,8 +7,11 @@
 // prefill/decode disaggregation workload (tools/kv_disagg.py) runs on.
 #include <unistd.h>
 
+#include <atomic>
+#include <chrono>
 #include <cstring>
 #include <string>
+#include <thread>
 #include <vector>
 
 #include "base/flags.h"
@@ -814,6 +817,334 @@ TEST_CASE(kv_prefix_demote_under_budget_drops_cold_last) {
   EXPECT_EQ(kv_store().prefix_count(), 0u);
   EXPECT_EQ(kv_store().fetch_prefix(again.hash, again.generation, &out),
             kEKvStale);
+}
+
+// The tier moves copy OUTSIDE the store's lock: while one thread's cold
+// hits promote 8 MB blocks (each displacing the other, so a demote a
+// promote), fetches of another, hot block are served — many a move, not
+// one a gap between moves — and publishes in place and by copy go on
+// beside them.  Under TSan this is the race check of the mover protocol
+// (moving flag, re-find by generation, reserved hot room).
+TEST_CASE(kv_prefix_moves_run_outside_the_lock) {
+  KvReset reset;
+  const size_t big = 8u << 20;
+  const size_t small = 64u << 10;
+  // Hot room for the small block and ONE big one; everything fits cold.
+  FlagGuard hot("trpc_kv_prefix_hot_bytes",
+                std::to_string(big + small + (1 << 20)));
+  FlagGuard total("trpc_kv_store_bytes", std::to_string(64ll << 20));
+  std::string a(big, '\0'), b(big, '\0'), c(small, '\0');
+  fill_pattern(a.data(), big, 71);
+  fill_pattern(b.data(), big, 72);
+  fill_pattern(c.data(), small, 73);
+  uint64_t toks[2] = {7, 8};
+  KvPrefixMeta ma, mb, mc;
+  EXPECT_EQ(kv_store().publish_prefix(k128(3, 1), 0, a.data(), big, toks, 2,
+                                      60000, &ma), 0);
+  EXPECT_EQ(kv_store().publish_prefix(k128(3, 2), 1, b.data(), big, toks, 2,
+                                      60000, &mb), 0);  // demotes A
+  EXPECT_EQ(kv_store().publish_prefix(k128(3, 3), 2, c.data(), small, toks,
+                                      2, 60000, &mc), 0);
+  EXPECT_EQ(kv_store().prefix_hot_bytes(), big + small);
+  EXPECT_EQ(kv_store().prefix_cold_bytes(), big);
+  const uint64_t promote0 =
+      KvPrefixCounters::read(kv_prefix_counters().promote);
+  const uint64_t demote0 =
+      KvPrefixCounters::read(kv_prefix_counters().demote);
+  const int kMoves = 24;
+  std::atomic<bool> moving{false};
+  std::atomic<bool> stop{false};
+  std::atomic<int> served_during_moves{0};
+  std::atomic<int> wrong{0};
+  std::thread mover([&] {
+    for (int i = 0; i < kMoves; ++i) {
+      const KvPrefixMeta& m = i % 2 == 0 ? ma : mb;  // always the cold one
+      IOBuf out;
+      moving.store(true);
+      const int rc = kv_store().fetch_prefix(m.hash, m.generation, &out);
+      moving.store(false);
+      if (rc != 0 || !check_pattern(out, big, i % 2 == 0 ? 71 : 72)) {
+        wrong.fetch_add(1);
+      }
+    }
+    stop.store(true);
+  });
+  std::thread publisher([&] {
+    // Fresh content each round, copied (a heap source): it lands cold or
+    // hot by what room there is, and is withdrawn again.
+    std::string d(small, '\0');
+    for (uint32_t i = 0; !stop.load(); ++i) {
+      fill_pattern(d.data(), small, 100 + i);
+      uint64_t t[1] = {i};
+      KvPrefixMeta md;
+      if (kv_store().publish_prefix(k128(4, i + 1), 0, d.data(), small, t,
+                                    1, 60000, &md) != 0 ||
+          kv_store().withdraw_prefix(md.hash) != 0) {
+        wrong.fetch_add(1);
+      }
+    }
+  });
+  while (!stop.load()) {
+    IOBuf out;
+    const bool during = moving.load();
+    if (kv_store().fetch_prefix(mc.hash, mc.generation, &out) != 0 ||
+        out.size() != small) {
+      wrong.fetch_add(1);
+    } else if (during && moving.load()) {
+      served_during_moves.fetch_add(1);
+    }
+  }
+  mover.join();
+  publisher.join();
+  EXPECT_EQ(wrong.load(), 0);
+  // Every mover fetch was a cold hit that promoted and displaced the
+  // other big block (and the small one, where a move found it the least
+  // recently touched: its next fetch promoted it back).
+  EXPECT(KvPrefixCounters::read(kv_prefix_counters().promote) >=
+         promote0 + kMoves);
+  EXPECT(KvPrefixCounters::read(kv_prefix_counters().demote) >=
+         demote0 + kMoves);
+  // A move is two copies of 8 MB; a fetch of the small block is
+  // microseconds.  Copies under the lock would let one through a move
+  // at best.
+  EXPECT(served_during_moves.load() > 10 * kMoves);
+  EXPECT(kv_store().prefix_hot_bytes() <= big + small + (1 << 20));
+  IOBuf last;
+  EXPECT_EQ(kv_store().fetch_prefix(mc.hash, mc.generation, &last), 0);
+  EXPECT(check_pattern(last, small, 73));
+}
+
+// The two tiers are ONE order by last touch: whatever touches a block
+// leaves it hot.  A fetch of a hot block that arrives while a demote is
+// copying that block (a window's fetches reach a chain's hot blocks while
+// its cold ones, promoted, push them out) cancels the demote: installed,
+// the block would lie in the heap tier in front of every hot block and be
+// dropped before them, the most recently used first.  In every
+// interleaving of the two fetches the block just fetched ends hot and the
+// block nobody touched ends in the heap tier.
+TEST_CASE(kv_prefix_a_block_touched_during_its_demote_stays_hot) {
+  KvReset reset;
+  const size_t big = 8u << 20;
+  FlagGuard hot("trpc_kv_prefix_hot_bytes",
+                std::to_string(2 * big + (1 << 20)));
+  FlagGuard total("trpc_kv_store_bytes", std::to_string(64ll << 20));
+  std::string buf(big, '\0');
+  KvPrefixMeta m[3];
+  uint64_t toks[1] = {3};
+  for (int i = 0; i < 3; ++i) {
+    fill_pattern(buf.data(), big, 90 + i);
+    EXPECT_EQ(kv_store().publish_prefix(k128(6, i + 1), i, buf.data(), big,
+                                        toks, 1, 60000, &m[i]), 0);
+  }
+  KvPrefixCounters& c = kv_prefix_counters();
+  int cold = 0, front = 1, back = 2;  // block 0 was demoted by block 2
+  std::atomic<int> wrong{0};
+  auto fetch = [&](int i) {
+    IOBuf out;
+    if (kv_store().fetch_prefix(m[i].hash, m[i].generation, &out) != 0 ||
+        !check_pattern(out, big, 90 + i)) {
+      wrong.fetch_add(1);
+    }
+  };
+  for (int round = 0; round < 24; ++round) {
+    fetch(back);  // a hot hit: `front` is the least recently touched now
+    std::thread promoter([&] { fetch(cold); });  // demotes `front` ...
+    std::thread toucher([&] {
+      std::this_thread::sleep_for(std::chrono::microseconds(80 * round));
+      fetch(front);  // ... which is fetched before, during or after that
+    });
+    promoter.join();
+    toucher.join();
+    const uint64_t hot_hits = KvPrefixCounters::read(c.hot_hits);
+    fetch(front);
+    fetch(cold);
+    EXPECT_EQ(KvPrefixCounters::read(c.hot_hits), hot_hits + 2);
+    EXPECT_EQ(kv_store().prefix_hot_bytes(), 2 * big);
+    EXPECT_EQ(kv_store().prefix_cold_bytes(), big);
+    std::swap(cold, back);  // the block nobody touched went to the heap
+  }
+  EXPECT_EQ(wrong.load(), 0);
+}
+
+// A publish of content the store holds in the heap tier brings the block
+// hot on the publisher's bytes, in place where they lie in registered
+// memory: same generation, the heap copy let go, and the block is then
+// dropped after the ones touched before it, not before them.
+TEST_CASE(kv_prefix_a_renewal_brings_a_heap_block_hot) {
+  KvReset reset;
+  const size_t len = 1 << 20;
+  FlagGuard hot("trpc_kv_prefix_hot_bytes", std::to_string(2 * len));
+  FlagGuard total("trpc_kv_store_bytes", std::to_string(4 * len));
+  uint64_t rkey = 0;
+  char* region = static_cast<char*>(rma_alloc(len, &rkey));
+  EXPECT(region != nullptr);
+  std::string buf(len, '\0');
+  KvPrefixMeta m[5];
+  auto publish = [&](uint64_t i, const void* data, bool in_place,
+                     KvPrefixMeta* out) {
+    uint64_t toks[1] = {i};
+    return kv_store().publish_prefix(k128(7, i + 1), 0, data, len, toks, 1,
+                                     60000, out, 0, in_place);
+  };
+  for (uint64_t i = 0; i < 3; ++i) {
+    fill_pattern(buf.data(), len, 110 + i);
+    EXPECT_EQ(publish(i, buf.data(), false, &m[i]), 0);
+  }
+  EXPECT_EQ(kv_store().prefix_cold_bytes(), len);  // block 0
+  KvPrefixCounters& c = kv_prefix_counters();
+  const uint64_t renewed0 = KvPrefixCounters::read(c.publish_renewed);
+  const uint64_t brought0 = KvPrefixCounters::read(c.renew_promote);
+  const uint64_t promote0 = KvPrefixCounters::read(c.promote);
+  const uint64_t bytes0 = KvPrefixCounters::read(c.publish_bytes);
+  fill_pattern(region, len, 110);
+  KvPrefixMeta again;
+  EXPECT_EQ(publish(0, region, true, &again), kEKvExists);
+  EXPECT_EQ(again.generation, m[0].generation);
+  EXPECT_EQ(again.rkey, rkey);  // served from the publisher's region now
+  EXPECT_EQ(KvPrefixCounters::read(c.publish_renewed), renewed0 + 1);
+  EXPECT_EQ(KvPrefixCounters::read(c.renew_promote), brought0 + 1);
+  EXPECT_EQ(KvPrefixCounters::read(c.promote), promote0);  // fetches' only
+  EXPECT_EQ(KvPrefixCounters::read(c.publish_bytes), bytes0);
+  EXPECT_EQ(kv_store().prefix_hot_bytes(), 2 * len);   // blocks 2 and 0
+  EXPECT_EQ(kv_store().prefix_cold_bytes(), len);      // block 1
+  rma_free(region);  // the block co-owns the mapping
+  // A renewal of a hot block, and one from a heap source, copy nothing
+  // they need not: the first touches, the second copies once.
+  fill_pattern(buf.data(), len, 112);
+  EXPECT_EQ(publish(2, buf.data(), false, &again), kEKvExists);
+  EXPECT_EQ(KvPrefixCounters::read(c.renew_promote), brought0 + 1);
+  // Two more blocks pass the total budget: block 1, touched least
+  // recently, goes; block 0 stays.
+  for (uint64_t i = 3; i < 5; ++i) {
+    fill_pattern(buf.data(), len, 110 + i);
+    EXPECT_EQ(publish(i, buf.data(), false, &m[i]), 0);
+  }
+  IOBuf out;
+  EXPECT_EQ(kv_store().fetch_prefix(m[1].hash, m[1].generation, &out),
+            kEKvStale);
+  EXPECT_EQ(kv_store().fetch_prefix(m[0].hash, m[0].generation, &out), 0);
+  EXPECT(check_pattern(out, len, 110));
+  fill_pattern(buf.data(), len, 112);
+  // Block 2 went to the heap meanwhile: renewed from a heap source it
+  // comes back on one copy.
+  EXPECT_EQ(publish(2, buf.data(), false, &again), kEKvExists);
+  EXPECT_EQ(KvPrefixCounters::read(c.renew_promote), brought0 + 2);
+  IOBuf two;
+  EXPECT_EQ(kv_store().fetch_prefix(m[2].hash, m[2].generation, &two), 0);
+  EXPECT(check_pattern(two, len, 112));
+}
+
+// A block taken IN PLACE is served from the caller's registered pages
+// and co-owns their mapping until it is demoted or dropped; any other
+// source is copied once.  The counters say which.
+TEST_CASE(kv_prefix_publish_in_place_or_one_copy) {
+  KvReset reset;
+  FlagGuard hot("trpc_kv_prefix_hot_bytes", std::to_string(4 << 20));
+  const size_t len = 1 << 20;
+  uint64_t rkey = 0;
+  char* region = static_cast<char*>(rma_alloc(2 * len, &rkey));
+  EXPECT(region != nullptr);
+  fill_pattern(region, len, 81);
+  fill_pattern(region + len, len, 82);
+  KvPrefixCounters& c = kv_prefix_counters();
+  const uint64_t in_place0 = KvPrefixCounters::read(c.publish_in_place_bytes);
+  const uint64_t copy0 = KvPrefixCounters::read(c.publish_copy_bytes);
+  uint64_t toks[1] = {9};
+  KvPrefixMeta m1, m2, m3;
+  EXPECT_EQ(kv_store().publish_prefix(k128(5, 1), 0, region, len, toks, 1,
+                                      60000, &m1, 0, /*in_place=*/true), 0);
+  // The second half of the same region, not offered in place: copied.
+  EXPECT_EQ(kv_store().publish_prefix(k128(5, 2), 1, region + len, len,
+                                      toks, 1, 60000, &m2), 0);
+  // A heap source offered in place: no registered memory, so copied.
+  std::string heap(len, '\0');
+  fill_pattern(heap.data(), len, 83);
+  EXPECT_EQ(kv_store().publish_prefix(k128(5, 3), 2, heap.data(), len, toks,
+                                      1, 60000, &m3, 0, /*in_place=*/true),
+            0);
+  EXPECT_EQ(KvPrefixCounters::read(c.publish_in_place_bytes),
+            in_place0 + len);
+  EXPECT_EQ(KvPrefixCounters::read(c.publish_copy_bytes), copy0 + 2 * len);
+  EXPECT_EQ(m1.rkey, rkey);  // served from the caller's region itself
+  EXPECT(m2.rkey != rkey && m2.rkey != 0);
+  IOBuf out;
+  EXPECT_EQ(kv_store().fetch_prefix(m1.hash, m1.generation, &out), 0);
+  EXPECT(check_pattern(out, len, 81));
+  // rma_free drops the caller's reference; the block co-owns the
+  // mapping, so its bytes stay served until it is dropped.
+  rma_free(region);
+  IOBuf again;
+  EXPECT_EQ(kv_store().fetch_prefix(m1.hash, m1.generation, &again), 0);
+  EXPECT(check_pattern(again, len, 81));
+  EXPECT_EQ(kv_store().withdraw_prefix(m1.hash), 0);
+  EXPECT_EQ(kv_store().fetch_prefix(m1.hash, m1.generation, &again),
+            kEKvStale);
+}
+
+// KvReg.PutPrefixMany records N replicas in one call, each entry with
+// the answer its own PutPrefix would have got.
+TEST_CASE(kv_prefix_put_many_is_n_puts) {
+  KvReset reset;
+  start_once();
+  Channel ch;
+  EXPECT_EQ(ch.Init(addr()), 0);
+  const uint64_t n = 5;
+  std::vector<KvPrefixWire> wires(n);
+  uint64_t tokens[5 * 4];
+  for (size_t i = 0; i < 20; ++i) {
+    tokens[i] = 1000 + i;
+  }
+  Key128 chain[5];
+  EXPECT_EQ(kv_prefix_chain(tokens, 20, 4, chain, 5), 5u);
+  for (uint64_t i = 0; i < n; ++i) {
+    memset(&wires[i], 0, sizeof(KvPrefixWire));
+    wires[i].key_hi = chain[i].hi;
+    wires[i].key_lo = chain[i].lo;
+    wires[i].hash_hi = 0x50 + i;
+    wires[i].hash_lo = 0x60 + i;
+    wires[i].generation = i == 3 ? 0 : 1;  // generation 0: never minted
+    wires[i].len = 4096;
+    wires[i].depth = static_cast<uint32_t>(i);
+    wires[i].lease_ms = 60000;
+    snprintf(wires[i].node, sizeof(wires[i].node), "127.0.0.1:1");
+  }
+  const uint64_t many0 =
+      KvPrefixCounters::read(kv_prefix_counters().put_many_records);
+  for (int round = 0; round < 2; ++round) {
+    IOBuf req, resp;
+    req.append(&n, sizeof(n));
+    req.append(wires.data(), n * sizeof(KvPrefixWire));
+    Controller cntl;
+    ch.CallMethod(kKvPrefixPutManyMethod, req, &resp, &cntl);
+    EXPECT(!cntl.Failed());
+    EXPECT_EQ(resp.size(), sizeof(n) + n * sizeof(KvManyGen));
+    uint64_t count = 0;
+    resp.copy_to(&count, sizeof(count));
+    EXPECT_EQ(count, n);
+    std::vector<KvManyGen> answers(n);
+    resp.copy_to(answers.data(), n * sizeof(KvManyGen), sizeof(count));
+    for (uint64_t i = 0; i < n; ++i) {
+      // The first call accepts, the second is the idempotent re-offer;
+      // the record that never had a generation is refused both times.
+      const int64_t want = i == 3 ? kEKvStale : round == 0 ? 0 : kEKvExists;
+      EXPECT_EQ(answers[i].status, want);
+      if (i != 3) {
+        EXPECT_EQ(answers[i].generation, 1u);
+      }
+    }
+  }
+  EXPECT_EQ(KvPrefixCounters::read(kv_prefix_counters().put_many_records),
+            many0 + 2 * n);
+  // The walk stops at the record that was refused.
+  EXPECT_EQ(kv_registry().match(chain, 5, nullptr), 3u);
+  EXPECT_EQ(kv_registry().prefix_count(), 4u);
+  // A request that does not parse fails the call, as RegisterMany's.
+  IOBuf bad, resp;
+  const uint64_t zero = 0;
+  bad.append(&zero, sizeof(zero));
+  Controller cntl;
+  ch.CallMethod(kKvPrefixPutManyMethod, bad, &resp, &cntl);
+  EXPECT(cntl.Failed());
 }
 
 TEST_MAIN
