@@ -345,6 +345,14 @@ def load_library() -> ctypes.CDLL:
                 ctypes.POINTER(ctypes.c_uint64),
             ]
             lib.trpc_kv_content_hash.restype = None
+            lib.trpc_kv_content_hash_lanes.argtypes = [
+                ctypes.POINTER(ctypes.c_void_p), ctypes.c_size_t,
+                ctypes.POINTER(ctypes.POINTER(ctypes.c_uint64)),
+                ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t,
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.POINTER(ctypes.c_uint64),
+            ]
+            lib.trpc_kv_content_hash_lanes.restype = None
             lib.trpc_kv_prefix_chain.argtypes = [
                 ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t,
                 ctypes.c_int64, ctypes.POINTER(ctypes.c_uint64),
@@ -363,6 +371,20 @@ def load_library() -> ctypes.CDLL:
                 ctypes.POINTER(ctypes.c_uint64),
             ]
             lib.trpc_kv_prefix_publish_at.restype = ctypes.c_int
+            lib.trpc_kv_prefix_publish_run.argtypes = [
+                ctypes.POINTER(ctypes.c_uint64), ctypes.c_uint32,
+                ctypes.POINTER(ctypes.c_void_p), ctypes.c_size_t,
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.POINTER(ctypes.c_int), ctypes.c_size_t,
+                ctypes.c_int64, ctypes.POINTER(ctypes.c_int),
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.POINTER(ctypes.c_uint64),
+            ]
+            lib.trpc_kv_prefix_publish_run.restype = ctypes.c_size_t
             lib.trpc_kv_prefix_withdraw.argtypes = [
                 ctypes.c_uint64, ctypes.c_uint64,
             ]

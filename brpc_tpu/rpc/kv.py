@@ -98,10 +98,10 @@ next turn of a conversation::
 `fetch_prefix_blocks` rides the node channel's pipeline as `Kv.Fetch`
 does (a window of blocks in flight, each over `trpc_stripe_threshold`
 through the connection's one-sided window) and ends the run at the first
-block no replica serves; `publish_prefix_run` hashes each page where its
-bytes lie and the store takes it there, with no copy, when they lie in
-the landing block its device-to-host transfer wrote (`prefix_publish`),
-else copies it once.
+block no replica serves; `publish_prefix_run` hashes the run's pages four
+at a time where their bytes lie, and the store takes each there, with no
+copy, when they lie in the landing block its device-to-host transfer
+wrote (`prefix_publish`), else copies it once.
 """
 
 from __future__ import annotations
@@ -716,21 +716,47 @@ def publish_prefix_run(keys, first_depth: int, pages, token_spans,
     """Publishes a run of consecutive prefix blocks, the new pages of one
     prompt: block `j` of `pages` under chain key `keys[j]` at depth
     `first_depth + j` with the token span `token_spans[j]`
-    (`prefix_publish`, so out of the block the pages' transfer landed in
-    where there is one).  `pages` is a device array, a numpy array, or
-    the view `zerocopy.host_view` made of one when its transfer was
-    started ahead; its bytes are the blocks end to end, equal in size.
+    (as `prefix_publish` would, block by block: out of the block the
+    pages' transfer landed in where there is one), in ONE call of the
+    store, which hashes the blocks' contents four at a time
+    (`kv_prefix_hash_lanes`) and admits them in order.  `pages` is a
+    device array, a numpy array, or the view `zerocopy.host_view` made
+    of one when its transfer was started ahead; its bytes are the blocks
+    end to end, equal in size.  A block the store cannot take raises
+    MemoryError, the blocks before it published and those after it not.
     With a `registry` the replicas are recorded in ONE
     `put_prefix_many`; a record it refuses raises its error.  Returns
     `prefix_publish`'s (meta, fresh) per block."""
     flat = _host_flat(pages)
-    if not keys or flat.nbytes % len(keys) or len(token_spans) != len(keys):
-        raise ValueError(f"{flat.nbytes} bytes are not {len(keys)} equal "
+    n = len(keys)
+    if not n or flat.nbytes % n or len(token_spans) != n:
+        raise ValueError(f"{flat.nbytes} bytes are not {n} equal "
                          f"blocks with {len(token_spans)} token spans")
-    nbytes = flat.nbytes // len(keys)
-    out = [prefix_publish(key, first_depth + j,
-                          flat[j * nbytes:(j + 1) * nbytes], token_spans[j],
-                          lease_ms=lease_ms, node=node)
+    nbytes = flat.nbytes // n
+    if not nbytes:
+        raise ValueError("empty prefix block")
+    lib = load_library()
+    u64s = ctypes.c_uint64 * n
+    key_arr = (ctypes.c_uint64 * (2 * n))(*(k for key in keys for k in key))
+    spans = [list(span) for span in token_spans]
+    tok_arr, _ = _token_array(t for span in spans for t in span)
+    addresses = [flat.ctypes.data + j * nbytes for j in range(n)]
+    rcs = (ctypes.c_int * n)()
+    hash_hi, hash_lo, gen, rkey, off = u64s(), u64s(), u64s(), u64s(), u64s()
+    handled = lib.trpc_kv_prefix_publish_run(
+        key_arr, first_depth, (ctypes.c_void_p * n)(*addresses), nbytes,
+        tok_arr, u64s(*(len(span) for span in spans)),
+        (ctypes.c_int * n)(*(lib.trpc_host_pool_holds(address, nbytes)
+                             for address in addresses)),
+        n, lease_ms, rcs, hash_hi, hash_lo, gen, rkey, off)
+    _miss, _stale, exists = _codes()
+    if any(rcs[j] != 0 and rcs[j] != exists for j in range(handled)):
+        raise MemoryError(
+            f"kv prefix publish failed (rc={rcs[handled - 1]}): the block "
+            "must fit trpc_kv_store_bytes")
+    out = [(KvPrefixMeta(key[0], key[1], hash_hi[j], hash_lo[j], gen[j],
+                         rkey[j], off[j], nbytes, first_depth + j, node),
+            rcs[j] == 0)
            for j, key in enumerate(keys)]
     if registry is not None:
         for answer in registry.put_prefix_many([meta for meta, _ in out],

@@ -6,6 +6,7 @@
 #include <string.h>
 
 #include <utility>
+#include <vector>
 
 #include "base/iobuf.h"
 #include "net/kvstore.h"
@@ -140,6 +141,22 @@ void trpc_kv_content_hash(const void* data, size_t len,
   }
 }
 
+// trpc_kv_content_hash of n blocks of `len` bytes each, side by side
+// (kv_content_hash_lanes): block j at data[j] with the token span of
+// ntokens[j] ids that starts at tokens[j]; hash j to hi[j], lo[j].
+void trpc_kv_content_hash_lanes(const void* const* data, size_t len,
+                                const uint64_t* const* tokens,
+                                const uint64_t* ntokens, size_t n,
+                                uint64_t* hi, uint64_t* lo) {
+  std::vector<size_t> counts(ntokens, ntokens + n);
+  std::vector<Key128> keys(n);
+  kv_content_hash_lanes(data, len, tokens, counts.data(), n, keys.data());
+  for (size_t j = 0; j < n; ++j) {
+    hi[j] = keys[j].hi;
+    lo[j] = keys[j].lo;
+  }
+}
+
 // Chain keys for a token-id sequence, written as interleaved (hi, lo)
 // u64 pairs (Key128's exact layout).  block_tokens <= 0 uses
 // trpc_kv_prefix_block_tokens.  Returns the number of FULL blocks.
@@ -193,6 +210,51 @@ int trpc_kv_prefix_publish_at(uint64_t key_hi, uint64_t key_lo,
     *off_out = m.off;
   }
   return rc;
+}
+
+// Publishes a run of n prefix blocks of `len` bytes each, as n calls of
+// trpc_kv_prefix_publish_at would in order (min_generation 0), with the
+// content hashes taken kKvHashLanes blocks at a time: block j at
+// data[j] under chain key (keys[2j], keys[2j+1]) at depth first_depth + j,
+// its token span the ntokens[j] ids from tokens' running offset on,
+// in_place[j] as trpc_kv_prefix_publish_at's.  Block j's return in
+// rcs[j], its outputs at [j] of the five arrays where it is 0 or
+// kEKvExists.  Stops after the first block that returns -1; returns the
+// blocks handled.
+size_t trpc_kv_prefix_publish_run(const uint64_t* keys, uint32_t first_depth,
+                                  const void* const* data, size_t len,
+                                  const uint64_t* tokens,
+                                  const uint64_t* ntokens,
+                                  const int* in_place, size_t n,
+                                  int64_t lease_ms, int* rcs,
+                                  uint64_t* hash_hi, uint64_t* hash_lo,
+                                  uint64_t* gen_out, uint64_t* rkey_out,
+                                  uint64_t* off_out) {
+  std::vector<KvStore::PrefixPage> pages(n);
+  size_t token_at = 0;
+  for (size_t j = 0; j < n; ++j) {
+    pages[j].key.hi = keys[2 * j];
+    pages[j].key.lo = keys[2 * j + 1];
+    pages[j].data = data[j];
+    pages[j].tokens = tokens + token_at;
+    pages[j].ntokens = ntokens[j];
+    pages[j].in_place = in_place[j] != 0;
+    token_at += ntokens[j];
+  }
+  std::vector<KvPrefixMeta> metas(n);
+  const size_t handled = kv_store().publish_prefix_run(
+      pages.data(), n, len, first_depth, lease_ms, rcs, metas.data());
+  for (size_t j = 0; j < handled; ++j) {
+    if (rcs[j] != 0 && rcs[j] != kEKvExists) {
+      continue;
+    }
+    hash_hi[j] = metas[j].hash.hi;
+    hash_lo[j] = metas[j].hash.lo;
+    gen_out[j] = metas[j].generation;
+    rkey_out[j] = metas[j].rkey;
+    off_out[j] = metas[j].off;
+  }
+  return handled;
 }
 
 // Evicts a local prefix block by content hash (generation tombstoned).

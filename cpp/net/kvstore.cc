@@ -339,7 +339,13 @@ KvPrefixCounters::KvPrefixCounters() {
       "one copy): a touch is a touch, a publisher's as a fetch's");
   hash_us.expose("kv_prefix_hash_us",
                  "time prefix publishes spent hashing block bytes and "
-                 "token spans (us)");
+                 "token spans (us; a group of a run's pages hashed side "
+                 "by side counts its time once)");
+  hash_lanes.expose(
+      "kv_prefix_hash_lanes",
+      "prefix pages hashed side by side with others of their run, in a "
+      "group of two to four (over kv_prefix_publish_total + "
+      "kv_prefix_publish_renewed, the share grouped)");
   fetch_total.expose("kv_prefix_fetch_total",
                      "prefix-block fetches served by this node: "
                      "kv_prefix_hot_hits + kv_prefix_cold_hits");
@@ -419,34 +425,78 @@ inline uint64_t kv_mix64(uint64_t x) {
 
 }  // namespace
 
-void kv_content_hash(const void* data, size_t len, const uint64_t* tokens,
-                     size_t ntokens, Key128* out) {
+namespace {
+
+// kv_content_hash of N blocks of one length, the word loop over all 2N
+// chains at once (N a constant: the chains stay in registers).
+template <size_t N>
+void content_hash_lanes(const void* const* data, size_t len,
+                        const uint64_t* const* tokens, const size_t* ntokens,
+                        Key128* out) {
   // Two lanes with distinct seeds and distinct fold ops (xor-mix vs
   // add-mix) so hi/lo fail independently — 128 bits of key space from
   // two 64-bit walks.  Length and token count seed the lanes: a prefix
   // of the bytes can never alias the whole.
-  uint64_t h1 = 0x9e3779b97f4a7c15ull ^ kv_mix64(len);
-  uint64_t h2 = 0xc2b2ae3d27d4eb4full ^ kv_mix64(ntokens + 0x100);
-  const unsigned char* p = static_cast<const unsigned char*>(data);
+  uint64_t h1[N];
+  uint64_t h2[N];
+  const unsigned char* p[N];
+  for (size_t j = 0; j < N; ++j) {
+    h1[j] = 0x9e3779b97f4a7c15ull ^ kv_mix64(len);
+    h2[j] = 0xc2b2ae3d27d4eb4full ^ kv_mix64(ntokens[j] + 0x100);
+    p[j] = static_cast<const unsigned char*>(data[j]);
+  }
   size_t i = 0;
   for (; i + 8 <= len; i += 8) {
-    uint64_t w;
-    memcpy(&w, p + i, 8);
-    h1 = kv_mix64(h1 ^ w);
-    h2 = kv_mix64(h2 + w);
+#pragma GCC unroll 4
+    for (size_t j = 0; j < N; ++j) {
+      uint64_t w;
+      memcpy(&w, p[j] + i, 8);
+      h1[j] = kv_mix64(h1[j] ^ w);
+      h2[j] = kv_mix64(h2[j] + w);
+    }
   }
-  if (i < len) {
-    uint64_t tail = 0;
-    memcpy(&tail, p + i, len - i);
-    h1 = kv_mix64(h1 ^ tail);
-    h2 = kv_mix64(h2 + tail);
+  for (size_t j = 0; j < N; ++j) {
+    if (i < len) {
+      uint64_t tail = 0;
+      memcpy(&tail, p[j] + i, len - i);
+      h1[j] = kv_mix64(h1[j] ^ tail);
+      h2[j] = kv_mix64(h2[j] + tail);
+    }
+    for (size_t t = 0; t < ntokens[j]; ++t) {
+      h1[j] = kv_mix64(h1[j] ^ tokens[j][t]);
+      h2[j] = kv_mix64(h2[j] + kv_mix64(tokens[j][t]));
+    }
+    out[j].hi = h1[j];
+    out[j].lo = h2[j];
   }
-  for (size_t t = 0; t < ntokens; ++t) {
-    h1 = kv_mix64(h1 ^ tokens[t]);
-    h2 = kv_mix64(h2 + kv_mix64(tokens[t]));
+}
+
+}  // namespace
+
+void kv_content_hash(const void* data, size_t len, const uint64_t* tokens,
+                     size_t ntokens, Key128* out) {
+  content_hash_lanes<1>(&data, len, &tokens, &ntokens, out);
+}
+
+void kv_content_hash_lanes(const void* const* data, size_t len,
+                           const uint64_t* const* tokens,
+                           const size_t* ntokens, size_t n, Key128* out) {
+  static_assert(kKvHashLanes == 4, "one case per group width");
+  switch (n) {
+    case 1:
+      return content_hash_lanes<1>(data, len, tokens, ntokens, out);
+    case 2:
+      return content_hash_lanes<2>(data, len, tokens, ntokens, out);
+    case 3:
+      return content_hash_lanes<3>(data, len, tokens, ntokens, out);
+    case 4:
+      return content_hash_lanes<4>(data, len, tokens, ntokens, out);
+    default:
+      for (size_t at = 0; at < n; at += kKvHashLanes) {
+        kv_content_hash_lanes(data + at, len, tokens + at, ntokens + at,
+                              std::min(kKvHashLanes, n - at), out + at);
+      }
   }
-  out->hi = h1;
-  out->lo = h2;
 }
 
 size_t kv_prefix_chain(const uint64_t* tokens, size_t ntokens,
@@ -1175,15 +1225,77 @@ int KvStore::publish_prefix(const Key128& key, uint32_t depth,
                             const uint64_t* tokens, size_t ntokens,
                             int64_t lease_ms, KvPrefixMeta* out,
                             uint64_t min_generation, bool in_place) {
-  kv_ensure_registered();
-  if (key.zero() || data == nullptr || len == 0) {
-    return -1;
+  PrefixPage page;
+  page.key = key;
+  page.data = data;
+  page.tokens = tokens;
+  page.ntokens = ntokens;
+  page.in_place = in_place;
+  int rc = -1;
+  KvPrefixMeta meta;
+  publish_prefix_run(&page, 1, len, depth, lease_ms, &rc, &meta,
+                     min_generation);
+  if (out != nullptr && (rc == 0 || rc == kEKvExists)) {
+    *out = meta;
   }
+  return rc;
+}
+
+size_t KvStore::publish_prefix_run(const PrefixPage* pages, size_t n,
+                                   size_t len, uint32_t first_depth,
+                                   int64_t lease_ms, int* rcs,
+                                   KvPrefixMeta* outs,
+                                   uint64_t min_generation) {
+  kv_ensure_registered();
   KvPrefixCounters& counted = kv_prefix_counters();
-  Key128 hash;
-  const int64_t hash_t0 = monotonic_time_us();
-  kv_content_hash(data, len, tokens, ntokens, &hash);
-  counted.hash_us << (monotonic_time_us() - hash_t0);
+  for (size_t at = 0; at < n;) {
+    // The group: the next pages up to kKvHashLanes, up to the first
+    // without a key or bytes, which fails (-1) and ends the run.
+    size_t width = 0;
+    while (width < std::min(kKvHashLanes, n - at)) {
+      const PrefixPage& page = pages[at + width];
+      if (page.key.zero() || page.data == nullptr || len == 0) {
+        break;
+      }
+      ++width;
+    }
+    if (width == 0) {
+      rcs[at] = -1;
+      return at + 1;
+    }
+    const void* data[kKvHashLanes];
+    const uint64_t* tokens[kKvHashLanes];
+    size_t ntokens[kKvHashLanes];
+    Key128 hashes[kKvHashLanes];
+    for (size_t j = 0; j < width; ++j) {
+      data[j] = pages[at + j].data;
+      tokens[j] = pages[at + j].tokens;
+      ntokens[j] = pages[at + j].ntokens;
+    }
+    const int64_t hash_t0 = monotonic_time_us();
+    kv_content_hash_lanes(data, len, tokens, ntokens, width, hashes);
+    counted.hash_us << (monotonic_time_us() - hash_t0);
+    if (width > 1) {
+      counted.hash_lanes << static_cast<int64_t>(width);
+    }
+    for (size_t j = 0; j < width; ++j, ++at) {
+      const uint32_t depth = first_depth + static_cast<uint32_t>(at);
+      rcs[at] = admit_prefix(pages[at], depth, len, hashes[j], lease_ms,
+                             &outs[at], min_generation);
+      if (rcs[at] == -1) {
+        return at + 1;
+      }
+    }
+  }
+  return n;
+}
+
+int KvStore::admit_prefix(const PrefixPage& page, uint32_t depth,
+                          size_t len, const Key128& hash, int64_t lease_ms,
+                          KvPrefixMeta* out, uint64_t min_generation) {
+  const Key128& key = page.key;
+  const void* data = page.data;
+  KvPrefixCounters& counted = kv_prefix_counters();
   const uint64_t total_budget =
       flag_bytes(store_bytes_flag(), 1ll << 30);
   if (len > total_budget) {
@@ -1194,7 +1306,7 @@ int KvStore::publish_prefix(const Key128& key, uint32_t depth,
   std::shared_ptr<RmaMapping> map;
   uint64_t rkey = 0;
   uint64_t off = 0;
-  if (in_place) {
+  if (page.in_place) {
     map = rma_pin_exportable(data, len, &rkey, &off);
   }
   const int64_t now = monotonic_time_us();

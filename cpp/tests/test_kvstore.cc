@@ -1147,4 +1147,51 @@ TEST_CASE(kv_prefix_put_many_is_n_puts) {
   EXPECT(cntl.Failed());
 }
 
+TEST_CASE(kv_prefix_run_hashes_four_at_a_time_as_one_by_one) {
+  KvReset reset;
+  const size_t len = 4093;  // a tail that is not a word
+  const size_t n = 9;       // groups of 4, 4 and 1
+  std::vector<std::string> bytes(n, std::string(len, '\0'));
+  std::vector<uint64_t> toks(n);
+  std::vector<KvStore::PrefixPage> pages(n);
+  const void* data[n];
+  const uint64_t* spans[n];
+  size_t ntokens[n];
+  for (size_t j = 0; j < n; ++j) {
+    fill_pattern(bytes[j].data(), len, 90 + static_cast<uint32_t>(j));
+    toks[j] = 7 * j;
+    pages[j].key = k128(8, j + 1);
+    pages[j].data = data[j] = bytes[j].data();
+    pages[j].tokens = spans[j] = &toks[j];
+    pages[j].ntokens = ntokens[j] = j % 2;  // spans empty and not
+  }
+  Key128 lanes[n];
+  kv_content_hash_lanes(data, len, spans, ntokens, n, lanes);
+  for (size_t j = 0; j < n; ++j) {
+    Key128 one;
+    kv_content_hash(data[j], len, spans[j], ntokens[j], &one);
+    EXPECT(lanes[j] == one);
+  }
+  KvPrefixCounters& c = kv_prefix_counters();
+  const uint64_t grouped0 = KvPrefixCounters::read(c.hash_lanes);
+  int rcs[n];
+  KvPrefixMeta metas[n];
+  EXPECT_EQ(kv_store().publish_prefix_run(pages.data(), n, len, 2, 60000,
+                                          rcs, metas),
+            n);
+  EXPECT_EQ(KvPrefixCounters::read(c.hash_lanes), grouped0 + 8);
+  for (size_t j = 0; j < n; ++j) {
+    EXPECT_EQ(rcs[j], 0);
+    EXPECT(metas[j].hash == lanes[j]);
+    EXPECT_EQ(metas[j].depth, 2 + j);
+  }
+  // A run of one is publish_prefix: renewed, and grouped with nothing.
+  KvPrefixMeta again;
+  EXPECT_EQ(kv_store().publish_prefix(pages[8].key, 10, data[8], len,
+                                      spans[8], ntokens[8], 60000, &again),
+            kEKvExists);
+  EXPECT(again.hash == lanes[8]);
+  EXPECT_EQ(KvPrefixCounters::read(c.hash_lanes), grouped0 + 8);
+}
+
 TEST_MAIN
