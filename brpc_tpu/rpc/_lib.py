@@ -385,6 +385,11 @@ def load_library() -> ctypes.CDLL:
                 ctypes.POINTER(ctypes.c_uint64),
             ]
             lib.trpc_kv_prefix_publish_run.restype = ctypes.c_size_t
+            lib.trpc_kv_prefix_publish_state.argtypes = (
+                lib.trpc_kv_prefix_publish_at.argtypes[:7]
+                + [ctypes.c_int64, ctypes.c_int]
+                + [ctypes.POINTER(ctypes.c_uint64)] * 5)
+            lib.trpc_kv_prefix_publish_state.restype = ctypes.c_int
             lib.trpc_kv_prefix_withdraw.argtypes = [
                 ctypes.c_uint64, ctypes.c_uint64,
             ]

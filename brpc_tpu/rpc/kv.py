@@ -102,6 +102,28 @@ block no replica serves; `publish_prefix_run` hashes the run's pages four
 at a time where their bytes lie, and the store takes each there, with no
 copy, when they lie in the landing block its device-to-host transfer
 wrote (`prefix_publish`), else copies it once.
+
+A hybrid cache (latent pages beside the recurrent state of
+linear-attention layers) is restored as the pages up to a boundary and
+the state taken there, never one without the other.  The state is a
+prefix record of its own kind (PREFIX_STATE), keyed by the chain key of
+the boundary's last page; the store moves it as it moves a page, and
+`KvReg.Match` ends its answer at the deepest boundary of the matched run
+at which a live state lies, with that state's replicas::
+
+    found = cli.match_prefix(tokens)             # found.boundary, found.state
+    landed = cli.fetch_prefix_blocks(found, landing=rows, window=64,
+                                     state=found.state,
+                                     state_landing=state_rows)
+    # landed.state is None: the hit ends at an earlier boundary
+    boundary, state = cli.fetch_prefix_state(tokens, block_tokens,
+                                             len(landed))
+    # ...prefill from the boundary on, then offer the pages and the state
+    # at the prompt's end in one PutPrefixMany:
+    state = kv.prefix_publish_state(keys[-1], len(keys), state_view,
+                                    last_span, node=addr)[0]
+    kv.publish_prefix_run(keys[boundary:], boundary, pages_view, spans,
+                          node=addr, registry=reg, state=state)
 """
 
 from __future__ import annotations
@@ -109,6 +131,7 @@ from __future__ import annotations
 import ctypes
 import dataclasses
 import struct
+import time
 
 import numpy as np
 
@@ -149,6 +172,9 @@ PREFIX_PUT_METHOD = "KvReg.PutPrefix"
 PREFIX_PUT_MANY_METHOD = "KvReg.PutPrefixMany"
 PREFIX_MATCH_METHOD = "KvReg.Match"
 PREFIX_FETCH_METHOD = "Kv.FetchPrefix"
+# The kind bit of a prefix record (cpp/net/kvstore.h kKvPrefixState): a
+# hybrid cache's recurrent state at a boundary, not a page.
+PREFIX_STATE = 2
 
 
 class KvError(RpcError):
@@ -590,7 +616,8 @@ class KvPrefixMeta:
     depth: int = 0
     node: str = ""
     lease_left_ms: int = 0
-    flags: int = 0  # bit 0: replica currently cold (tier telemetry)
+    flags: int = 0  # bit 0: replica currently cold (tier telemetry);
+    #                 PREFIX_STATE: a state at the boundary `depth`
 
     @property
     def key(self) -> tuple[int, int]:
@@ -617,6 +644,12 @@ class KvPrefixMeta:
 
 
 def _token_array(tokens):
+    if isinstance(tokens, np.ndarray):
+        # A prompt of 100k+ tokens, handed over where it lies.
+        toks = np.ascontiguousarray(tokens, dtype=np.uint64)
+        if len(toks):
+            return toks.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)), len(
+                toks)
     toks = list(tokens)
     return (ctypes.c_uint64 * max(len(toks), 1))(*toks), len(toks)
 
@@ -709,9 +742,45 @@ def prefix_publish(key: tuple[int, int], depth: int, data, tokens,
     return meta, rc == 0
 
 
+def prefix_publish_state(key: tuple[int, int], boundary: int, data, tokens,
+                         lease_ms: int = 0,
+                         node: str = "") -> tuple[KvPrefixMeta, bool]:
+    """Publishes into the local store the recurrent state of a hybrid
+    cache's linear-attention layers taken after `boundary` pages of a
+    prompt, under `key`, the chain key of the boundary's last page, with
+    `tokens` that page's token span: the store admits, hashes, demotes,
+    promotes and drops it as it does a page (`prefix_publish`: taken
+    where a view's transfer landed it, else copied once); only its record
+    says what it is (flags PREFIX_STATE, depth the boundary).  The
+    registry files such a record apart from the pages; it is recorded
+    with the pages of its turn by `publish_prefix_run(..., state=)`.
+    Returns (meta, fresh) as `prefix_publish` does."""
+    lib = load_library()
+    flat = _byte_view(data)
+    if not flat.nbytes or boundary < 1:
+        raise ValueError(f"a state of {flat.nbytes} bytes at {boundary}")
+    tok_arr, ntok = _token_array(tokens)
+    outs = [ctypes.c_uint64() for _ in range(5)]
+    address = flat.ctypes.data
+    rc = lib.trpc_kv_prefix_publish_state(
+        key[0], key[1], boundary, address, flat.nbytes, tok_arr, ntok,
+        lease_ms, lib.trpc_host_pool_holds(address, flat.nbytes),
+        *(ctypes.byref(v) for v in outs))
+    _miss, _stale, exists = _codes()
+    if rc != 0 and rc != exists:
+        raise MemoryError(
+            f"kv prefix publish failed (rc={rc}): the state must fit "
+            "trpc_kv_store_bytes")
+    hash_hi, hash_lo, gen, rkey, off = (v.value for v in outs)
+    return KvPrefixMeta(key[0], key[1], hash_hi, hash_lo, gen, rkey, off,
+                        flat.nbytes, boundary, node,
+                        flags=PREFIX_STATE), rc == 0
+
+
 def publish_prefix_run(keys, first_depth: int, pages, token_spans,
                        lease_ms: int = 0, node: str = "",
-                       registry: "KvRegistryClient | None" = None
+                       registry: "KvRegistryClient | None" = None,
+                       state: KvPrefixMeta | None = None
                        ) -> list[tuple[KvPrefixMeta, bool]]:
     """Publishes a run of consecutive prefix blocks, the new pages of one
     prompt: block `j` of `pages` under chain key `keys[j]` at depth
@@ -725,8 +794,17 @@ def publish_prefix_run(keys, first_depth: int, pages, token_spans,
     end to end, equal in size.  A block the store cannot take raises
     MemoryError, the blocks before it published and those after it not.
     With a `registry` the replicas are recorded in ONE
-    `put_prefix_many`; a record it refuses raises its error.  Returns
-    `prefix_publish`'s (meta, fresh) per block."""
+    `put_prefix_many`, together with `state` where there is one: the
+    record `prefix_publish_state` made of the state at the run's end
+    boundary (a hybrid cache's turn is its pages and that state); a
+    record it refuses raises its error.  Returns `prefix_publish`'s
+    (meta, fresh) per block."""
+    if state is not None and (
+            not state.flags & PREFIX_STATE
+            or state.depth != first_depth + len(keys)
+            or state.key != tuple(keys[-1])):
+        raise ValueError(f"a state at {state.depth} is not the state of "
+                         f"the run's end, {first_depth + len(keys)}")
     flat = _host_flat(pages)
     n = len(keys)
     if not n or flat.nbytes % n or len(token_spans) != n:
@@ -759,8 +837,9 @@ def publish_prefix_run(keys, first_depth: int, pages, token_spans,
             rcs[j] == 0)
            for j, key in enumerate(keys)]
     if registry is not None:
-        for answer in registry.put_prefix_many([meta for meta, _ in out],
-                                               lease_ms=lease_ms):
+        metas = [meta for meta, _ in out] + ([] if state is None
+                                             else [state])
+        for answer in registry.put_prefix_many(metas, lease_ms=lease_ms):
             if isinstance(answer, RpcError):
                 raise answer
     return out
@@ -946,6 +1025,32 @@ class KvRegistryClient:
     def close(self) -> None:
         if self._owns:
             self._ch.close()
+
+
+class PrefixMatch(list):
+    """`KvClient.match_prefix`'s answer: the matched blocks' replica
+    groups in chain order, and for a hybrid cache (latent pages beside
+    the recurrent state of linear-attention layers) the state to restore
+    with them: where a live state lies at a boundary of the matched run,
+    `boundary` is the deepest such, the groups end there and `state`
+    holds every live replica of it; else `boundary` is 0 and `state`
+    empty."""
+
+    def __init__(self, groups=()):
+        super().__init__(groups)
+        self.boundary = 0
+        self.state: list[KvPrefixMeta] = []
+
+
+class PrefixBlocks(list):
+    """`KvClient.fetch_prefix_blocks`' answer: the landed blocks, and
+    where a state was asked for, `state` (its landed bytes, or None) and
+    `state_landed_at` (the `time.perf_counter()` of the poll that handed
+    its last answer out)."""
+
+    def __init__(self):
+        super().__init__()
+        self.state = self.state_landed_at = None
 
 
 def _block_fetch(meta: KvBlockMeta, buf) -> tuple:
@@ -1158,7 +1263,8 @@ class KvClient:
                 raise
         raise last
 
-    def _fetch_round(self, wanted, method: str = FETCH_METHOD) -> list:
+    def _fetch_round(self, wanted, method: str = FETCH_METHOD,
+                     landed_at: list | None = None) -> list:
         """One call of `method` (`Kv.Fetch`, `Kv.FetchPrefix`) per (node,
         request, landing buffer) of `wanted`, all in flight together: the
         requests to one node cross in ONE `pipeline.submit`, then the
@@ -1166,10 +1272,13 @@ class KvClient:
         order: the landed length, or its error as an instance (the
         one-sided direct path where the buffer is RmaBuffer-backed and
         stripe-eligible and the connection is shm/ici; else the runtime
-        copies the response out on completion).  A node whose poll times
-        out is dropped, so that a late completion cannot be taken for a
-        later call's."""
+        copies the response out on completion), and in `landed_at`, where
+        given, the `time.perf_counter()` of the poll that handed it out.
+        A node whose poll times out is dropped, so that a late completion
+        cannot be taken for a later call's."""
         out: list = [None] * len(wanted)
+        if landed_at is not None:
+            landed_at[:] = [None] * len(wanted)
         by_node: dict[str, list[int]] = {}
         for i, (node, _request, _buf) in enumerate(wanted):
             by_node.setdefault(node, []).append(i)
@@ -1190,10 +1299,14 @@ class KvClient:
                         out[i] = RpcError(-1, "kv fetch timed out in poll")
                     self._drop_node(node)
                     break
+                if landed_at is not None:
+                    at = time.perf_counter()
                 for c in done:
                     i = waiting.pop(c.token, None)
                     if i is None:
                         continue
+                    if landed_at is not None:
+                        landed_at[i] = at
                     if not c.ok:
                         out[i] = _kv_error(RpcError(c.status, c.error))
                         continue
@@ -1310,18 +1423,23 @@ class KvClient:
 
     # ---- content-addressed prefix cache (ISSUE 17) ----
 
-    def match_prefix(self, tokens,
-                     block_tokens: int = 0) -> list[list[KvPrefixMeta]]:
+    def match_prefix(self, tokens, block_tokens: int = 0) -> "PrefixMatch":
         """Longest cached prefix for `tokens`: replica groups in chain
         order (groups[i] = every live replica of prefix block i).  An
-        empty list means nothing is cached — full recompute."""
+        empty list means nothing is cached — full recompute.  For a
+        hybrid cache the answer also says the deepest boundary of the run
+        at which a live state lies, and the groups end there
+        (`PrefixMatch`)."""
         keys = prefix_chain(tokens, block_tokens)
+        groups = PrefixMatch()
         if not keys:
-            return []
-        records = self.registry.match(keys)
-        groups: list[list[KvPrefixMeta]] = []
+            return groups
         cur = None
-        for r in records:
+        for r in self.registry.match(keys):
+            if r.flags & PREFIX_STATE:
+                groups.state.append(r)
+                groups.boundary = r.depth
+                continue
             if r.key != cur:
                 groups.append([])
                 cur = r.key
@@ -1337,8 +1455,9 @@ class KvClient:
         vetoes."""
         return groups[-1][0].node if groups else ""
 
-    def fetch_prefix_blocks(self, groups, landing=None,
-                            window: int = 16) -> list:
+    def fetch_prefix_blocks(self, groups, landing=None, window: int = 16,
+                            state=None, state_landing=None
+                            ) -> "PrefixBlocks":
         """Lands the matched run `groups` (`match_prefix`'s answer, or a
         slice of it) block by block in chain order and returns the landed
         blocks, each a flat uint8 array of its block's length.  The
@@ -1356,10 +1475,25 @@ class KvClient:
         serves nothing and the block's next replica is asked; where every
         replica of a block fails the cacheable prefix ends there and the
         list is that much shorter than `groups` (callers recompute the
-        rest; blocks behind the hole are never handed out)."""
-        landed: list = []
-        for at in range(0, len(groups), max(1, window)):
-            part = groups[at:at + max(1, window)]
+        rest; blocks behind the hole are never handed out).
+
+        `state` (a `PrefixMatch`'s `state`: the replicas of a hybrid
+        cache's state) lands in `state_landing` (a writable C-contiguous
+        numpy array of its size; a registered buffer's for the one-sided
+        landing) or a recycled block, in the first round, beside the
+        first window's blocks, with the same fail-over: the answer's
+        `state` is it, and None where it did not land or where a block of
+        `groups` did not (a state is handed out with all its pages)."""
+        landed = PrefixBlocks()
+        state = list(state or ())
+        state_buf = state_ok = None
+        if state:
+            state_buf = (zerocopy.landing_block(state[0].length)
+                         if state_landing is None
+                         else state_landing.reshape(-1).view(np.uint8))
+        window = max(1, window)
+        for at in range(0, max(len(groups), 1), window):
+            part = groups[at:at + window]
             bufs = [(zerocopy.landing_block(group[0].length)
                      if landing is None
                      else landing[at + i].reshape(-1).view(np.uint8))
@@ -1369,21 +1503,54 @@ class KvClient:
             while True:
                 asked = [i for i, group in enumerate(part)
                          if not ok[i] and replica < len(group)]
-                if not asked:
+                wanted = [(part[i][replica].node, part[i][replica].pack(),
+                           bufs[i]) for i in asked]
+                ask_state = at == 0 and not state_ok and replica < len(state)
+                if ask_state:
+                    wanted.append((state[replica].node,
+                                   state[replica].pack(), state_buf))
+                if not wanted:
                     break
-                answers = self._fetch_round(
-                    [(part[i][replica].node, part[i][replica].pack(),
-                      bufs[i]) for i in asked], PREFIX_FETCH_METHOD)
+                times: list = []
+                answers = self._fetch_round(wanted, PREFIX_FETCH_METHOD,
+                                            times)
                 for i, answer in zip(asked, answers):
                     # Stale, chunk-faulted, short or dead replica: the
                     # block is never admitted partially.
                     ok[i] = answer == part[i][replica].length
+                if ask_state:
+                    state_ok = answers[-1] == state[replica].length
+                    landed.state_landed_at = times[-1]
                 replica += 1
             run = ok.index(False) if False in ok else len(ok)
             landed.extend(bufs[:run])
             if run < len(part):
                 break
+        if state_ok and len(landed) == len(groups):
+            landed.state = state_buf
         return landed
+
+    def fetch_prefix_state(self, tokens, block_tokens: int, below: int,
+                           state_landing=None) -> tuple:
+        """For a hybrid cache whose matched state, or a page under it, did
+        not land (dropped, stale, missing): the deepest boundary at or
+        under `below` pages at which a state of this prompt lands, and
+        that state, landed as `fetch_prefix_blocks` lands one; (0, None)
+        where none does.  One `KvReg.Match` over the prompt's first
+        `below` pages (`block_tokens` tokens each) and the state's fetch,
+        then one boundary shallower each time the state does not land;
+        the pages under the boundary landed with the run before."""
+        while below > 0:
+            found = self.match_prefix(tokens[:below * block_tokens],
+                                      block_tokens)
+            if not found.state:
+                break
+            got = self.fetch_prefix_blocks([], state=found.state,
+                                           state_landing=state_landing)
+            if got.state is not None:
+                return found.boundary, got.state
+            below = found.boundary - 1
+        return 0, None
 
     def fetch_prefix(self, tokens, block_tokens: int = 0) -> list[bytes]:
         """Fetches every cached prefix block for `tokens` in chain order

@@ -257,6 +257,40 @@ size_t trpc_kv_prefix_publish_run(const uint64_t* keys, uint32_t first_depth,
   return handled;
 }
 
+// Publishes the recurrent state of a hybrid cache taken at `boundary`
+// pages, under the chain key (key_hi, key_lo) of the boundary's last
+// page: the store's one admission path, as trpc_kv_prefix_publish_run
+// admits a run of one, its record flagged kKvPrefixState.  Returns and
+// fills as trpc_kv_prefix_publish_at.
+int trpc_kv_prefix_publish_state(uint64_t key_hi, uint64_t key_lo,
+                                 uint32_t boundary, const void* data,
+                                 size_t len, const uint64_t* tokens,
+                                 size_t ntokens, int64_t lease_ms,
+                                 int in_place, uint64_t* hash_hi,
+                                 uint64_t* hash_lo, uint64_t* gen_out,
+                                 uint64_t* rkey_out, uint64_t* off_out) {
+  KvStore::PrefixPage page;
+  page.key.hi = key_hi;
+  page.key.lo = key_lo;
+  page.data = data;
+  page.tokens = tokens;
+  page.ntokens = ntokens;
+  page.in_place = in_place != 0;
+  page.flags = kKvPrefixState;
+  int rc = -1;
+  KvPrefixMeta m;
+  kv_store().publish_prefix_run(&page, 1, len, boundary, lease_ms, &rc, &m);
+  if (rc != 0 && rc != kEKvExists) {
+    return rc;
+  }
+  *hash_hi = m.hash.hi;
+  *hash_lo = m.hash.lo;
+  *gen_out = m.generation;
+  *rkey_out = m.rkey;
+  *off_out = m.off;
+  return rc;
+}
+
 // Evicts a local prefix block by content hash (generation tombstoned).
 int trpc_kv_prefix_withdraw(uint64_t hash_hi, uint64_t hash_lo) {
   Key128 h;

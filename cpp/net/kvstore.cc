@@ -401,6 +401,26 @@ KvPrefixCounters::KvPrefixCounters() {
       "kv_prefix_match_blocks",
       "prefix blocks matched across all KvReg.Match answers (sum of "
       "matched depths: over kv_prefix_match_keys, the share cached)");
+  state_publish_total.expose(
+      "kv_prefix_state_publish_total",
+      "recurrent-state snapshots admitted fresh into the prefix store "
+      "(also counted in kv_prefix_publish_total)");
+  state_publish_bytes.expose("kv_prefix_state_publish_bytes",
+                             "bytes of those admitted states");
+  state_fetch_total.expose(
+      "kv_prefix_state_fetch_total",
+      "states served by this node's prefix store (also counted in "
+      "kv_prefix_fetch_total)");
+  state_fetch_bytes.expose("kv_prefix_state_fetch_bytes",
+                           "bytes of those served states");
+  match_states.expose(
+      "kv_prefix_match_states",
+      "KvReg.Match answers that carried a live state: the pages up to its "
+      "boundary, then the state");
+  match_states_shallow.expose(
+      "kv_prefix_match_states_shallow",
+      "of those, answers whose deepest live state lay short of the "
+      "matched run of pages (the pages past it left out)");
 }
 
 KvPrefixCounters& kv_prefix_counters() {
@@ -1414,6 +1434,7 @@ int KvStore::admit_prefix(const PrefixPage& page, uint32_t depth,
   prefix_tombstones_[hash] = b.meta.generation;
   b.meta.len = len;
   b.meta.depth = depth;
+  b.meta.flags = page.flags;
   set_prefix_lease_locked(&b, hash, effective_lease_us(lease_ms));
   if (hot_data != nullptr) {
     b.hot_data = hot_data;
@@ -1435,6 +1456,10 @@ int KvStore::admit_prefix(const PrefixPage& page, uint32_t depth,
   record_kv(hash.lo, kKvOpPublish, len);
   counted.publish_total << 1;
   counted.publish_bytes << static_cast<int64_t>(len);
+  if ((page.flags & kKvPrefixState) != 0) {
+    counted.state_publish_total << 1;
+    counted.state_publish_bytes << static_cast<int64_t>(len);
+  }
   (b.hot && !b.owned ? counted.publish_in_place_bytes
                      : counted.publish_copy_bytes)
       << static_cast<int64_t>(len);
@@ -1472,8 +1497,13 @@ int KvStore::fetch_prefix(const Key128& hash, uint64_t expected_gen,
     return kEKvStale;
   }
   const uint64_t len = b->meta.len;
+  const bool state = (b->meta.flags & kKvPrefixState) != 0;
   auto served = [&] {
     counted.fetch_total << 1;
+    if (state) {
+      counted.state_fetch_total << 1;
+      counted.state_fetch_bytes << static_cast<int64_t>(len);
+    }
     kv_vars().fetch_bytes << static_cast<int64_t>(len);
     record_kv(hash.lo, kKvOpServe, len);
     return 0;
@@ -1649,13 +1679,15 @@ int KvRegistry::put_prefix(const KvPrefixMeta& meta, int64_t lease_ms,
   }
   const int64_t now = monotonic_time_us();
   std::lock_guard<std::mutex> g(mu_);
-  auto it = prefix_.find(meta.key);
-  if (it == prefix_.end()) {
+  auto& records =
+      (meta.flags & kKvPrefixState) != 0 ? prefix_states_ : prefix_;
+  auto it = records.find(meta.key);
+  if (it == records.end()) {
     PrefixEntry e;
     e.hash = meta.hash;
     e.depth = meta.depth;
     e.len = meta.len;
-    it = prefix_.emplace(meta.key, std::move(e)).first;
+    it = records.emplace(meta.key, std::move(e)).first;
   } else if (it->second.hash != meta.hash) {
     // Same chain key, different bytes: token/content divergence (a
     // nondeterministic prefill, or corruption).  Never silently alias —
@@ -1714,30 +1746,53 @@ int KvRegistry::put_prefix(const KvPrefixMeta& meta, int64_t lease_ms,
   return 0;
 }
 
+namespace {
+
+// Drops an entry's lapsed replicas; true if a live one is left.
+template <typename Entry>
+bool prune_lapsed(Entry* e, int64_t now) {
+  auto& reps = e->replicas;
+  reps.erase(std::remove_if(reps.begin(), reps.end(),
+                            [now](const auto& r) {
+                              return r.deadline_us <= now;
+                            }),
+             reps.end());
+  return !reps.empty();
+}
+
+}  // namespace
+
 size_t KvRegistry::match(const Key128* keys, size_t n,
                          std::vector<KvPrefixMeta>* out,
                          std::vector<int64_t>* lease_out) {
   kv_ensure_registered();
+  KvPrefixCounters& counted = kv_prefix_counters();
   const int64_t now = monotonic_time_us();
   std::lock_guard<std::mutex> g(mu_);
-  kv_prefix_counters().match_total << 1;
-  kv_prefix_counters().match_keys << static_cast<int64_t>(n);
-  size_t matched = 0;
+  counted.match_total << 1;
+  counted.match_keys << static_cast<int64_t>(n);
+  std::vector<PrefixEntry*> run;
   for (size_t i = 0; i < n; ++i) {
     auto it = prefix_.find(keys[i]);
-    if (it == prefix_.end()) {
-      break;  // first miss ends the longest cached prefix
+    // The first miss, or a block whose replicas all lapsed, ends the
+    // longest cached prefix.
+    if (it == prefix_.end() || !prune_lapsed(&it->second, now)) {
+      break;
     }
-    PrefixEntry& e = it->second;
-    e.replicas.erase(
-        std::remove_if(e.replicas.begin(), e.replicas.end(),
-                       [now](const PrefixReplica& r) {
-                         return r.deadline_us <= now;
-                       }),
-        e.replicas.end());
-    if (e.replicas.empty()) {
-      break;  // all replicas lapsed: the chain stops here
+    run.push_back(&it->second);
+  }
+  // The deepest boundary of the run at which a live state lies.
+  PrefixEntry* state = nullptr;
+  size_t answered = run.size();
+  for (size_t b = run.size(); b > 0 && !prefix_states_.empty(); --b) {
+    auto it = prefix_states_.find(keys[b - 1]);
+    if (it != prefix_states_.end() && prune_lapsed(&it->second, now)) {
+      state = &it->second;
+      answered = b;
+      break;
     }
+  }
+  auto answer = [&](const PrefixEntry& e) {
     for (const PrefixReplica& r : e.replicas) {
       if (out != nullptr) {
         out->push_back(r.meta);
@@ -1746,27 +1801,42 @@ size_t KvRegistry::match(const Key128* keys, size_t n,
         lease_out->push_back((r.deadline_us - now) / 1000);
       }
     }
-    ++matched;
+  };
+  for (size_t i = 0; i < answered; ++i) {
+    answer(*run[i]);
   }
-  kv_prefix_counters().match_blocks << static_cast<int64_t>(matched);
-  return matched;
+  if (state != nullptr) {
+    answer(*state);
+    counted.match_states << 1;
+    if (answered < run.size()) {
+      counted.match_states_shallow << 1;
+    }
+  }
+  counted.match_blocks << static_cast<int64_t>(answered);
+  return answered;
 }
 
 int KvRegistry::evict_prefix(const Key128& key, const char* node) {
   std::lock_guard<std::mutex> g(mu_);
-  auto it = prefix_.find(key);
-  if (it == prefix_.end()) {
-    return kEKvMiss;
-  }
-  std::vector<PrefixReplica>& reps = it->second.replicas;
-  for (auto r = reps.begin(); r != reps.end(); ++r) {
-    if (node != nullptr && strncmp(r->meta.node, node,
-                                   sizeof(r->meta.node)) == 0) {
-      reps.erase(r);
-      return 0;  // the fence map stays: no zombie window reopens
+  // The page under this chain key and the state at its boundary: both
+  // are the node's replicas of this prefix.
+  int rc = kEKvMiss;
+  for (auto* records : {&prefix_, &prefix_states_}) {
+    auto it = records->find(key);
+    if (it == records->end()) {
+      continue;
+    }
+    std::vector<PrefixReplica>& reps = it->second.replicas;
+    for (auto r = reps.begin(); r != reps.end(); ++r) {
+      if (node != nullptr && strncmp(r->meta.node, node,
+                                     sizeof(r->meta.node)) == 0) {
+        reps.erase(r);
+        rc = 0;  // the fence map stays: no zombie window reopens
+        break;
+      }
     }
   }
-  return kEKvMiss;
+  return rc;
 }
 
 size_t KvRegistry::prefix_count() {
@@ -1808,6 +1878,7 @@ void KvRegistry::clear() {
   entries_.clear();
   last_gen_.clear();
   prefix_.clear();
+  prefix_states_.clear();
 }
 
 // ---- native handlers -----------------------------------------------------
@@ -1845,6 +1916,7 @@ void prefix_meta_to_wire(const KvPrefixMeta& m, int64_t lease_ms,
   w->len = m.len;
   w->lease_ms = lease_ms;
   w->depth = m.depth;
+  w->flags = m.flags & kKvPrefixState;
   memcpy(w->node, m.node, sizeof(w->node));
 }
 
@@ -1859,6 +1931,7 @@ KvPrefixMeta wire_to_prefix_meta(const KvPrefixWire& w) {
   m.off = w.off;
   m.len = w.len;
   m.depth = w.depth;
+  m.flags = w.flags & kKvPrefixState;
   memcpy(m.node, w.node, sizeof(m.node));
   return m;
 }

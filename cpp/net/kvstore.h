@@ -219,6 +219,16 @@ void kv_content_hash_lanes(const void* const* data, size_t len,
 size_t kv_prefix_chain(const uint64_t* tokens, size_t ntokens,
                        int64_t block_tokens, Key128* keys, size_t max_keys);
 
+// The kind bit of a prefix record (KvPrefixWire.flags, KvPrefixMeta.flags):
+// set, the record is the recurrent STATE of a hybrid cache's
+// linear-attention layers taken at a boundary, not a page.  Its key is
+// the chain key of the boundary's last page and its depth the boundary
+// in pages; the registry files it apart from the pages, so that no walk
+// over page keys finds it and a state of another boundary or prompt is
+// another key.  The store moves it as it moves a page: only its size
+// differs.
+constexpr uint32_t kKvPrefixState = 2;
+
 // Addressing record for one prefix-block replica: chain key (where in
 // the trie), content hash (what bytes), and where THIS replica lives.
 struct KvPrefixMeta {
@@ -228,7 +238,9 @@ struct KvPrefixMeta {
   uint64_t rkey = 0;  // valid while the replica is hot
   uint64_t off = 0;
   uint64_t len = 0;
-  uint32_t depth = 0;  // 0-based block index in the prefix chain
+  uint32_t depth = 0;  // 0-based block index in the prefix chain; a
+                       // state's boundary in pages
+  uint32_t flags = 0;  // kKvPrefixState for a state
   char node[64] = {};
 };
 
@@ -237,7 +249,10 @@ struct KvPrefixMeta {
 // PutPrefix sends all fields; FetchPrefix sends hash + generation;
 // Match sends a u64 count + count x 16-byte chain keys and answers a
 // u64 record count + that many KvPrefixWire records (one per live
-// replica, grouped in chain order — lease_ms = remaining ms).
+// replica, grouped in chain order — lease_ms = remaining ms).  Where a
+// live state lies at a boundary of the matched run, the pages end at the
+// deepest such boundary and that state's replicas follow them, flagged
+// kKvPrefixState (KvRegistry::match).
 // PutPrefixMany is RegisterMany's shape over this record: a u64 count
 // (1..kKvManyMax) then that many KvPrefixWire in, the count then one
 // KvManyGen per record out, in order (status 0, kEKvExists for the
@@ -254,7 +269,8 @@ struct KvPrefixWire {
   uint64_t len;
   int64_t lease_ms;
   uint32_t depth;
-  uint32_t flags;  // bit 0: replica currently cold (tier telemetry)
+  uint32_t flags;  // bit 0: replica currently cold (tier telemetry);
+                   // kKvPrefixState: the record is a state
   char node[64];
 };
 static_assert(sizeof(KvPrefixWire) == 144,
@@ -291,6 +307,14 @@ struct KvPrefixCounters {
   Adder match_total;     // KvReg.Match queries answered
   Adder match_keys;      // chain keys they asked
   Adder match_blocks;    // blocks they matched (sum of matched depths)
+  // The state kind (kKvPrefixState), counted besides the totals above,
+  // which hold both kinds:
+  Adder state_publish_total;   // states admitted fresh (in publish_total)
+  Adder state_publish_bytes;   // their bytes
+  Adder state_fetch_total;     // states served (in fetch_total)
+  Adder state_fetch_bytes;     // their bytes
+  Adder match_states;          // Match answers that carried a state
+  Adder match_states_shallow;  // ... at a boundary short of the matched run
   KvPrefixCounters();    // exposes each under its name
   static uint64_t read(const Adder& c) {
     return static_cast<uint64_t>(c.get_value());
@@ -382,12 +406,15 @@ class KvStore {
                      uint64_t min_generation = 0, bool in_place = false);
   // One page of a run: its chain key, its bytes, its token span, and
   // whether the store may take the bytes where they lie (`in_place`).
+  // A state (flags kKvPrefixState) is admitted as a page is; the flag is
+  // kept in its record and counted, nothing else reads it.
   struct PrefixPage {
     Key128 key;
     const void* data = nullptr;
     const uint64_t* tokens = nullptr;
     size_t ntokens = 0;
     bool in_place = false;
+    uint32_t flags = 0;
   };
   // Publishes the n pages of a run, all `len` bytes, page j at depth
   // first_depth + j: hashed kKvHashLanes at a time
@@ -539,11 +566,19 @@ class KvRegistry {
   // registration is idempotent).
   int put_prefix(const KvPrefixMeta& meta, int64_t lease_ms,
                  uint64_t* gen_out);
+  // A record flagged kKvPrefixState is a state: it is filed under its
+  // chain key apart from the pages, with the same rules.
+  //
   // Longest cached prefix: walks keys[0..n) in order, stopping at the
   // first key with no live replica.  Appends one KvPrefixMeta per LIVE
   // replica of every matched block (grouped in chain order; expired
   // replicas prune here) plus its remaining lease into the parallel
-  // lease_out (ms).  Returns the number of matched BLOCKS (depths).
+  // lease_out (ms).  Where a state with a live replica lies at a
+  // boundary b of the matched run (the state filed under keys[b - 1]),
+  // the deepest such b ends the answer: the pages under it, then every
+  // live replica of that state; a hybrid cache is restored as the pages
+  // up to a state and the state, never one without the other.  Returns
+  // the number of BLOCKS answered (b where a state was, else the run).
   size_t match(const Key128* keys, size_t n,
                std::vector<KvPrefixMeta>* out,
                std::vector<int64_t>* lease_out = nullptr);
@@ -577,6 +612,8 @@ class KvRegistry {
   std::unordered_map<uint64_t, Entry> entries_;
   std::unordered_map<uint64_t, uint64_t> last_gen_;
   std::unordered_map<Key128, PrefixEntry, Key128Hash> prefix_;
+  // States, by the chain key of their boundary's last page.
+  std::unordered_map<Key128, PrefixEntry, Key128Hash> prefix_states_;
 };
 KvRegistry& kv_registry();
 
