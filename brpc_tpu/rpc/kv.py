@@ -96,8 +96,8 @@ next turn of a conversation::
                           node=addr, registry=reg)   # one PutPrefixMany
 
 `fetch_prefix_blocks` rides the node channel's pipeline as `Kv.Fetch`
-does (a window of blocks in flight, each over `trpc_stripe_threshold`
-through the connection's one-sided window) and ends the run at the first
+does (a window of blocks in flight, each put by the server straight into
+its row of a registered landing over the shm ring) and ends the run at the first
 block no replica serves; `publish_prefix_run` hashes the run's pages four
 at a time where their bytes lie, and the store takes each there, with no
 copy, when they lie in the landing block its device-to-host transfer
@@ -1270,9 +1270,10 @@ class KvClient:
         requests to one node cross in ONE `pipeline.submit`, then the
         pipelines are polled until every record is in.  Per record, in
         order: the landed length, or its error as an instance (the
-        one-sided direct path where the buffer is RmaBuffer-backed and
-        stripe-eligible and the connection is shm/ici; else the runtime
-        copies the response out on completion), and in `landed_at`, where
+        one-sided direct path where the buffer lies in an RmaBuffer, the
+        response is at least `kRmaDirectFloor` (cpp/net/rma.h) and the
+        connection is shm/ici; else the runtime copies the response out
+        on completion), and in `landed_at`, where
         given, the `time.perf_counter()` of the poll that handed it out.
         A node whose poll times out is dropped, so that a late completion
         cannot be taken for a later call's."""
@@ -1363,9 +1364,9 @@ class KvClient:
 
     def _land_records(self, records, bufs) -> None:
         """Lands `records` ((id, bytes), in order) in `bufs`, every
-        fetch in flight together (`fetch_many`): where a buffer is
-        stripe-eligible and RmaBuffer-backed its record can land there
-        one-sided.  A record that is missing or of another length fails
+        fetch in flight together (`fetch_many`): where a buffer lies in
+        an RmaBuffer its record lands there one-sided.  A record that is
+        missing or of another length fails
         them all (KvFetchManyError)."""
         lengths = self.fetch_many([rid for rid, _ in records], bufs)
         short = {rid: RpcError(-1, f"record of {n} bytes, layer of {want}")
@@ -1381,9 +1382,9 @@ class KvClient:
         sequence, paged layers), `states` one of leading axis (snapshot
         layers), None where the layout has none (as there, an array's
         bytes are its records end to end); views of an RmaBuffer for
-        the one-sided path, which one record a region takes at a time
-        (cpp/net/rma.cc `rma_landing_bind`): the others of a region
-        cross the connection's window and are copied out on landing.
+        the one-sided path, where every record of at least
+        `kRmaDirectFloor` (cpp/net/rma.h) is put in its place, each call
+        holding its own completion descriptor of the region.
         One `lookup_many`, then every record of both kinds in flight in
         one round, each landing in its place; the snapshots asked for
         are those of the boundary `pages.shape[0]`.  Returns `(pages,
@@ -1462,9 +1463,12 @@ class KvClient:
         slice of it) block by block in chain order and returns the landed
         blocks, each a flat uint8 array of its block's length.  The
         `Kv.FetchPrefix` calls ride the node channel's pipeline as
-        `Kv.Fetch` does, at most `window` blocks in flight (a block over
-        `trpc_stripe_threshold` on the shm ring crosses the connection's
-        one-sided window, so `window` blocks must fit
+        `Kv.Fetch` does, at most `window` blocks in flight (over the shm
+        ring a block is put in place where its buffer lies in a
+        registered region, as the rows of an RmaBuffer and the host
+        pool's blocks of 1 MB or more do; into any other buffer a block
+        over `trpc_stripe_threshold` crosses the connection's one-sided
+        window, so `window` such blocks must fit
         `trpc_rma_window_bytes`).  Block `i` lands in `landing[i]`
         (writable C-contiguous numpy arrays of the blocks' sizes, or one
         array whose leading axis is the blocks: rows of a registered

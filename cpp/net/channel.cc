@@ -96,9 +96,14 @@ void complete_locked_call(fid_t cid, Controller* cntl) {
     submit_span(span, cntl->error_code());
   }
   // Landing registration must die BEFORE the fid can recycle: a late
-  // stripe chunk for this cid must never memcpy into a buffer the caller
-  // has already reclaimed (stripe_unregister_landing drains in-flight
+  // one-sided control frame for this cid must reject (unbind first), and
+  // a late stripe chunk must never memcpy into a buffer the caller has
+  // already reclaimed (stripe_unregister_landing drains in-flight
   // landers).  Cheap no-op for the unregistered (non-batch) hot path.
+  if (cntl->call().land_bound) {
+    rma_landing_unbind(cid);
+    cntl->call().land_bound = false;
+  }
   if (cntl->call().land_registered) {
     stripe_unregister_landing(cid);
     cntl->call().land_registered = false;
@@ -712,20 +717,24 @@ void Channel::CallMethod(const std::string& method, const IOBuf& request,
   if (cntl->checksum_enabled()) {
     meta.has_checksum = true;  // striped sends CRC per chunk (stripe.cc)
   }
-  // Striped response landing (batch plane): register the caller's buffer
-  // under the cid BEFORE the request can reach the server, so even a
-  // chunk that beats the head frame lands in place.  Only worth it when
-  // the buffer could hold a striped (above-threshold) response.
-  if (cntl->call().land_buf != nullptr &&
-      stripe_eligible(cntl->call().land_cap)) {
-    stripe_register_landing(cid, cntl->call().land_buf,
-                            cntl->call().land_cap);
-    cntl->call().land_registered = true;
-    // One-sided advertisement (net/rma.h): when the landing buffer is
-    // itself an exportable rma region and this connection has an rma
-    // session, the request's meta names it — the server then PUTS the
-    // response straight into the caller's buffer.
-    rma_advertise_response(sid, cid, &meta);
+  // Response landing (batch plane), registered under the cid BEFORE the
+  // request can reach the server.  Striped: so even a chunk that beats
+  // the head frame lands in place; only worth it when the buffer could
+  // hold a striped (above-threshold) response.  One-sided (net/rma.h):
+  // when this connection has an rma session and the buffer lies in an
+  // exportable region, the request's meta names a descriptor and the
+  // range — the server then PUTS the response straight into the
+  // caller's buffer, from kRmaDirectFloor up.  A smaller buffer pays
+  // neither lookup.
+  void* land_buf = cntl->call().land_buf;
+  const size_t land_cap = cntl->call().land_cap;
+  if (land_buf != nullptr && rma_direct_eligible(land_cap)) {
+    if (stripe_eligible(land_cap)) {
+      stripe_register_landing(cid, land_buf, land_cap);
+      cntl->call().land_registered = true;
+    }
+    cntl->call().land_bound =
+        rma_bind_response(sid, cid, land_buf, land_cap, &meta);
   }
 
   bool write_ok;

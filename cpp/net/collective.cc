@@ -1762,12 +1762,18 @@ int GroupChannel::run(const TransferSchedule& plan, const void* sendbuf,
     }
     // Cancel the still-in-flight chunks, then drain them: the contexts
     // (and the caller's buffers) must not be touched by a late
-    // completion after run() returns.
+    // completion after run() returns.  The cancels go out with rs.mu
+    // released: a cancel can complete its call inline, and the done
+    // closure takes rs.mu to record the first failure.
+    std::vector<fid_t> in_flight;
     {
       std::lock_guard<std::mutex> g(rs.mu);
       for (auto& c : rs.ctxs) {
-        StartCancel(c->cntl.call_id());
+        in_flight.push_back(c->cntl.call_id());
       }
+    }
+    for (const fid_t cid : in_flight) {
+      StartCancel(cid);
     }
     while (rs.outstanding.load(std::memory_order_acquire) != 0) {
       const uint32_t v =

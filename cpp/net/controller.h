@@ -179,7 +179,8 @@ class Controller {
     // the response stripes back across the same connections.
     void* land_buf = nullptr;
     size_t land_cap = 0;
-    bool land_registered = false;
+    bool land_registered = false;  // stripe landing (net/stripe.h)
+    bool land_bound = false;       // one-sided bind (net/rma.h)
     // One-sided RMA (net/rma.h), server side: the request's advertised
     // response-landing region — when set (and the connection has an rma
     // session) the response is PUT straight into the caller's registered

@@ -110,6 +110,15 @@ HotPathVars::HotPathVars() {
       "rma_rejected",
       "rma control frames dropped whole (incomplete completion bitmap, "
       "bad bounds, or an unknown/unbound region)");
+  rma_direct_tx_bytes.expose(
+      "rma_direct_tx_bytes",
+      "of rma_tx_bytes, the response bytes put straight into the "
+      "caller's bound landing range (no window span, no landing copy)");
+  rma_direct_fallback.expose(
+      "rma_direct_fallback",
+      "landing binds refused because the range overlapped another live "
+      "bind or the region's descriptor table was full: the call landed "
+      "by the copy path");
 }
 
 HotPathVars& hotpath_vars() {

@@ -60,8 +60,8 @@ struct HotPathVars {
   Adder cut_budget_yields;
 
   // One-sided RMA plane (net/rma.h).  Like the stripe vars, every one
-  // of these stays EXACTLY zero on sub-threshold traffic — the proof
-  // that small RPCs never touch the rma layer.
+  // of these stays EXACTLY zero on sub-threshold traffic with no bound
+  // landing — the proof that small RPCs never touch the rma layer.
   Adder rma_tx_msgs;      // one-sided transfers sent (control frames)
   Adder rma_tx_chunks;    // chunks written into peer regions
   Adder rma_tx_bytes;     // payload bytes moved one-sided
@@ -69,6 +69,10 @@ struct HotPathVars {
   Adder rma_window_full;  // sends that fell back (no window span free)
   Adder rma_rejected;     // control frames dropped whole (incomplete
                           // bitmap, bad bounds, unknown region)
+  Adder rma_direct_tx_bytes;  // of rma_tx_bytes, put into a caller's
+                              // bound landing range
+  Adder rma_direct_fallback;  // binds refused (range overlaps a live
+                              // bind, or no free descriptor): copy path
 
   HotPathVars();
 };

@@ -103,15 +103,17 @@ struct RpcMeta {
   // or a kStreamFrame of kStreamData whose correlation_id names the
   // transfer, with rma_rkey != 0 and an EMPTY payload): the body landed
   // out-of-band — rma_len bytes written by the sender into the named
-  // registered region at rma_off of its data area (kRmaDirectOff = the
-  // region's own data start, completion bitmap in the region header),
-  // in rma_chunk-sized chunks whose release-fenced completion bits the
-  // receiver verifies before dispatch.  rma_resp_rkey/rma_resp_max on a
-  // REQUEST advertise the caller's registered landing region so the
-  // response can be put straight into the caller's buffer, rma_resp_off
-  // bytes into its data area (collective pulls land a shard mid-region;
-  // 0 = the region start, the batch-plane shape).  Sixth optional
-  // wire-tail group — all-zero (absent) on every non-rma frame.
+  // registered region at rma_off of its data area (kRmaDirectOff = at the
+  // caller's bound landing, completion bitmap in the bind's descriptor of
+  // the region header), in rma_chunk-sized chunks whose release-fenced
+  // completion bits the receiver verifies before dispatch.
+  // rma_resp_rkey/rma_resp_max on a REQUEST advertise the caller's
+  // registered landing region so the response can be put straight into
+  // the caller's buffer: rma_resp_off's low 48 bits are the offset into
+  // its data area (collective pulls land a shard mid-region; 0 = the
+  // region start), the bits above them the call's completion descriptor
+  // + 1 (net/rma.h rma_bind_response).  Sixth optional wire-tail group —
+  // all-zero (absent) on every non-rma frame.
   uint64_t rma_rkey = 0;
   uint64_t rma_off = 0;
   uint64_t rma_len = 0;

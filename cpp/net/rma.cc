@@ -9,6 +9,8 @@
 
 #include <algorithm>
 #include <functional>
+#include <iterator>
+#include <map>
 #include <memory>
 #include <unordered_map>
 #include <vector>
@@ -32,34 +34,49 @@ namespace trpc {
 namespace {
 
 constexpr uint64_t kRmaMagic = 0x545250524d413154ull;  // "TRPRMA1T"
-// Region layout: [RmaSegHdr, padded to kRmaDataOffset][data area].
-// Window spans reserve kRmaSpanHdr at their start for the transfer
-// header; direct (caller-buffer) transfers use the RmaSegHdr's embedded
-// header so the payload can land at data offset 0.
-constexpr uint32_t kRmaDataOffset = 8192;
+// Region layout: [RmaSegHdr][direct descriptor table, from kRmaDescOff]
+// [pad to data_off, a page multiple][data area].  Window spans reserve
+// kRmaSpanHdr at their start for the transfer header; a direct (caller-
+// buffer) transfer completes in one descriptor of its region's table so
+// its payload lands at the bound offset, and as many bound calls as the
+// table holds land in one region at once.
+constexpr uint32_t kRmaPage = 4096;
+constexpr uint32_t kRmaDescOff = 64;
 constexpr uint32_t kRmaSpanHdr = 8192;
 constexpr uint32_t kRmaMaxChunks = 1024;
-constexpr uint32_t kRmaBitWords = kRmaMaxChunks / 64;
+// A direct transfer is cut into at most 64 chunks (one bitmap word), so
+// its descriptor is 320 B where a window span's is 4.3 KB.
+constexpr uint32_t kRmaDirectChunks = 64;
+// Table size cap: a 64 MB region at the 64 KB floor (320 KB of header).
+constexpr uint32_t kRmaMaxDirectDescs = 1024;
 // Window slots fit ONE bitmap word: span allocation is a single CAS and
 // a span is always a contiguous run of ≤ 64 slots.
 constexpr uint32_t kRmaWindowSlots = 64;
 constexpr uint32_t kXferCrcPresent = 1u << 0;
+// rma_resp_off on the wire: the landing offset in its low 48 bits (a
+// region is at most 4 GB), the bind's descriptor + 1 above them.
+constexpr int kRmaDescShift = 48;
+constexpr uint64_t kRmaOffMask = (1ull << kRmaDescShift) - 1;
 
 // One transfer's completion state, shared memory.  The sender writes
 // the scalar fields before any chunk, sets chunk_bits with release as
 // each chunk's bytes land, and the receiver admits the payload only
 // when every bit reads set (acquire) — the control frame alone never
 // proves the bytes arrived (a faulted chunk leaves its bit clear).
-struct RmaXfer {
+// Cache-line aligned: neighbouring descriptors of one table belong to
+// calls whose rails write them at once.
+template <uint32_t kChunks>
+struct alignas(64) XferDesc {
+  static constexpr uint32_t kMaxChunks = kChunks;
   // Release/acquire: `total` is the sender's first store and doubles as
-  // the header-initialized marker for the direct path.
+  // the header-initialized marker.
   std::atomic<uint64_t> total;
   // The transfer's correlation id, stamped at init and matched at
   // resolve: a LATE put from a timed-out call that re-initializes a
-  // reused direct landing region after the live call's init makes the
-  // live resolve reject (clean whole-call failure) instead of admitting
-  // interleaved bytes.  (A stale writer racing mid-flight is inherent to
-  // shared memory — see the reuse contract in rma.h/RmaBuffer.)
+  // descriptor after the live call's init makes the live resolve reject
+  // (clean whole-call failure) instead of admitting interleaved bytes.
+  // (A stale writer racing mid-flight is inherent to shared memory —
+  // see the reuse contract in rma.h/RmaBuffer.)
   uint64_t token;
   uint32_t chunk_bytes;
   uint32_t nchunks;
@@ -67,10 +84,13 @@ struct RmaXfer {
   uint32_t pad;
   // Release per set bit (pairs with the receiver's acquire scan): a set
   // bit publishes that chunk's payload bytes.
-  std::atomic<uint64_t> chunk_bits[kRmaBitWords];
-  uint32_t chunk_crc[kRmaMaxChunks];
+  std::atomic<uint64_t> chunk_bits[(kChunks + 63) / 64];
+  uint32_t chunk_crc[kChunks];
 };
+using RmaXfer = XferDesc<kRmaMaxChunks>;           // a window span's
+using RmaDirectXfer = XferDesc<kRmaDirectChunks>;  // a bound landing's
 static_assert(sizeof(RmaXfer) <= kRmaSpanHdr, "span header overflow");
+static_assert(sizeof(RmaDirectXfer) == 320, "direct descriptor size");
 
 struct RmaSegHdr {
   uint64_t magic;
@@ -78,16 +98,28 @@ struct RmaSegHdr {
   uint32_t nslots;  // 0: plain region (no window allocator)
   uint64_t data_len;
   uint32_t slot_bytes;
-  uint32_t reserved;
+  uint32_t ndesc;  // direct descriptors at kRmaDescOff (0 for windows)
   // Window slot bitmap, shared: the PEER allocates spans (CAS set,
   // acquire — a freed slot's payload reads must not be reordered before
   // the claim), the owner frees them (fetch_and clear, release — the
   // consumer finished reading before the slot recycles).
   std::atomic<uint64_t> slot_map;
-  // Direct-to-region transfers (caller landing buffers) complete here.
-  RmaXfer direct;
 };
-static_assert(sizeof(RmaSegHdr) <= kRmaDataOffset, "region header overflow");
+static_assert(sizeof(RmaSegHdr) <= kRmaDescOff, "region header overflow");
+
+// Descriptors a caller region of data_len bytes gets: one per floor's
+// worth of data, which is the most disjoint binds at or above the floor
+// the region can hold at once (kRmaMaxDirectDescs past 128 MB).
+uint32_t direct_descs_for(uint64_t data_len) {
+  return static_cast<uint32_t>(std::clamp<uint64_t>(
+      data_len / kRmaDirectFloor, 1, kRmaMaxDirectDescs));
+}
+
+uint32_t data_off_for(uint32_t ndesc) {
+  const uint64_t hdr =
+      kRmaDescOff + static_cast<uint64_t>(ndesc) * sizeof(RmaDirectXfer);
+  return static_cast<uint32_t>((hdr + kRmaPage - 1) / kRmaPage * kRmaPage);
+}
 
 int64_t flag_value(Flag* f, int64_t dflt) {
   return f != nullptr ? f->int64_value() : dflt;
@@ -177,8 +209,10 @@ Flag* scavenge_flag() {
 // (slot_bytes=0 division, data_off past the mapping, ...).
 struct RmaGeom {
   uint64_t data_len = 0;
+  uint32_t data_off = 0;
   uint32_t slot_bytes = 0;
   uint32_t nslots = 0;  // 0: plain region
+  uint32_t ndesc = 0;   // direct descriptors (plain regions)
 };
 
 // Scavenger state for one receive window (owner side).  `admitted`
@@ -208,21 +242,58 @@ struct RegionRec {
   // landing memcpy.
   bool free_pending = false;
   RmaGeom geom;
+  // Owner-side record of the direct descriptor table (plain exportable
+  // regions): which cid holds each descriptor and the range it bound.
+  struct DescUse {
+    uint64_t cid = 0;  // 0: free
+    uint64_t off = 0;
+    uint64_t cap = 0;
+    // The offset this descriptor last bound: a range bound again takes
+    // its old descriptor back, so a late put of a timed-out call into
+    // that range re-initializes the descriptor the new call resolves
+    // against, and its token gate rejects the mix.
+    uint64_t last_off = UINT64_MAX;
+  };
+  std::vector<DescUse> descs;
+  uint32_t nbinds = 0;
 };
 
 struct LandingBind {
   uint64_t rkey = 0;
   uint64_t cap = 0;
   uint64_t off = 0;  // landing offset inside the region's data area
+  uint32_t desc = 0;  // the region's descriptor this call completes in
 };
 
 std::mutex& reg_mu() {
   static std::mutex* mu = new std::mutex();
   return *mu;
 }
-std::vector<RegionRec>& regions() {
-  static auto* v = new std::vector<RegionRec>();
-  return *v;
+// The registry, guarded by reg_mu: every region by rkey, and the plain
+// exportable ones (caller landings: not windows, not pins) by the start
+// of their data area too, so that either side of a transfer finds its
+// region by a hash probe or a tree walk (a prefix store holds thousands
+// of regions, and a direct landing looks one up three times a call).
+struct Registry {
+  std::unordered_map<uint64_t, RegionRec> by_rkey;
+  std::map<const char*, uint64_t> by_data;
+};
+Registry& registry() {
+  static auto* r = new Registry();
+  return *r;
+}
+
+const char* data_of(const RegionRec& r) {
+  return r.map->base + r.geom.data_off;
+}
+
+void erase_region_locked(
+    std::unordered_map<uint64_t, RegionRec>::iterator it) {
+  const RegionRec& r = it->second;
+  if (r.map != nullptr && !r.window) {
+    registry().by_data.erase(data_of(r));
+  }
+  registry().by_rkey.erase(it);  // mapping refcount defers the munmap
 }
 std::unordered_map<uint64_t, LandingBind>& landing_binds() {
   static auto* m = new std::unordered_map<uint64_t, LandingBind>();
@@ -246,6 +317,11 @@ RmaSegHdr* hdr_of(const std::shared_ptr<RmaMapping>& m) {
   return reinterpret_cast<RmaSegHdr*>(m->base);
 }
 
+// Descriptor i of a plain region's table (i < the trusted geom.ndesc).
+RmaDirectXfer* desc_of(const std::shared_ptr<RmaMapping>& m, uint32_t i) {
+  return reinterpret_cast<RmaDirectXfer*>(m->base + kRmaDescOff) + i;
+}
+
 // Creates + registers one exportable region.  window: initialize the
 // slot allocator over the data area.
 void* region_create(size_t data_len, bool window, uint64_t* rkey_out) {
@@ -256,7 +332,9 @@ void* region_create(size_t data_len, bool window, uint64_t* rkey_out) {
   const uint32_t ord =
       g_next_ordinal.fetch_add(1, std::memory_order_relaxed);
   const std::string name = rma_shm_name(getpid(), ord);
-  const size_t bytes = kRmaDataOffset + data_len;
+  const uint32_t ndesc = window ? 0 : direct_descs_for(data_len);
+  const uint32_t data_off = data_off_for(ndesc);
+  const size_t bytes = data_off + data_len;
   int fd = shm_open(name.c_str(), O_CREAT | O_EXCL | O_RDWR, 0600);
   if (fd < 0 && errno == EEXIST) {
     // The name holds this pid and an ordinal minted once in this process:
@@ -281,10 +359,12 @@ void* region_create(size_t data_len, bool window, uint64_t* rkey_out) {
     shm_unlink(name.c_str());
     return nullptr;
   }
+  // A fresh shm object reads zero: the descriptor table starts free.
   auto* h = static_cast<RmaSegHdr*>(mem);
   memset(static_cast<void*>(h), 0, sizeof(RmaSegHdr));
-  h->data_off = kRmaDataOffset;
+  h->data_off = data_off;
   h->data_len = data_len;
+  h->ndesc = ndesc;
   if (window) {
     h->nslots = kRmaWindowSlots;
     h->slot_bytes = static_cast<uint32_t>(data_len / kRmaWindowSlots);
@@ -306,16 +386,22 @@ void* region_create(size_t data_len, bool window, uint64_t* rkey_out) {
     rec.scav = std::make_shared<WindowScav>();
   }
   rec.geom.data_len = data_len;
+  rec.geom.data_off = data_off;
   rec.geom.slot_bytes = h->slot_bytes;
   rec.geom.nslots = h->nslots;
+  rec.geom.ndesc = ndesc;
+  rec.descs.resize(ndesc);
   {
     std::lock_guard<std::mutex> g(reg_mu());
-    regions().push_back(std::move(rec));
+    if (!window) {
+      registry().by_data[data_of(rec)] = rec.rkey;
+    }
+    registry().by_rkey[rec.rkey] = std::move(rec);
   }
   if (rkey_out != nullptr) {
     *rkey_out = make_rkey(ord);
   }
-  return static_cast<char*>(mem) + kRmaDataOffset;
+  return static_cast<char*>(mem) + data_off;
 }
 
 // Local-registry lookup (receiver side; loopback peer resolution) with
@@ -324,21 +410,21 @@ std::shared_ptr<RmaMapping> local_region(
     uint64_t rkey, bool* window, RmaGeom* geom,
     std::shared_ptr<WindowScav>* scav = nullptr) {
   std::lock_guard<std::mutex> g(reg_mu());
-  for (const RegionRec& r : regions()) {
-    if (r.rkey == rkey && r.map != nullptr) {
-      if (window != nullptr) {
-        *window = r.window;
-      }
-      if (geom != nullptr) {
-        *geom = r.geom;
-      }
-      if (scav != nullptr) {
-        *scav = r.scav;
-      }
-      return r.map;
-    }
+  auto it = registry().by_rkey.find(rkey);
+  if (it == registry().by_rkey.end() || it->second.map == nullptr) {
+    return nullptr;
   }
-  return nullptr;
+  const RegionRec& r = it->second;
+  if (window != nullptr) {
+    *window = r.window;
+  }
+  if (geom != nullptr) {
+    *geom = r.geom;
+  }
+  if (scav != nullptr) {
+    *scav = r.scav;
+  }
+  return r.map;
 }
 
 // Slot-run mask of a span [off, off+need) under geometry g.
@@ -427,8 +513,7 @@ std::shared_ptr<RmaMapping> map_peer_region(uint64_t rkey, RmaGeom* geom) {
     return nullptr;
   }
   struct stat st;
-  if (fstat(fd, &st) != 0 ||
-      st.st_size < static_cast<off_t>(kRmaDataOffset)) {
+  if (fstat(fd, &st) != 0 || st.st_size < static_cast<off_t>(kRmaPage)) {
     close(fd);
     return nullptr;
   }
@@ -447,14 +532,18 @@ std::shared_ptr<RmaMapping> map_peer_region(uint64_t rkey, RmaGeom* geom) {
   // live header may be scribbled by its owner afterwards.
   RmaGeom snap;
   snap.data_len = h->data_len;
+  snap.data_off = h->data_off;
   snap.slot_bytes = h->slot_bytes;
   snap.nslots = h->nslots;
-  if (h->magic != kRmaMagic || h->data_off != kRmaDataOffset ||
-      snap.data_len == 0 || snap.data_len > m->len - kRmaDataOffset) {
+  snap.ndesc = h->ndesc;
+  if (h->magic != kRmaMagic || snap.ndesc > kRmaMaxDirectDescs ||
+      snap.data_off != data_off_for(snap.ndesc) || snap.data_off > m->len ||
+      snap.data_len == 0 || snap.data_len > m->len - snap.data_off) {
     return nullptr;  // mapping dtor unmaps
   }
   if (snap.nslots != 0 &&
       (snap.nslots != kRmaWindowSlots || snap.slot_bytes < kRmaSpanHdr ||
+       snap.ndesc != 0 ||
        static_cast<uint64_t>(snap.slot_bytes) * snap.nslots >
            snap.data_len)) {
     return nullptr;
@@ -536,16 +625,17 @@ void span_free(RmaSegHdr* h, const RmaGeom& g, uint64_t off,
 // ---- send path -----------------------------------------------------------
 
 // Effective chunk size: the configured stripe chunk, grown until the
-// count fits the bitmap.
-uint64_t effective_chunk(uint64_t total) {
+// count fits a descriptor's bitmap of max_chunks.
+uint64_t effective_chunk(uint64_t total, uint32_t max_chunks = kRmaMaxChunks) {
   uint64_t chunk = std::max<uint64_t>(64 << 10, stripe_chunk_bytes());
-  while ((total + chunk - 1) / chunk > kRmaMaxChunks) {
+  while ((total + chunk - 1) / chunk > max_chunks) {
     chunk *= 2;
   }
   return chunk;
 }
 
-void xfer_init(RmaXfer* x, uint64_t total, uint64_t chunk, bool crc,
+template <typename X>
+void xfer_init(X* x, uint64_t total, uint64_t chunk, bool crc,
                uint64_t token) {
   const uint32_t nchunks =
       static_cast<uint32_t>((total + chunk - 1) / chunk);
@@ -553,19 +643,20 @@ void xfer_init(RmaXfer* x, uint64_t total, uint64_t chunk, bool crc,
   x->chunk_bytes = static_cast<uint32_t>(chunk);
   x->nchunks = nchunks;
   x->flags = crc ? kXferCrcPresent : 0;
-  for (uint32_t i = 0; i < kRmaBitWords; ++i) {
+  for (auto& word : x->chunk_bits) {
     // Relaxed: bits are re-published per chunk with release below; the
     // zeroing itself is ordered by the `total` release store that marks
     // the header live.
-    x->chunk_bits[i].store(0, std::memory_order_relaxed);
+    word.store(0, std::memory_order_relaxed);
   }
   // Release: publishes the scalar header fields (and the cleared bitmap)
   // before any chunk bit can be observed set.
   x->total.store(total, std::memory_order_release);
 }
 
+template <typename X>
 struct RailJob {
-  RmaXfer* x = nullptr;
+  X* x = nullptr;
   char* dst_base = nullptr;  // payload base in the peer region
   IOBuf data;                // this rail's contiguous byte range
   uint32_t first_chunk = 0;
@@ -587,7 +678,8 @@ struct RailJob {
 // release-fenced bit per chunk.  Fault points compose with the global
 // transport actor (kTx): drop skips write+bit, trunc writes a prefix and
 // skips the bit (whole-call failure either way), delay parks first.
-void rail_run(RailJob* j) {
+template <typename X>
+void rail_run(RailJob<X>* j) {
   FaultActor& fa = FaultActor::global();
   const bool tl = timeline::enabled();
   uint32_t ci = j->first_chunk;
@@ -732,14 +824,15 @@ void run_rails(uint32_t rails, const std::function<void(uint32_t)>& run) {
 // every rail finished.  payload_dst points at the transfer's payload
 // base in the peer region.
 // Returns the bytes SKIPPED by a mid-transfer cancel (0 = fully put).
-uint64_t put_body(RmaXfer* x, char* payload_dst, IOBuf&& body,
-                  uint64_t chunk, int rails, uint64_t cid, bool crc,
-                  const EndPoint& peer, const DeadlineToken& tok) {
+template <typename X>
+uint64_t put_body(X* x, char* payload_dst, IOBuf&& body, uint64_t chunk,
+                  int rails, uint64_t cid, bool crc, const EndPoint& peer,
+                  const DeadlineToken& tok) {
   const RailCut cut = cut_rails(body.size(), chunk, rails);
   std::atomic<uint64_t> aborted_bytes{0};
-  std::vector<RailJob> jobs(cut.rails);
+  std::vector<RailJob<X>> jobs(cut.rails);
   for (uint32_t i = 0; i < cut.rails; ++i) {
-    RailJob& j = jobs[i];
+    RailJob<X>& j = jobs[i];
     j.x = x;
     j.dst_base = payload_dst;
     j.first_chunk = i * cut.per;
@@ -790,11 +883,13 @@ std::shared_ptr<RmaMapping> resolve_peer_window(RmaSession* rs,
     rs->peer_map = std::move(m);
     rs->peer_rkey = prk;
     rs->peer_data_len = snap.data_len;
+    rs->peer_data_off = snap.data_off;
     rs->peer_slot_bytes = snap.slot_bytes;
     rs->peer_nslots = snap.nslots;
   }
   *rkey_out = rs->peer_rkey;
   geom_out->data_len = rs->peer_data_len;
+  geom_out->data_off = rs->peer_data_off;
   geom_out->slot_bytes = rs->peer_slot_bytes;
   geom_out->nslots = rs->peer_nslots;
   return rs->peer_map;
@@ -862,7 +957,8 @@ void direct_deleter(void*, void* vctx) {
 // Verifies a transfer header + bitmap + optional CRCs against the data
 // area.  All header fields are copied locally FIRST: the header lives in
 // shared memory and a hostile peer can mutate it between check and use.
-bool xfer_verify(const RmaXfer* x, uint64_t want_token, const char* payload,
+template <typename X>
+bool xfer_verify(const X* x, uint64_t want_token, const char* payload,
                  uint64_t want_len, uint64_t avail) {
   // Acquire: pairs with the sender's header-publishing release store.
   const uint64_t total = x->total.load(std::memory_order_acquire);
@@ -872,7 +968,7 @@ bool xfer_verify(const RmaXfer* x, uint64_t want_token, const char* payload,
   const uint32_t flags = x->flags;
   if (token != want_token || total == 0 || total != want_len ||
       total > avail || chunk < 1024 ||
-      nchunks == 0 || nchunks > kRmaMaxChunks ||
+      nchunks == 0 || nchunks > X::kMaxChunks ||
       static_cast<uint64_t>(nchunks - 1) * chunk >= total ||
       static_cast<uint64_t>(nchunks) * chunk < total) {
     return false;
@@ -912,15 +1008,12 @@ RmaSession::~RmaSession() {
     // Release the window region: unlink + drop the registry ref; the
     // munmap defers past any still-wrapped payload.
     std::lock_guard<std::mutex> g(reg_mu());
-    auto& v = regions();
-    for (auto it = v.begin(); it != v.end(); ++it) {
-      if (it->rkey == local_rkey) {
-        if (!it->name.empty()) {
-          shm_unlink(it->name.c_str());
-        }
-        v.erase(it);
-        break;
+    auto it = registry().by_rkey.find(local_rkey);
+    if (it != registry().by_rkey.end()) {
+      if (!it->second.name.empty()) {
+        shm_unlink(it->second.name.c_str());
       }
+      erase_region_locked(it);
     }
   }
 }
@@ -948,27 +1041,25 @@ void rma_free(void* data) {
   if (data == nullptr) {
     return;
   }
-  const char* base = static_cast<const char*>(data) - kRmaDataOffset;
   std::lock_guard<std::mutex> g(reg_mu());
-  auto& v = regions();
-  for (auto it = v.begin(); it != v.end(); ++it) {
-    if (it->map != nullptr && it->map->base == base) {
-      if (!it->name.empty()) {
-        shm_unlink(it->name.c_str());  // no NEW peer maps either way
-      }
-      for (const auto& [cid, bind] : landing_binds()) {
-        if (bind.rkey == it->rkey) {
-          // An in-flight call still lands here (possibly via the striped
-          // copy path, which holds the raw pointer): defer the erase —
-          // and with it the munmap — to the last unbind.
-          it->free_pending = true;
-          return;
-        }
-      }
-      v.erase(it);  // mapping refcount defers the munmap
-      return;
-    }
+  Registry& reg = registry();
+  auto at = reg.by_data.find(static_cast<const char*>(data));
+  if (at == reg.by_data.end()) {
+    return;
   }
+  auto it = reg.by_rkey.find(at->second);
+  RegionRec& r = it->second;
+  if (!r.name.empty()) {
+    shm_unlink(r.name.c_str());  // no NEW peer maps either way
+  }
+  if (r.nbinds != 0) {
+    // An in-flight call still lands here (possibly via the striped copy
+    // path, which holds the raw pointer): defer the erase — and with it
+    // the munmap — to the last unbind.
+    r.free_pending = true;
+    return;
+  }
+  erase_region_locked(it);
 }
 
 uint64_t rma_reg(const void* buf, size_t len) {
@@ -984,20 +1075,18 @@ uint64_t rma_reg(const void* buf, size_t len) {
   rec.pin_base = static_cast<const char*>(buf);
   rec.pin_len = len;
   std::lock_guard<std::mutex> g(reg_mu());
-  regions().push_back(std::move(rec));
+  registry().by_rkey[rkey] = std::move(rec);
   return rkey;
 }
 
 int rma_unreg(uint64_t rkey) {
   std::lock_guard<std::mutex> g(reg_mu());
-  auto& v = regions();
-  for (auto it = v.begin(); it != v.end(); ++it) {
-    if (it->rkey == rkey && it->map == nullptr) {
-      v.erase(it);
-      return 0;
-    }
+  auto it = registry().by_rkey.find(rkey);
+  if (it == registry().by_rkey.end() || it->second.map != nullptr) {
+    return -1;
   }
-  return -1;
+  registry().by_rkey.erase(it);
+  return 0;
 }
 
 bool rma_exportable(const void* buf, size_t len, uint64_t* rkey,
@@ -1007,7 +1096,7 @@ bool rma_exportable(const void* buf, size_t len, uint64_t* rkey,
 
 size_t rma_region_count() {
   std::lock_guard<std::mutex> g(reg_mu());
-  return regions().size();
+  return registry().by_rkey.size();
 }
 
 namespace {
@@ -1036,7 +1125,7 @@ size_t rma_scavenge(int64_t now_us) {
   const int64_t age_us = flag_value(scavenge_flag(), 10000) * 1000;
   size_t reclaimed = 0;
   std::lock_guard<std::mutex> g(reg_mu());
-  for (RegionRec& r : regions()) {
+  for (auto& [rkey, r] : registry().by_rkey) {
     if (!r.window || r.map == nullptr || r.scav == nullptr) {
       continue;
     }
@@ -1080,7 +1169,7 @@ size_t rma_spans_in_use() {
   rma_scavenge();
   std::lock_guard<std::mutex> g(reg_mu());
   size_t n = 0;
-  for (const RegionRec& r : regions()) {
+  for (const auto& [rkey, r] : registry().by_rkey) {
     if (!r.window || r.map == nullptr) {
       continue;
     }
@@ -1092,75 +1181,127 @@ size_t rma_spans_in_use() {
   return n;
 }
 
-// The one authoritative exportable-region scan: rma_exportable is a
-// thin boolean wrapper over it.
-std::shared_ptr<RmaMapping> rma_pin_exportable(const void* buf, size_t len,
-                                               uint64_t* rkey,
-                                               uint64_t* off) {
+namespace {
+
+// The one authoritative exportable-region scan (reg_mu held): the plain
+// region whose data area holds [buf, buf+len), its offset in *off.
+RegionRec* exportable_locked(const void* buf, size_t len, uint64_t* off) {
+  // Windows are connection-owned, not caller landings, and pins are not
+  // exportable: neither is indexed by address.  Mappings never overlap,
+  // so the one region starting at or below buf is the only candidate.
   const char* p = static_cast<const char*>(buf);
-  std::lock_guard<std::mutex> g(reg_mu());
-  for (const RegionRec& r : regions()) {
-    if (r.map == nullptr || r.window || r.free_pending) {
-      continue;  // windows are connection-owned, not caller landings;
-                 // free_pending regions accept no NEW registrations
-    }
-    const char* data = r.map->base + kRmaDataOffset;
-    if (p >= data && len <= r.geom.data_len &&
-        p + len <= data + r.geom.data_len) {
-      if (rkey != nullptr) {
-        *rkey = r.rkey;
-      }
-      if (off != nullptr) {
-        *off = static_cast<uint64_t>(p - data);
-      }
-      return r.map;
-    }
+  Registry& reg = registry();
+  auto at = reg.by_data.upper_bound(p);
+  if (at == reg.by_data.begin()) {
+    return nullptr;
   }
-  return nullptr;
+  RegionRec& r = reg.by_rkey.at(std::prev(at)->second);
+  const char* data = std::prev(at)->first;
+  if (r.free_pending || len > r.geom.data_len ||
+      p + len > data + r.geom.data_len) {
+    return nullptr;  // free_pending regions accept no NEW registrations
+  }
+  *off = static_cast<uint64_t>(p - data);
+  return &r;
 }
 
-void rma_landing_bind(uint64_t cid, void* buf, size_t cap) {
-  uint64_t rkey = 0;
-  uint64_t off = 0;
-  if (!rma_exportable(buf, cap, &rkey, &off)) {
-    return;  // copy-path landing only (arbitrary caller memory)
-  }
-  std::lock_guard<std::mutex> g(reg_mu());
-  for (const auto& [other_cid, bind] : landing_binds()) {
-    if (bind.rkey == rkey && other_cid != cid) {
-      // One direct transfer per region at a time: the region header
-      // holds a single completion descriptor.  This call still lands
-      // via the striped copy path — correct, just not zero-copy.
-      return;
-    }
-  }
-  landing_binds()[cid] = LandingBind{rkey, cap, off};
-}
-
-void rma_landing_unbind(uint64_t cid) {
-  std::lock_guard<std::mutex> g(reg_mu());
+// Drops cid's bind and frees its descriptor (reg_mu held); the last
+// bind of a region rma_free already released completes that free.
+void unbind_locked(uint64_t cid) {
   auto it = landing_binds().find(cid);
   if (it == landing_binds().end()) {
     return;
   }
-  const uint64_t rkey = it->second.rkey;
+  const LandingBind b = it->second;
   landing_binds().erase(it);
-  for (const auto& [other_cid, bind] : landing_binds()) {
-    if (bind.rkey == rkey) {
-      return;  // another in-flight call still lands in the region
-    }
+  auto rit = registry().by_rkey.find(b.rkey);
+  if (rit == registry().by_rkey.end()) {
+    return;
   }
-  auto& v = regions();
-  for (auto rit = v.begin(); rit != v.end(); ++rit) {
-    if (rit->rkey == rkey && rit->free_pending) {
-      v.erase(rit);  // the deferred rma_free completes here
-      return;
-    }
+  RegionRec& r = rit->second;
+  r.descs[b.desc].cid = 0;
+  r.nbinds -= 1;
+  if (r.nbinds == 0 && r.free_pending) {
+    erase_region_locked(rit);  // the deferred rma_free completes here
   }
 }
 
+// Binds cid to a free descriptor of the region holding [buf, buf+cap)
+// (reg_mu held).  False when the buffer is in no exportable region, or
+// when the range overlaps another live bind or the table is full: the
+// call then lands by the copy path, and the last two are counted.
+bool bind_locked(uint64_t cid, void* buf, size_t cap, LandingBind* out) {
+  uint64_t off = 0;
+  RegionRec* r = exportable_locked(buf, cap, &off);
+  if (r == nullptr) {
+    return false;  // copy-path landing only (arbitrary caller memory)
+  }
+  unbind_locked(cid);  // a cid holds one bind
+  int pick = -1;
+  bool same_range = false;
+  for (size_t i = 0; i < r->descs.size(); ++i) {
+    const RegionRec::DescUse& d = r->descs[i];
+    if (d.cid != 0) {
+      if (off < d.off + d.cap && d.off < off + cap) {
+        // Two puts into one byte range cannot both be admitted whole.
+        hotpath_vars().rma_direct_fallback << 1;
+        return false;
+      }
+    } else if (!same_range && d.last_off == off) {
+      pick = static_cast<int>(i);
+      same_range = true;
+    } else if (pick < 0) {
+      pick = static_cast<int>(i);
+    }
+  }
+  if (pick < 0) {
+    hotpath_vars().rma_direct_fallback << 1;
+    return false;
+  }
+  r->descs[pick] = RegionRec::DescUse{cid, off, cap, off};
+  r->nbinds += 1;
+  *out = LandingBind{r->rkey, cap, off, static_cast<uint32_t>(pick)};
+  landing_binds()[cid] = *out;
+  return true;
+}
+
+}  // namespace
+
+std::shared_ptr<RmaMapping> rma_pin_exportable(const void* buf, size_t len,
+                                               uint64_t* rkey,
+                                               uint64_t* off) {
+  std::lock_guard<std::mutex> g(reg_mu());
+  uint64_t at = 0;
+  const RegionRec* r = exportable_locked(buf, len, &at);
+  if (r == nullptr) {
+    return nullptr;
+  }
+  if (rkey != nullptr) {
+    *rkey = r->rkey;
+  }
+  if (off != nullptr) {
+    *off = at;
+  }
+  return r->map;
+}
+
+bool rma_direct_eligible(uint64_t n) {
+  return n >= kRmaDirectFloor || stripe_eligible(n);
+}
+
+bool rma_landing_bind(uint64_t cid, void* buf, size_t cap) {
+  std::lock_guard<std::mutex> g(reg_mu());
+  LandingBind b;
+  return bind_locked(cid, buf, cap, &b);
+}
+
+void rma_landing_unbind(uint64_t cid) {
+  std::lock_guard<std::mutex> g(reg_mu());
+  unbind_locked(cid);
+}
+
 uint64_t rma_landing_rkey(uint64_t cid, uint64_t* max_out,
-                          uint64_t* off_out) {
+                          uint64_t* off_out, uint32_t* desc_out) {
   std::lock_guard<std::mutex> g(reg_mu());
   auto it = landing_binds().find(cid);
   if (it == landing_binds().end()) {
@@ -1172,7 +1313,31 @@ uint64_t rma_landing_rkey(uint64_t cid, uint64_t* max_out,
   if (off_out != nullptr) {
     *off_out = it->second.off;
   }
+  if (desc_out != nullptr) {
+    *desc_out = it->second.desc;
+  }
   return it->second.rkey;
+}
+
+bool rma_bind_response(SocketId sid, uint64_t cid, void* buf, size_t cap,
+                       RpcMeta* meta) {
+  SocketRef s(Socket::Address(sid));
+  if (!s || s->transport() == nullptr ||
+      s->transport()->rma(s.get()) == nullptr) {
+    return false;  // no one-sided plane on this connection
+  }
+  LandingBind b;
+  {
+    std::lock_guard<std::mutex> g(reg_mu());
+    if (!bind_locked(cid, buf, cap, &b)) {
+      return false;
+    }
+  }
+  meta->rma_resp_rkey = b.rkey;
+  meta->rma_resp_max = b.cap;
+  meta->rma_resp_off =
+      b.off | (static_cast<uint64_t>(b.desc) + 1) << kRmaDescShift;
+  return true;
 }
 
 int rma_rails_for(int socket_mode) {
@@ -1205,23 +1370,6 @@ uint32_t rma_land(const IOBuf& resp, void* dst, size_t n) {
   return 1;
 }
 
-void rma_advertise_response(SocketId sid, uint64_t cid, RpcMeta* meta) {
-  uint64_t max = 0;
-  uint64_t off = 0;
-  const uint64_t rkey = rma_landing_rkey(cid, &max, &off);
-  if (rkey == 0) {
-    return;
-  }
-  SocketRef s(Socket::Address(sid));
-  if (!s || s->transport() == nullptr ||
-      s->transport()->rma(s.get()) == nullptr) {
-    return;  // no one-sided plane on this connection
-  }
-  meta->rma_resp_rkey = rkey;
-  meta->rma_resp_max = max;
-  meta->rma_resp_off = off;
-}
-
 int rma_try_send(SocketId primary, RpcMeta* meta, IOBuf* body,
                  uint64_t target_rkey, uint64_t target_max,
                  uint64_t target_off, const DeadlineToken& tok) {
@@ -1233,7 +1381,12 @@ int rma_try_send(SocketId primary, RpcMeta* meta, IOBuf* body,
   // stream stays in band.
   const bool stream_data = meta->type == RpcMeta::kStreamFrame &&
                            meta->stream_flags == RpcMeta::kStreamData;
-  if ((meta->stream_id != 0 && !stream_data) || !stripe_eligible(total)) {
+  // A response whose caller bound a landing goes direct from the floor
+  // on; anything else one-sided only over the stripe threshold.
+  const bool to_landing = target_rkey != 0 && total <= target_max &&
+                          rma_direct_eligible(total);
+  if ((meta->stream_id != 0 && !stream_data) ||
+      (!to_landing && !stripe_eligible(total))) {
     return 1;
   }
   SocketRef s(Socket::Address(primary));
@@ -1248,57 +1401,62 @@ int rma_try_send(SocketId primary, RpcMeta* meta, IOBuf* body,
       ici_payload_prefers_descriptors(*body)) {
     return 1;  // staging-backed bodies ride sender-owned descriptors
   }
-  const uint64_t chunk = effective_chunk(total);
   const bool crc = meta->has_checksum;
   const int rails = rma_rails_for(static_cast<int>(s->mode()));
   const uint64_t cid = meta->correlation_id;
   const EndPoint peer = s->remote();
 
-  // Direct-to-region: the peer advertised a registered caller buffer for
-  // this payload (response landing) — write at data offset 0, completion
-  // bitmap in the region header.
-  if (target_rkey != 0 && total <= target_max) {
+  // Direct-to-region: the peer bound a registered caller buffer for this
+  // payload (response landing) — write at the bound offset, completion
+  // in the descriptor the bind holds in the region's table.
+  if (to_landing) {
+    const uint64_t desc = target_off >> kRmaDescShift;
+    const uint64_t land_off = target_off & kRmaOffMask;
     RmaGeom tg;
     std::shared_ptr<RmaMapping> m = map_peer_region(target_rkey, &tg);
-    if (m != nullptr) {
-      RmaSegHdr* h = hdr_of(m);
-      if (tg.nslots == 0 && target_off <= tg.data_len &&
-          total <= tg.data_len - target_off) {
-        if (timeline::enabled()) {
-          timeline::record(timeline::kStripeCut, cid, total);
-        }
-        xfer_init(&h->direct, total, chunk, crc, cid);
-        const uint32_t nchunks =
-            static_cast<uint32_t>((total + chunk - 1) / chunk);
-        const uint64_t skipped =
-            put_body(&h->direct, m->base + kRmaDataOffset + target_off,
-                     std::move(*body), chunk, rails, cid, crc, peer, tok);
-        if (skipped != 0) {
-          // Cancelled mid-transfer: no control frame — the receiver
-          // never admits the partial put; the caller's fid is already
-          // dying (the cancel reached it first).
-          deadline_vars().cancel_saved_bytes
-              << static_cast<int64_t>(skipped);
-          return -1;
-        }
-        meta->rma_rkey = target_rkey;
-        meta->rma_off = kRmaDirectOff;
-        meta->rma_len = total;
-        meta->rma_chunk = chunk;
-        // The control frame's payload is empty, so a checksummed call's
-        // frame carries crc32c("") == 0 — has_checksum stays SET (the
-        // server derives response-checksum intent from it; the real
-        // integrity rides the per-chunk CRCs in the transfer header).
-        meta->checksum = 0;
-        hotpath_vars().rma_tx_msgs << 1;
-        hotpath_vars().rma_tx_chunks << nchunks;
-        hotpath_vars().rma_tx_bytes << static_cast<int64_t>(total);
-        return send_control(primary, std::move(*meta)) == 0 ? 0 : -1;
+    if (m != nullptr && tg.nslots == 0 && desc >= 1 && desc <= tg.ndesc &&
+        land_off <= tg.data_len && total <= tg.data_len - land_off) {
+      RmaDirectXfer* x = desc_of(m, static_cast<uint32_t>(desc - 1));
+      const uint64_t chunk = effective_chunk(total, kRmaDirectChunks);
+      if (timeline::enabled()) {
+        timeline::record(timeline::kStripeCut, cid, total);
       }
+      xfer_init(x, total, chunk, crc, cid);
+      const uint32_t nchunks =
+          static_cast<uint32_t>((total + chunk - 1) / chunk);
+      const uint64_t skipped =
+          put_body(x, m->base + tg.data_off + land_off, std::move(*body),
+                   chunk, rails, cid, crc, peer, tok);
+      if (skipped != 0) {
+        // Cancelled mid-transfer: no control frame — the receiver
+        // never admits the partial put; the caller's fid is already
+        // dying (the cancel reached it first).
+        deadline_vars().cancel_saved_bytes << static_cast<int64_t>(skipped);
+        return -1;
+      }
+      meta->rma_rkey = target_rkey;
+      meta->rma_off = kRmaDirectOff;
+      meta->rma_len = total;
+      meta->rma_chunk = chunk;
+      // The control frame's payload is empty, so a checksummed call's
+      // frame carries crc32c("") == 0 — has_checksum stays SET (the
+      // server derives response-checksum intent from it; the real
+      // integrity rides the per-chunk CRCs in the transfer header).
+      meta->checksum = 0;
+      hotpath_vars().rma_tx_msgs << 1;
+      hotpath_vars().rma_tx_chunks << nchunks;
+      hotpath_vars().rma_tx_bytes << static_cast<int64_t>(total);
+      hotpath_vars().rma_direct_tx_bytes << static_cast<int64_t>(total);
+      return send_control(primary, std::move(*meta)) == 0 ? 0 : -1;
     }
-    // Advertised region unusable: fall through to the window path.
+    // Advertised region unusable: over the threshold the window path
+    // carries it, under it the frame.
+    if (!stripe_eligible(total)) {
+      return 1;
+    }
   }
 
+  const uint64_t chunk = effective_chunk(total);
   uint64_t peer_rkey = 0;
   RmaGeom wg;
   std::shared_ptr<RmaMapping> m = resolve_peer_window(rs, &peer_rkey, &wg);
@@ -1312,7 +1470,7 @@ int rma_try_send(SocketId primary, RpcMeta* meta, IOBuf* body,
     hotpath_vars().rma_window_full << 1;
     return 1;  // window full: copy path carries this one
   }
-  auto* x = reinterpret_cast<RmaXfer*>(m->base + kRmaDataOffset + off);
+  auto* x = reinterpret_cast<RmaXfer*>(m->base + wg.data_off + off);
   if (timeline::enabled()) {
     timeline::record(timeline::kStripeCut, cid, total);
   }
@@ -1384,7 +1542,8 @@ bool rma_resolve(InputMessage* msg, Socket* sock) {
     }
     uint64_t cap = 0;
     uint64_t land_off = 0;
-    if (rma_landing_rkey(m.correlation_id, &cap, &land_off) != rkey ||
+    uint32_t desc = 0;
+    if (rma_landing_rkey(m.correlation_id, &cap, &land_off, &desc) != rkey ||
         total > cap) {
       return reject("not the advertised landing");
     }
@@ -1394,15 +1553,15 @@ bool rma_resolve(InputMessage* msg, Socket* sock) {
     if (map == nullptr || window) {
       return reject("unknown region");
     }
-    RmaSegHdr* h = hdr_of(map);
-    // The landing offset comes from the LOCAL bind (what this process
-    // registered), never the frame — a control frame cannot steer the
-    // payload pointer anywhere the caller didn't bind.
-    if (land_off > geom.data_len || total > geom.data_len - land_off) {
+    // The landing offset and the descriptor come from the LOCAL bind
+    // (what this process registered), never the frame — a control frame
+    // cannot steer the payload pointer anywhere the caller didn't bind.
+    if (desc >= geom.ndesc || land_off > geom.data_len ||
+        total > geom.data_len - land_off) {
       return reject("landing out of bounds");
     }
-    char* payload = map->base + kRmaDataOffset + land_off;
-    if (!xfer_verify(&h->direct, m.correlation_id, payload, total,
+    char* payload = map->base + geom.data_off + land_off;
+    if (!xfer_verify(desc_of(map, desc), m.correlation_id, payload, total,
                      geom.data_len - land_off)) {
       return reject("incomplete or corrupt transfer");
     }
@@ -1441,7 +1600,7 @@ bool rma_resolve(InputMessage* msg, Socket* sock) {
         slot_mask) {
       return reject("span was scavenged");
     }
-    auto* x = reinterpret_cast<RmaXfer*>(map->base + kRmaDataOffset +
+    auto* x = reinterpret_cast<RmaXfer*>(map->base + geom.data_off +
                                          m.rma_off);
     char* payload = reinterpret_cast<char*>(x) + kRmaSpanHdr;
     // Token gate on RECLAMATION: only a frame whose correlation id owns

@@ -38,10 +38,13 @@
 //    chunks leave their bit clear and fail the CALL whole-or-nothing.
 //  - The batch plane's registered resp_bufs become genuine remote-write
 //    targets: when a caller's landing buffer lives in an rma_alloc'd
-//    region, the REQUEST advertises {rkey, cap} (meta tail-group 6) and
-//    the server puts the response straight into the caller's buffer
-//    (control offset kRmaDirectOff; completion bitmap in the region
-//    header), with zero receiver-side copies.
+//    region and is at least kRmaDirectFloor bytes, the REQUEST advertises
+//    {rkey, cap, offset, descriptor} (meta tail-group 6) and the server
+//    puts the response straight into the caller's buffer (control offset
+//    kRmaDirectOff; completion bitmap in the descriptor the call holds in
+//    the region header's table), with zero receiver-side copies.  Every
+//    in-flight call holds its own descriptor, so a window of calls into
+//    disjoint ranges of one region all land in place.
 #pragma once
 
 #include <atomic>
@@ -58,11 +61,21 @@ namespace trpc {
 
 class Socket;
 
-// Control-frame rma_off value meaning "the payload landed at offset 0 of
-// the named region's data area, completion bitmap in the REGION header"
-// (the direct-to-caller-buffer path).  Window spans use their byte
-// offset inside the window's data area instead.
+// Control-frame rma_off value meaning "the payload landed at the bound
+// offset of the named region's data area, completion bitmap in the
+// bind's descriptor of the REGION header" (the direct-to-caller-buffer
+// path).  Window spans use their byte offset inside the window's data
+// area instead.
 constexpr uint64_t kRmaDirectOff = UINT64_MAX;
+
+// Smallest response put straight into a caller's bound landing range
+// over a connection with a one-sided session; under it the response
+// rides one frame of the ring and is copied out.  Direct beats the
+// in-band frame on the shm ring at every size tools/rma_floor_probe.py
+// measured, from 147,456 B up on a TPU v5e host and from 4 KB up on a
+// build host (PERF.md §7); the floor is one minimum rma chunk, which
+// keeps the descriptor table of a region (one a floor of data) small.
+constexpr uint64_t kRmaDirectFloor = 65536;
 
 // Refcounted mapping of one region's shm object.  The registry, peer
 // caches and every wrapped-payload consumer co-own it: neither a dying
@@ -91,6 +104,7 @@ struct RmaSession {
   uint64_t peer_rkey = 0;
   std::shared_ptr<RmaMapping> peer_map;
   uint64_t peer_data_len = 0;
+  uint32_t peer_data_off = 0;
   uint32_t peer_slot_bytes = 0;
   uint32_t peer_nslots = 0;
 
@@ -107,7 +121,9 @@ std::shared_ptr<RmaSession> rma_session_create();
 
 // Allocates an exportable (shm-backed) region and returns its DATA
 // pointer (len usable bytes, page-aligned); *rkey_out names it for peers.
-// nullptr on failure.
+// Its header holds a table of one completion descriptor per
+// kRmaDirectFloor of data (at least 1, at most 1024): as many calls may
+// land in it at once.  nullptr on failure.
 void* rma_alloc(size_t len, uint64_t* rkey_out);
 // Unlinks the shm name and drops the registry reference; the munmap is
 // deferred by the mapping refcount until the last wrapped-payload
@@ -143,44 +159,59 @@ std::shared_ptr<RmaMapping> rma_pin_exportable(const void* buf, size_t len,
 
 // -- landing binds (batch plane) ------------------------------------------
 
-// Binds cid → the exportable region holding [buf, buf+cap) so the
-// request can advertise it as the response's remote-write target.  The
-// buffer may sit at ANY offset inside the region's data area
-// (collective pulls land shards mid-buffer); the offset is recorded
-// locally and advertised, and resolve trusts only the LOCAL record.
-// No-op when the buffer is not inside an exportable region, or when
-// another in-flight cid is already bound to the same region — the
-// region header holds ONE direct-transfer completion descriptor, so
-// direct puts into one region are serialized; the striped copy path
-// still catches the refused call.  Called by stripe_register_landing —
-// one registration surface for both paths.
-void rma_landing_bind(uint64_t cid, void* buf, size_t cap);
+// True when a body of n bytes may be put straight into a bound landing:
+// at or over kRmaDirectFloor, or stripe-eligible (net/stripe.h).
+bool rma_direct_eligible(uint64_t n);
+
+// Binds cid → a free completion descriptor of the exportable region
+// holding [buf, buf+cap) so the request can advertise the range as the
+// response's remote-write target.  The buffer may sit at ANY offset
+// inside the region's data area (collective pulls land shards
+// mid-buffer; a KV window lands a record a row); the offset and the
+// descriptor are recorded locally and advertised, and resolve trusts
+// only the LOCAL record.  Any number of calls may be bound at once to
+// disjoint ranges of one region, up to its descriptor table; a range
+// bound again takes the descriptor it had last (so a late put of a
+// timed-out call into it fails the new call's token check, whole).
+// False, and the call lands by the copy path, when the buffer is not
+// inside an exportable region, or — counted in rma_direct_fallback —
+// when the range overlaps another live bind or no descriptor is free.
+bool rma_landing_bind(uint64_t cid, void* buf, size_t cap);
+// Frees cid's descriptor; completes an rma_free deferred by the bind.
 void rma_landing_unbind(uint64_t cid);
 // The bound rkey for cid (0 = none); *max_out = usable bytes,
-// *off_out = byte offset of the landing inside the region's data area.
+// *off_out = byte offset of the landing inside the region's data area,
+// *desc_out = the descriptor the call completes in.
 uint64_t rma_landing_rkey(uint64_t cid, uint64_t* max_out,
-                          uint64_t* off_out = nullptr);
+                          uint64_t* off_out = nullptr,
+                          uint32_t* desc_out = nullptr);
 
 // -- send (channel.cc / server.cc) ----------------------------------------
 
-// Stamps meta's response-advertisement fields (tail-group 6) when cid has
-// a bound exportable landing region AND the socket has an rma session —
-// the server may then put the response straight into the caller's buffer.
-void rma_advertise_response(SocketId sid, uint64_t cid, RpcMeta* meta);
+// The client's half of a direct landing: when the socket has an rma
+// session, binds cid's landing buffer (rma_landing_bind) and stamps
+// meta's response-advertisement fields (tail-group 6: rkey, cap, and in
+// rma_resp_off the offset below bit 48, the descriptor + 1 above it).
+// True when bound — the caller then rma_landing_unbind(cid)s before the
+// call's id recycles.  No lookup at all without a session (tcp).
+bool rma_bind_response(SocketId sid, uint64_t cid, void* buf, size_t cap,
+                       RpcMeta* meta);
 
 // Attempts the one-sided path for meta+body on `primary`: a request, a
 // response, or a stream's DATA frame (net/stream.cc; its correlation_id
 // is the transfer's token, as a call's is).
 //   0  sent: body consumed, chunks written into the peer region, control
 //      frame queued on the primary socket.
-//   1  not applicable (below threshold, no session, descriptor path
-//      preferred, window full): body untouched — caller falls back to
-//      the stripe/frame path.
+//   1  not applicable (below threshold with no usable bound landing, no
+//      session, descriptor path preferred, window full): body untouched
+//      — caller falls back to the stripe/frame path.
 //  -1  hard failure (control write failed / fault reset): the call fails.
 // target_rkey (from the request's advertisement) routes a response
-// direct-to-region when the body fits target_max — written target_off
-// bytes into the region's data area; otherwise the connection window
-// is used.
+// direct-to-region when the body fits target_max and is direct-eligible
+// — written at the advertised offset into the region's data area,
+// completing in the advertised descriptor (target_off as
+// rma_bind_response encodes it); otherwise, over the threshold, the
+// connection window is used.
 // tok (net/deadline.h): the rail writers poll it between chunks — a
 // cancelled request / expired budget stops the transfer within one
 // chunk (remaining chunks never written, their bits never set, the

@@ -16,7 +16,6 @@
 #include "base/rand.h"
 #include "base/time.h"
 #include "fiber/fiber.h"
-#include "net/rma.h"
 #include "net/hotpath_stats.h"
 #include "net/socket.h"
 #include "stat/timeline.h"
@@ -544,17 +543,9 @@ void stripe_register_landing(uint64_t cid, void* buf, size_t cap) {
     std::lock_guard<std::mutex> g(map_mu());
     landings()[cid] = LandingReg{buf, cap, nullptr};
   }
-  // One registration surface for both landing paths (net/rma.h): when
-  // the buffer is an exportable rma region, bind it so the request can
-  // advertise a genuine remote-write target; otherwise only the striped
-  // copy path above catches the response.
-  rma_landing_bind(cid, buf, cap);
 }
 
 void stripe_unregister_landing(uint64_t cid) {
-  // Unbind FIRST: a control frame arriving after this point must reject
-  // (use-after-unregister), not resolve into a buffer being recycled.
-  rma_landing_unbind(cid);
   std::shared_ptr<StripeEntry> e;
   {
     std::lock_guard<std::mutex> g(map_mu());

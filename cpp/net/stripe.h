@@ -101,11 +101,9 @@ struct StripeArrival {
 
 // Registers a caller-owned buffer as the landing destination for the
 // striped RESPONSE of call `cid`: chunks memcpy straight into it (no
-// arena bounce, no extra copy at the Python boundary).  Also a thin
-// wrapper over rma_landing_bind (net/rma.h): a buffer that is itself an
-// exportable rma region is additionally EXPORTED, so the request can
-// advertise it and the server's one-sided put lands the response with
-// zero receiver-side copies.  The buffer must stay valid until
+// arena bounce, no extra copy at the Python boundary).  The one-sided
+// landing of the same buffer is bound apart (rma_bind_response,
+// net/rma.h).  The buffer must stay valid until
 // stripe_unregister_landing(cid) returns.
 void stripe_register_landing(uint64_t cid, void* buf, size_t cap);
 // Idempotent.  Blocks (bounded: at most one in-flight chunk memcpy per

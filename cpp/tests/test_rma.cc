@@ -1,6 +1,9 @@
 // One-sided RMA plane tests (net/rma.h): region registration lifecycle,
 // use-after-unregister rejection, shm multi-rail 64MB integrity, ici
 // parallel-rail integrity, direct-to-caller-region response landing,
+// a window of calls landing in place in one region (one descriptor a
+// call), a late put into a rebound range rejected whole, overlapping
+// binds and a full descriptor table falling back to the copy path,
 // cancel-mid-put buffer quiescence, sub-threshold bypass byte-identity,
 // window-full fallback to the striped copy path, and chunk-level fault
 // injection (drop / trunc / corrupt) asserting whole-or-nothing failure —
@@ -8,12 +11,15 @@
 #include <unistd.h>
 
 #include <cstring>
+#include <memory>
 #include <string>
+#include <vector>
 
 #include "base/flags.h"
 #include "base/proc.h"
 #include "base/time.h"
 #include "fiber/fiber.h"
+#include "fiber/sync.h"
 #include "net/channel.h"
 #include "net/fault.h"
 #include "net/hotpath_stats.h"
@@ -30,6 +36,30 @@ namespace {
 Server* g_server = nullptr;
 int g_port = 0;
 
+// Patterned payload: a mis-offset one-sided write changes bytes, unlike
+// a constant fill.
+std::string pattern(size_t n, uint32_t salt = 0) {
+  std::string s(n, 0);
+  for (size_t i = 0; i < n; ++i) {
+    s[i] = static_cast<char>(((i + salt) * 2654435761u) >> 13);
+  }
+  return s;
+}
+
+// Fill.Bytes answers pattern(n, salt) for a 16-byte request {n, salt}
+// (salt < 64), as a view of one shared pattern: a large response from a
+// small request, the shape of a KV fetch.
+constexpr size_t kFillMax = 8994816;
+const std::string& fill_source() {
+  static const std::string* s = new std::string(pattern(kFillMax + 64));
+  return *s;
+}
+
+struct FillReq {
+  uint64_t n = 0;
+  uint64_t salt = 0;
+};
+
 void start_once() {
   if (g_server != nullptr) {
     return;
@@ -40,20 +70,42 @@ void start_once() {
     resp->append(req);  // zero-copy ref share
     done();
   });
+  g_server->RegisterMethod("Fill.Bytes", [](Controller*, const IOBuf& req,
+                                            IOBuf* resp, Closure done) {
+    FillReq r;
+    req.copy_to(&r, sizeof(r));
+    if (r.n <= kFillMax && r.salt < 64) {
+      resp->append_user_data(
+          const_cast<char*>(fill_source().data()) + r.salt, r.n,
+          [](void*, void*) {});
+    }
+    done();
+  });
   EXPECT_EQ(g_server->Start(0), 0);
   g_port = g_server->port();
 }
 
 std::string addr() { return "127.0.0.1:" + std::to_string(g_port); }
 
-// Patterned payload: a mis-offset one-sided write changes bytes, unlike
-// a constant fill.
-std::string pattern(size_t n, uint32_t salt = 0) {
-  std::string s(n, 0);
-  for (size_t i = 0; i < n; ++i) {
-    s[i] = static_cast<char>(((i + salt) * 2654435761u) >> 13);
-  }
-  return s;
+IOBuf fill_request(size_t n, uint64_t salt) {
+  FillReq r{n, salt};
+  IOBuf req;
+  req.append(&r, sizeof(r));
+  return req;
+}
+
+int64_t read_var(const char* name) {
+  std::string out;
+  return Variable::read_exposed(name, &out)
+             ? strtoll(out.c_str(), nullptr, 10)
+             : 0;
+}
+
+// True when resp is one block whose bytes start at `at`: the response IS
+// the caller's landing range, nothing was copied into it.
+bool in_place(const IOBuf& resp, const void* at) {
+  return resp.block_count() == 1 &&
+         resp.ref_at(0).block->data + resp.ref_at(0).offset == at;
 }
 
 struct FaultGuard {
@@ -213,6 +265,261 @@ TEST_CASE(rma_direct_response_lands_in_caller_region) {
   rma_free(land);
 }
 
+namespace {
+
+// One call of a window: Fill.Bytes landing at `target`.
+struct WindowCall {
+  Controller cntl;
+  IOBuf req;
+  IOBuf resp;
+  char* target = nullptr;
+};
+
+// Issues n Fill.Bytes calls of `size` bytes at once, call i landing at
+// base + i * stride, and waits for all of them.
+std::vector<std::unique_ptr<WindowCall>> fetch_window(Channel* ch, char* base,
+                                                      size_t stride,
+                                                      size_t size, int n) {
+  std::vector<std::unique_ptr<WindowCall>> calls;
+  CountdownEvent done(n);
+  for (int i = 0; i < n; ++i) {
+    auto c = std::make_unique<WindowCall>();
+    c->target = base + static_cast<size_t>(i) * stride;
+    c->req = fill_request(size, static_cast<uint64_t>(i));
+    c->cntl.call().land_buf = c->target;  // the batch plane's registration
+    c->cntl.call().land_cap = size;
+    calls.push_back(std::move(c));
+  }
+  for (auto& c : calls) {
+    ch->CallMethod("Fill.Bytes", c->req, &c->resp, &c->cntl,
+                   [&done] { done.signal(); });
+  }
+  EXPECT_EQ(done.wait(monotonic_time_us() + 120 * 1000 * 1000), 0);
+  return calls;
+}
+
+}  // namespace
+
+TEST_CASE(rma_window_of_calls_lands_in_place_one_descriptor_each) {
+  // A window of 64 calls into disjoint ranges of ONE registered region,
+  // all in flight at once: each holds its own completion descriptor, so
+  // every response is put straight into its range — the KV prefix
+  // window's page (1,032,192 B), the hybrid cache's snapshot record
+  // (2,170,880 B, over the stripe threshold) and the prefix block
+  // (8,994,816 B).
+  start_once();
+  Channel ch;
+  Channel::Options opts;
+  opts.use_shm = true;
+  opts.timeout_ms = 60000;
+  EXPECT_EQ(ch.Init(addr(), &opts), 0);
+  {
+    Controller cntl;  // establish the ring and the session first
+    IOBuf req, resp;
+    req.append("warm");
+    ch.CallMethod("Echo.Echo", req, &resp, &cntl);
+    EXPECT(!cntl.Failed());
+  }
+  const int kCalls = 64;
+  for (const size_t size : {size_t{1032192}, size_t{2170880}, kFillMax}) {
+    uint64_t rkey = 0;
+    auto* land = static_cast<char*>(rma_alloc(kCalls * size, &rkey));
+    EXPECT(land != nullptr);
+    RmaDelta d;
+    const int64_t direct0 = read_var("rma_direct_tx_bytes");
+    const int64_t fallback0 = read_var("rma_direct_fallback");
+    const int64_t stripe0 = hotpath_vars().stripe_tx_chunks.get_value();
+    auto calls = fetch_window(&ch, land, size, size, kCalls);
+    int exact = 0;
+    int placed = 0;
+    for (int i = 0; i < kCalls; ++i) {
+      const WindowCall& c = *calls[i];
+      EXPECT(!c.cntl.Failed());
+      placed += in_place(c.resp, c.target) ? 1 : 0;
+      exact += c.resp.size() == size &&
+                       memcmp(c.target, fill_source().data() + i, size) == 0
+                   ? 1
+                   : 0;
+    }
+    EXPECT_EQ(exact, kCalls);
+    EXPECT_EQ(placed, kCalls);  // no landing copy: every response in place
+    EXPECT_EQ(read_var("rma_direct_tx_bytes") - direct0,
+              static_cast<int64_t>(kCalls * size));
+    EXPECT_EQ(d.d_tx_bytes(), static_cast<int64_t>(kCalls * size));
+    EXPECT_EQ(read_var("rma_direct_fallback") - fallback0, 0);
+    EXPECT_EQ(hotpath_vars().stripe_tx_chunks.get_value() - stripe0, 0);
+    EXPECT_EQ(d.d_rejected(), 0);
+    calls.clear();  // drop the views before the region goes away
+    rma_free(land);
+  }
+}
+
+TEST_CASE(rma_late_put_into_a_rebound_range_rejected_whole) {
+  // Call A times out while the server still holds it; the caller binds
+  // the same range for call B.  A's late put must not pass for B's: the
+  // range takes back its descriptor, A's put stamps A's token into it,
+  // and B's completion over it is rejected whole — as A's own control
+  // frame is (A is unbound).
+  start_once();
+  Channel ch;
+  Channel::Options opts;
+  opts.use_shm = true;
+  opts.timeout_ms = 60000;
+  EXPECT_EQ(ch.Init(addr(), &opts), 0);
+  {
+    Controller cntl;
+    IOBuf req, resp;
+    req.append("warm");
+    ch.CallMethod("Echo.Echo", req, &resp, &cntl);
+    EXPECT(!cntl.Failed());
+  }
+  const size_t size = 1032192;
+  uint64_t rkey = 0;
+  auto* land = static_cast<char*>(rma_alloc(4 * size, &rkey));
+  EXPECT(land != nullptr);
+  // The peer that never learned of the abandonment (see the cancel test
+  // below): no deadline stamp, so the server answers late, not never.
+  FlagGuard wire("trpc_deadline_wire", "false");
+  EXPECT_EQ(g_server->SetFaults("svr_delay=1:800"), 0);
+  RmaDelta d;
+  const int64_t direct0 = read_var("rma_direct_tx_bytes");
+  // Another range holds the region's first descriptor while A is bound.
+  const uint64_t holder = 0x0aaaaaaaaaaaaaa2ull;
+  EXPECT(rma_landing_bind(holder, land, size));
+  uint32_t desc_a = 0;
+  {
+    Controller cntl;
+    cntl.set_timeout_ms(150);
+    cntl.call().land_buf = land + size;
+    cntl.call().land_cap = size;
+    IOBuf req = fill_request(size, 5);
+    IOBuf resp;
+    CountdownEvent done(1);
+    ch.CallMethod("Fill.Bytes", req, &resp, &cntl, [&done] { done.signal(); });
+    fiber_sleep_us(30 * 1000);
+    uint64_t cap = 0, off = 0;
+    EXPECT_EQ(rma_landing_rkey(cntl.call_id(), &cap, &off, &desc_a), rkey);
+    EXPECT_EQ(off, static_cast<uint64_t>(size));
+    EXPECT_EQ(done.wait(monotonic_time_us() + 5 * 1000 * 1000), 0);
+    EXPECT(cntl.Failed());  // timed out; its bind dropped on completion
+  }
+  g_server->SetFaults("");
+  rma_landing_unbind(holder);  // the lower descriptor is free again
+  const uint64_t cid_b = 0x0b0b0b0b0b0b0b0bull;
+  EXPECT(rma_landing_bind(cid_b, land + size, size));
+  uint64_t cap = 0, off = 0;
+  uint32_t desc_b = 0;
+  EXPECT_EQ(rma_landing_rkey(cid_b, &cap, &off, &desc_b), rkey);
+  EXPECT_EQ(off, static_cast<uint64_t>(size));
+  EXPECT_EQ(desc_b, desc_a);  // the range took its descriptor back
+  EXPECT(desc_a != 0);
+  // The late put lands in the range and its control frame is rejected.
+  const int64_t deadline = monotonic_time_us() + 5 * 1000 * 1000;
+  while (d.d_rejected() == 0 && monotonic_time_us() < deadline) {
+    fiber_sleep_us(20 * 1000);
+  }
+  EXPECT_EQ(d.d_rejected(), 1);
+  EXPECT_EQ(read_var("rma_direct_tx_bytes") - direct0,
+            static_cast<int64_t>(size));
+  // B's completion over the same descriptor finds A's token: rejected.
+  InputMessage msg;
+  msg.meta.type = RpcMeta::kResponse;
+  msg.meta.correlation_id = cid_b;
+  msg.meta.rma_rkey = rkey;
+  msg.meta.rma_off = kRmaDirectOff;
+  msg.meta.rma_len = size;
+  msg.meta.rma_chunk = 2 << 20;
+  EXPECT(!rma_resolve(&msg, nullptr));
+  EXPECT_EQ(d.d_rejected(), 2);
+  EXPECT(msg.payload.empty());
+  rma_landing_unbind(cid_b);
+  rma_free(land);
+  // The channel still lands in place afterwards.
+  uint64_t rkey2 = 0;
+  auto* land2 = static_cast<char*>(rma_alloc(size, &rkey2));
+  Controller ok;
+  ok.call().land_buf = land2;
+  ok.call().land_cap = size;
+  IOBuf resp;
+  ch.CallMethod("Fill.Bytes", fill_request(size, 7), &resp, &ok);
+  EXPECT(!ok.Failed());
+  EXPECT(in_place(resp, land2));
+  EXPECT_EQ(memcmp(land2, fill_source().data() + 7, size), 0);
+  resp.clear();
+  rma_free(land2);
+}
+
+TEST_CASE(rma_overlap_and_full_table_fall_back_to_copy_counted) {
+  // A range that overlaps a live bind, and a range of a region whose
+  // descriptors are all held, are not bound: the call lands by the copy
+  // path, whole and correct, and each is counted in rma_direct_fallback.
+  start_once();
+  Channel ch;
+  Channel::Options opts;
+  opts.use_shm = true;
+  opts.timeout_ms = 60000;
+  EXPECT_EQ(ch.Init(addr(), &opts), 0);
+  {
+    Controller cntl;
+    IOBuf req, resp;
+    req.append("warm");
+    ch.CallMethod("Echo.Echo", req, &resp, &cntl);
+    EXPECT(!cntl.Failed());
+  }
+  const size_t size = 1032192;
+  uint64_t rkey = 0;
+  auto* land = static_cast<char*>(rma_alloc(2 * size, &rkey));
+  EXPECT(land != nullptr);
+  auto fetch_into = [&](char* at, uint64_t salt, bool want_in_place) {
+    RmaDelta d;
+    const int64_t direct0 = read_var("rma_direct_tx_bytes");
+    Controller cntl;
+    cntl.call().land_buf = at;
+    cntl.call().land_cap = size;
+    IOBuf resp;
+    ch.CallMethod("Fill.Bytes", fill_request(size, salt), &resp, &cntl);
+    EXPECT(!cntl.Failed());
+    EXPECT(resp.equals(fill_source().data() + salt, size));
+    EXPECT_EQ(in_place(resp, at), want_in_place);
+    EXPECT_EQ(read_var("rma_direct_tx_bytes") - direct0,
+              want_in_place ? static_cast<int64_t>(size) : 0);
+    EXPECT_EQ(d.d_rejected(), 0);
+  };
+  // Overlap: a live bind over [size/2, size/2 + size).
+  const uint64_t holder = 0x0aaaaaaaaaaaaaa1ull;
+  EXPECT(rma_landing_bind(holder, land + size / 2, size));
+  int64_t fallback0 = read_var("rma_direct_fallback");
+  fetch_into(land, 1, false);
+  EXPECT_EQ(read_var("rma_direct_fallback") - fallback0, 1);
+  rma_landing_unbind(holder);
+  fetch_into(land, 2, true);  // the range is free again: in place
+  // Full table: hold every descriptor with one-byte binds in the second
+  // half, then a call into the free first half finds none.
+  std::vector<uint64_t> held;
+  fallback0 = read_var("rma_direct_fallback");
+  while (held.size() < size &&
+         rma_landing_bind(0x0ccc000000000000ull + held.size(),
+                          land + size + held.size(), 1)) {
+    held.push_back(0x0ccc000000000000ull + held.size());
+  }
+  EXPECT(!held.empty());
+  EXPECT_EQ(read_var("rma_direct_fallback") - fallback0, 1);  // the last
+  fallback0 = read_var("rma_direct_fallback");
+  fetch_into(land, 3, false);
+  EXPECT_EQ(read_var("rma_direct_fallback") - fallback0, 1);
+  for (const uint64_t cid : held) {
+    rma_landing_unbind(cid);
+  }
+  fetch_into(land, 4, true);
+  // A freed region's deferred unmap waits for its last bind.
+  const size_t regions = rma_region_count();
+  EXPECT(rma_landing_bind(holder, land, size));
+  rma_free(land);
+  EXPECT_EQ(rma_region_count(), regions);
+  rma_landing_unbind(holder);
+  EXPECT_EQ(rma_region_count(), regions - 1);
+}
+
 TEST_CASE(rma_use_after_unregister_rejected) {
   start_once();
   // A control frame naming a landing that is no longer bound (the
@@ -222,8 +529,8 @@ TEST_CASE(rma_use_after_unregister_rejected) {
   void* land = rma_alloc(cap, &rkey);
   EXPECT(land != nullptr);
   const uint64_t cid = 0x5eed5eed12345678ull;
-  stripe_register_landing(cid, land, cap);
-  stripe_unregister_landing(cid);  // caller cancelled: bind must be gone
+  EXPECT(rma_landing_bind(cid, land, cap));
+  rma_landing_unbind(cid);  // caller cancelled: bind must be gone
   RmaDelta d;
   InputMessage msg;
   msg.meta.type = RpcMeta::kResponse;
@@ -235,7 +542,7 @@ TEST_CASE(rma_use_after_unregister_rejected) {
   EXPECT(!rma_resolve(&msg, nullptr));
   EXPECT_EQ(d.d_rejected(), 1);
   // Freed region + still-bound cid is equally rejected (use after free).
-  stripe_register_landing(cid, land, cap);
+  EXPECT(rma_landing_bind(cid, land, cap));
   rma_free(land);
   InputMessage msg2;
   msg2.meta.type = RpcMeta::kResponse;
@@ -246,7 +553,9 @@ TEST_CASE(rma_use_after_unregister_rejected) {
   msg2.meta.rma_chunk = 1 << 20;
   EXPECT(!rma_resolve(&msg2, nullptr));
   EXPECT_EQ(d.d_rejected(), 2);
-  stripe_unregister_landing(cid);
+  const size_t regions = rma_region_count();
+  rma_landing_unbind(cid);  // the last bind completes the deferred free
+  EXPECT_EQ(rma_region_count(), regions - 1);
   // A window-path control frame with no socket/session context is
   // rejected too (never resolves arbitrary local regions).
   InputMessage msg3;

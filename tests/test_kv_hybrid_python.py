@@ -27,7 +27,8 @@ VARS = ("kv_seq_total", "kv_seq_page_records", "kv_seq_snapshot_records",
         "kv_seq_page_bytes", "kv_seq_snapshot_bytes", "kv_seq_refused",
         "kv_reg_many_total", "kv_reg_many_records", "kv_fetch_many_total",
         "kv_fetch_many_records", "kv_fetch_total", "rma_tx_bytes",
-        "stripe_tx_bytes", "batch_resp_bytes", "batch_land_copy_bytes")
+        "rma_direct_tx_bytes", "stripe_tx_bytes", "batch_resp_bytes",
+        "batch_land_copy_bytes")
 
 
 def _vars():
@@ -240,10 +241,11 @@ def test_a_1k_prompt_of_kimi_linear_is_76_records_an_rpc(node):
 
 
 def test_records_on_both_sides_of_the_threshold_land_in_one_round(node):
-    """Snapshot records of 2,097,152 B (not over `trpc_stripe_threshold`:
-    one frame), 2,097,153 B and Kimi-Linear's 2,170,880 B (over it: the
-    one-sided window over shm, stripe frames over tcp) in one round
-    beside page records of 147,456 B, each byte-exact in its place."""
+    """Snapshot records of 2,097,152 B (not over `trpc_stripe_threshold`),
+    2,097,153 B and Kimi-Linear's 2,170,880 B (over it) in one round
+    beside page records of 147,456 B, each byte-exact in its place: over
+    shm every record is put in place one-sided, over tcp the two over the
+    threshold ride stripe frames and the rest one frame each."""
     addr, reg, cli = node
     sizes = (THRESHOLD, PAGE_RECORD, THRESHOLD + 1, SNAPSHOT_RECORD,
              PAGE_RECORD)
@@ -273,16 +275,19 @@ def test_records_on_both_sides_of_the_threshold_land_in_one_round(node):
         kv.withdraw_sequence(3, layout, 3, registry=reg)
     moved = _moved(before)
     assert moved["batch_resp_bytes"] == total
-    # A body goes one of the large ways only if it is over the
-    # threshold: the record of exactly 2 MiB rides a frame.
-    assert moved["rma_tx_bytes"] + moved["stripe_tx_bytes"] == large
     if cli._use_shm:
-        assert moved["rma_tx_bytes"] == large
-        # A region takes one record in place at a time (its header holds
-        # one completion descriptor): the other large record crossed the
-        # window and was copied out, as every frame's body was.
-        assert moved["batch_land_copy_bytes"] in (
-            total - SNAPSHOT_RECORD, total - THRESHOLD - 1)
+        # Every record is over the direct floor and its landing lies in
+        # one registered region: each call holds a completion descriptor
+        # of its own, so all nine are put in their places, the record of
+        # exactly 2 MiB too, and nothing is copied out.
+        assert moved["rma_tx_bytes"] == moved["rma_direct_tx_bytes"] == total
+        assert moved["stripe_tx_bytes"] == 0
+        assert moved["batch_land_copy_bytes"] == 0
+    else:
+        # A body goes one of the large ways only if it is over the
+        # threshold: the record of exactly 2 MiB rides a frame.
+        assert moved["stripe_tx_bytes"] == large
+        assert moved["rma_tx_bytes"] == 0
     assert (moved["kv_seq_page_bytes"], moved["kv_seq_snapshot_bytes"]) == (
         pages.nbytes, states.nbytes)
 

@@ -14,7 +14,7 @@ import pytest
 
 from benchmark import reference_kv_prefix as pages_ref
 from benchmark import reference_kv_prefix_linear as ref
-from brpc_tpu.rpc import kv, set_flag
+from brpc_tpu.rpc import RmaBuffer, kv, observe, set_flag
 from test_kv_prefix_lanes import GOLDEN, golden_page, golden_tokens
 from test_kv_prefix_python import counted, delta, hub  # noqa: F401
 
@@ -257,6 +257,69 @@ def test_the_counters_rise_as_documented(hub):
     assert got["kv_prefix_fetch_total"] == 7 == got["kv_prefix_hot_hits"]
     assert got["kv_prefix_match_states"] == 1
     assert got.get("kv_prefix_match_states_shallow", 0) == 0
+
+
+LANDING_COUNTERS = ("batch_resp_bytes", "batch_land_copy_bytes",
+                    "rma_direct_tx_bytes", "rma_direct_fallback")
+
+
+@pytest.mark.parametrize("use_shm", [True, False], ids=["shm", "tcp"])
+def test_a_window_of_pages_and_the_state_land_in_one_registered_buffer(
+        hub, use_shm):
+    """One window of the cell: 64 pages of Kimi-Linear's 1,032,192 B and
+    the state, fetched into ranges of ONE `RmaBuffer`.  Over the shm ring
+    every record is put straight into its range, each call holding its
+    own completion descriptor of the region, so nothing is copied out;
+    over tcp there is no one-sided plane and every page lands by copy.
+    Either way byte-identical."""
+    addr, reg, shm_cli = hub
+    page, n = 1032192, 64
+    set_flag("trpc_kv_prefix_hot_bytes", str(256 << 20))
+    set_flag("trpc_kv_store_bytes", str(512 << 20))
+    tokens = np.arange(n * BT, dtype=np.uint64) + 9000
+    keys = kv.prefix_chain(tokens, BT)
+    pages = page_bytes(41, n * page).reshape(n, page)
+    state = page_bytes(42, STATE)
+    published, _ = kv.prefix_publish_state(
+        keys[n - 1], n, state, tokens[(n - 1) * BT:], lease_ms=60000,
+        node=addr)
+    kv.publish_prefix_run(keys, 0, pages.reshape(-1),
+                          [tokens[i * BT:(i + 1) * BT] for i in range(n)],
+                          lease_ms=60000, node=addr, registry=reg,
+                          state=published)
+    cli = shm_cli if use_shm else kv.KvClient(addr, timeout_ms=20000,
+                                              use_shm=False)
+    try:
+        found = cli.match_prefix(tokens, BT)
+        assert len(found) == n and found.state
+        with RmaBuffer(n * page + STATE) as land:
+            area = np.frombuffer(land.view, np.uint8)
+            rows = area[:n * page].reshape(n, page)
+            before = observe.Vars.dump()
+            got = cli.fetch_prefix_blocks(found, landing=rows, window=n,
+                                          state=found.state,
+                                          state_landing=area[n * page:])
+            after = observe.Vars.dump()
+            moved = {k: after.get(k, 0) - before.get(k, 0)
+                     for k in LANDING_COUNTERS}
+            assert len(got) == n and got.state is not None
+            assert np.array_equal(rows, pages)
+            assert np.array_equal(area[n * page:], state)
+            del got, rows, area
+    finally:
+        if not use_shm:
+            cli.close()
+    restored = n * page + STATE
+    assert moved["batch_resp_bytes"] == restored
+    assert moved["rma_direct_fallback"] == 0
+    if use_shm:
+        assert moved["batch_land_copy_bytes"] == 0
+        assert moved["rma_direct_tx_bytes"] == restored
+    else:
+        # Every page is copied out of its frame; the state, over the
+        # stripe threshold, is striped into its range chunk by chunk.
+        assert moved["batch_land_copy_bytes"] == n * page
+        assert moved["rma_direct_tx_bytes"] == 0
 
 
 def test_page_keys_stay_bit_identical():
