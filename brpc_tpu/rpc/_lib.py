@@ -691,14 +691,6 @@ def load_library() -> ctypes.CDLL:
                 ctypes.c_char_p, ctypes.c_size_t,
             ]
             lib.trpc_rpcz_dump.restype = ctypes.c_size_t
-            # Timeline flight recorder (ISSUE 9).
-            lib.trpc_timeline_dump.argtypes = [
-                ctypes.c_int, ctypes.c_size_t, ctypes.c_char_p,
-                ctypes.c_size_t,
-            ]
-            lib.trpc_timeline_dump.restype = ctypes.c_size_t
-            lib.trpc_timeline_enabled.restype = ctypes.c_int
-            lib.trpc_timeline_reset.restype = None
             # SLO engine + fleet observability (capi/slo_capi.cc;
             # stat/slo.h, net/naming.h fleet publication).
             lib.trpc_server_set_slo.argtypes = [

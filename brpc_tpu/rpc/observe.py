@@ -247,9 +247,6 @@ class Span:
     request_bytes: int
     response_bytes: int
     annotations: list = field(default_factory=list)  # [(ts_us, text)]
-    # Fiber the span ran on (16-hex digits; all zeros off-fiber) — the
-    # exact join key onto timeline fiber_run/fiber_park events.
-    fid: str = "0" * 16
 
     @classmethod
     def from_dict(cls, d: dict) -> "Span":
@@ -263,7 +260,6 @@ class Span:
             response_bytes=int(d["response_bytes"]),
             annotations=[(int(a["ts_us"]), a["text"])
                          for a in d.get("annotations", [])],
-            fid=d.get("fid", "0" * 16),
         )
 
 
@@ -301,196 +297,6 @@ def enable_rpcz(on: bool = True) -> None:
 
 def rpcz_enabled() -> bool:
     return get_flag("rpcz_enabled") == "true"
-
-
-# ------------------------------------------------------------- timeline ----
-
-
-# Decoder side of the flight recorder's event-type table
-# (cpp/stat/timeline.h kEventNames).  tools/lint_trpc.py's timeline-event
-# rule keeps BOTH tables in lockstep via the `timeline-event N (name)`
-# markers: ids must be unique, consecutive from 1, and identical on the
-# C++ encoder and this decoder.  Ids are APPEND-ONLY — a recorded binary
-# dump must stay decodable by a newer reader.
-TIMELINE_EVENTS = {
-    1: "fiber_create",    # timeline-event 1 (fiber_create)
-    2: "fiber_ready",     # timeline-event 2 (fiber_ready)
-    3: "fiber_run",       # timeline-event 3 (fiber_run)
-    4: "fiber_park",      # timeline-event 4 (fiber_park)
-    5: "fiber_wake",      # timeline-event 5 (fiber_wake)
-    6: "fiber_steal",     # timeline-event 6 (fiber_steal)
-    7: "fiber_migrate",   # timeline-event 7 (fiber_migrate)
-    8: "fiber_done",      # timeline-event 8 (fiber_done)
-    9: "sweep_start",     # timeline-event 9 (sweep_start)
-    10: "sweep_end",      # timeline-event 10 (sweep_end)
-    11: "inline_begin",   # timeline-event 11 (inline_begin)
-    12: "inline_end",     # timeline-event 12 (inline_end)
-    13: "bulk_wake",      # timeline-event 13 (bulk_wake)
-    14: "write_flush",    # timeline-event 14 (write_flush)
-    15: "writer_handoff",  # timeline-event 15 (writer_handoff)
-    16: "write_coalesce",  # timeline-event 16 (write_coalesce)
-    17: "stripe_cut",     # timeline-event 17 (stripe_cut)
-    18: "stripe_send",    # timeline-event 18 (stripe_send)
-    19: "stripe_land",    # timeline-event 19 (stripe_land)
-    20: "stripe_done",    # timeline-event 20 (stripe_done)
-    21: "qos_drain",      # timeline-event 21 (qos_drain)
-    22: "kv_block",       # timeline-event 22 (kv_block)
-    23: "coll_step",      # timeline-event 23 (coll_step)
-    24: "tuner_decision",  # timeline-event 24 (tuner_decision)
-    25: "deadline",       # timeline-event 25 (deadline)
-    26: "capture",        # timeline-event 26 (capture)
-    27: "coll_ready",     # timeline-event 27 (coll_ready)
-    28: "slo_breach",     # timeline-event 28 (slo_breach)
-    29: "token_step",     # timeline-event 29 (token_step)
-}
-
-# kCapture `b` op tags (cpp/stat/capture.cc: b = op << 56 | request
-# bytes, or records written for "dump") — traffic-capture reservoir
-# keep/drop decisions and file dumps.
-TIMELINE_CAPTURE_OPS = {1: "keep", 2: "drop", 3: "dump"}
-
-# kKvBlock `b` op tags (cpp/net/kvstore.h: b = op << 56 | payload len) —
-# how a kv_block event reads: the store published / served / evicted a
-# block, rejected a stale-generation fetch, or moved a prefix block
-# between the hot (registered) and cold (heap) tiers.
-TIMELINE_KV_OPS = {1: "publish", 2: "serve", 3: "evict", 4: "stale",
-                   5: "promote", 6: "demote"}
-
-# kCollStep `b` op tags (cpp/net/collective.h CollOp: b = op << 56 |
-# step bytes; a = step index) — one event per completed collective
-# schedule step on the member that completed it.
-TIMELINE_COLL_OPS = {1: "all_gather", 2: "reduce_scatter",
-                     3: "all_to_all", 4: "reshard"}
-
-# kSloBreach `b` op tags (cpp/stat/slo.cc: b = op << 56 | fast-window
-# burn rate in milli-units; a = FNV-1a hash of the tenant name) — one
-# event per breach-state EDGE, never per evaluation.
-TIMELINE_SLO_OPS = {1: "breach", 2: "clear"}
-
-# kTokenStep `b` op tags (cpp/net/infer.h: b = op << 56 | low bits;
-# a = request id) — one request's life through the continuous batch:
-# admit (low bits = prefix-cache-matched tokens), prefill_done, one
-# `token` per decode step (low bits = token index), eos / cancel (low
-# bits = tokens emitted), shed (low bits = error code; a = 0).
-TIMELINE_TOKEN_OPS = {1: "admit", 2: "prefill_done", 3: "token",
-                      4: "eos", 5: "cancel", 6: "shed"}
-
-# kStripeSend rail index meaning "the call's primary socket" (head
-# frame / dead-rail fallback) — cpp/stat/timeline.h kStripePrimaryRail.
-TIMELINE_STRIPE_PRIMARY_RAIL = 0xFFFF
-
-# kStripeSend rail values with this bit set are one-sided RMA rails
-# (net/rma.h): the chunk was WRITTEN into the peer's registered region
-# by rail (value & 0x7FFF) — no ring/socket copy happened.  Mirrors
-# cpp/stat/timeline.h kStripeRmaRailBit.
-TIMELINE_STRIPE_RMA_BIT = 0x8000
-
-_TL_MAGIC = b"TRPCTL01"
-_TL_HEADER = struct.Struct("<qqI")       # now_mono_us, now_wall_us, nrings
-_TL_RING = struct.Struct("<Q16sI")       # tid, name, nevents
-_TL_EVENT = struct.Struct("<Iq5Q")       # type, ts, a, b, trace, span, fid
-
-
-@dataclass(frozen=True)
-class TimelineEvent:
-    """One flight-recorder event (ids are 16-hex-digit strings, like
-    rpcz spans — 64-bit values that would truncate as floats)."""
-
-    ts_us: int
-    type: int
-    name: str
-    a: int
-    b: int
-    trace_id: str
-    span_id: str
-    fid: str
-    tid: int
-    thread: str
-
-
-def enable_timeline(on: bool = True) -> None:
-    """Flips the flight recorder (the reloadable `trpc_timeline` flag;
-    off by default — every hook costs one relaxed load while off)."""
-    set_flag("trpc_timeline", "true" if on else "false")
-
-
-def timeline_enabled() -> bool:
-    return load_library().trpc_timeline_enabled() == 1
-
-
-def reset_timeline() -> None:
-    """Hides everything recorded so far (per-ring floors — safe against
-    concurrent writers; lifetime counters keep counting)."""
-    load_library().trpc_timeline_reset()
-
-
-def timeline_dump(limit: int = 4096) -> dict:
-    """The raw structured timeline dump for THIS process — the same
-    shape `/timeline` serves: {"pid", "now_mono_us", "now_wall_us",
-    "enabled", "threads": [{"tid", "name", "events": [...]}]} (the clock
-    pair lets tools/trace_stitch.py --timeline place these events on the
-    same wall-clock timeline as the node's rpcz spans)."""
-    lib = load_library()
-    raw = _dump_with_retry(
-        lambda buf, n: lib.trpc_timeline_dump(0, limit, buf, n))
-    return json.loads(raw.decode())
-
-
-def timeline_binary(limit: int = 4096) -> bytes:
-    """The packed binary dump (the /timeline?format=binary body)."""
-    lib = load_library()
-    return _dump_with_retry(
-        lambda buf, n: lib.trpc_timeline_dump(1, limit, buf, n))
-
-
-def parse_timeline_binary(raw: bytes) -> dict:
-    """Decodes a binary timeline dump into the JSON dump's dict shape.
-    The event-type ids resolve through TIMELINE_EVENTS — the table the
-    lint rule pins against the C++ encoder."""
-    if raw[:8] != _TL_MAGIC:
-        raise ValueError(f"bad timeline magic: {raw[:8]!r}")
-    off = 8
-    now_mono, now_wall, nrings = _TL_HEADER.unpack_from(raw, off)
-    off += _TL_HEADER.size
-    threads = []
-    for _ in range(nrings):
-        tid, name, nevents = _TL_RING.unpack_from(raw, off)
-        off += _TL_RING.size
-        events = []
-        for _ in range(nevents):
-            etype, ts, a, b, trace, span, fid = _TL_EVENT.unpack_from(
-                raw, off)
-            off += _TL_EVENT.size
-            events.append({
-                "ts_us": ts, "type": etype,
-                "name": TIMELINE_EVENTS.get(etype, "unknown"),
-                # a/b as 16-hex strings, matching the JSON dump (they
-                # often carry 64-bit handles a JSON double would round).
-                "a": f"{a:016x}", "b": f"{b:016x}",
-                "trace_id": f"{trace:016x}",
-                "span_id": f"{span:016x}", "fid": f"{fid:016x}",
-            })
-        threads.append({"tid": tid,
-                        "name": name.split(b"\0")[0].decode(),
-                        "events": events})
-    return {"now_mono_us": now_mono, "now_wall_us": now_wall,
-            "threads": threads}
-
-
-def timeline(limit: int = 4096) -> list[TimelineEvent]:
-    """Flight-recorder events of THIS process, flattened across threads
-    and sorted by timestamp (per-thread order is exact; cross-thread
-    order is clock order)."""
-    out = []
-    for t in timeline_dump(limit)["threads"]:
-        for e in t["events"]:
-            out.append(TimelineEvent(
-                ts_us=int(e["ts_us"]), type=int(e["type"]),
-                name=e["name"], a=int(e["a"], 16), b=int(e["b"], 16),
-                trace_id=e["trace_id"], span_id=e["span_id"],
-                fid=e["fid"], tid=int(t["tid"]), thread=t["name"]))
-    out.sort(key=lambda e: e.ts_us)
-    return out
 
 
 # ------------------------------------------------- digests + SLO fleet ----

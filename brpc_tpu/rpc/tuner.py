@@ -15,10 +15,6 @@ validated flag-reload path only.  This module is the ctypes surface:
   sampled input vars, and the structured decision journal;
 - `decisions()` returns the journal as typed records — every knob
   change, revert and freeze, with the metric readings that drove it.
-
-Every decision is also a `tuner_decision` timeline event (a = knob
-hash, b = old<<32|new), so a tuning run shows up as its own track in
-`tools/trace_stitch.py --timeline` Perfetto artifacts.
 """
 
 from __future__ import annotations

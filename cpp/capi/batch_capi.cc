@@ -243,7 +243,7 @@ struct BatchCall {
   SubmitGroup* group = nullptr;  // non-null iff rpcz was on at submit
   // Phase clocks, each one monotonic_time_us() reading: CLOCK_MONOTONIC,
   // the clock of Python's time.perf_counter (so of the benchmark's window
-  // and spans) and of rpcz and the timeline.  enter <= issue <= reply <=
+  // and spans) and of rpcz.  enter <= issue <= reply <=
   // landed <= polled; the fifth is read in trpc_batch_poll, which folds
   // the differences into BatchPipelineVars.  Plain fields: the issuer
   // hand-off, the done-ring's release push and poll's acquire pop

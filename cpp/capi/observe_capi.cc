@@ -23,7 +23,6 @@
 #include "capi/capi_util.h"
 #include "net/span.h"
 #include "stat/latency_recorder.h"
-#include "stat/timeline.h"
 #include "stat/variable.h"
 
 using namespace trpc;
@@ -124,35 +123,6 @@ size_t trpc_rpcz_dump(size_t limit, uint64_t trace_id, int format,
   }
   return copy_out(rpcz_dump_json(limit, trace_id), out, out_len);
 }
-
-// ---- timeline flight recorder -------------------------------------------
-
-// The /timeline body, in-process (brpc_tpu/rpc/observe.py timeline()).
-// format 0: JSON (see timeline::dump_json for the shape); format 1: the
-// packed binary form (timeline::dump_binary — observe.py's struct
-// parser).  Same buffer-retry contract as the other dumps: returns the
-// FULL byte length; a caller seeing ret >= out_len re-calls bigger.
-// Note the binary body may contain NULs — callers must slice by the
-// returned length, never strlen.
-size_t trpc_timeline_dump(int format, size_t per_thread_limit, char* out,
-                          size_t out_len) {
-  if (per_thread_limit == 0 || per_thread_limit > (1 << 16)) {
-    per_thread_limit = per_thread_limit == 0 ? 4096 : (1 << 16);
-  }
-  return copy_out(format == 1 ? timeline::dump_binary(per_thread_limit)
-                              : timeline::dump_json(per_thread_limit),
-                  out, out_len);
-}
-
-// 1 while the trpc_timeline flag is on (events are being recorded).
-int trpc_timeline_enabled() {
-  timeline::ensure_registered();
-  return timeline::enabled() ? 1 : 0;
-}
-
-// Test support: hides everything recorded so far (per-ring floors; no
-// deallocation, safe against concurrent writers).
-void trpc_timeline_reset() { timeline::reset(); }
 
 // ---- ambient trace context ----------------------------------------------
 

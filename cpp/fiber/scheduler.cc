@@ -16,7 +16,6 @@
 #include <dlfcn.h>
 
 #include "fiber/event.h"
-#include "stat/timeline.h"
 
 // ASan fiber-switch annotations (parity: the reference's ASan-aware stack
 // switching, task_group.h:311 asan_task_runner + stack poisoning).  No-ops
@@ -58,20 +57,6 @@ thread_local Worker* tls_worker = nullptr;
 namespace {
 
 using FiberPool = ResourcePool<FiberMeta>;
-
-// Flight-recorder hook for transitions about a SPECIFIC fiber: ready/
-// wake/steal fire on the waker's/thief's thread, so the event stamps
-// the TARGET fiber's ambient trace (FiberMeta fields), not the
-// emitter's.  Callers gate on timeline::enabled() so the flag-off cost
-// stays at one relaxed load per transition.
-inline void timeline_fiber_event(uint32_t type, FiberMeta* m,
-                                 uint64_t b = 0) {
-  // Relaxed: diagnostic snapshot of the target's context (see the
-  // ambient_trace comment in scheduler.h).
-  timeline::record_ctx(type, m->id(), b,
-                       m->ambient_trace.load(std::memory_order_relaxed),
-                       m->ambient_span.load(std::memory_order_relaxed));
-}
 
 void requeue_post(void* a1, void*) {
   Scheduler::instance()->ready_to_run(static_cast<FiberMeta*>(a1));
@@ -185,14 +170,6 @@ void Scheduler::start_tag(int tag, int workers) {
 }
 
 void Scheduler::ready_to_run(FiberMeta* m, bool urgent) {
-  if (timeline::enabled()) {
-    // Relaxed: written only by the worker that last ran m; a stale read
-    // can only misname ready-vs-wake on a racing transition.
-    timeline_fiber_event(m->last_worker.load(std::memory_order_relaxed) < 0
-                             ? timeline::kFiberReady
-                             : timeline::kFiberWake,
-                         m);
-  }
   TagGroup& g = tags_[m->tag];
   Worker* w = tls_worker;
   // A thread about to block pthread-style must not trap work in its own
@@ -234,17 +211,6 @@ void Scheduler::ready_to_run_batch(FiberMeta* const* ms, size_t n,
   if (n == 1) {
     ready_to_run(ms[0], urgent);
     return;
-  }
-  if (timeline::enabled()) {
-    timeline::record(timeline::kBulkWake, n, 0);
-    for (size_t i = 0; i < n; ++i) {
-      // Relaxed: same ready-vs-wake naming tolerance as ready_to_run.
-      timeline_fiber_event(
-          ms[i]->last_worker.load(std::memory_order_relaxed) < 0
-              ? timeline::kFiberReady
-              : timeline::kFiberWake,
-          ms[i]);
-    }
   }
   TagGroup& g = tags_[ms[0]->tag];
   Worker* w = tls_worker;
@@ -322,10 +288,6 @@ bool Scheduler::steal(FiberMeta** out, Worker* thief) {
       continue;
     }
     if (victim->runq().steal(out)) {
-      if (timeline::enabled()) {
-        timeline_fiber_event(timeline::kFiberSteal, *out,
-                             static_cast<uint64_t>(victim->index()));
-      }
       return true;
     }
     // The victim may be pthread-blocked with a fiber parked in its urgent
@@ -334,10 +296,6 @@ bool Scheduler::steal(FiberMeta** out, Worker* thief) {
         victim->urgent_.exchange(nullptr, std::memory_order_acq_rel);
     if (urgent != nullptr) {
       *out = urgent;
-      if (timeline::enabled()) {
-        timeline_fiber_event(timeline::kFiberSteal, urgent,
-                             static_cast<uint64_t>(victim->index()));
-      }
       return true;
     }
   }
@@ -366,18 +324,6 @@ FiberMeta* Worker::pick_next() {
 
 void Worker::run_fiber(FiberMeta* m) {
   current_ = m;
-  // Relaxed last_worker: only the worker about to run m writes it, and
-  // the scheduler queue handoff orders successive runners.
-  const int32_t prev_w = m->last_worker.load(std::memory_order_relaxed);
-  if (timeline::enabled()) {
-    if (prev_w >= 0 && prev_w != index_) {
-      timeline_fiber_event(timeline::kFiberMigrate, m,
-                           static_cast<uint64_t>(index_));
-    }
-    timeline_fiber_event(timeline::kFiberRun, m,
-                         static_cast<uint64_t>(index_));
-  }
-  m->last_worker.store(index_, std::memory_order_relaxed);
   __sanitizer_start_switch_fiber(&asan_fake_stack_, m->stack.base,
                                  m->stack.size);
   if (TRPC_TSAN_FIBERS) {
@@ -399,13 +345,6 @@ void Worker::run_fiber(FiberMeta* m) {
 void Worker::suspend_current(PostSwitchFn post_fn, void* a1, void* a2,
                              bool dying) {
   FiberMeta* m = current_;
-  if (timeline::enabled()) {
-    // Still on the fiber's logical context: park/done events carry its
-    // own ambient trace, so a span's gap decomposes into parked time.
-    timeline_fiber_event(dying ? timeline::kFiberDone
-                               : timeline::kFiberPark,
-                         m);
-  }
   post_fn_ = post_fn;
   post_a1_ = a1;
   post_a2_ = a2;
@@ -513,12 +452,11 @@ FiberMeta* make_fiber_meta(void (*fn)(void*), void* arg, int tag) {
   m->parked_on.store(nullptr, std::memory_order_relaxed);
   // Relaxed: pre-publication init (the slot is not yet visible), same as
   // the surrounding stores; a recycled meta must not leak the previous
-  // fiber's trace context or worker history.
+  // fiber's trace or deadline context.
   m->ambient_trace.store(0, std::memory_order_relaxed);
   m->ambient_span.store(0, std::memory_order_relaxed);
   m->ambient_deadline.store(0, std::memory_order_relaxed);
   m->ambient_cancel.store(nullptr, std::memory_order_relaxed);
-  m->last_worker.store(-1, std::memory_order_relaxed);
   const uint32_t ver = m->version.load(std::memory_order_relaxed) + 1;  // odd
   m->done_event.value.store(ver, std::memory_order_relaxed);
   m->version.store(ver, std::memory_order_relaxed);
@@ -541,9 +479,6 @@ int fiber_start(fiber_t* out, void (*fn)(void*), void* arg, int flags) {
   FiberMeta* m = make_fiber_meta(fn, arg, tag);
   if (m == nullptr) {
     return -1;
-  }
-  if (timeline::enabled()) {
-    timeline::record(timeline::kFiberCreate, m->id(), 0);
   }
   if (out != nullptr) {
     *out = m->id();
@@ -575,9 +510,6 @@ size_t fiber_start_batch(void (*fn)(void*), void* const* args, size_t n,
       FiberMeta* m = make_fiber_meta(fn, args[started + got], tag);
       if (m == nullptr) {
         break;  // pool exhausted: publish what we have
-      }
-      if (timeline::enabled()) {
-        timeline::record(timeline::kFiberCreate, m->id(), 0);
       }
       ms[got++] = m;
     }

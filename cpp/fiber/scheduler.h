@@ -47,29 +47,17 @@ struct FiberMeta {
   std::atomic<bool> interrupted{false};
   std::atomic_flag park_mu = ATOMIC_FLAG_INIT;
   // Ambient trace context (net/span.cc reads/writes these when the
-  // fiber installs a span; the timeline recorder stamps them into every
-  // event).  Value storage directly on the meta instead of FLS slots:
-  // scheduler-side emitters (ready/wake on the WAKER's thread) must be
-  // able to read the TARGET fiber's context, which fls_get — keyed off
-  // the calling thread — cannot do.  Atomics because those cross-thread
-  // reads race the owning fiber's stores; relaxed everywhere (same-fiber
-  // accesses are program-ordered across migration by the scheduler's
-  // queue handoff, cross-thread reads are diagnostic-only).
+  // fiber installs a span).  Value storage directly on the meta instead
+  // of FLS slots so the values follow the fiber across worker migration.
+  // Relaxed: same-fiber accesses are program-ordered across migration by
+  // the scheduler's queue handoff.
   std::atomic<uint64_t> ambient_trace{0};
   std::atomic<uint64_t> ambient_span{0};
   // Ambient deadline plane (net/deadline.h): the absolute monotonic
   // deadline (µs; 0 = none) and cancel scope of the request this fiber
-  // is serving.  Same storage rationale as ambient_trace; unlike the
-  // trace pair these are only ever read by the OWNING fiber, but they
-  // live here (not FLS) so the values follow the fiber across worker
-  // migration.  Relaxed: same-fiber accesses are program-ordered across
-  // migration by the scheduler's queue handoff.
+  // is serving.  Same storage rationale and ordering as ambient_trace.
   std::atomic<int64_t> ambient_deadline{0};
   std::atomic<void*> ambient_cancel{nullptr};
-  // Last worker index this fiber ran on (-1 = never ran).  Written only
-  // by the running worker; ready_to_run on a waker thread reads it to
-  // tell first-ready from wake — atomic for that cross-thread read.
-  std::atomic<int32_t> last_worker{-1};
 
   void park_lock() {
     while (park_mu.test_and_set(std::memory_order_acquire)) {

@@ -31,7 +31,6 @@
 #include "stat/heap_profiler.h"
 #include "stat/slo.h"
 #include "stat/profiler.h"
-#include "stat/timeline.h"
 #include "stat/tuner.h"
 #include "stat/variable.h"
 
@@ -325,37 +324,6 @@ bool builtin_http_dispatch(Server* srv, const HttpRequest& req,
     *body = contention_dump();
     return true;
   }
-  if (path == "/timeline") {
-    // Flight recorder (stat/timeline.h): per-thread rings of fiber/
-    // messenger/socket/stripe/QoS events recorded while the reloadable
-    // trpc_timeline flag is on.  Served even while recording is off —
-    // the rings may hold events from an earlier enabled window, and
-    // tools/trace_stitch.py --timeline needs a parseable body from
-    // every node it fans out to.  ?limit=N caps events per thread
-    // (default 4096, max 65536); ?format=binary streams the packed
-    // form observe.py's reader parses.
-    size_t limit = 4096;
-    const std::string* lq = req.query("limit");
-    if (lq != nullptr) {
-      const long v = atol(lq->c_str());
-      if (v > 0) {
-        // Clamp (don't silently fall back to the default): a caller
-        // asking for more than the cap gets the cap — same behavior as
-        // trpc_timeline_dump.
-        limit = std::min(static_cast<size_t>(v),
-                         static_cast<size_t>(1 << 16));
-      }
-    }
-    const std::string* fmt = req.query("format");
-    if (fmt != nullptr && *fmt == "binary") {
-      *body = timeline::dump_binary(limit);
-      *content_type = "application/octet-stream";
-    } else {
-      *body = timeline::dump_json(limit);
-      *content_type = "application/json";
-    }
-    return true;
-  }
   if (path == "/capture") {
     // Traffic capture (stat/capture.h): arrival-process summary +
     // per-tenant baseline over the records held while the reloadable
@@ -644,7 +612,6 @@ bool builtin_http_dispatch(Server* srv, const HttpRequest& req,
         "/threads\n"
         "/memory\n/list\n/protobufs\n/index\n"
         "/rpcz[?trace_id=hex&format=json&limit=N]\n"
-        "/timeline[?format=binary&limit=N]\n"
         "/capture[?records=N&dump=path&reset=1]\n"
         "/tuner[?limit=N]\n"
         "/slo\n/fleet[?service=name]\n"

@@ -26,7 +26,6 @@
 #include "net/lb_hint.h"
 #include "net/naming.h"
 #include "stat/reducer.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -1221,8 +1220,7 @@ void hedge_attempt_fiber(void* p) {
   HedgeCtx* ctx = arg->ctx.get();
   const int i = arg->index;
   // Both racing attempts carry the caller's trace: their spans show up
-  // side by side under one parent in /rpcz (hedges are exactly the kind
-  // of tail behavior a timeline exists to expose).
+  // side by side under one parent in /rpcz.
   set_ambient_trace(ctx->amb_trace, ctx->amb_span);
   set_ambient_deadline(ctx->amb_deadline);
   ctx->channels[i]->CallMethod(ctx->method, ctx->request,
@@ -1347,19 +1345,9 @@ void ClusterChannel::call_hedged(std::shared_ptr<Cluster> cluster,
       if (p50 > 0 && remaining < p50) {
         allow = false;
         deadline_vars().hedge_suppressed << 1;
-        if (timeline::enabled()) {
-          timeline::record(
-              timeline::kDeadline, 0,
-              (timeline::kDeadlineHedgeSuppressed << 56) |
-                  static_cast<uint64_t>(remaining > 0 ? remaining : 0));
-        }
       } else if (!retry_budget_take()) {
         allow = false;
         deadline_vars().hedge_suppressed << 1;
-        if (timeline::enabled()) {
-          timeline::record(timeline::kDeadline, 0,
-                           timeline::kDeadlineRetrySuppressed << 56);
-        }
       }
     }
     if (allow) {
@@ -1490,10 +1478,6 @@ void ClusterChannel::CallMethod(const std::string& method,
       // amplification at ~(1 + pct/100)x under total downstream failure
       // — every layer retrying independently is how outages multiply.
       deadline_vars().retry_suppressed << 1;
-      if (timeline::enabled()) {
-        timeline::record(timeline::kDeadline, 0,
-                         timeline::kDeadlineRetrySuppressed << 56);
-      }
       break;
     }
     const int64_t now = monotonic_time_us();

@@ -18,7 +18,6 @@
 #include "net/server.h"
 #include "stat/latency_recorder.h"
 #include "stat/reducer.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -401,26 +400,6 @@ bool parse_put_wire(const IOBuf& req, CollPutWire* w) {
   }
   req.copy_to(w, sizeof(CollPutWire));
   return true;
-}
-
-void record_coll_step(CollOp op, uint32_t step, uint64_t bytes) {
-  if (timeline::enabled()) {
-    timeline::record(timeline::kCollStep, step,
-                     (static_cast<uint64_t>(op) << 56) |
-                         (bytes & ((1ull << 56) - 1)));
-  }
-}
-
-// A transfer fired off a readiness stamp instead of the barrier:
-// a = step, b = chunk<<32|bytes (chunk = dep offset / granularity).
-void record_coll_ready(uint32_t step, uint64_t dep_off, uint64_t bytes) {
-  coll_vars().ready_triggers_total << 1;
-  if (timeline::enabled()) {
-    const uint64_t g =
-        static_cast<uint64_t>(flag_val(ready_granularity_flag(), 1 << 20));
-    timeline::record(timeline::kCollReady, step,
-                     ((dep_off / g) << 32) | (bytes & 0xFFFFFFFFull));
-  }
 }
 
 }  // namespace
@@ -897,7 +876,8 @@ void handle_get(Controller* cntl, const IOBuf& req, IOBuf* resp,
                  rma_ready_test(s->ready_handle, w.shard_off, w.len) == 1);
       if (dep_ok) {
         if (!from_recv && s->ready_handle != 0 && overlap_enabled()) {
-          record_coll_ready(w.step, w.shard_off, w.len);
+          // A transfer fired off a readiness stamp, not the barrier.
+          coll_vars().ready_triggers_total << 1;
         }
         s->busy += 1;  // released by the response payload's deleter
         s->served_bytes[w.step] += w.len;
@@ -1478,7 +1458,7 @@ int GroupChannel::run(const TransferSchedule& plan, const void* sendbuf,
           fail(rc, "local copy input never stamped ready");
           break;
         }
-        record_coll_ready(0, d.off, d.len);
+        coll_vars().ready_triggers_total << 1;
       }
     }
     memcpy(acc + t.dst_off,
@@ -1506,7 +1486,6 @@ int GroupChannel::run(const TransferSchedule& plan, const void* sendbuf,
       fail(rc, "membership epoch moved under the schedule");
       break;
     }
-    uint64_t step_bytes = 0;
     // Shared (not raw) handles for the done closures — see rs_owner.
     std::shared_ptr<RunState> rsp = rs_owner;
     std::shared_ptr<RecvSession> sess = rs.sess;
@@ -1582,7 +1561,6 @@ int GroupChannel::run(const TransferSchedule& plan, const void* sendbuf,
         rs.outstanding.fetch_add(1, std::memory_order_release);
         coll_vars().puts_total << 1;
         coll_vars().put_bytes << static_cast<int64_t>(t.len);
-        step_bytes += t.len;
         const uint64_t want_len = t.len;
         chans_[t.src]->CallMethod(
             kCollGetMethod, raw->req, &raw->resp, &raw->cntl,
@@ -1653,8 +1631,7 @@ int GroupChannel::run(const TransferSchedule& plan, const void* sendbuf,
                          std::to_string(s));
             break;
           }
-          record_coll_ready(static_cast<uint32_t>(s), t.src_off + off,
-                            len);
+          coll_vars().ready_triggers_total << 1;
         }
         throttle();
         if (rc != 0) {
@@ -1693,7 +1670,6 @@ int GroupChannel::run(const TransferSchedule& plan, const void* sendbuf,
         rs.outstanding.fetch_add(1, std::memory_order_release);
         coll_vars().puts_total << 1;
         coll_vars().put_bytes << static_cast<int64_t>(len);
-        step_bytes += len;
         chans_[t.dst]->CallMethod(
             kCollPutMethod, raw->req, &raw->resp, &raw->cntl,
             [rsp, sess, raw, s, fail_call]() {
@@ -1740,7 +1716,6 @@ int GroupChannel::run(const TransferSchedule& plan, const void* sendbuf,
     if (rc == 0) {
       steps_done += 1;
       coll_vars().steps_total << 1;
-      record_coll_step(plan.op, static_cast<uint32_t>(s), step_bytes);
       coll_vars().step_lat(plan.op)
           << (monotonic_time_us() - step_start);
       {

@@ -92,9 +92,6 @@ inline constexpr const char* kCollAbortMethod = "Coll.Abort";
 inline constexpr const char* kReshardPlanMethod = "Reshard.Plan";
 inline constexpr const char* kReshardExecuteMethod = "Reshard.Execute";
 
-// Collective ops (also the kCollStep timeline `b` op tags, b = op<<56 |
-// step bytes; mirrored by observe.py TIMELINE_COLL_OPS and
-// tools/trace_stitch.py).
 enum class CollOp : uint32_t {
   kAllGather = 1,
   kReduceScatter = 2,

@@ -21,7 +21,6 @@
 #include "net/server.h"
 #include "net/stream.h"
 #include "stat/slo.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -256,11 +255,6 @@ class InferScheduler {
           break;
         }
       }
-    }
-    if (timeline::enabled()) {
-      timeline::record(timeline::kTokenStep, 0,
-                       (timeline::kTokenStepShed << 56) |
-                           static_cast<uint64_t>(kEOverloaded));
     }
     cntl->SetFailed(kEOverloaded, "inference batch + queue saturated");
   }
@@ -533,10 +527,6 @@ void InferScheduler::begin_prefill(const ReqPtr& r, int64_t now) {
   infer_vars().prefill_bytes_recomputed << recompute * bpt;
   r->ready_at_us =
       now + static_cast<int64_t>(recompute) * prefill_us_flag()->int64_value();
-  if (timeline::enabled()) {
-    timeline::record(timeline::kTokenStep, r->id,
-                     (timeline::kTokenStepAdmit << 56) | r->cached_tokens);
-  }
   if (!r->matched.empty()) {
     r->fetch_state.store(kFetchRunning, std::memory_order_release);
     struct FetchArg {
@@ -724,10 +714,6 @@ bool InferScheduler::step_request(const ReqPtr& r, int64_t now) {
     r->prompt.clear();
     r->prompt.shrink_to_fit();
     r->decoding = true;
-    if (timeline::enabled()) {
-      timeline::record(timeline::kTokenStep, r->id,
-                       timeline::kTokenStepPrefillDone << 56);
-    }
   }
   TokenRecord rec;
   rec.token = splitmix64(r->prompt_hash ^ (r->emitted + 1));
@@ -749,10 +735,6 @@ bool InferScheduler::step_request(const ReqPtr& r, int64_t now) {
   r->last_token_us = t;
   r->emitted += 1;
   infer_vars().tokens_total << 1;
-  if (timeline::enabled()) {
-    timeline::record(timeline::kTokenStep, r->id,
-                     (timeline::kTokenStepToken << 56) | (r->emitted - 1));
-  }
   if (r->emitted >= r->max_new) {
     finish(r, false);
     return false;
@@ -785,13 +767,6 @@ void InferScheduler::finish(const ReqPtr& r, bool cancelled) {
     infer_vars().cancelled_total << 1;
   } else {
     infer_vars().done_total << 1;
-  }
-  if (timeline::enabled()) {
-    timeline::record(
-        timeline::kTokenStep, r->id,
-        ((cancelled ? timeline::kTokenStepCancel : timeline::kTokenStepEos)
-         << 56) |
-            r->emitted);
   }
   StreamClose(r->sid);
   release_slot(r->tenant);

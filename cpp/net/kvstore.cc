@@ -17,7 +17,6 @@
 #include "net/server.h"
 #include "stat/latency_recorder.h"
 #include "stat/reducer.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -267,13 +266,6 @@ struct KvPrefixVars {
 KvPrefixVars& kv_prefix_vars() {
   static KvPrefixVars* v = new KvPrefixVars();
   return *v;
-}
-
-void record_kv(uint64_t block_id, uint64_t op, uint64_t len) {
-  if (timeline::enabled()) {
-    timeline::record(timeline::kKvBlock, block_id,
-                     (op << 56) | (len & ((1ull << 56) - 1)));
-  }
 }
 
 }  // namespace
@@ -751,7 +743,6 @@ void KvStore::evict_locked(uint64_t block_id, bool count_var) {
   }
   tombstones_[block_id] = it->second.meta.generation;
   bytes_ -= it->second.meta.len;
-  record_kv(block_id, kKvOpEvict, it->second.meta.len);
   blocks_.erase(it);
   if (count_var) {
     kv_vars().evict_total << 1;
@@ -827,7 +818,6 @@ int KvStore::publish(uint64_t block_id, const void* data, size_t len,
   if (out != nullptr) {
     *out = b.meta;
   }
-  record_kv(block_id, kKvOpPublish, len);
   blocks_[block_id] = std::move(b);
   kv_vars().publish_total << 1;
   return 0;
@@ -897,7 +887,6 @@ int KvStore::fetch(uint64_t block_id, uint64_t expected_gen, IOBuf* out) {
     const bool known = tombstones_.find(block_id) != tombstones_.end();
     if (known) {
       kv_vars().stale_total << 1;
-      record_kv(block_id, kKvOpStale, 0);
       return kEKvStale;
     }
     return kEKvMiss;
@@ -905,7 +894,6 @@ int KvStore::fetch(uint64_t block_id, uint64_t expected_gen, IOBuf* out) {
   Block& b = it->second;
   if (b.meta.generation != expected_gen) {
     kv_vars().stale_total << 1;
-    record_kv(block_id, kKvOpStale, b.meta.len);
     return kEKvStale;
   }
   b.touch_seq = ++touch_counter_;
@@ -914,7 +902,6 @@ int KvStore::fetch(uint64_t block_id, uint64_t expected_gen, IOBuf* out) {
                         &kv_serve_deleter, ctx);
   kv_vars().fetch_total << 1;
   kv_vars().fetch_bytes << static_cast<int64_t>(b.meta.len);
-  record_kv(block_id, kKvOpServe, b.meta.len);
   return 0;
 }
 
@@ -1086,7 +1073,6 @@ void KvStore::evict_prefix_locked(const Key128& hash, PrefixTrash* trash) {
   }
   prefix_leases_.erase(b.lease_at);
   trash->take(&b);
-  record_kv(hash.lo, kKvOpEvict, b.meta.len);
   kv_vars().evict_total << 1;
   kv_prefix_counters().dropped << 1;
   prefix_blocks_.erase(it);
@@ -1172,7 +1158,6 @@ bool KvStore::demote_one(std::unique_lock<std::mutex>* lk,
   prefix_hot_bytes_ -= len;
   prefix_cold_bytes_ += len;
   kv_prefix_counters().demote << 1;
-  record_kv(hash.lo, kKvOpDemote, len);
   return true;
 }
 
@@ -1453,7 +1438,6 @@ int KvStore::admit_prefix(const PrefixPage& page, uint32_t depth,
   if (out != nullptr) {
     *out = b.meta;
   }
-  record_kv(hash.lo, kKvOpPublish, len);
   counted.publish_total << 1;
   counted.publish_bytes << static_cast<int64_t>(len);
   if ((page.flags & kKvPrefixState) != 0) {
@@ -1482,7 +1466,6 @@ int KvStore::fetch_prefix(const Key128& hash, uint64_t expected_gen,
     if (prefix_tombstones_.find(hash) != prefix_tombstones_.end()) {
       kv_vars().stale_total << 1;
       counted.fetch_stale << 1;
-      record_kv(hash.lo, kKvOpStale, 0);
       return kEKvStale;
     }
     return kEKvMiss;
@@ -1493,7 +1476,6 @@ int KvStore::fetch_prefix(const Key128& hash, uint64_t expected_gen,
   if (expected_gen != 0 && b->meta.generation != expected_gen) {
     kv_vars().stale_total << 1;
     counted.fetch_stale << 1;
-    record_kv(hash.lo, kKvOpStale, b->meta.len);
     return kEKvStale;
   }
   const uint64_t len = b->meta.len;
@@ -1505,7 +1487,6 @@ int KvStore::fetch_prefix(const Key128& hash, uint64_t expected_gen,
       counted.state_fetch_bytes << static_cast<int64_t>(len);
     }
     kv_vars().fetch_bytes << static_cast<int64_t>(len);
-    record_kv(hash.lo, kKvOpServe, len);
     return 0;
   };
   auto serve_heap = [&](const std::shared_ptr<const KvHeapBlock>& bytes) {
@@ -1533,7 +1514,6 @@ int KvStore::fetch_prefix(const Key128& hash, uint64_t expected_gen,
       }
       if (b->hot) {
         counted.promote << 1;
-        record_kv(hash.lo, kKvOpPromote, len);
       }
     }
   }

@@ -161,15 +161,6 @@ inline constexpr const char* kKvPrefixPutManyMethod = "KvReg.PutPrefixMany";
 inline constexpr const char* kKvPrefixMatchMethod = "KvReg.Match";
 inline constexpr const char* kKvPrefixFetchMethod = "Kv.FetchPrefix";
 
-// timeline kKvBlock `b` op tags (b = op<<56 | len; mirrored by
-// observe.py TIMELINE_KV_OPS and tools/trace_stitch.py).
-constexpr uint64_t kKvOpPublish = 1;
-constexpr uint64_t kKvOpServe = 2;
-constexpr uint64_t kKvOpEvict = 3;
-constexpr uint64_t kKvOpStale = 4;
-constexpr uint64_t kKvOpPromote = 5;  // cold prefix block re-pinned hot
-constexpr uint64_t kKvOpDemote = 6;   // hot prefix block spilled cold
-
 // ---- content addressing (prefix cache, ISSUE 17) -------------------------
 
 // 128-bit content key.  crc32c is taken by the transport checksum

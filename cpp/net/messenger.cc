@@ -16,7 +16,6 @@
 #include "net/stream.h"
 #include "net/rma.h"
 #include "net/stripe.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -181,19 +180,12 @@ struct DispatchBatch {
       // parks this connection's dispatch fiber.  The analysis scope
       // (ISSUE 7) turns any park that slips through into a reported
       // no-pinned-read-fiber violation.
-      const SocketId sid = inline_msg->socket;
-      if (timeline::enabled()) {
-        timeline::record(timeline::kInlineBegin, sid, 0);
-      }
       tls_inline_dispatch = true;
       {
         analysis::ScopedDispatch scope("messenger inline-response window");
         process_parsed_message(inline_msg);
       }
       tls_inline_dispatch = false;
-      if (timeline::enabled()) {
-        timeline::record(timeline::kInlineEnd, sid, 0);
-      }
     }
   }
 };
@@ -203,12 +195,9 @@ struct DispatchBatch {
 // rest via one bulk fiber wakeup).  Order-sensitive frames (streams,
 // auth, in-order protocols) flush the batch first and run inline, so
 // per-connection processing order is exactly the pre-batching order.
-// Returns the number of whole messages cut (the flight recorder's
-// sweep_end cut count).
-size_t cut_and_dispatch(Socket* s, SocketId id) {
+void cut_and_dispatch(Socket* s, SocketId id) {
   IOBuf& buf = s->read_buf();
   DispatchBatch batch;
-  size_t cuts = 0;
   // QoS lane routing (net/qos.h): hoisted flag read — one atomic load
   // per sweep, zero when disabled (the default).
   const int qos_lanes = qos_lane_count();
@@ -246,7 +235,6 @@ size_t cut_and_dispatch(Socket* s, SocketId id) {
     }
     switch (rc) {
       case ParseError::kOk: {
-        ++cuts;
         if (msg->meta.type == RpcMeta::kStreamFrame) {
           // Stream frames keep per-connection arrival order: handled inline
           // (the per-stream ExecutionQueue serializes the user callback).
@@ -342,7 +330,7 @@ size_t cut_and_dispatch(Socket* s, SocketId id) {
       case ParseError::kNotEnoughData:
         free_input_message(msg);
         batch.flush();
-        return cuts;
+        return;
       default:
         LOG(Warning) << "corrupted input on " << endpoint2str(s->remote())
                      << " (pinned=" << s->pinned_protocol << " proto="
@@ -355,11 +343,10 @@ size_t cut_and_dispatch(Socket* s, SocketId id) {
         // Messages cut intact BEFORE the corruption still get delivered.
         batch.flush();
         s->SetFailed(EBADMSG);
-        return cuts;
+        return;
     }
   }
   batch.flush();
-  return cuts;
 }
 
 }  // namespace
@@ -373,11 +360,6 @@ void messenger_on_readable(SocketId id, void* /*ctx*/) {
   }
   const int64_t budget = cut_budget_flag()->int64_value();
   int64_t swept = 0;
-  size_t cuts_total = 0;
-  const bool tl = timeline::enabled();  // hoisted: one load per sweep
-  if (tl) {
-    timeline::record(timeline::kSweepStart, id, 0);
-  }
   while (!s->Failed()) {
     // Bulk hint: a parser that knows the current frame's remainder lets
     // this sweep read it in few large-block readvs instead of 512KB
@@ -389,7 +371,7 @@ void messenger_on_readable(SocketId id, void* /*ctx*/) {
     const ssize_t rc =
         s->transport()->append_to_iobuf(s, &s->read_buf(), want);
     if (rc > 0) {
-      cuts_total += cut_and_dispatch(s, id);
+      cut_and_dispatch(s, id);
       swept += rc;
       if (budget > 0 && swept >= budget) {
         // Cut budget spent: hand the worker to whatever queued behind
@@ -413,9 +395,6 @@ void messenger_on_readable(SocketId id, void* /*ctx*/) {
     }
     s->SetFailed(errno != 0 ? errno : ECONNRESET);
     break;
-  }
-  if (tl) {
-    timeline::record(timeline::kSweepEnd, id, cuts_total);
   }
   s->Dereference();
 }

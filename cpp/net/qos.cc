@@ -12,7 +12,6 @@
 #include "fiber/analysis.h"
 #include "fiber/fiber.h"
 #include "net/protocol.h"
-#include "stat/timeline.h"
 #include "stat/variable.h"
 
 namespace trpc {
@@ -249,15 +248,6 @@ bool drain_all(void (*process)(void*), std::vector<void*>* overflow) {
       }
       any = true;
       lane.deficit += weights[i] * kQuantumUnit;
-      if (timeline::enabled()) {
-        // a = lane | shard cursor at round start << 8; b = the DRR
-        // quantum this round granted the lane.
-        timeline::record(
-            timeline::kQosDrain,
-            static_cast<uint64_t>(i) |
-                (static_cast<uint64_t>(lane.cursor) << 8),
-            static_cast<uint64_t>(weights[i] * kQuantumUnit));
-      }
       while (lane.deficit > 0) {
         InputMessage* m = lane_pop(lane);
         if (m == nullptr) {

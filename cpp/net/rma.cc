@@ -27,7 +27,6 @@
 #include "net/socket.h"
 #include "net/stripe.h"
 #include "stat/reducer.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -661,8 +660,6 @@ struct RailJob {
   IOBuf data;                // this rail's contiguous byte range
   uint32_t first_chunk = 0;
   uint64_t chunk = 0;
-  uint64_t cid = 0;     // timeline correlation
-  uint32_t rail = 0;
   bool crc = false;
   EndPoint peer;
   // Deadline plane (net/deadline.h): polled between chunks; a triggered
@@ -681,7 +678,6 @@ struct RailJob {
 template <typename X>
 void rail_run(RailJob<X>* j) {
   FaultActor& fa = FaultActor::global();
-  const bool tl = timeline::enabled();
   uint32_t ci = j->first_chunk;
   uint64_t off = static_cast<uint64_t>(ci) * j->chunk;
   while (!j->data.empty()) {
@@ -739,15 +735,6 @@ void rail_run(RailJob<X>* j) {
       // to the receiver's acquire bitmap scan.
       j->x->chunk_bits[ci / 64].fetch_or(1ull << (ci % 64),
                                          std::memory_order_release);
-    }
-    if (tl) {
-      // Rail index carries the rma marker bit so Perfetto's rail tracks
-      // show one-sided puts distinctly from ring-copied stripe sends.
-      timeline::record(timeline::kStripeSend, j->cid,
-                       ((timeline::kStripeRmaRailBit |
-                         static_cast<uint64_t>(j->rail))
-                        << 48) |
-                           off);
     }
     ci += 1;
     off += n;
@@ -826,7 +813,7 @@ void run_rails(uint32_t rails, const std::function<void(uint32_t)>& run) {
 // Returns the bytes SKIPPED by a mid-transfer cancel (0 = fully put).
 template <typename X>
 uint64_t put_body(X* x, char* payload_dst, IOBuf&& body, uint64_t chunk,
-                  int rails, uint64_t cid, bool crc, const EndPoint& peer,
+                  int rails, bool crc, const EndPoint& peer,
                   const DeadlineToken& tok) {
   const RailCut cut = cut_rails(body.size(), chunk, rails);
   std::atomic<uint64_t> aborted_bytes{0};
@@ -837,8 +824,6 @@ uint64_t put_body(X* x, char* payload_dst, IOBuf&& body, uint64_t chunk,
     j.dst_base = payload_dst;
     j.first_chunk = i * cut.per;
     j.chunk = chunk;
-    j.cid = cid;
-    j.rail = i;
     j.crc = crc;
     j.peer = peer;
     j.tok = tok;
@@ -1418,15 +1403,12 @@ int rma_try_send(SocketId primary, RpcMeta* meta, IOBuf* body,
         land_off <= tg.data_len && total <= tg.data_len - land_off) {
       RmaDirectXfer* x = desc_of(m, static_cast<uint32_t>(desc - 1));
       const uint64_t chunk = effective_chunk(total, kRmaDirectChunks);
-      if (timeline::enabled()) {
-        timeline::record(timeline::kStripeCut, cid, total);
-      }
       xfer_init(x, total, chunk, crc, cid);
       const uint32_t nchunks =
           static_cast<uint32_t>((total + chunk - 1) / chunk);
       const uint64_t skipped =
           put_body(x, m->base + tg.data_off + land_off, std::move(*body),
-                   chunk, rails, cid, crc, peer, tok);
+                   chunk, rails, crc, peer, tok);
       if (skipped != 0) {
         // Cancelled mid-transfer: no control frame — the receiver
         // never admits the partial put; the caller's fid is already
@@ -1471,15 +1453,12 @@ int rma_try_send(SocketId primary, RpcMeta* meta, IOBuf* body,
     return 1;  // window full: copy path carries this one
   }
   auto* x = reinterpret_cast<RmaXfer*>(m->base + wg.data_off + off);
-  if (timeline::enabled()) {
-    timeline::record(timeline::kStripeCut, cid, total);
-  }
   xfer_init(x, total, chunk, crc, cid);
   const uint32_t nchunks =
       static_cast<uint32_t>((total + chunk - 1) / chunk);
   const uint64_t skipped =
       put_body(x, reinterpret_cast<char*>(x) + kRmaSpanHdr,
-               std::move(*body), chunk, rails, cid, crc, peer, tok);
+               std::move(*body), chunk, rails, crc, peer, tok);
   if (skipped != 0) {
     // Cancelled mid-transfer: reclaim the span now (no control frame
     // will ever admit it) and fail the call whole.
@@ -1630,9 +1609,6 @@ bool rma_resolve(InputMessage* msg, Socket* sock) {
                             m.rma_off, need,
                             static_cast<int>(sock->mode())};
     msg->payload.append_user_data(payload, total, &span_deleter, ctx);
-  }
-  if (timeline::enabled()) {
-    timeline::record(timeline::kStripeDone, m.correlation_id, total);
   }
   hotpath_vars().rma_rx_msgs << 1;
   // The payload is in place: clear the transfer fields (the response

@@ -37,7 +37,6 @@
 #include "net/span.h"
 #include "stat/capture.h"
 #include "stat/slo.h"
-#include "stat/timeline.h"
 #include "net/stream.h"
 #include "net/rma.h"
 #include "net/stripe.h"
@@ -1054,11 +1053,7 @@ void tstd_process_request(InputMessage&& msg) {
     // other clients' work.
     if (srv == nullptr || srv->authenticator() == nullptr ||
         sock->auth_ok.load(std::memory_order_acquire)) {
-      if (cancel_fire(msg.socket, msg.meta.correlation_id) &&
-          timeline::enabled()) {
-        timeline::record(timeline::kDeadline, msg.meta.correlation_id,
-                         timeline::kDeadlineCancelFanout << 56);
-      }
+      cancel_fire(msg.socket, msg.meta.correlation_id);
     }
     return;
   }
@@ -1371,12 +1366,6 @@ void tstd_process_request(InputMessage&& msg) {
     // parse).  Shed with the distinct non-retriable status: executing
     // (or retrying) a dead budget is pure wasted work.
     deadline_vars().shed_total << 1;
-    if (timeline::enabled()) {
-      timeline::record(timeline::kDeadline, cid,
-                       (timeline::kDeadlineShedPreDispatch << 56) |
-                           static_cast<uint64_t>(msg.meta.deadline_us &
-                                                 0xffffffffffffffull));
-    }
     cntl->SetFailed(kEDeadlineExpired,
                     "deadline expired before dispatch: " + method);
     done();
@@ -1428,10 +1417,6 @@ void tstd_process_request(InputMessage&& msg) {
     // queueing class the plane exists to shed: never half-execute work
     // whose caller has already given up.
     deadline_vars().shed_total << 1;
-    if (timeline::enabled()) {
-      timeline::record(timeline::kDeadline, cid,
-                       timeline::kDeadlineShedQueued << 56);
-    }
     cntl->SetFailed(kEDeadlineExpired,
                     "deadline expired in dispatch queue: " + method);
     done();
@@ -1482,10 +1467,6 @@ void tstd_process_request(InputMessage&& msg) {
     // nobody wants is the waste this plane exists to stop.  The scope
     // was never registered, so done() has nothing to unregister.
     deadline_vars().tombstone_shed << 1;
-    if (timeline::enabled()) {
-      timeline::record(timeline::kDeadline, cid,
-                       timeline::kDeadlineCancelFanout << 56);
-    }
     cntl->SetFailed(ECANCELED, "request cancelled before dispatch");
     done();
     return;

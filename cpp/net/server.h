@@ -93,8 +93,8 @@ class Server {
   //   "<tenant>:p99_us=N,avail=P" with tenant "*" as the default clause;
   //   avail is a percent like 99.9.  "" removes.  Call before Start.
   // Returns 0, or -1 on a malformed spec (previous engine kept).
-  // Surfaced by /slo, slo_* vars, timeline event 28 and — with
-  // trpc_fleet_publish on — the naming:// fleet publication.
+  // Surfaced by /slo, slo_* vars and — with trpc_fleet_publish on —
+  // the naming:// fleet publication.
   int SetSlo(const std::string& spec);
   std::shared_ptr<SloEngine> slo_engine() const { return slo_; }
 

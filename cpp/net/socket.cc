@@ -17,7 +17,6 @@
 #include "net/hotpath_stats.h"
 #include "net/protocol.h"
 #include "net/dispatcher.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -504,11 +503,6 @@ int Socket::Write(IOBuf&& data, bool close_after) {
     abort_writer(ECONNRESET);
     return -1;
   }
-  if (timeline::enabled()) {
-    // The wait-free fast path ends here: the role (and any EAGAIN
-    // leftovers) hand off to a KeepWrite fiber.
-    timeline::record(timeline::kWriterHandoff, id(), 0);
-  }
   fiber_start(nullptr, &Socket::keep_write_thunk, self,
               kFiberUrgent | fiber_tag_flags(worker_tag));
   return 0;
@@ -544,11 +538,6 @@ size_t Socket::drain_queue_into_pending() {
   if (hotpath_sample16()) {
     hv.write_coalesce_batch << static_cast<int64_t>(n);
   }
-  if (timeline::enabled() && n > 1) {
-    // Coalesce depth > 1 is the interesting signal (a writer absorbed
-    // concurrent producers); depth-1 drains are every uncontended write.
-    timeline::record(timeline::kWriteCoalesce, id(), n);
-  }
   return n;
 }
 
@@ -580,7 +569,6 @@ bool Socket::try_inline_write() {
   }
   HotPathVars& hv = hotpath_vars();
   hv.inline_write_attempts << 1;
-  uint64_t flushed = 0;  // bytes cut inline (the write_flush event arg)
   // Bounded rounds: an inline writer should flush what WAS queued, not
   // become an unwitting forever-writer for every concurrent producer.
   for (int round = 0; round < 4; ++round) {
@@ -597,9 +585,6 @@ bool Socket::try_inline_write() {
       }
       if (release_writer_role()) {
         hv.inline_write_hits << 1;
-        if (timeline::enabled() && flushed > 0) {
-          timeline::record(timeline::kWriteFlush, id(), flushed);
-        }
         return true;
       }
       continue;  // late node adopted with the role
@@ -615,7 +600,6 @@ bool Socket::try_inline_write() {
         transport_->flush(this);
         return false;
       }
-      flushed += static_cast<uint64_t>(rc);
     }
     transport_->flush(this);
     if (pending_close_) {

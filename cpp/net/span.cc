@@ -10,9 +10,7 @@
 #include "base/json.h"
 #include "base/rand.h"
 #include "base/time.h"
-#include "fiber/fiber.h"
 #include "fiber/scheduler.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -99,25 +97,13 @@ Flag* rpcz_ring_size_flag() {
 // Ambient (fiber-local) trace context, stored by VALUE directly on the
 // FiberMeta (two relaxed-atomic u64 fields — no per-RPC allocation, no
 // destructor, and the Span object may die before a child fiber reads
-// the context).  Moved off FLS slots in ISSUE 9: the timeline recorder's
-// scheduler-side events (ready/wake, emitted on the WAKER's thread) must
-// read the TARGET fiber's context, which thread-keyed fls_get cannot
-// serve.
+// the context).
 
 // Off-fiber fallback: ctypes callers (Python threads) have no fiber
 // context, but must still be able to install a trace around their sync
 // calls — trpc_trace_set / trpc_span_start land here.
 thread_local uint64_t tls_ambient_trace = 0;
 thread_local uint64_t tls_ambient_span = 0;
-
-// Register the ambient context as the flight recorder's context reader
-// (stat/timeline.h): every timeline event carries the trace/span of the
-// fiber (or pthread) that emitted it.  Safe at static init — the hook
-// slot is a constant-initialized atomic.
-[[maybe_unused]] const bool g_timeline_ctx_hook = [] {
-  timeline::set_context_reader(&get_ambient_trace);
-  return true;
-}();
 
 }  // namespace
 
@@ -136,7 +122,6 @@ Span* start_span(bool server_side, const std::string& method,
   auto* s = new Span();
   s->server_side = server_side;
   s->method = method;
-  s->fid = fiber_self();  // exact span↔timeline join key (0 off-fiber)
   s->start_us = monotonic_time_us();
   s->span_id = new_span_id();
   if (trace_id != 0) {
@@ -190,8 +175,7 @@ void set_ambient_trace(uint64_t trace_id, uint64_t span_id) {
   Worker* w = tls_worker;
   if (w != nullptr && w->current() != nullptr) {
     // Relaxed: same-fiber reads are program-ordered (migration rides the
-    // scheduler's queue handoff); cross-thread timeline reads tolerate a
-    // stale snapshot (see scheduler.h).
+    // scheduler's queue handoff; see scheduler.h).
     w->current()->ambient_trace.store(trace_id, std::memory_order_relaxed);
     w->current()->ambient_span.store(span_id, std::memory_order_relaxed);
   } else {
@@ -258,7 +242,6 @@ std::string rpcz_dump_json(size_t limit, uint64_t trace_id) {
     j.set("trace_id", Json::str(hex_id(s.trace_id)));
     j.set("span_id", Json::str(hex_id(s.span_id)));
     j.set("parent_span_id", Json::str(hex_id(s.parent_span_id)));
-    j.set("fid", Json::str(hex_id(s.fid)));
     j.set("side", Json::str(s.server_side ? "server" : "client"));
     j.set("method", Json::str(s.method));
     j.set("start_us", Json::number(static_cast<double>(s.start_us)));

@@ -27,10 +27,6 @@ struct Span {
   uint64_t trace_id = 0;
   uint64_t span_id = 0;
   uint64_t parent_span_id = 0;
-  // Fiber the span was started on (0 off-fiber) — makes the
-  // span↔timeline join exact: a timeline fiber_run slice with the same
-  // fid IS this span's execution, no timestamp inference needed.
-  uint64_t fid = 0;
   bool server_side = false;
   std::string method;
   int64_t start_us = 0;

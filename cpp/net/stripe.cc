@@ -18,7 +18,6 @@
 #include "fiber/fiber.h"
 #include "net/hotpath_stats.h"
 #include "net/socket.h"
-#include "stat/timeline.h"
 
 namespace trpc {
 
@@ -296,9 +295,6 @@ void maybe_finalize(const std::shared_ptr<StripeEntry>& e) {
     }
     e->dispatched = true;
   }
-  if (timeline::enabled()) {
-    timeline::record(timeline::kStripeDone, e->id, e->total);
-  }
   {
     std::lock_guard<std::mutex> g(map_mu());
     drop_entry_locked(e);
@@ -320,9 +316,6 @@ void land_job_run(LandJob* j) {
   // e->dest after reclaim would scribble freed arena memory.
   if (!e->abandoned.load(std::memory_order_acquire)) {
     j->data.copy_to(e->dest + j->offset, n);
-  }
-  if (timeline::enabled()) {
-    timeline::record(timeline::kStripeLand, e->id, j->offset);
   }
   j->data.clear();  // release parse-buffer blocks before the dispatch
   const uint64_t landed =
@@ -407,10 +400,6 @@ int stripe_send(SocketId primary, const std::vector<SocketId>& rails,
   const uint64_t total = body.size();
   const uint64_t chunk =
       std::max<uint64_t>(64 << 10, stripe_chunk_bytes());
-  const bool tl = timeline::enabled();  // hoisted: one load per message
-  if (tl) {
-    timeline::record(timeline::kStripeCut, stripe_id, total);
-  }
   meta.stripe_id = stripe_id;
   meta.stripe_offset = 0;
   meta.stripe_total = total;
@@ -428,11 +417,6 @@ int stripe_send(SocketId primary, const std::vector<SocketId>& rails,
     SocketRef p(Socket::Address(primary));
     if (!p || p->Write(std::move(frame)) != 0) {
       return -1;
-    }
-    if (tl) {
-      // Head rides the primary, never a numbered rail.
-      timeline::record(timeline::kStripeSend, stripe_id,
-                       timeline::kStripePrimaryRail << 48);
     }
   }
   uint64_t off = chunk;
@@ -459,9 +443,6 @@ int stripe_send(SocketId primary, const std::vector<SocketId>& rails,
       cm.checksum = crc32c(piece);
     }
     ++nchunks;
-    uint64_t tl_rail =
-        rails.empty() ? timeline::kStripePrimaryRail
-                      : static_cast<uint64_t>(rail_i % rails.size());
     const SocketId rid =
         rails.empty() ? primary : rails[rail_i++ % rails.size()];
     bool sent = false;
@@ -483,14 +464,6 @@ int stripe_send(SocketId primary, const std::vector<SocketId>& rails,
       if (!p || p->Write(std::move(frame)) != 0) {
         return -1;  // primary gone: the whole call fails, cleanly
       }
-      tl_rail = timeline::kStripePrimaryRail;  // dead rail: retried there
-    }
-    if (tl) {
-      // Recorded AFTER the send resolved so the event names the rail
-      // the chunk actually traveled; b packs (rail << 48 | offset) —
-      // totals are capped at kMaxStripeTotal (3GB), far inside 48 bits.
-      timeline::record(timeline::kStripeSend, cm.stripe_id,
-                       (tl_rail << 48) | cm.stripe_offset);
     }
   }
   hotpath_vars().stripe_tx_chunks << static_cast<int64_t>(nchunks);

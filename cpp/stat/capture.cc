@@ -16,7 +16,6 @@
 #include "base/recordio.h"
 #include "base/time.h"
 #include "stat/reducer.h"
-#include "stat/timeline.h"
 #include "stat/variable.h"
 
 namespace trpc {
@@ -33,11 +32,6 @@ constexpr size_t kMaxStringBytes = 64;
 constexpr uint8_t kRecordVersion = 1;
 // Fixed-width prefix of a serialized record before the two strings.
 constexpr size_t kRecordFixedBytes = 68;
-
-// Timeline event 26 ops (high byte of b).
-constexpr uint64_t kOpKeep = 1;
-constexpr uint64_t kOpDrop = 2;
-constexpr uint64_t kOpDump = 3;
 
 uint64_t splitmix64(uint64_t x) {
   x += 0x9e3779b97f4a7c15ULL;
@@ -461,7 +455,7 @@ Json record_json(const Sample& s) {
 }
 
 // Eager registration: /flags can list+flip trpc_capture and /vars shows
-// the zeroed series before any traffic (same pattern as trpc_timeline).
+// the zeroed series before any traffic.
 [[maybe_unused]] const bool g_capture_eager = [] {
   ensure_registered();
   return true;
@@ -494,8 +488,6 @@ void record(Sample&& s) {
   const int64_t permille = sample_permille_flag()->int64_value();
   const size_t cap = std::max<int64_t>(
       256, max_records_flag()->int64_value());
-  const uint64_t trace = s.trace_id;
-  const uint64_t req_bytes = s.request_bytes;
   bool kept = false;
   {
     Buf& b = buf();
@@ -540,16 +532,10 @@ void record(Sample&& s) {
           splitmix64(seed ^ (idx * 0x9e3779b97f4a7c15ULL)) % st.seen;
       if (j < st.recs.size()) {
         st.recs[j] = std::move(s);
-        kept = true;
       }
       vars()->dropped << 1;  // exactly one record (old or new) dropped
       b.w_dropped++;
     }
-  }
-  if (timeline::enabled()) {
-    timeline::record(timeline::kCapture, trace,
-                     ((kept ? kOpKeep : kOpDrop) << 56) |
-                         (req_bytes & 0x00ffffffffffffffULL));
   }
 }
 
@@ -611,7 +597,7 @@ std::string dump_json(size_t max_records) {
   const WindowSnapshot w = snapshot();
   Json root = Json::object();
   root.set("pid", Json::number(getpid()));
-  // Mono/wall pair read back-to-back (same contract as timeline): maps
+  // Mono/wall pair read back-to-back (same contract as rpcz): maps
   // this node's monotonic arrival times onto wall clock.
   root.set("now_mono_us",
            Json::number(static_cast<double>(monotonic_time_us())));
@@ -695,11 +681,6 @@ int64_t dump_file(const std::string& path) {
     }
   }
   writer.flush();
-  if (timeline::enabled()) {
-    timeline::record(timeline::kCapture, 0,
-                     (kOpDump << 56) |
-                         (w.recs.size() & 0x00ffffffffffffffULL));
-  }
   return static_cast<int64_t>(w.recs.size());
 }
 

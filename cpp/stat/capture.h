@@ -21,7 +21,7 @@
 // `capture_dropped_total` — a capture that silently thins would lie
 // about coverage and poison every downstream regression run.
 //
-// Off-cost contract (same as trpc_timeline / trpc_analysis): with the
+// Off-cost contract (same as trpc_analysis): with the
 // flag off every hook is one relaxed atomic load + branch, and the
 // capture_* vars are provably frozen at 0.
 //

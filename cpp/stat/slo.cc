@@ -13,7 +13,6 @@
 #include "stat/digest.h"
 #include "stat/latency_recorder.h"
 #include "stat/reducer.h"
-#include "stat/timeline.h"
 #include "stat/variable.h"
 
 namespace trpc {
@@ -84,7 +83,7 @@ Flag* slo_flag() {
         "trpc_slo", false,
         "per-tenant SLO engine: windowed attainment + multi-window "
         "error-budget burn rates over Server::SetSlo targets, surfaced "
-        "as slo_* vars, /slo, timeline event 28 and the naming:// fleet "
+        "as slo_* vars, /slo and the naming:// fleet "
         "publication (default off; flag-off cost is one relaxed load "
         "per response and every slo_* var stays frozen at 0)");
     if (flag != nullptr) {
@@ -177,15 +176,6 @@ int64_t fast_window_ms() { return fast_window_flag()->int64_value(); }
 int64_t slow_window_ms() { return slow_window_flag()->int64_value(); }
 double burn_alert() { return burn_alert_flag()->double_value(); }
 
-uint64_t tenant_hash(const std::string& name) {
-  uint64_t h = 1469598103934665603ULL;  // FNV-1a (matches tuner::knob_hash)
-  for (char c : name) {
-    h ^= static_cast<uint8_t>(c);
-    h *= 1099511628211ULL;
-  }
-  return h;
-}
-
 uint64_t breach_total() {
   return static_cast<uint64_t>(slo_vars()->breaches.get_value());
 }
@@ -263,7 +253,6 @@ struct BurnWindow {
 
 struct SloEngine::Entry {
   std::string name;
-  uint64_t hash = 0;
   int64_t p99_target_us = 0;  // INT64_MAX when only avail was declared
   double avail_target = 0;    // fraction, e.g. 0.999
   double allowed = 0;         // error budget = max(1 - avail_target, 1e-6)
@@ -291,8 +280,8 @@ namespace {
 
 // Breach-state transition: both windows must burn to fire; either window
 // recovering clears (fast recovers within one fast window of the fault
-// ending — the "clear within one fast window" contract).  Only EDGES emit
-// timeline event 28 / bump slo_breach_total.
+// ending — the "clear within one fast window" contract).  Only EDGES
+// bump slo_breach_total.
 void evaluate_breach(SloEngine::Entry* e, double burn_fast,
                      double burn_slow);
 
@@ -341,12 +330,6 @@ void evaluate_breach(SloEngine::Entry* e, double burn_fast,
   }
   if (now_breached) {
     slo::slo_vars()->breaches << 1;
-  }
-  if (timeline::enabled()) {
-    const uint64_t op = now_breached ? 1 : 2;
-    const uint64_t milli = static_cast<uint64_t>(std::min(
-        burn_fast * 1000.0, static_cast<double>((uint64_t{1} << 56) - 1)));
-    timeline::record(timeline::kSloBreach, e->hash, (op << 56) | milli);
   }
 }
 
@@ -470,7 +453,6 @@ std::shared_ptr<SloEngine> SloEngine::parse(const std::string& spec,
       *err = "clause declares no target: " + clause;
       return nullptr;
     }
-    entry->hash = slo::tenant_hash(entry->name);
     entry->allowed = std::max(1.0 - entry->avail_target, 1e-6);
     entry->fast.init(fast_ms);
     entry->slow.init(slow_ms);

@@ -1,8 +1,8 @@
 // Per-tenant SLO engine (ISSUE 19) — windowed attainment tracking with
 // multi-window error-budget burn rates, fed from the server dispatch path
-// and surfaced as slo_* vars, the /slo builtin, timeline event 28
-// (slo_breach), and the fleet publication blob the Announcer pushes over
-// naming:// (see stat/digest.h digest-wire 2).
+// and surfaced as slo_* vars, the /slo builtin, and the fleet
+// publication blob the Announcer pushes over naming:// (see
+// stat/digest.h digest-wire 2).
 //
 // Model (SRE multi-window multi-burn-rate alerting): a response is BAD
 // when it errors or exceeds the tenant's p99 latency target; the error
@@ -13,8 +13,7 @@
 // per window.  A breach requires BOTH burns >= trpc_slo_burn_alert:
 // the slow window proves sustained damage, the fast window proves it is
 // still happening — and lets the alert clear within one fast window of
-// recovery.  Transitions (and only transitions) emit timeline event 28
-// and bump slo_breach_total.
+// recovery.  Breach edges (and only edges) bump slo_breach_total.
 //
 // Gating: everything is behind the default-off reloadable `trpc_slo`
 // flag.  Flag off, the dispatch hook is ONE relaxed atomic load — no
@@ -50,10 +49,6 @@ void ensure_registered();
 int64_t fast_window_ms();
 int64_t slow_window_ms();
 double burn_alert();
-
-// FNV-1a of the tenant name — the `a` field of timeline event 28, so
-// stitched traces can correlate breaches to qos_tenant_* tracks.
-uint64_t tenant_hash(const std::string& name);
 
 // Lifetime count of breach EDGES (fires), across all engines.
 uint64_t breach_total();

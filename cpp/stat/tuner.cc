@@ -18,7 +18,6 @@
 #include "base/flags.h"
 #include "base/json.h"
 #include "base/time.h"
-#include "stat/timeline.h"
 #include "stat/variable.h"
 
 namespace trpc {
@@ -101,9 +100,8 @@ Flag* tuner_flag() {
         "self-tuning controller: samples the var surfaces and drives "
         "per-knob feedback rules (hill-climb/AIMD with hysteresis, "
         "cooldown, revert-on-regression + freeze) through the validated "
-        "flag-reload path; decisions journal to /tuner and emit "
-        "tuner_decision timeline events (default off; while off no "
-        "thread runs and nothing is sampled)");
+        "flag-reload path; decisions journal to /tuner (default off; "
+        "while off no thread runs and nothing is sampled)");
     if (flag != nullptr) {
       flag->set_validator([](const std::string& v) {
         return v == "true" || v == "false" || v == "1" || v == "0" ||
@@ -446,17 +444,11 @@ void journal_decision(Engine& e, const std::string& knob, int64_t old_num,
     e.journal.pop_front();
   }
   // Relaxed: pure telemetry counters.  Only APPLIED changes count —
-  // reverts/freezes journal too (and emit timeline events) but have
-  // their own counters; tuner_decisions_total must mean "the tuner
-  // retuned something", not "the journal grew".
+  // reverts/freezes journal too but have their own counters;
+  // tuner_decisions_total must mean "the tuner retuned something", not
+  // "the journal grew".
   if (strcmp(action, "apply") == 0) {
     e.decisions.fetch_add(1, std::memory_order_relaxed);
-  }
-  if (timeline::enabled()) {
-    timeline::record(
-        timeline::kTunerDecision, knob_hash(knob),
-        ((static_cast<uint64_t>(old_num) & 0xffffffffull) << 32) |
-            (static_cast<uint64_t>(new_num) & 0xffffffffull));
   }
 }
 
@@ -912,7 +904,7 @@ void start_loop_if_needed() {
 }
 
 // Eager registration: /flags can list+flip trpc_tuner before traffic
-// (same pattern as the timeline/stripe eager definitions).
+// (same pattern as the stripe eager definitions).
 [[maybe_unused]] const bool g_tuner_eager = [] {
   ensure_registered();
   return true;
@@ -942,15 +934,6 @@ int add_rule(const Rule& r) {
     return 0;
   }
   return install_rule(e, r, /*quiet=*/false) ? 0 : -1;
-}
-
-uint64_t knob_hash(const std::string& name) {
-  uint64_t h = 1469598103934665603ull;  // FNV-1a 64
-  for (const char c : name) {
-    h ^= static_cast<unsigned char>(c);
-    h *= 1099511628211ull;
-  }
-  return h;
 }
 
 std::string dump_json(size_t limit) {

@@ -3,9 +3,9 @@
 //
 // The runtime carries ~30 validated reloadable flags (stripe rails and
 // chunk size, QoS lane weights, messenger cut budget, rma window,
-// collective chunk/inflight ...) and, since the flight recorder, the
-// vars to see exactly where time goes — but every number was hand-tuned
-// per box, which no production fleet does.  This tier closes the loop:
+// collective chunk/inflight ...) and the vars to see where time goes —
+// but every number was hand-tuned per box, which no production fleet
+// does.  This tier closes the loop:
 // a control loop on its own background thread (never a dispatch fiber —
 // tuning must not compete with the traffic it tunes, and it must run in
 // fiber-less client processes too) samples the existing var surfaces on
@@ -33,12 +33,10 @@
 // correctly-tuned box is never perturbed); and at most ONE knob change
 // per evaluation window process-wide, so attribution stays clean.
 //
-// Every decision lands twice: a structured journal entry served by
-// /tuner (and trpc_tuner_dump), and a `tuner_decision` timeline event
-// (a = knob_hash(name), b = old<<32|new) so a tuning run is itself a
-// Perfetto artifact via tools/trace_stitch.py --timeline.
+// Every decision lands in a structured journal served by /tuner (and
+// trpc_tuner_dump).
 //
-// Flag-off contract (same as trpc_analysis / trpc_timeline): default
+// Flag-off contract (same as trpc_analysis): default
 // off; while off, no thread runs, nothing is sampled, every tuner var
 // is provably frozen at 0, and no flag is ever touched.
 #pragma once
@@ -109,10 +107,6 @@ bool enabled();
 // when the knob is not a defined reloadable flag.  Built-in rules are
 // installed automatically on the first tick.
 int add_rule(const Rule& r);
-
-// FNV-1a 64 of the knob name — the `a` payload of tuner_decision
-// timeline events.
-uint64_t knob_hash(const std::string& name);
 
 // The /tuner body: {"enabled", counters, "rules": [...], "inputs":
 // {...}, "decisions": [newest `limit` entries, oldest first]}.  Served

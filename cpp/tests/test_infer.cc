@@ -6,8 +6,8 @@
 // same step, the chaos case (mid-stream disconnect under svr_delay
 // aborts remote prefix fetches whole-or-nothing, credits
 // deadline_cancel_saved_bytes, wedges nothing), per-tenant typed
-// shedding under overload, flag-bound validation, and token_step
-// timeline events.  Runs under TSan + ASan via tests/test_cpp.py.
+// shedding under overload, flag-bound validation, and the lifecycle
+// counters of one request.  Runs under TSan + ASan via tests/test_cpp.py.
 #include "net/infer.h"
 
 #include <unistd.h>
@@ -27,7 +27,6 @@
 #include "net/kvstore.h"
 #include "net/server.h"
 #include "net/stream.h"
-#include "stat/timeline.h"
 #include "tests/test_util.h"
 
 using namespace trpc;
@@ -551,30 +550,27 @@ TEST_CASE(infer_flag_bounds_validated) {
   reset_infer_flags();
 }
 
-TEST_CASE(infer_timeline_token_step_events) {
+TEST_CASE(infer_eos_request_moves_lifecycle_counters) {
   reset_infer_flags();
-  timeline::ensure_registered();
-  EXPECT_EQ(Flag::set("trpc_timeline", "true"), 0);
-  timeline::reset();
   Serving s;
   make_serving(&s);
   Channel ch;
   EXPECT_EQ(ch.Init(addr_of(s)), 0);
+  const int64_t admitted0 = infer_vars().admitted_total.get_value();
+  const int64_t tokens0 = infer_vars().tokens_total.get_value();
+  const int64_t done0 = infer_vars().done_total.get_value();
+  const int64_t cancelled0 = infer_vars().cancelled_total.get_value();
 
   TokenClient c = submit(&ch, make_prompt(40, 4), 4);
   EXPECT(c.ok);
   EXPECT(c.wait_closed());
   EXPECT_EQ(wait_live_zero(s.sched), 0);
 
-  // admit + prefill_done + 4 tokens + eos = 7 token_step events.
-  const std::string dump = timeline::dump_json(1 << 16);
-  size_t count = 0;
-  for (size_t pos = dump.find("\"token_step\""); pos != std::string::npos;
-       pos = dump.find("\"token_step\"", pos + 1)) {
-    ++count;
-  }
-  EXPECT(count >= 7);
-  EXPECT_EQ(Flag::set("trpc_timeline", "false"), 0);
+  // One admission, four tokens, one EOS finish, no cancel.
+  EXPECT_EQ(infer_vars().admitted_total.get_value() - admitted0, 1);
+  EXPECT_EQ(infer_vars().tokens_total.get_value() - tokens0, 4);
+  EXPECT_EQ(infer_vars().done_total.get_value() - done0, 1);
+  EXPECT_EQ(infer_vars().cancelled_total.get_value(), cancelled0);
 }
 
 TEST_MAIN

@@ -4,7 +4,7 @@
 // merge-vs-pooled-oracle property (fleet percentiles from octave-wise
 // sample pooling stay within the recorder's one-octave bound of a
 // single recorder that saw all the traffic), spec parsing, compressed-
-// window burn-rate breach fire + clear with timeline event 28 edges,
+// window burn-rate breach fire + clear counted on edges only,
 // the fleet blob roundtrip, and in-process Announcer publication +
 // /fleet merge over a live naming registry.  Rides TSan/ASan via
 // tests/test_cpp.py with zero new suppressions.
@@ -27,7 +27,6 @@
 #include "net/server.h"
 #include "stat/digest.h"
 #include "stat/latency_recorder.h"
-#include "stat/timeline.h"
 #include "stat/variable.h"
 #include "tests/test_util.h"
 
@@ -268,14 +267,10 @@ TEST_CASE(slo_burn_breach_fires_and_clears) {
   FlagGuard fast("trpc_slo_fast_window_ms", "300");
   FlagGuard slow("trpc_slo_slow_window_ms", "1200");
   FlagGuard on("trpc_slo", "true");
-  FlagGuard tl("trpc_timeline", "true");
-  timeline::ensure_registered();
-  timeline::reset();
 
   std::string err;
   auto e = SloEngine::parse("tenantA:p99_us=2000,avail=99.0", &err);
   EXPECT(e != nullptr);
-  const uint64_t h = slo::tenant_hash("tenantA");
   const uint64_t breaches_before = slo::breach_total();
 
   // Sustained damage: every response blows the latency target, so both
@@ -301,39 +296,8 @@ TEST_CASE(slo_burn_breach_fires_and_clears) {
   }
   EXPECT(!e->any_breached());
 
-  // Both transition EDGES (and only edges) hit the flight recorder:
-  // one breach (op 1) and one clear (op 2), a = FNV-1a(tenant).
-  Json root;
-  EXPECT(Json::parse(timeline::dump_json(1 << 14), &root));
-  const Json* threads = root.find("threads");
-  EXPECT(threads != nullptr);
-  int fired = 0, cleared = 0;
-  for (size_t i = 0; i < threads->size(); ++i) {
-    const Json* evs = (*threads)[i].find("events");
-    for (size_t j = 0; j < evs->size(); ++j) {
-      const Json& ev = (*evs)[j];
-      if (static_cast<int>(ev.find("type")->as_number()) !=
-          timeline::kSloBreach) {
-        continue;
-      }
-      const uint64_t a =
-          strtoull(ev.find("a")->as_string().c_str(), nullptr, 16);
-      const uint64_t b =
-          strtoull(ev.find("b")->as_string().c_str(), nullptr, 16);
-      EXPECT_EQ(a, h);
-      const uint64_t op = b >> 56;
-      if (op == 1) {
-        ++fired;
-        // burn milli in the low bits: 100x burn = 100000 milli.
-        EXPECT((b & ((uint64_t{1} << 56) - 1)) >= 2000);
-      } else {
-        EXPECT_EQ(op, 2u);
-        ++cleared;
-      }
-    }
-  }
-  EXPECT_EQ(fired, 1);
-  EXPECT_EQ(cleared, 1);
+  // Clearing is an edge too, but not a breach: the count stays.
+  EXPECT_EQ(slo::breach_total(), breaches_before + 1);
 }
 
 TEST_CASE(slo_error_responses_burn_budget) {

@@ -3,8 +3,8 @@
 // touched), convergence from a deliberately-wrong knob on a synthetic
 // metric, the revert-on-regression guard + freeze/backoff, bounds
 // clamping (the validated set path is never even offered an
-// out-of-range value), journal/timeline agreement (every decision is
-// both a /tuner journal entry and a tuner_decision event), and the
+// out-of-range value), journal/var agreement (every journaled action
+// moves its own counter, and a knob's entries chain), and the
 // background control loop's tick/stop behavior.  Also runs under TSan
 // via tests/test_cpp.py — the control loop races live /vars and /tuner
 // dumps by design.
@@ -24,7 +24,6 @@
 
 #include "base/flags.h"
 #include "base/json.h"
-#include "stat/timeline.h"
 #include "stat/variable.h"
 #include "tests/test_util.h"
 
@@ -283,76 +282,59 @@ TEST_CASE(tuner_bounds_clamping_never_offers_invalid_values) {
   EXPECT_EQ(tuner::add_rule(bad3), -1);
 }
 
-TEST_CASE(tuner_journal_and_timeline_agree) {
+TEST_CASE(tuner_journal_and_vars_agree) {
   set_tuner(false);
   tuner::reset_for_test();
-  timeline::ensure_registered();
-  timeline::reset();
   deterministic_mode();
-  Flag* knob = test_knob("trpc_tuner_test_tl", 64, 1, 4096);
-  EXPECT_EQ(Flag::set("trpc_tuner_test_tl", "8"), 0);
+  Flag* knob = test_knob("trpc_tuner_test_journal", 64, 1, 4096);
+  EXPECT_EQ(Flag::set("trpc_tuner_test_journal", "8"), 0);
   IntGauge metric;
-  metric.expose("tuner_test_tl_metric", "synthetic tuner tl metric");
+  metric.expose("tuner_test_journal_metric", "synthetic tuner journal metric");
   tuner::Rule r;
-  r.knob = "trpc_tuner_test_tl";
+  r.knob = "trpc_tuner_test_journal";
   r.mode = tuner::Mode::kHillClimb;
-  r.target = "tuner_test_tl_metric";
+  r.target = "tuner_test_journal_metric";
   r.target_is_level = true;
   r.step_mul = 2.0;
   EXPECT_EQ(tuner::add_rule(r), 0);
-  EXPECT_EQ(Flag::set("trpc_timeline", "true"), 0);
   set_tuner(true);
   for (int i = 0; i < 24; ++i) {
     metric.set(peak_metric(knob->int64_value()));
     EXPECT_EQ(tuner::tick_once_for_test(), 0);
   }
   set_tuner(false);
-  EXPECT_EQ(Flag::set("trpc_timeline", "false"), 0);
-  // Every journal entry for this knob has a matching tuner_decision
-  // event: a = knob_hash, b = (old & 0xffffffff) << 32 | (new &
-  // 0xffffffff).
+  // Each journaled action moves exactly its own counter, and the
+  // counters read the same through the exposed vars.
   const auto js = journal_entries();
+  size_t applies = 0;
+  size_t reverts = 0;
+  size_t freezes = 0;
+  for (const Entry& e : js) {
+    applies += e.action == "apply" ? 1 : 0;
+    reverts += e.action == "revert" ? 1 : 0;
+    freezes += e.action == "freeze" ? 1 : 0;
+  }
+  EXPECT_EQ(applies + reverts + freezes, js.size());
+  EXPECT_EQ(tuner::decisions_total(), applies);
+  EXPECT_EQ(tuner::reverts_total(), reverts);
+  EXPECT_EQ(tuner::freezes_total(), freezes);
+  std::string v;
+  EXPECT(Variable::read_exposed("tuner_decisions_total", &v));
+  EXPECT(v == std::to_string(applies));
+  // The knob's entries chain: each starts where the previous left the
+  // flag, from the value set above to the value the flag holds now.
+  int64_t at = 8;
   size_t jn = 0;
   for (const Entry& e : js) {
-    jn += e.knob == "trpc_tuner_test_tl" ? 1 : 0;
-  }
-  EXPECT(jn >= 2);
-  Json root;
-  EXPECT(Json::parse(timeline::dump_json(1 << 16), &root));
-  const Json* threads = root.find("threads");
-  EXPECT(threads != nullptr);
-  const uint64_t want_a = tuner::knob_hash("trpc_tuner_test_tl");
-  std::vector<uint64_t> tl_b;
-  for (size_t i = 0; i < threads->size(); ++i) {
-    const Json* evs = (*threads)[i].find("events");
-    for (size_t j = 0; j < evs->size(); ++j) {
-      const Json& e = (*evs)[j];
-      if (static_cast<uint32_t>(e.find("type")->as_number()) !=
-          timeline::kTunerDecision) {
-        continue;
-      }
-      const uint64_t a =
-          strtoull(e.find("a")->as_string().c_str(), nullptr, 16);
-      if (a != want_a) {
-        continue;  // decisions for other knobs (other cases' residue)
-      }
-      tl_b.push_back(
-          strtoull(e.find("b")->as_string().c_str(), nullptr, 16));
-    }
-  }
-  EXPECT_EQ(tl_b.size(), jn);
-  size_t k = 0;
-  for (const Entry& e : js) {
-    if (e.knob != "trpc_tuner_test_tl") {
+    if (e.knob != "trpc_tuner_test_journal") {
       continue;
     }
-    const uint64_t want_b =
-        ((static_cast<uint64_t>(e.old_num) & 0xffffffffull) << 32) |
-        (static_cast<uint64_t>(e.new_num) & 0xffffffffull);
-    EXPECT_EQ(tl_b[k], want_b);
-    ++k;
+    EXPECT_EQ(e.old_num, at);
+    at = e.new_num;
+    ++jn;
   }
-  timeline::reset();
+  EXPECT(jn >= 2);
+  EXPECT_EQ(at, knob->int64_value());
   metric.hide();
 }
 

@@ -14,8 +14,8 @@ Covers:
 - chaos composition: chunk drops fail runs whole-or-nothing (no member
   ever reports success with torn bytes), sessions quiesce, and the same
   fleet recovers byte-exact after the faults clear;
-- observability: coll_step timeline events with the op tag, coll_* vars
-  moving, and the per-op step latency recorders registered with HELP.
+- observability: coll_* vars moving, and the per-op step latency
+  recorders registered with HELP.
 """
 
 import json
@@ -31,6 +31,16 @@ import pytest
 
 from brpc_tpu.rpc import (Channel, Server, collective, fault, get_flag,
                           observe, rma, set_flag)
+
+
+def _assert_sessions_drained(timeout_s: float = 5.0) -> None:
+    """sessions_live is process-global: another test's fleet on this
+    worker may still be finishing its last completions.  Waits up to
+    `timeout_s` for the count to drain, then asserts it is exactly 0."""
+    deadline = time.time() + timeout_s
+    while collective.sessions_live() != 0 and time.time() < deadline:
+        time.sleep(0.05)
+    assert collective.sessions_live() == 0
 
 
 class Fleet:
@@ -114,7 +124,7 @@ def test_all_gather_byte_exact_and_one_sided():
         # The MB-scale pulls rode the one-sided plane (direct landings
         # resolve as rma messages), not the frame path.
         assert observe.Vars.dump().get("rma_rx_msgs", 0) > rx0
-        assert collective.sessions_live() == 0
+        _assert_sessions_drained()
     finally:
         fleet.close()
 
@@ -291,7 +301,7 @@ def test_chaos_chunk_faults_whole_or_nothing_and_scavenge():
                     assert np.array_equal(
                         got[src * shard:(src + 1) * shard], want), \
                         f"rank {r} reported success with torn shard {src}"
-        assert collective.sessions_live() == 0
+        _assert_sessions_drained()
         # Scavenge any span whose control frame the chaos dropped; after
         # two aged passes the windows must be clean.
         collective.rma_scavenge()
@@ -312,10 +322,8 @@ def test_chaos_chunk_faults_whole_or_nothing_and_scavenge():
         fleet.close()
 
 
-def test_coll_step_timeline_and_vars():
+def test_coll_step_vars():
     n, shard = 3, 256 << 10
-    observe.enable_timeline(True)
-    observe.reset_timeline()
     fleet = Fleet(n)
     try:
         sends = [rma.RmaBuffer(shard) for _ in range(n)]
@@ -334,14 +342,7 @@ def test_coll_step_timeline_and_vars():
         # series — lint guards the HELP, this guards the feed).
         stats = observe.Latency.read("coll_step_all_gather")
         assert stats.count > 0
-        # coll_step events carry the op in b's top byte and the step in a.
-        events = [e for e in observe.timeline(8192) if e.name == "coll_step"]
-        assert events, "no coll_step timeline events recorded"
-        ops = {e.b >> 56 for e in events}
-        assert 1 in ops  # all_gather (TIMELINE_COLL_OPS)
-        assert observe.TIMELINE_COLL_OPS[1] == "all_gather"
     finally:
-        observe.enable_timeline(False)
         fleet.close()
 
 
@@ -426,12 +427,7 @@ def test_error_mapping_and_mismatch():
         with pytest.raises(collective.CollMismatchError):
             fleet.groups[0].all_gather(small, small,
                                        shard_bytes=1 << 16, run_seq=1)
-        # sessions_live is process-global: give a previous test's last
-        # completions a moment to drain before asserting quiescence.
-        deadline = time.time() + 5
-        while collective.sessions_live() != 0 and time.time() < deadline:
-            time.sleep(0.05)
-        assert collective.sessions_live() == 0
+        _assert_sessions_drained()
     finally:
         fleet.close()
 
@@ -514,7 +510,7 @@ def test_overlap_off_ready_attached_is_invisible_and_exact():
             assert np.array_equal(got, want), f"rank {r} reduction wrong"
         for m in maps:
             m.close()
-        assert collective.sessions_live() == 0
+        _assert_sessions_drained()
     finally:
         fleet.close()
 

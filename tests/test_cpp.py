@@ -38,7 +38,7 @@ _NO_CMAKE = shutil.which("cmake") is None or shutil.which("ctest") is None
 # cpp/tests/ so a new suite gates automatically.
 TSAN_SUITES = [
     "fiber", "rpc", "stream", "shm", "ici", "chaos", "stat", "qos",
-    "stripe", "analysis", "timeline", "rma", "kvstore", "naming",
+    "stripe", "analysis", "rma", "kvstore", "naming",
     "collective", "tuner", "deadline", "capture", "slo", "infer",
 ]
 ALL_SUITES = sorted(
@@ -149,16 +149,6 @@ def test_analysis_cpp_suite_native():
                       "analysis suite")
 
 
-def test_timeline_cpp_suite_native():
-    """ISSUE 9: the flight recorder gates tier-1 — flag-off
-    invisibility (vars frozen at 0, zero rings), ring wrap keeping the
-    newest gap-free window, per-thread event ordering under live load,
-    stripe/QoS lifecycle events present under the matching workloads,
-    and reset() hiding history."""
-    _run_native_suite("test_timeline.cc", "test_timeline_native",
-                      "timeline suite")
-
-
 def test_rma_cpp_suite_native():
     """ISSUE 10: the one-sided RMA plane gates tier-1 — registration
     lifecycle, use-after-unregister rejection, shm multi-rail 64MB and
@@ -199,7 +189,7 @@ def test_tuner_cpp_suite_native():
     from a deliberately-wrong knob on a synthetic metric, the
     revert-on-regression guard + freeze/backoff, bounds clamping
     through the declared-bounds path (tuner_set_rejected provably 0),
-    journal/timeline agreement, and the background control loop's
+    journal/var agreement, and the background control loop's
     tick/stop behavior."""
     _run_native_suite("test_tuner.cc", "test_tuner_native",
                       "tuner suite")
@@ -239,7 +229,7 @@ def test_slo_cpp_suite_native():
     oracle property (fleet percentiles from octave-wise sample pooling
     within the recorder's one-octave bound of a single recorder that
     saw all the traffic, across seeds), spec parse/reject, compressed-
-    window burn-rate breach fire + clear with timeline event 28 edges
+    window burn-rate breach fire + clear with slo_breach_total edges
     only on transitions, fleet blob roundtrip, and in-process Announcer
     publication + merged /fleet dump over a live naming registry."""
     _run_native_suite("test_slo.cc", "test_slo_native", "slo suite")
@@ -273,8 +263,7 @@ def test_infer_cpp_suite_native():
     recompute, deadline expiry and client close cancelling mid-stream,
     the chaos disconnect-under-svr_delay case (prefix fetches abort
     whole-or-nothing, deadline_cancel_saved_bytes credited, nothing
-    wedged), per-tenant typed shedding, flag bounds, and token_step
-    timeline events."""
+    wedged), per-tenant typed shedding, and flag bounds."""
     _run_native_suite("test_infer.cc", "test_infer_native",
                       "infer suite")
 

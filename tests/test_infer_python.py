@@ -18,7 +18,8 @@ the Python-visible contract:
   (deadline_cancel_saved_bytes grows, nothing wedges, slot reused);
 - per-tenant admission: an over-share tenant sheds TYPED
   (OverloadedError) while an in-share tenant still admits;
-- flag validation + the token_step timeline surface.
+- flag validation, and the always-on counters of each way a request
+  ends (EOS, cancel, shed).
 """
 
 import time
@@ -352,29 +353,59 @@ def test_infer_flag_validation():
     set_flag("trpc_infer_batch_max", "256")
 
 
-def test_infer_timeline_token_step_events():
+_OUTCOME_VARS = ("infer_admitted_total", "infer_done_total",
+                 "infer_cancelled_total", "infer_shed_total",
+                 "infer_tokens_total")
+
+
+def _outcome_vars() -> dict:
+    v = observe.Vars.dump()
+    return {k: int(v.get(k, 0)) for k in _OUTCOME_VARS}
+
+
+@pytest.mark.parametrize("outcome", ["eos", "cancel", "shed"])
+def test_infer_outcome_moves_its_counters(outcome):
+    """Each way a request ends is counted by its own always-on var: a
+    request decoded to EOS is admitted and done, one the client walks
+    away from is admitted and cancelled, and one past batch+queue is
+    shed without being admitted."""
+    set_flag("trpc_infer_step_us", "5000")
+    if outcome == "shed":
+        set_flag("trpc_infer_batch_max", "1")
+        set_flag("trpc_infer_queue_max", "0")  # cap 1: the holder fills it
     srv = Server()
     srv.enable_infer(prefix_cache=False)
     port = srv.start()
     ch = Channel(f"127.0.0.1:{port}", timeout_ms=5000)
-    observe.enable_timeline(True)
-    observe.reset_timeline()
     try:
-        comp = InferClient(ch).submit(_prompt(30, 4), max_new_tokens=4,
-                                      timeout_ms=30000)
-        toks = list(comp)
-        assert len(toks) == 4
-        dump = observe.timeline_dump(1 << 16)
-        steps = [e for t in dump["threads"] for e in t["events"]
-                 if e["name"] == "token_step"]
-        # admit + prefill_done + 4 tokens + eos = 7 events minimum.
-        assert len(steps) >= 7
-        ops = {int(e["b"], 16) >> 56 for e in steps}
-        assert {1, 2, 3, 4} <= ops  # admit, prefill_done, token, eos
-        assert all(
-            (int(e["b"], 16) >> 56) in observe.TIMELINE_TOKEN_OPS
-            for e in steps)
+        client = InferClient(ch)
+        v0 = _outcome_vars()
+        if outcome == "eos":
+            toks = list(client.submit(_prompt(30, 4), max_new_tokens=4,
+                                      timeout_ms=30000))
+            assert len(toks) == 4
+        else:
+            held = client.submit(_prompt(31, 4), max_new_tokens=200,
+                                 timeout_ms=30000)
+            next(iter(held))  # admitted and decoding
+            if outcome == "shed":
+                with pytest.raises(OverloadedError):
+                    client.submit(_prompt(32, 4), max_new_tokens=4,
+                                  timeout_ms=30000)
+            held.close()
+        assert _wait(lambda: srv.infer_streams_live() == 0, 10.0)
+        d = {k: n - v0[k] for k, n in _outcome_vars().items()}
+        assert d["infer_admitted_total"] == 1, d
+        if outcome == "eos":
+            assert d["infer_done_total"] == 1, d
+            assert d["infer_cancelled_total"] == 0, d
+            assert d["infer_shed_total"] == 0, d
+            assert d["infer_tokens_total"] == 4, d
+        else:
+            assert d["infer_done_total"] == 0, d
+            assert d["infer_cancelled_total"] == 1, d
+            assert d["infer_shed_total"] == (1 if outcome == "shed" else 0), d
+            assert 1 <= d["infer_tokens_total"] < 200, d
     finally:
-        observe.enable_timeline(False)
         ch.close()
         srv.close()

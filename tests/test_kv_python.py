@@ -18,7 +18,7 @@ contract:
 - chaos composition: chunk drops in the prefill process fail block
   pulls whole-or-nothing while the decode node's token stream stays
   clean, and registry svr_delay slows lookups without touching it;
-- flag validators + the kv_block timeline event surface.
+- flag validators + the serving node's fetch counters.
 """
 
 import os
@@ -473,27 +473,17 @@ def test_kv_flag_validators():
         set_flag("trpc_kv_store_bytes", old_bytes)
 
 
-def test_kv_block_timeline_events(node):
-    """The kv_block flight-recorder event (timeline-event 22) fires on
-    serve with the block id and op tag, and the decoder table knows it —
-    the stitched Perfetto artifact can render block transfers as their
-    own track."""
-    assert observe.TIMELINE_EVENTS[22] == "kv_block"
-    assert observe.TIMELINE_KV_OPS[2] == "serve"
+def test_kv_served_fetch_moves_fetch_counters(node):
+    """A served fetch of block 1 is counted on the serving node: one
+    more `kv_fetch_total` and the block's bytes more `kv_fetch_bytes`."""
     srv, addr, pages, reg, metas = node
-    old = get_flag("trpc_timeline")
-    observe.enable_timeline(True)
+    keys = ("kv_fetch_total", "kv_fetch_bytes")
+    before = _vars(keys)
+    cli = kv.KvClient(addr, use_shm=True)
     try:
-        cli = kv.KvClient(addr, use_shm=True)
-        try:
-            cli.fetch(1)
-        finally:
-            cli.close()
-        events = [e for e in observe.timeline(limit=4096)
-                  if e.name == "kv_block"]
-        assert events, "no kv_block events recorded"
-        serve = [e for e in events if e.b >> 56 == 2]
-        assert serve and serve[-1].a == 1  # block id
-        assert serve[-1].b & ((1 << 56) - 1) == BB
+        assert cli.fetch(1) == _pattern(BB, 1).tobytes()
     finally:
-        set_flag("trpc_timeline", old)
+        cli.close()
+    after = _vars(keys)
+    assert after["kv_fetch_total"] - before["kv_fetch_total"] == 1
+    assert after["kv_fetch_bytes"] - before["kv_fetch_bytes"] == BB

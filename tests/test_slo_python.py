@@ -7,9 +7,9 @@ Acceptance exercised here:
   registry and the /fleet merged per-tenant p99 matches a pooled
   single-digest oracle within the octave error bound (ratio <= 2);
 - an induced latency regression (svr_delay chaos) flips the tenant's
-  burn-rate alert within ONE fast window, emits timeline event 28
-  (slo_breach, op=breach), and CLEARS after recovery (op=clear) —
-  breach_total counts edges, not evaluations;
+  burn-rate alert within ONE fast window, counts one breach edge in
+  slo_breach_total, and CLEARS after recovery — breach_total counts
+  edges, not evaluations;
 - flag-off (a fresh process, `trpc_slo` at its default false) the whole
   plane is invisible: every slo_* var frozen at 0, dump empty;
 - the /slo and /fleet builtins serve the same JSON the C API dumps, and
@@ -35,17 +35,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
 _SAVED_FLAGS = ("trpc_slo", "trpc_fleet_publish", "trpc_slo_fast_window_ms",
-                "trpc_slo_slow_window_ms", "trpc_naming_lease_ms",
-                "trpc_timeline")
-
-
-def _fnv1a64(data: bytes) -> int:
-    """Mirror of slo::tenant_hash (timeline event 28's `a` field) — the
-    same basis as tuner::knob_hash, NOT the textbook FNV-1a offset."""
-    h = 1469598103934665603
-    for b in data:
-        h = ((h ^ b) * 1099511628211) & 0xFFFFFFFFFFFFFFFF
-    return h
+                "trpc_slo_slow_window_ms", "trpc_naming_lease_ms")
 
 
 def _http(port: int, path: str) -> str:
@@ -376,16 +366,14 @@ def test_three_process_fleet_matches_pooled_oracle(slo_flags, tmp_path):
 
 def test_burn_alert_fires_within_fast_window_and_clears(slo_flags):
     """Induced latency regression (svr_delay chaos) must flip tenantA's
-    burn-rate alert within ONE fast window, emit exactly one breach
-    EDGE (timeline event 28 op=breach, slo_breach_total +1), and clear
-    (op=clear) once the fault lifts and healthy traffic dilutes the
-    fast window — with no extra edges from re-evaluation."""
+    burn-rate alert within ONE fast window, count exactly one breach
+    EDGE (slo_breach_total +1), and clear once the fault lifts and
+    healthy traffic dilutes the fast window — with no extra edges from
+    re-evaluation or from the clear."""
     fast_ms = 1500
     set_flag("trpc_slo_fast_window_ms", str(fast_ms))
     set_flag("trpc_slo_slow_window_ms", "6000")
     observe.enable_slo(True)
-    observe.enable_timeline(True)
-    observe.reset_timeline()
     srv = Server()
     srv.register_native_echo("Echo.Echo")
     srv.set_slo("tenantA:p99_us=2000,avail=99.0")
@@ -433,19 +421,7 @@ def test_burn_alert_fires_within_fast_window_and_clears(slo_flags):
             time.sleep(0.05)
         assert cleared, "burn alert never cleared after recovery"
         assert observe.slo_breach_total() == base_edges + 1
-
-        # Timeline event 28 carries both edges, keyed by tenant hash.
-        want_hash = _fnv1a64(b"tenantA")
-        edges = [e for e in observe.timeline()
-                 if e.name == "slo_breach" and e.a == want_hash]
-        ops = [e.b >> 56 for e in edges]
-        assert ops.count(1) == 1, f"breach edges: {ops}"
-        assert ops.count(2) == 1, f"clear edges: {ops}"
-        # breach edge carries the fast burn (milli) that tripped it.
-        trip = [e for e in edges if e.b >> 56 == 1][0]
-        assert (trip.b & ((1 << 56) - 1)) >= 2000
     finally:
         srv.set_faults("")
         srv.stop()
         observe.enable_slo(False)
-        observe.enable_timeline(False)

@@ -49,10 +49,9 @@ def test_tuner_defaults_off_and_flags_validate():
         set_flag("trpc_tuner_hysteresis_pct", "95")
 
 
-@pytest.mark.parametrize("flag", ["rpcz_enabled", "trpc_timeline",
-                                  "trpc_tuner"])
+@pytest.mark.parametrize("flag", ["rpcz_enabled", "trpc_tuner"])
 def test_opt_in_planes_default_off(flag):
-    """Spans, timeline events and the tuner's sampling cost the hot path
+    """Spans and the tuner's sampling cost the hot path
     one relaxed load unless switched on: the compiled-in default, whatever
     an earlier test of this process left the value at."""
     get_flag(flag)                       # rpcz registers its flags lazily
@@ -147,9 +146,3 @@ def test_tuner_status_counters_and_decisions_bindings(parked_tuner):
     for d in tuner.decisions():
         assert d.action in ("apply", "revert", "freeze")
         assert d.knob.startswith("trpc_")
-
-
-def test_tuner_decision_timeline_event_table():
-    """The tuner_decision event id is decodable on the Python side (the
-    lint rule pins both tables; this asserts the decoder half)."""
-    assert observe.TIMELINE_EVENTS[24] == "tuner_decision"

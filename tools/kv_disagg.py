@@ -16,15 +16,14 @@ from the repo's own planes:
   (cached lookups, generation-checked) and pulls them continuously over
   an shm connection with a D-deep pipeline, each block landing
   ONE-SIDED in a registered RmaBuffer (the PR 10 direct path).  Runs its
-  own Server purely to export /rpcz + /timeline for stitching.
+  own Server purely to export /rpcz for stitching.
 
   DRIVER (this process) — orchestrates both, samples the token-RPC p99
   against the prefill server UNLOADED and then LOADED (while the decode
   process saturates the same server with block pulls — the load
   generator and the latency sampler are separate processes, per the
   qos_mixed bench discipline), stitches a cross-node Perfetto trace
-  (spans + flight-recorder timelines from BOTH roles, kv_block events on
-  their own track), and prints one JSON row:
+  (rpcz spans from BOTH roles), and prints one JSON row:
 
     kv_goodput_gbps AND token p99 ratio, held simultaneously.
 
@@ -192,8 +191,6 @@ def run_prefill(args) -> None:
     from brpc_tpu.rpc import (Channel, RmaBuffer, Server, kv, observe,
                               set_flag)
 
-    if args.timeline:
-        set_flag("trpc_timeline", "true")
     observe.enable_rpcz()
     set_flag("trpc_qos_lanes", str(args.qos_lanes))
     set_flag("trpc_qos_lane_weights", args.lane_weights)
@@ -243,12 +240,10 @@ def run_prefill(args) -> None:
 def run_decode(args) -> None:
     import numpy as np
 
-    from brpc_tpu.rpc import RmaBuffer, Server, kv, observe, set_flag
+    from brpc_tpu.rpc import RmaBuffer, Server, kv, observe
 
-    if args.timeline:
-        set_flag("trpc_timeline", "true")
     observe.enable_rpcz()
-    # Observability-only server: /rpcz + /timeline for the stitcher.
+    # Observability-only server: /rpcz for the stitcher.
     srv = Server()
     srv.start(args.port)
     print(f"PORT {srv.port}", flush=True)
@@ -417,8 +412,6 @@ def run_driver(args) -> dict:
         "--lane-weights", args.lane_weights,
         "--qos-spec", args.qos_spec, "--lease-ms", str(args.lease_ms),
     ]
-    if args.timeline:
-        base_flags.append("--timeline")
     pre_extra = list(base_flags)
     if args.chaos:
         pre_extra += ["--chaos", args.chaos]
@@ -533,10 +526,7 @@ def run_driver(args) -> dict:
             eps = [f"127.0.0.1:{pre_port}", f"127.0.0.1:{dec_port}"]
             dumps = {ep: trace_stitch.fetch_rpcz(ep) for ep in eps}
             dumps["driver"] = trace_stitch.local_rpcz()
-            tl = None
-            if args.timeline:
-                tl = {ep: trace_stitch.fetch_timeline(ep) for ep in eps}
-            trace = trace_stitch.stitch(dumps, timeline_dumps=tl)
+            trace = trace_stitch.stitch(dumps)
             trace_summary = trace["stitch"]
             trace_summary["path"] = args.out
             # Per-node span presence: the artifact must carry BOTH roles.
@@ -568,7 +558,6 @@ def run_driver(args) -> dict:
             "lane_weights": args.lane_weights,
             "qos_spec": args.qos_spec,
             "rma_rails_shm": get_flag("trpc_shm_rails"),
-            "timeline": bool(args.timeline),
             "chaos": args.chaos or None,
             "shape": args.shape or None,
             **(prefix_row or {}),
@@ -616,8 +605,6 @@ def main(argv=None) -> int:
                     default=PREFIX_DEFAULTS["samples"])
     ap.add_argument("--prefix-seed", type=int,
                     default=PREFIX_DEFAULTS["seed"])
-    ap.add_argument("--timeline", action="store_true",
-                    help="record + stitch flight-recorder timelines")
     ap.add_argument("--out", default="",
                     help="driver: write the stitched Perfetto trace here")
     ap.add_argument("--json", action="store_true",

@@ -34,18 +34,6 @@ Rules (each names the incident class it prevents):
                      between encode_meta and decode_meta — adding a
                      sixth group to one side only is a wire break.
 
-  timeline-event     The flight recorder's event-type table is binary on
-                     the wire (/timeline?format=binary, the C API dump):
-                     the `timeline-event N (name)` markers in
-                     cpp/stat/timeline.h (encoder) and
-                     brpc_tpu/rpc/observe.py (decoder — trace_stitch
-                     resolves names through the same JSON/observe
-                     surface) must be unique, consecutive from 1, and
-                     identical on both sides.  Ids are append-only by
-                     convention (old dumps must stay decodable); this
-                     rule catches renames/renumbers/one-sided additions,
-                     the same incident class as tail-group.
-
   digest-wire        The mergeable latency digest and the fleet
                      publication blob are binary on the wire (naming://
                      payloads, /fleet, fleet_top.py): the
@@ -319,41 +307,6 @@ def check_tail_groups() -> None:
              "a one-sided group is a wire break")
 
 
-# ---- timeline-event ------------------------------------------------------
-
-def check_timeline_events() -> None:
-    cpp_path = CPP / "stat" / "timeline.h"
-    py_path = REPO / "brpc_tpu" / "rpc" / "observe.py"
-    marker = r"timeline-event\s+(\d+)\s*\(([a-z0-9_]+)\)"
-
-    def table(path: pathlib.Path, comment: str) -> list:
-        out = []
-        for m in re.finditer(comment + r"\s*" + marker, path.read_text()):
-            out.append((int(m.group(1)), m.group(2)))
-        return out
-
-    enc = table(cpp_path, r"//")
-    dec = table(py_path, r"#")
-    for path, side, seq in ((cpp_path, "encoder", enc),
-                            (py_path, "decoder", dec)):
-        if not seq:
-            flag(path, 1, "timeline-event",
-                 f"no timeline-event markers found on the {side} side")
-            continue
-        ids = [n for n, _ in seq]
-        if len(ids) != len(set(ids)):
-            flag(path, 1, "timeline-event",
-                 f"{side} has duplicate timeline-event ids: {ids}")
-        if ids != list(range(1, len(ids) + 1)):
-            flag(path, 1, "timeline-event",
-                 f"{side} timeline-event ids not consecutive from 1 "
-                 f"(append-only table): {ids}")
-    if enc and dec and enc != dec:
-        flag(cpp_path, 1, "timeline-event",
-             f"encoder/decoder timeline tables diverge: {enc} vs {dec} "
-             "— a one-sided event type breaks every recorded binary dump")
-
-
 # ---- digest-wire ---------------------------------------------------------
 
 def check_digest_wire() -> None:
@@ -595,7 +548,6 @@ def main() -> int:
     check_var_help()
     check_capi_bindings()
     check_tail_groups()
-    check_timeline_events()
     check_digest_wire()
     check_flag_references()
     check_tuner_rules()

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
-"""Cross-node trace stitcher: rpcz span sets (+ flight-recorder
-timelines) → ONE Chrome trace-event JSON.
+"""Cross-node trace stitcher: rpcz span sets → ONE Chrome trace-event
+JSON.
 
 Given N node endpoints and a trace_id, pulls every node's spans from
 `/rpcz?format=json&trace_id=...`, joins parent/child links across hops,
@@ -8,17 +8,6 @@ and emits Chrome trace-event JSON loadable in Perfetto (ui.perfetto.dev)
 or chrome://tracing: one process track per node, spans as complete
 events (ph "X", server vs client on separate thread tracks), span
 annotations as instant events (ph "i").
-
-With `--timeline` (ISSUE 9) each node's `/timeline` flight-recorder dump
-merges into the SAME file: a thread track per worker pthread carrying
-fiber run→park slices (named by the rpcz method whose span they execute
-when the span_id stamped into the run event resolves), messenger sweep
-and inline-response slices nested under them, scheduler instants
-(create/ready/wake/steal/migrate), and synthetic per-node async tracks
-for stripe rails (one per rail, chunk sends + lifecycle) and QoS lanes
-(DRR drain rounds).  Spans and timeline events join exactly: every event
-carries the emitting fiber's ambient trace/span ids, and every span
-carries the fid it ran on.
 
 Clock model: span times are each node's CLOCK_MONOTONIC, mutually
 meaningless across processes.  Every rpcz dump carries a
@@ -36,13 +25,9 @@ Usage:
         --out trace.json host1:port1 host2:port2
     # merge spans of THIS process (e.g. the client side of the trace):
     python tools/trace_stitch.py --trace-id 1f00d... --local client ...
-    # one file with spans AND the flight-recorder timeline of every node:
-    python tools/trace_stitch.py --trace-id 1f00d... --timeline \\
-        --local client --out trace.json host1:port1 host2:port2
 
-Importable pieces (used by tests/test_observe.py and
-tests/test_timeline_python.py): `fetch_rpcz`, `local_rpcz`, `stitch`,
-`fetch_timeline`, `local_timeline`.
+Importable pieces (used by tests/test_observe.py): `fetch_rpcz`,
+`local_rpcz`, `stitch`.
 """
 
 from __future__ import annotations
@@ -71,22 +56,6 @@ def local_rpcz(trace_id: str | None = None, limit: int = 4096) -> dict:
     from brpc_tpu.rpc import observe
 
     return observe.rpcz_dump(limit=limit, trace_id=trace_id)
-
-
-def fetch_timeline(endpoint: str, limit: int = 4096,
-                   timeout: float = 5.0) -> dict:
-    """One node's flight-recorder dump ({"pid","now_mono_us",
-    "now_wall_us","threads":[...]}) via its builtin HTTP service."""
-    url = f"http://{endpoint}/timeline?limit={limit}"
-    with urllib.request.urlopen(url, timeout=timeout) as r:
-        return json.loads(r.read().decode())
-
-
-def local_timeline(limit: int = 4096) -> dict:
-    """THIS process's flight-recorder dump (no server needed)."""
-    from brpc_tpu.rpc import observe
-
-    return observe.timeline_dump(limit=limit)
 
 
 def _mid(s: dict) -> float:
@@ -140,269 +109,13 @@ def _node_offsets(dumps: dict[str, dict]) -> dict[str, float]:
     return offsets
 
 
-# Synthetic per-node track ids for the flight-recorder's async lanes.
-# Real worker tids are kernel tids (well below these); span tracks use
-# tid 0/1 — no collisions.
-_TL_STRIPE_TID = 900000       # stripe lifecycle (cut / land / done)
-_TL_STRIPE_RAIL_TID = 900001  # + rail index: one track per stripe rail
-_TL_QOS_TID = 950000          # + lane index: one track per QoS lane
-# kStripeSend rail index meaning "the call's primary socket" (head
-# frame / dead-rail fallback) — cpp/stat/timeline.h kStripePrimaryRail.
-_TL_PRIMARY_RAIL = 0xFFFF
-# Rail values with this bit set are one-sided RMA rails (net/rma.h): the
-# chunk was written straight into the peer's registered region — no
-# ring/socket copy.  Own track family so Perfetto shows the elided
-# memcpys next to the copy-path rails.  cpp/stat/timeline.h
-# kStripeRmaRailBit.
-_TL_RMA_RAIL_BIT = 0x8000
-_TL_RMA_RAIL_TID = 900800  # + rma rail index
-_TL_PRIMARY_RAIL_TID = 900900  # its own track, distinct from real rails
-# kv_block events (net/kvstore.h): block publishes / zero-copy serves /
-# evictions / stale-generation rejects on their own per-node track, so a
-# disaggregation trace shows block transfers next to the rails that
-# carried them.  b = op << 56 | payload len (TIMELINE_KV_OPS mirror).
-_TL_KV_TID = 970000
-_TL_KV_OPS = {1: "publish", 2: "serve", 3: "evict", 4: "stale",
-              5: "promote", 6: "demote"}
-# coll_step events (net/collective.h): one instant per completed
-# collective schedule step on its own per-node "collective" track —
-# a = step index, b = op << 56 | step bytes (TIMELINE_COLL_OPS mirror),
-# so a group-transfer trace shows schedule progress next to the rma
-# rails that moved the shards.
-_TL_COLL_TID = 980000
-_TL_COLL_OPS = {1: "all_gather", 2: "reduce_scatter", 3: "all_to_all",
-                4: "reshard"}
-# coll_ready events (net/collective.h): one instant per transfer fired
-# by a producer readiness stamp before the whole-buffer barrier would
-# have released it — a = step index, b = chunk << 32 | bytes (chunk =
-# dep offset / trpc_coll_ready_granularity_bytes) — its own per-node
-# "coll ready" track NEXT to "collective", so compute/comm overlap is
-# visible as ready instants interleaving step completions.
-_TL_COLL_READY_TID = 981000
-# tuner_decision events (stat/tuner.h): one instant per knob actuation
-# by the self-tuning controller on its own per-node "tuner" track —
-# a = knob hash (tuner::knob_hash of the flag name), b = old << 32 |
-# new (32-bit-truncated; the /tuner journal keeps exact values) — so a
-# tuning run reads as a Perfetto artifact: decisions next to the rails/
-# lanes they retuned.
-_TL_TUNER_TID = 990000
-# slo_breach events (stat/slo.h): one instant per breach-state EDGE on
-# its own per-node "slo" track — a = FNV-1a hash of the tenant name,
-# b = op << 56 | fast-window burn rate in milli-units
-# (TIMELINE_SLO_OPS mirror: 1 = breach, 2 = clear) — so an incident
-# trace shows exactly when a tenant's error budget started and stopped
-# burning, next to the fibers and rails that caused it.
-_TL_SLO_TID = 991000
-_TL_SLO_OPS = {1: "breach", 2: "clear"}
-# token_step events (net/infer.h): one instant per continuous-batching
-# scheduler transition on its own per-node "inference" track — a =
-# request id, b = op << 56 | low bits (TIMELINE_TOKEN_OPS mirror:
-# admit carries prefix-cache-matched tokens, token carries the token
-# index, eos/cancel carry tokens emitted, shed carries the error
-# code) — so a serving trace shows requests joining/leaving the batch
-# and every decode step next to the streams that carried the tokens.
-_TL_TOKEN_TID = 992000
-_TL_TOKEN_OPS = {1: "admit", 2: "prefill_done", 3: "token", 4: "eos",
-                 5: "cancel", 6: "shed"}
-
-
-def _timeline_chrome_events(pid: int, dump: dict, base: float,
-                            span_by_id: dict, span_by_fid: dict) -> list:
-    """One node's flight-recorder dump → Chrome events: per-worker
-    thread tracks with fiber run→park slices (named by the rpcz span
-    they execute when the join resolves) and messenger sweep /
-    inline-response slices nested under them, scheduler/write-path
-    instants, plus synthetic stripe-rail and QoS-lane tracks."""
-    events = []
-    named_tids = set()
-
-    def track(tid: int, name: str) -> int:
-        if tid not in named_tids:
-            named_tids.add(tid)
-            events.append({"ph": "M", "name": "thread_name", "pid": pid,
-                           "tid": tid, "args": {"name": name}})
-        return tid
-
-    for thr in dump.get("threads", []):
-        tid = int(thr["tid"])
-        track(tid, f"{thr.get('name', 'thread')} (tid {tid})")
-        open_fiber: dict = {}  # fid(hex of event `a`... keyed by a) -> (ts, ev)
-        open_span: dict = {}   # (event name, a) -> (ts, ev)
-        for e in thr.get("events", []):
-            name = e.get("name", "?")
-            ts = float(e["ts_us"]) + base
-            if name == "fiber_run":
-                open_fiber[e["a"]] = (ts, e)
-                continue
-            if name in ("fiber_park", "fiber_done") and e["a"] in open_fiber:
-                t0, run = open_fiber.pop(e["a"])
-                # Exact span join: the run/park events carry the fiber's
-                # own id in `fid` and its ambient span in `span_id`;
-                # spans carry the fid they started on.
-                method = (span_by_id.get(run["span_id"])
-                          or span_by_fid.get(run["fid"]))
-                label = (f"fiber:{method}" if method
-                         else f"fiber {run['fid'][-8:]}")
-                events.append({
-                    "ph": "X", "name": label, "cat": "fiber",
-                    "pid": pid, "tid": tid, "ts": t0,
-                    "dur": max(ts - t0, 1.0),
-                    "args": {"fid": run["fid"],
-                             "trace_id": run["trace_id"],
-                             "span_id": run["span_id"],
-                             "worker": int(run["b"], 16),
-                             "end": name},
-                })
-                continue
-            if name in ("sweep_start", "inline_begin"):
-                open_span[(name, e["a"])] = (ts, e)
-                continue
-            if name == "sweep_end" and ("sweep_start", e["a"]) in open_span:
-                t0, _ = open_span.pop(("sweep_start", e["a"]))
-                events.append({
-                    "ph": "X", "name": "sweep", "cat": "messenger",
-                    "pid": pid, "tid": tid, "ts": t0,
-                    "dur": max(ts - t0, 1.0),
-                    "args": {"socket": e["a"], "cuts": int(e["b"], 16),
-                             "trace_id": e["trace_id"]},
-                })
-                continue
-            if name == "inline_end" and \
-                    ("inline_begin", e["a"]) in open_span:
-                t0, _ = open_span.pop(("inline_begin", e["a"]))
-                events.append({
-                    "ph": "X", "name": "inline-response",
-                    "cat": "messenger", "pid": pid, "tid": tid, "ts": t0,
-                    "dur": max(ts - t0, 1.0),
-                    "args": {"socket": e["a"], "trace_id": e["trace_id"]},
-                })
-                continue
-            # Everything else renders as an instant; stripe/QoS/kv events
-            # additionally land on their synthetic async tracks.
-            out_tid = tid
-            if name == "kv_block":
-                b = int(e["b"], 16)
-                op = b >> 56
-                out_tid = track(_TL_KV_TID, "kv blocks")
-                events.append({
-                    "ph": "i", "s": "t", "cat": "timeline",
-                    "name": f"kv_{_TL_KV_OPS.get(op, op)}",
-                    "pid": pid, "tid": out_tid, "ts": ts,
-                    "args": {"block_id": e["a"],
-                             "len": b & ((1 << 56) - 1),
-                             "trace_id": e["trace_id"],
-                             "span_id": e["span_id"], "fid": e["fid"]},
-                })
-                continue
-            if name == "coll_step":
-                b = int(e["b"], 16)
-                op = b >> 56
-                out_tid = track(_TL_COLL_TID, "collective")
-                events.append({
-                    "ph": "i", "s": "t", "cat": "timeline",
-                    "name": f"coll_{_TL_COLL_OPS.get(op, op)}",
-                    "pid": pid, "tid": out_tid, "ts": ts,
-                    "args": {"step": int(e["a"], 16),
-                             "bytes": b & ((1 << 56) - 1),
-                             "trace_id": e["trace_id"],
-                             "span_id": e["span_id"], "fid": e["fid"]},
-                })
-                continue
-            if name == "coll_ready":
-                b = int(e["b"], 16)
-                out_tid = track(_TL_COLL_READY_TID, "coll ready")
-                events.append({
-                    "ph": "i", "s": "t", "cat": "timeline",
-                    "name": "coll_ready",
-                    "pid": pid, "tid": out_tid, "ts": ts,
-                    "args": {"step": int(e["a"], 16),
-                             "chunk": b >> 32,
-                             "bytes": b & 0xFFFFFFFF,
-                             "trace_id": e["trace_id"],
-                             "span_id": e["span_id"], "fid": e["fid"]},
-                })
-                continue
-            if name == "slo_breach":
-                b = int(e["b"], 16)
-                op = b >> 56
-                out_tid = track(_TL_SLO_TID, "slo")
-                events.append({
-                    "ph": "i", "s": "t", "cat": "timeline",
-                    "name": f"slo_{_TL_SLO_OPS.get(op, op)}",
-                    "pid": pid, "tid": out_tid, "ts": ts,
-                    "args": {"tenant_hash": e["a"],
-                             "burn_fast_milli": b & ((1 << 56) - 1),
-                             "trace_id": e["trace_id"],
-                             "span_id": e["span_id"], "fid": e["fid"]},
-                })
-                continue
-            if name == "token_step":
-                b = int(e["b"], 16)
-                op = b >> 56
-                out_tid = track(_TL_TOKEN_TID, "inference")
-                events.append({
-                    "ph": "i", "s": "t", "cat": "timeline",
-                    "name": f"infer_{_TL_TOKEN_OPS.get(op, op)}",
-                    "pid": pid, "tid": out_tid, "ts": ts,
-                    "args": {"request_id": e["a"],
-                             "value": b & ((1 << 56) - 1),
-                             "trace_id": e["trace_id"],
-                             "span_id": e["span_id"], "fid": e["fid"]},
-                })
-                continue
-            if name == "tuner_decision":
-                b = int(e["b"], 16)
-                out_tid = track(_TL_TUNER_TID, "tuner")
-                events.append({
-                    "ph": "i", "s": "t", "cat": "timeline",
-                    "name": "tuner_decision",
-                    "pid": pid, "tid": out_tid, "ts": ts,
-                    "args": {"knob_hash": e["a"],
-                             "old": b >> 32,
-                             "new": b & 0xFFFFFFFF,
-                             "trace_id": e["trace_id"],
-                             "span_id": e["span_id"], "fid": e["fid"]},
-                })
-                continue
-            if name == "stripe_send":
-                rail = int(e["b"], 16) >> 48
-                if rail == _TL_PRIMARY_RAIL:
-                    out_tid = track(_TL_PRIMARY_RAIL_TID,
-                                    "stripe primary (head/fallback)")
-                elif rail & _TL_RMA_RAIL_BIT:
-                    rma_rail = rail & 0x7FFF
-                    out_tid = track(_TL_RMA_RAIL_TID + rma_rail,
-                                    f"rma rail {rma_rail}")
-                else:
-                    out_tid = track(_TL_STRIPE_RAIL_TID + rail,
-                                    f"stripe rail {rail}")
-            elif name in ("stripe_cut", "stripe_land", "stripe_done"):
-                out_tid = track(_TL_STRIPE_TID, "stripe lifecycle")
-            elif name == "qos_drain":
-                lane = int(e["a"], 16) & 0xff
-                out_tid = track(_TL_QOS_TID + lane, f"qos lane {lane}")
-            events.append({
-                "ph": "i", "name": name, "s": "t", "cat": "timeline",
-                "pid": pid, "tid": out_tid, "ts": ts,
-                "args": {"a": e["a"], "b": e["b"],
-                         "trace_id": e["trace_id"],
-                         "span_id": e["span_id"], "fid": e["fid"]},
-            })
-    return events
-
-
-def stitch(dumps: dict[str, dict], trace_id: str | None = None,
-           timeline_dumps: dict[str, dict] | None = None) -> dict:
+def stitch(dumps: dict[str, dict], trace_id: str | None = None) -> dict:
     """Joins {node_name: rpcz_dump} into one Chrome trace-event object.
 
     Returns {"traceEvents": [...], "displayTimeUnit": "ms", "stitch":
     {summary}} — JSON-dumpable straight into Perfetto.  When `trace_id`
     is given, spans from other traces are dropped (belt + braces for
-    dumps fetched without the server-side filter).  `timeline_dumps`
-    ({node_name: /timeline dump}) merges each node's flight-recorder
-    events into the same file on the same corrected clocks — timeline
-    events are NOT trace-filtered (the scheduling/transport context
-    AROUND a span is exactly what the timeline tier exists to show)."""
+    dumps fetched without the server-side filter)."""
     offsets = _node_offsets(dumps)
     # Global index for parent-link accounting (across ALL nodes).
     all_ids = set()
@@ -442,7 +155,6 @@ def stitch(dumps: dict[str, dict], trace_id: str | None = None,
                     "trace_id": s["trace_id"], "span_id": s["span_id"],
                     "parent_span_id": s["parent_span_id"],
                     "parent_linked": linked,
-                    "fid": s.get("fid", "0" * 16),
                     "error_code": s["error_code"],
                     "request_bytes": s["request_bytes"],
                     "response_bytes": s["response_bytes"],
@@ -454,39 +166,7 @@ def stitch(dumps: dict[str, dict], trace_id: str | None = None,
                     "pid": pid, "tid": tid,
                     "ts": float(a["ts_us"]) + base,
                 })
-    timeline_events = 0
-    if timeline_dumps:
-        pid_of = {node: p for p, node in enumerate(sorted(dumps))}
-        next_pid = len(pid_of)
-        for node in sorted(timeline_dumps):
-            tl = timeline_dumps[node]
-            if node not in pid_of:  # timeline-only node: its own track
-                pid_of[node] = next_pid
-                events.append({
-                    "ph": "M", "name": "process_name",
-                    "pid": next_pid,
-                    "args": {"name": f"{node} (pid {tl.get('pid', '?')})"},
-                })
-                next_pid += 1
-            base = float(tl.get("now_wall_us", 0)) - \
-                float(tl.get("now_mono_us", 0)) + offsets.get(node, 0.0)
-            # Span join tables for fiber-slice naming, restricted to
-            # this node's spans (fibers never execute a remote span).
-            span_by_id: dict = {}
-            span_by_fid: dict = {}
-            for s in dumps.get(node, {}).get("spans", []):
-                if trace_id and s["trace_id"] != trace_id:
-                    continue
-                span_by_id[s["span_id"]] = s["method"]
-                fid = s.get("fid", "0" * 16)
-                if fid != "0" * 16:
-                    span_by_fid.setdefault(fid, s["method"])
-            evs = _timeline_chrome_events(pid_of[node], tl, base,
-                                          span_by_id, span_by_fid)
-            timeline_events += sum(1 for e in evs if e["ph"] != "M")
-            events.extend(evs)
-    # Rebase so the trace starts near 0 (Perfetto-friendly); timeline
-    # events can precede the first span, so take the global minimum.
+    # Rebase so the trace starts near 0 (Perfetto-friendly).
     t0 = min((e["ts"] for e in events if "ts" in e), default=None)
     if t0 is not None:
         for e in events:
@@ -500,8 +180,6 @@ def stitch(dumps: dict[str, dict], trace_id: str | None = None,
             "nodes": sorted(dumps),
             "spans": spans_total,
             "parent_linked": parent_linked,
-            "timeline_events": timeline_events,
-            "timeline_nodes": sorted(timeline_dumps or {}),
             "node_offsets_us": {n: round(v, 1)
                                 for n, v in offsets.items()},
         },
@@ -520,12 +198,6 @@ def main(argv: list[str] | None = None) -> int:
                     help="max spans pulled per node")
     ap.add_argument("--local", metavar="NAME", default=None,
                     help="also merge THIS process's spans as node NAME")
-    ap.add_argument("--timeline", action="store_true",
-                    help="also pull each node's /timeline flight-recorder "
-                         "dump and merge fiber/messenger/stripe/QoS "
-                         "events into the same Perfetto file")
-    ap.add_argument("--timeline-limit", type=int, default=4096,
-                    help="max timeline events pulled per node thread")
     ap.add_argument("--out", default="-",
                     help="output path (default: stdout)")
     args = ap.parse_args(argv)
@@ -536,15 +208,7 @@ def main(argv: list[str] | None = None) -> int:
         dumps[args.local] = local_rpcz(args.trace_id, args.limit)
     if not dumps:
         ap.error("no endpoints given (and --local not set)")
-    timeline_dumps: dict[str, dict] | None = None
-    if args.timeline:
-        timeline_dumps = {}
-        for ep in args.endpoints:
-            timeline_dumps[ep] = fetch_timeline(ep, args.timeline_limit)
-        if args.local:
-            timeline_dumps[args.local] = local_timeline(
-                args.timeline_limit)
-    trace = stitch(dumps, args.trace_id, timeline_dumps)
+    trace = stitch(dumps, args.trace_id)
     text = json.dumps(trace)
     if args.out == "-":
         print(text)
@@ -553,8 +217,7 @@ def main(argv: list[str] | None = None) -> int:
             f.write(text)
         s = trace["stitch"]
         print(f"wrote {args.out}: {s['spans']} spans "
-              f"({s['parent_linked']} parent-linked) + "
-              f"{s['timeline_events']} timeline events from "
+              f"({s['parent_linked']} parent-linked) from "
               f"{len(s['nodes'])} nodes", file=sys.stderr)
     return 0
 
